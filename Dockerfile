@@ -1,16 +1,17 @@
 # gubernator-tpu server image (reference: the Go repo's multi-stage
 # Dockerfile; here the runtime is Python + JAX, so one stage suffices).
 #
-# The base image must provide jax for your accelerator:
-#   CPU:  python:3.12 + pip install jax
-#   TPU:  a jax[tpu] image for your libtpu release
+# The one installation this code is written and tested against: Python 3.12,
+# jax/jaxlib 0.9.0, numpy 2.0.2 (pyproject.toml pins the same jax). On a
+# TPU host add libtpu 0.0.34 (jax[tpu]==0.9.0), the release chip_smoke.py
+# last passed on.
 ARG BASE_IMAGE=python:3.12-slim
 FROM ${BASE_IMAGE}
 
 WORKDIR /opt/gubernator-tpu
 
 RUN pip install --no-cache-dir \
-    "jax>=0.4.30" numpy aiohttp grpcio protobuf prometheus_client xxhash
+    "jax==0.9.0" "numpy==2.0.2" aiohttp grpcio protobuf prometheus_client xxhash
 
 COPY gubernator_tpu/ ./gubernator_tpu/
 COPY example.conf ./

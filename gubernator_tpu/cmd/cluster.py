@@ -1,6 +1,12 @@
 """Local in-process cluster binary for client development (reference
 cmd/gubernator-cluster/main.go:30-56): boots N daemons on consecutive local
 ports, wires them with explicit set_peers, and serves until interrupted.
+
+One process, one device: every daemon is a LocalEngine on JAX's default
+device, so on a four-chip host all N tables land on chip 0. This is a tool
+for developing clients against the peer plane, not the multi-chip path —
+that is ONE daemon with GUBER_ENGINE=sharded, whose mesh spans every local
+device (a chip belongs to one process at a time).
 """
 
 from __future__ import annotations

@@ -316,11 +316,11 @@ class DaemonConfig:
     # config wins; O(1) host planning, kernel2.dedup_packed_cols)
     shard_dedup: str = "auto"
     # ownership-exchange schedule for route="device" dispatches
-    # (parallel/ring.py): "auto" (ring on TPU backends, collective
-    # elsewhere) | "ring" (hand-rolled per-hop remote-DMA/ppermute
-    # schedule, double-buffered hops) | "collective" (one monolithic
-    # lax.all_to_all per direction — the parity oracle). Byte-identical
-    # results either way; GUBER_A2A_IMPL.
+    # (parallel/ring.py): "auto" (= collective) | "collective" (one
+    # lax.all_to_all per direction) | "ring" (hand-rolled per-hop
+    # schedule: ppermute shifts on CPU meshes; its TPU remote-DMA kernel
+    # is refused by the compiler and raises when selected).
+    # Byte-identical results either way; GUBER_A2A_IMPL.
     a2a_impl: str = "auto"
     # fold the mesh's devices into this many (simulated) host rows — the
     # 2-D (host, device) topology used by multi-host tests/CI on one

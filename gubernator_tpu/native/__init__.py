@@ -9,6 +9,11 @@ np.frombuffer zero-copy).
 
 from __future__ import annotations
 
+import contextlib
+import glob
+import hashlib
+import importlib.machinery
+import importlib.util
 import logging
 import os
 import subprocess
@@ -19,31 +24,40 @@ log = logging.getLogger("gubernator_tpu.native")
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "guberhost.cpp")
+_CXXFLAGS = ("-O2", "-std=c++17", "-shared", "-fPIC")
 _mod = None
 _tried = False
+# "built" (this process compiled it), "reused" (a binary of the same
+# source hash was already there) or None (pure-Python door) — surfaced by
+# /v1/debug/pipeline so a run that silently lost the parser can be refused
+state: Optional[str] = None
 
 
 def _so_path() -> str:
+    """The binary's name carries the hash of the source and the flags it
+    was built from, so a stale or foreign `.so` left in the tree (they are
+    git-ignored, and tools copy the tree as it stands) can never be loaded
+    for a different guberhost.cpp."""
+    h = hashlib.sha256(" ".join(_CXXFLAGS).encode())
+    with open(_SRC, "rb") as f:
+        h.update(f.read())
     suffix = sysconfig.get_config_var("EXT_SUFFIX") or ".so"
-    return os.path.join(_DIR, f"_guberhost{suffix}")
+    return os.path.join(_DIR, f"_guberhost_{h.hexdigest()[:16]}{suffix}")
 
 
 def build(force: bool = False) -> Optional[str]:
     """Compile the extension in-place; returns the .so path or None."""
+    global state
     so = _so_path()
-    if (
-        not force
-        and os.path.exists(so)
-        and os.path.getmtime(so) >= os.path.getmtime(_SRC)
-    ):
+    if not force and os.path.exists(so):
+        state = "reused"
         return so
     include = sysconfig.get_paths()["include"]
-    cmd = [
-        "g++", "-O2", "-std=c++17", "-shared", "-fPIC",
-        f"-I{include}", "-o", so, _SRC,
-    ]
+    tmp = f"{so}.{os.getpid()}.tmp"  # concurrent builders never share a file
+    cmd = ["g++", *_CXXFLAGS, f"-I{include}", "-o", tmp, _SRC]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+        os.replace(tmp, so)
     except (OSError, subprocess.SubprocessError) as exc:
         detail = getattr(exc, "stderr", b"") or b""
         log.warning(
@@ -51,24 +65,36 @@ def build(force: bool = False) -> Optional[str]:
             exc, detail.decode(errors="replace")[:500],
         )
         return None
+    for old in glob.glob(os.path.join(_DIR, "_guberhost*.so")):
+        if old != so:  # binaries of earlier sources
+            with contextlib.suppress(OSError):
+                os.remove(old)
+    state = "built"
     return so
 
 
 def load():
     """The extension module, building if needed; None if unavailable."""
-    global _mod, _tried
+    global _mod, _tried, state
     if _mod is not None or _tried:
         return _mod
     _tried = True
     if os.environ.get("GUBER_NATIVE", "").lower() in ("0", "false", "off"):
         return None
-    if build() is None:
+    so = build()
+    if so is None:
         return None
     try:
-        from gubernator_tpu.native import _guberhost  # type: ignore
-
-        _mod = _guberhost
+        # the file name carries a hash, the module (and its PyInit symbol)
+        # does not: load by path under the fixed name
+        name = "gubernator_tpu.native._guberhost"
+        spec = importlib.util.spec_from_loader(
+            name, importlib.machinery.ExtensionFileLoader(name, so)
+        )
+        _mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(_mod)
     except ImportError as exc:  # pragma: no cover - toolchain-specific
         log.warning("native guberhost import failed: %s", exc)
         _mod = None
+        state = None
     return _mod

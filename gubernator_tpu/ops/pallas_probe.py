@@ -17,8 +17,8 @@ streams exactly the touched bucket rows through VMEM:
 * the grid walks the sorted batch in blocks of ``GUBER_PROBE_BLK``
   requests with **double-buffered async row copies**: while block *i* is
   being decided, block *i+1*'s bucket rows are already in flight
-  (`pltpu.make_async_copy` into the alternate VMEM slot — the SNIPPETS
-  [1]–[3] pattern the PR-8 remote-DMA ring uses), and only rows a decision
+  (`pltpu.make_async_copy` into the alternate VMEM slot — the pattern
+  the PR-8 remote-DMA ring uses), and only rows a decision
   actually dirtied are copied back;
 * a bucket whose request run straddles a block boundary is **carried**:
   its lane updates accumulate in VMEM scratch across steps and the row is
@@ -103,10 +103,6 @@ from gubernator_tpu.ops.table2 import (
 
 i64 = jnp.int64
 i32 = jnp.int32
-
-_ANY = getattr(pltpu, "ANY", None)
-if _ANY is None:  # jax 0.4.x spells it TPUMemorySpace.ANY
-    _ANY = pltpu.TPUMemorySpace.ANY
 
 # out_resp columns (sorted-domain, un-sorted by the epilogue)
 _OC_STATUS, _OC_REM, _OC_RESET, _OC_EXISTS = 0, 1, 2, 3
@@ -877,14 +873,14 @@ def _launch_walk(table: Table2, arr_s, meta, sb, bkf, G: int, rblk: int, *,
             jax.ShapeDtypeStruct((G, layout.row), jnp.int32),  # crows
             jax.ShapeDtypeStruct((B, outw), jnp.int64),  # resp
         )
-        out_specs = [pl.BlockSpec(memory_space=_ANY)] * 5
+        out_specs = [pl.BlockSpec(memory_space=pl.ANY)] * 5
         aliases = {}
     else:
         out_shape = (
             jax.ShapeDtypeStruct(table.rows.shape, table.rows.dtype),
             jax.ShapeDtypeStruct((B, outw), jnp.int64),
         )
-        out_specs = [pl.BlockSpec(memory_space=_ANY)] * 2
+        out_specs = [pl.BlockSpec(memory_space=pl.ANY)] * 2
         aliases = {5: 0}
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -893,7 +889,7 @@ def _launch_walk(table: Table2, arr_s, meta, sb, bkf, G: int, rblk: int, *,
             pl.BlockSpec((nl, rblk), lambda g, sb, bkf: (0, g)),
             pl.BlockSpec((3, rblk), lambda g, sb, bkf: (0, g)),
             pl.BlockSpec((1, rblk), lambda g, sb, bkf: (0, g)),
-            pl.BlockSpec(memory_space=_ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=out_specs,
         scratch_shapes=[

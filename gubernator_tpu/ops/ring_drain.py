@@ -202,7 +202,7 @@ def _fence_claim_kernel(seq_in_ref, _seq_out_in, grids_ref, ctl_ref,
     Walks tickets from ctl[0], and for each CONTIGUOUSLY published slot
     (seq_in[t%S] == t+1 — a gap stops the claim, preserving strict ticket
     order) async-copies the slot's wire grid into the claim bank and bumps
-    the egress-side fence, up to ctl[1] claims. This is the SNIPPETS
+    the egress-side fence, up to ctl[1] claims. This is the Pallas
     async-copy/DMA-semaphore recipe applied to slot claiming; the resident
     production loop wraps this step in an outer poll that never exits.
     Fence words are int32 here (tickets wrap at 2^31 — years of uptime at

@@ -1,6 +1,6 @@
 """Packed-row HBM table (v2): one bucket per TPU lane row.
 
-Layout chosen from measured v5e memory-op costs (exp/exp_mem*.py):
+Layout chosen from measured v5e memory-op costs (exp/README.md, exp_mem*):
 
 * XLA scatters serialize (~8 ns/element regardless of layout) — the v1 design's
   15 plane scatters cost ~16 ms per 131K-row dispatch;

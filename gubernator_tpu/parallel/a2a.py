@@ -50,7 +50,7 @@ from gubernator_tpu.ops.kernel2 import (
 )
 from gubernator_tpu.ops.engine import default_write_mode
 from gubernator_tpu.ops.table2 import Table2
-from gubernator_tpu.parallel.mesh import shard_map_compat, shard_of, shard_spec
+from gubernator_tpu.parallel.mesh import shard_of, shard_spec
 from gubernator_tpu.parallel.ring import a2a_impl, exchange
 
 i32 = jnp.int32
@@ -120,7 +120,7 @@ def make_a2a_decide(
     one lax.all_to_all per direction (the seed path — and the parity
     oracle), "ring" = the hand-rolled per-hop schedule with double-buffered
     remote DMA on TPU / ppermute shifts elsewhere; None resolves through
-    GUBER_A2A_IMPL (auto = ring on TPU). The two produce byte-identical
+    GUBER_A2A_IMPL (auto = collective). The two produce byte-identical
     grids — impl is a schedule knob, never a semantics one."""
     write = write or default_write_mode()
     impl = a2a_impl(impl)
@@ -216,7 +216,7 @@ def make_a2a_decide(
         return expand(table), packed_out[None]
 
     spec = shard_spec(mesh)
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         per_device, mesh=mesh, in_specs=(spec, spec, spec),
         # check_vma=False: the Pallas sweep's out_shape carries no vma
         # annotation, which the checker (jax>=0.9) rejects inside shard_map

@@ -34,25 +34,6 @@ HOST_AXIS = "host"  # pod meshes: leading axis, one row per host
 DEVICE_AXIS = "device"  # pod meshes: trailing axis, devices within a host
 
 
-def shard_map_compat(f, *, mesh: Mesh, in_specs, out_specs, check_vma: bool = True):
-    """`jax.shard_map` across the jax versions this repo runs on: new jax
-    exposes it top-level with the `check_vma` flag; 0.4.x has
-    `jax.experimental.shard_map.shard_map` where the same knob is named
-    `check_rep`. Every shard_map call site routes through here so version
-    drift stays in one place."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
-    )
-
-
 def env_mesh_hosts() -> Optional[int]:
     """GUBER_MESH_HOSTS: fold the device pool into this many simulated hosts
     (2-D mesh in ONE process — the CI/test stand-in for a real multi-process

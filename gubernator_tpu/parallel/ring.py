@@ -23,20 +23,26 @@ Two lowerings share that schedule:
 
 * **TPU** — a Pallas kernel (`_ring_pallas`): per-hop
   `pltpu.make_async_remote_copy` with two send/recv DMA-semaphore slots
-  alternating per hop parity (the SNIPPETS [1]-[3] remote-DMA pattern, cf.
-  the jax Pallas TPU distributed-programming recipe). The send buffer
-  stays in HBM (memory_space ANY); the DMA engines move blocks while the
-  core is free — this is what lets hop N+1's DMA ride under hop N's
-  owner-side work.
+  alternating per hop parity (the remote-DMA pattern of the jax Pallas TPU
+  distributed-programming recipe). The send buffer stays in HBM
+  (memory_space ANY); the DMA engines move blocks while the core is free.
+  **Refused by the compiler as written** (jax 0.9.0 / libtpu 0.0.34, TPU
+  v5 lite, PR 21): the kernel is traced under x64 over int64 blocks, and
+  the Pallas TPU lowering recurses without end converting its 64-bit
+  index arithmetic (`RecursionError` in `_convert_element_type_lowering_
+  rule`); XLA's x64 rewriter also has no rule for a pallas_call with s64
+  operands. Selecting it raises that error; ROADMAP S3/D1 decides whether
+  it is repaired (32-bit views of the blocks, i32 scalars) or deleted.
 * **CPU / parity oracle** — per-hop `lax.ppermute` shifts
   (`_ring_shifts`): the same hop decomposition expressed in XLA
   collectives, runnable on the simulated CPU meshes, byte-identical to
   the Pallas schedule AND to the all_to_all oracle. This is the lowering
   the parity suites (tests/test_ring_exchange.py, ci mesh_smoke) pin.
 
-`GUBER_A2A_IMPL=auto` (default) picks ring on TPU backends — per-hop
-overlap where there is real DMA hardware — and collective elsewhere, so
-CPU test meshes keep the seed's exact lowering unless a suite opts in.
+`GUBER_A2A_IMPL=auto` (default) is the collective everywhere: it is the
+one lowering that compiles on the chip. `ring` stays selectable — the
+ppermute schedule on CPU meshes (the parity suites), the refused Pallas
+kernel on TPU.
 """
 
 from __future__ import annotations
@@ -59,17 +65,15 @@ A2A_IMPLS = ("auto", "ring", "collective")
 
 def a2a_impl(override: "str | None" = None) -> str:
     """Resolve the exchange implementation: explicit override, then
-    GUBER_A2A_IMPL, then auto (ring on TPU, collective elsewhere). Read at
-    trace time like the sparse-write knobs — flipping the env re-selects on
-    the next compile, no restart."""
+    GUBER_A2A_IMPL, then auto (= collective; the ring's TPU kernel does not
+    compile — module docstring). Read at trace time like the sparse-write
+    knobs — flipping the env re-selects on the next compile, no restart."""
     impl = override or os.environ.get("GUBER_A2A_IMPL", "auto")
     if impl not in A2A_IMPLS:
         raise ValueError(
             f"GUBER_A2A_IMPL must be one of {A2A_IMPLS}, got {impl!r}"
         )
-    if impl == "auto":
-        return "ring" if jax.default_backend() == "tpu" else "collective"
-    return impl
+    return "collective" if impl == "auto" else impl
 
 
 def exchange(block: jnp.ndarray, mesh: Mesh, impl: str) -> jnp.ndarray:
@@ -187,38 +191,25 @@ def _ring_pallas(block: jnp.ndarray, mesh: Mesh) -> jnp.ndarray:
     from jax.experimental.pallas import tpu as pltpu
 
     D = int(mesh.devices.size)
-    any_space = getattr(pltpu, "ANY", None)
-    if any_space is None:  # jax 0.4.x spells it TPUMemorySpace.ANY
-        any_space = pltpu.TPUMemorySpace.ANY
     kernel = functools.partial(
         _ring_kernel, D=D, axes=shard_axes(mesh), dl=devices_per_host(mesh)
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=0,
-        in_specs=[pl.BlockSpec(memory_space=any_space)],
-        out_specs=pl.BlockSpec(memory_space=any_space),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=(
             [pltpu.SemaphoreType.DMA]  # local-copy completion
             + [pltpu.SemaphoreType.DMA((2,))] * 2  # send/recv, 2 slots each
         ),
     )
-    compiler_params = None
-    if hasattr(pltpu, "CompilerParams"):
-        compiler_params = pltpu.CompilerParams(
-            has_side_effects=True, collective_id=0
-        )
-    elif hasattr(pltpu, "TPUCompilerParams"):
-        compiler_params = pltpu.TPUCompilerParams(
-            has_side_effects=True, collective_id=0
-        )
-    kw = {}
-    if compiler_params is not None:
-        kw["compiler_params"] = compiler_params
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct(block.shape, block.dtype),
         grid_spec=grid_spec,
-        **kw,
+        compiler_params=pltpu.CompilerParams(
+            has_side_effects=True, collective_id=0
+        ),
     )(block)
 
 
@@ -256,10 +247,8 @@ def make_exchange_probe(
             out = _ring_shifts(x, axes, D, hops=hops)
         return out[None]
 
-    from gubernator_tpu.parallel.mesh import shard_map_compat
-
     spec = shard_spec(mesh)
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         per_device, mesh=mesh, in_specs=(spec,), out_specs=spec,
         check_vma=False,
     )

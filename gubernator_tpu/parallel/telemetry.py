@@ -25,7 +25,7 @@ from gubernator_tpu.ops.telemetry import (
     block_width,
 )
 from gubernator_tpu.ops.table2 import K
-from gubernator_tpu.parallel.mesh import shard_map_compat, shard_spec
+from gubernator_tpu.parallel.mesh import shard_spec
 
 
 def make_sharded_scan(mesh: Mesh, n_buckets: int, layout=None):
@@ -39,7 +39,7 @@ def make_sharded_scan(mesh: Mesh, n_buckets: int, layout=None):
         return _scan_body(rows[0], now[0, 0], blk, layout)[None]
 
     spec = shard_spec(mesh)
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         per_device, mesh=mesh, in_specs=(spec, spec), out_specs=spec,
         check_vma=False,
     )

@@ -57,7 +57,7 @@ from gubernator_tpu.service.wire import (
     subset_columns,
 )
 from gubernator_tpu.types import Behavior, HitEvent, PeerInfo, has_behavior
-from gubernator_tpu import tracing
+from gubernator_tpu import native, tracing
 
 import logging
 
@@ -133,7 +133,7 @@ class Daemon:
                 route=None if conf.shard_route == "auto" else conf.shard_route,
                 dedup=None if conf.shard_dedup == "auto" else conf.shard_dedup,
                 # exchange schedule for device-routed dispatches
-                # (parallel/ring.py; "auto" = ring on TPU backends)
+                # (parallel/ring.py; "auto" = collective)
                 a2a=None if conf.a2a_impl == "auto" else conf.a2a_impl,
                 # table-walk kernel (ops/pallas_probe.py; "auto" = xla
                 # until the device bench record flips the default)
@@ -304,6 +304,19 @@ class Daemon:
                 tracing.set_exporter(exp)
                 log.info("OTLP trace export enabled → %s", exp.endpoint)
         d.maybe_restore()
+        native.load()  # build/load the door's parser before the line below
+        eng_dbg = d.debug_pipeline()
+        log.info(
+            "engine: %s native_parser=%s",
+            " ".join(
+                f"{k}={eng_dbg['engine'][k]}" for k in (
+                    "kind", "platform", "device_kind", "device_count",
+                    "table_bytes", "n_shards", "write_mode", "wire",
+                    "probe_kernel", "route", "dedup", "a2a_impl",
+                )
+            ),
+            eng_dbg["native_parser"],
+        )
         await d.warm_up()
         if d.checkpointer.enabled:
             # epoch tracker attaches BEFORE the listeners open: every
@@ -602,7 +615,13 @@ class Daemon:
             while size <= top:
                 for a in algos:
                     warm = RequestColumns(
-                        fp=np.arange(1, size + 1, dtype=np.int64),
+                        # i in both halves: the high bits pick the shard
+                        # (mesh.shard_of), so the warm rows spread over a
+                        # mesh like hashed keys do and compile the shapes
+                        # real traffic will use — fingerprints 1..n all
+                        # land on shard 0 and overflow the exchange
+                        fp=np.arange(1, size + 1, dtype=np.int64)
+                        * ((1 << 32) + 1),
                         algo=np.full(size, a, dtype=np.int32),
                         behavior=np.zeros(size, dtype=np.int32),
                         hits=np.zeros(size, dtype=np.int64),
@@ -1609,14 +1628,36 @@ class Daemon:
         fault-back behavior is in question (docs/tiering.md)."""
         return self.tier.debug()
 
+    @staticmethod
+    def _device_identity() -> dict:
+        """The device as JAX reports it, from the process that holds it."""
+        import jax
+
+        devs = jax.devices()
+        # per-device HBM in use (None where the backend reports no stats,
+        # e.g. CPU): shows a mesh's table spread over its chips
+        mem = [(d.memory_stats() or {}).get("bytes_in_use") for d in devs]
+        return {
+            "platform": devs[0].platform,
+            "device_kind": devs[0].device_kind,
+            "device_count": len(devs),
+            "device_bytes_in_use": mem,
+        }
+
     def debug_pipeline(self) -> dict:
         """Front-door + engine pipeline state: ring depth, worker liveness,
         dispatch-path counters, adaptive-close reasons, engine identity."""
         eng = self.engine
         return {
             "batcher": self.batcher.debug(),
+            # which request parser serves the door: "built"/"reused" = the
+            # native extension (compiled by this process / found with a
+            # matching source hash), None = the pure-Python fallback
+            "native_parser": native.state,
             "engine": {
                 "kind": type(eng).__name__,
+                **self._device_identity(),
+                "table_bytes": int(eng.table.rows.nbytes),
                 "wire": getattr(eng, "wire", None),
                 "write_mode": getattr(eng, "write_mode", None),
                 # table-walk kernel (GUBER_PROBE_KERNEL) + the modeled HBM

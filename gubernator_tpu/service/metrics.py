@@ -257,7 +257,7 @@ class DaemonMetrics:
             # service/ring.py, docs/latency.md "Dispatch budget".
             # A HISTOGRAM (was a Summary) so per-stage TAILS are scrapeable:
             # _sum/_count keep the same series names the e2e bench means
-            # used, and the buckets let BENCH_r06+ report per-stage p99 —
+            # used, and the buckets let later records report per-stage p99 —
             # means hid exactly the tail behavior the serving plane is
             # judged on (docs/latency.md "Serving plane")
             ["stage"],

@@ -69,6 +69,7 @@ submit-side staging and the egress-fence wait.
 from __future__ import annotations
 
 import asyncio
+import logging
 import time
 from typing import List, Optional, Tuple
 
@@ -76,6 +77,8 @@ import numpy as np
 
 from gubernator_tpu.ops.batch import ResponseColumns
 from gubernator_tpu.service.wire import concat_columns
+
+log = logging.getLogger("gubernator_tpu.ring")
 
 
 class RingClosed(RuntimeError):
@@ -367,7 +370,13 @@ class RequestRing:
                 except Exception as exc:
                     if self.issue_mode == "persistent":
                         # watchdog: a preempted/failed drain re-launches
-                        # once before the group is failed out
+                        # once before the group is failed out. The first
+                        # failure is logged, never swallowed: a kernel the
+                        # chip refuses raises the same compiler message on
+                        # the re-launch and fails the group with it.
+                        log.warning(
+                            "ring drain failed, re-launching once: %r", exc
+                        )
                         self.watchdog_relaunches += 1
                         try:
                             bank, n = await self.runner.drain_ring_issue(

@@ -2,7 +2,7 @@
 
 This was the round-1 production kernel (15 f32-carrier plane scatters); the
 round-2 packed-row kernel (gubernator_tpu/ops/kernel2.py) replaced it on every
-production path after real-TPU measurements (exp/exp_mem*.py, ~4x faster).
+production path after real-TPU measurements (exp/README.md, exp_mem*, ~4x faster).
 It is kept here because the reference-semantics suites were originally
 validated against it, making it an independent implementation to diff v2
 against on randomized traffic (tests/test_kernel2.py).

@@ -158,7 +158,7 @@ def test_gcra_token_equivalence_at_burst_limit():
             _cols(["ge"], Algorithm.GCRA, [1], [limit], [dur], t), now_ms=t
         )
         g_admit += int(rc.status[0]) == 0
-        st, _ = tok.check(1, t, 1, limit, dur)
+        st, _, _ = tok.check(1, t, 1, limit, dur)
         t_admit += st == 0
     assert g_admit == t_admit == limit
     # randomized OVERLOADED schedule (arrivals ~2× the sustainable rate):
@@ -173,7 +173,7 @@ def test_gcra_token_equivalence_at_burst_limit():
             _cols(["gr"], Algorithm.GCRA, [1], [limit], [dur], t), now_ms=t
         )
         g_total += int(rc.status[0]) == 0
-        st, _ = tok.check(2, t, 1, limit, dur)
+        st, _, _ = tok.check(2, t, 1, limit, dur)
         t_total += st == 0
     assert abs(g_total - t_total) <= 2 * limit, (g_total, t_total)
     # and both sit at the configured rate (±1 window) over the elapsed span

@@ -477,7 +477,9 @@ async def _fake_k8s_api(state):
         "/apis/discovery.k8s.io/v1/namespaces/default/endpointslices",
         endpointslices,
     )
-    runner = web.AppRunner(app)
+    # a watch handler blocked on its queue never ends by itself: without a
+    # short shutdown_timeout, cleanup() waits aiohttp's default 60 s for it
+    runner = web.AppRunner(app, shutdown_timeout=0.5)
     await runner.setup()
     site = web.TCPSite(runner, "127.0.0.1", 0)
     await site.start()

@@ -354,7 +354,7 @@ def test_store_engine_sync_stays_serial(mesh, frozen_now):
 
 def test_sync_launch_failure_requeues_hits_and_poisons(mesh, frozen_now):
     """A collective sync launch that dies AFTER the accumulators were popped
-    must not lose the hits (ADVICE r5): the popped boxes re-merge into
+    must not lose the hits: the popped boxes re-merge into
     pending, and the engine is marked poisoned so health surfaces unhealthy
     instead of serving from the donated (now-suspect) tables."""
     eng = GlobalShardedEngine(mesh, capacity_per_shard=1024, sync_out=64)

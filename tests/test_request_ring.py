@@ -29,7 +29,7 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 from gubernator_tpu.service.ring import RequestRing, RingClosed
 
 # one fresh XLA compile fires exactly one of these events; cached
-# executions fire none (verified against jax 0.4.x)
+# executions fire none
 COMPILE_EVENT = "/jax/compilation_cache/compile_requests_use_cache"
 
 

@@ -120,7 +120,7 @@ def test_a2a_impl_resolution(monkeypatch):
     monkeypatch.setenv("GUBER_A2A_IMPL", "ring")
     assert a2a_impl() == "ring"
     monkeypatch.setenv("GUBER_A2A_IMPL", "auto")
-    # CPU backend: auto = collective (the seed lowering)
+    # auto = collective on every backend (the ring TPU kernel is refused)
     assert a2a_impl() == "collective"
     monkeypatch.setenv("GUBER_A2A_IMPL", "bogus")
     with pytest.raises(ValueError):
