@@ -839,9 +839,6 @@ def tiering_case(rng, now) -> dict:
     ratio = rates["tiering"] / rates["baseline"]
     out["hot_set_ratio"] = round(ratio, 3)
     out["accept_ge_0_9x"] = bool(ratio >= 0.9)
-    out["hbm_bytes_per_decision"] = round(
-        eng.hbm_bytes_per_decision_estimate(), 1
-    )
     out["backend"] = jax.default_backend()
     return out
 

@@ -835,7 +835,9 @@ def _pending_with_out(pend, out):
 # device->host fetch is a host sync, and a multi-pass batch (hot-key herds
 # plan up to max_exact sequential passes) otherwise pays N of them per
 # request (the saving is not measured on a co-located host)
-_stack_outs = jax.jit(lambda xs: jnp.stack(xs))
+@jax.jit
+def _stack_outs(xs):
+    return jnp.stack(xs)
 
 
 def _stack_pass_outputs(outs):
@@ -1286,23 +1288,7 @@ class LocalEngine:
             # engine thread — the only thread allowed to swap the table
             self.migrate_layout_full()
         self._seen_pad_sizes.add(batch_rows)
-        self.last_dispatch_rows = batch_rows
         return self._issue_from_dev(dev, batch_rows, math, wired, cascade)
-
-    def hbm_bytes_per_decision_estimate(self) -> float:
-        """Modeled HBM bytes the table walk moves per decision at the last
-        dispatch geometry (ops/pallas_probe.hbm_bytes_per_decision) — the
-        gubernator_table_hbm_bytes_per_decision gauge and the
-        /v1/debug/pipeline roofline field."""
-        from gubernator_tpu.ops.pallas_probe import hbm_bytes_per_decision
-
-        rows = getattr(self, "last_dispatch_rows", 0)
-        if not rows:
-            rows = max(self._seen_pad_sizes, default=4096)
-        return hbm_bytes_per_decision(
-            self.table.layout, rows, int(self.table.rows.shape[-2]),
-            self.write_mode, getattr(self, "probe_mode", "xla"),
-        )
 
     def finish_staged(self, pending, n: int):
         """Materialize one pass's packed output → ((s, l, r, t, dropped,

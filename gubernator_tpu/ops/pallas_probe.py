@@ -172,8 +172,8 @@ def hbm_bytes_per_decision(
     """Roofline model: HBM bytes the table walk moves per decision, from
     the layout's row width, the dispatch geometry and the write mode —
     the denominator of the "is the chip HBM-bound?" argument
-    (docs/kernel.md "Probe pipeline"), exported as the
-    gubernator_table_hbm_bytes_per_decision gauge.
+    (docs/kernel.md "Probe pipeline"). A model: the measured share is
+    the benchmark's `decide_roofline`, from the trace.
 
     Per decision the PROBE reads one bucket row (`layout.row` i32 lanes).
     The write side depends on the mode: the dense sweep streams the whole

@@ -93,6 +93,7 @@ from gubernator_tpu.ops.table2 import Table2, n_buckets_for
 from gubernator_tpu.parallel.mesh import (
     devices_per_host,
     mesh_hosts,
+    shard_axes,
     shard_of,
     shard_spec,
 )
@@ -439,6 +440,7 @@ class ShardedEngine:
         # linearization, so no routing code below reads these
         self.n_hosts = mesh_hosts(mesh)
         self.devices_per_host = devices_per_host(mesh)
+        self._live_count_fn = None  # (layout, compiled count), live_count()
         # ownership-exchange schedule for route="device" dispatches
         # (parallel/ring.py): "ring" | "collective", resolved once from the
         # override / GUBER_A2A_IMPL / backend auto rule
@@ -792,10 +794,22 @@ class ShardedEngine:
             self.ckpt.mark_all()
 
     def live_count(self, now_ms: Optional[int] = None) -> int:
-        from gubernator_tpu.ops.table2 import live_count2
+        """Every shard counts its own rows where they live, the counts meet
+        in one psum, and one integer comes back (cf. table2.live_count2)."""
+        from gubernator_tpu.ops.table2 import live_count_rows
 
-        # live_count2 reshapes (-1, K, F), so the leading shard axis folds in
-        return live_count2(self.table, now_ms if now_ms is not None else ms_now())
+        lay = self.table.layout
+        if self._live_count_fn is None or self._live_count_fn[0] is not lay:
+            axes = shard_axes(self.mesh)
+            self._live_count_fn = lay, jax.jit(jax.shard_map(
+                lambda rows, now: jax.lax.psum(
+                    live_count_rows(rows[0], now, lay), axes
+                ),
+                mesh=self.mesh, in_specs=(shard_spec(self.mesh), P()),
+                out_specs=P(), check_vma=False,
+            ))
+        now = now_ms if now_ms is not None else ms_now()
+        return int(self._live_count_fn[1](self.table.rows, jnp.int64(now)))
 
     # ----------------------------------------------------------- handoff
     # Same surface as LocalEngine (extract_live / merge_rows /
@@ -1156,22 +1170,9 @@ class ShardedEngine:
 
     def issue_staged(self, staged: "_Staged", batch_rows: int):
         # dispatch count is folded in via the finish delta (engine thread)
-        self.last_dispatch_rows = batch_rows
         table, out = self._decide(self.table, staged)
         self.table = table
         return staged, out
-
-    def hbm_bytes_per_decision_estimate(self) -> float:
-        """Per-shard table-walk bytes/decision at the last dispatch
-        geometry (the LocalEngine twin; rows here are PER-SHARD rows)."""
-        from gubernator_tpu.ops.pallas_probe import hbm_bytes_per_decision
-
-        rows = getattr(self, "last_dispatch_rows", 0) or 4096
-        per_shard = max(1, rows // self.n_shards)
-        return hbm_bytes_per_decision(
-            self.table.layout, per_shard, int(self.table.rows.shape[-2]),
-            self.write_mode, self.probe_mode,
-        )
 
     def finish_staged(self, pending, n: int):
         staged, out = pending

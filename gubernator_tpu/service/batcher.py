@@ -246,6 +246,8 @@ class Batcher:
         self._closed = False
         self._inflight = 0
         # introspection counters (CI serving smoke + tests read these)
+        self.dispatches = 0  # every _dispatch, whatever path it took
+        self.requests = 0  # entries (enqueued batches) dispatched
         self.fused_dispatches = 0  # rode the fused wire→grid path
         self.column_dispatches = 0  # generic columns path
         self.wire_fallbacks = 0  # all-wire chunk that could NOT fuse
@@ -271,6 +273,7 @@ class Batcher:
         """Enqueue a column batch (RequestColumns) or a pre-parsed wire
         batch (service/wire.WireBatch); resolves with this batch's slice of
         the coalesced response."""
+        t_in = time.perf_counter()
         now = now_ms if now_ms is not None else ms_now()
         # stamp unset created_at at ENQUEUE time (reference stamps at request
         # entry, gubernator.go:225-227), not at flush time
@@ -369,7 +372,13 @@ class Batcher:
                 or self._pending_bytes >= self.close_bytes
             ):
                 self._full.set()
-        return await entry.fut
+        rc = await entry.fut
+        # the caller's whole stay here: queue wait + its dispatch + the
+        # loop's wake-up of this coroutine (one line of the request's budget)
+        tracing.observe(
+            "batch_wait", self.metrics, time.perf_counter() - t_in, entry.span
+        )
+        return rc
 
     # ------------------------------------------------------ overload plane
     def _item_deadline(self) -> Optional[float]:
@@ -510,59 +519,60 @@ class Batcher:
                     continue
                 await self._wake.wait()
                 continue
-            await self._window()
-            chunk = self._take_chunk()
+            chunk = self._take_chunk(await self._window())
             if chunk is None:
                 continue
             await self._dispatch(chunk)
 
-    async def _window(self) -> None:
+    async def _window(self) -> str:
         """Hold the coalesce window open until it should close: on
         accumulated rows/bytes (engine-sized dispatch ready), on an idle
         engine (light load — why wait?), on a dispatch slot freeing (refill
-        the pipeline), or on the `batch_wait_ms` wall-clock ceiling."""
+        the pipeline), or on the `batch_wait_ms` wall-clock ceiling.
+        Returns what closed it (the `reason` of the gub:close span)."""
         if self.batch_wait_s <= 0:
-            return
+            return "nowait"
         if (
             self._pending_rows >= self.close_rows
             or self._pending_bytes >= self.close_bytes
         ):
-            self._close_adaptive()
-            return
+            return self._close_adaptive()
         if self.adaptive and self._inflight == 0:
             # engine idle: dispatching now beats waiting for company —
             # requests arriving during THIS dispatch coalesce into the next
             self.adaptive_closes += 1
             self.close_reasons["idle"] += 1
-            return
+            return "idle"
         if not self.adaptive:
             await asyncio.sleep(self.batch_wait_s)
-            return
+            return "expire"
         self._full.clear()
         if (
             self._pending_rows >= self.close_rows
             or self._pending_bytes >= self.close_bytes
         ):  # filled while clearing
-            self._close_adaptive()
-            return
+            return self._close_adaptive()
         try:
             await asyncio.wait_for(self._full.wait(), self.batch_wait_s)
-            self._close_adaptive()
+            return self._close_adaptive()
         except asyncio.TimeoutError:
             self.window_expires += 1
+            return "expire"
 
-    def _close_adaptive(self) -> None:
+    def _close_adaptive(self) -> str:
         """Count one adaptive close, attributed to what actually tripped it
         (rows/bytes threshold, else a freed dispatch slot re-evaluating)."""
         self.adaptive_closes += 1
         if self._pending_rows >= self.close_rows:
-            self.close_reasons["rows"] += 1
+            reason = "rows"
         elif self._pending_bytes >= self.close_bytes:
-            self.close_reasons["bytes"] += 1
+            reason = "bytes"
         else:
-            self.close_reasons["slot"] += 1
+            reason = "slot"
+        self.close_reasons[reason] += 1
+        return reason
 
-    def _take_chunk(self):
+    def _take_chunk(self, reason: str = "drain"):
         """Pop a chunk of whole enqueued batches up to the coalesce limit
         (a single oversized enqueue dispatches alone), bounding dispatch
         latency and compile-shape spread. Armed mode orders the window by
@@ -571,9 +581,24 @@ class Batcher:
         — an answer after the caller stopped waiting is pure waste. One
         clamped gauge update per flush — per-enqueue sets only churned the
         gauge with intermediate values (hot-path metric cost at high
-        request rates)."""
+        request rates). The work is one gub:close span: what closed the
+        window, the chunk's rows, and how long its oldest entry had waited
+        (the span's end less `waited_us` is when the window opened)."""
         if not self._pending:
             return None
+        with tracing.stage("close", self.metrics, reason=reason) as st:
+            chunk = self._form_chunk()
+            if chunk:
+                st.note(
+                    rows=sum(e.rows for e in chunk),
+                    waited_us=int(
+                        (time.perf_counter() - min(e.t_enq for e in chunk))
+                        * 1e6
+                    ),
+                )
+        return chunk if chunk else None
+
+    def _form_chunk(self) -> list:
         if (
             self.armed
             and len(self._pending) > 1
@@ -608,42 +633,43 @@ class Batcher:
             self._space.set()
         if self.metrics is not None:
             self.metrics.queue_length.set(max(self._pending_rows, 0))
-        return chunk if chunk else None
+        return chunk
 
     # ------------------------------------------------------------ dispatch
+    def _observe_dispatch(self, t0: float, disp) -> None:
+        """The dispatch's budget lines: the whole of it, and its self time
+        (what put/issue/fetch did not cover: three executor hops and the
+        loop's wake-ups between them)."""
+        dt = time.perf_counter() - t0
+        tracing.observe("dispatch", self.metrics, dt)
+        tracing.observe(
+            "dispatch_wait", self.metrics, max(0.0, dt - disp.work_s)
+        )
+
     async def _dispatch(self, batch) -> None:
+        t0 = time.perf_counter()
         self._inflight += 1
+        self.dispatches += 1
+        self.requests += len(batch)
         # one `dispatch` span per flush: batching breaks request→engine
         # parent-child causality (N requests share one flush), so the flush
         # gets its OWN trace with stage child spans (queue here; put/issue/
         # fetch in the runner) and every request span gains an OTLP link to
-        # it — minted only when spans actually export
+        # it — minted only when spans actually export. Its number rides on
+        # the profiler spans of its stages either way.
         disp_span = tracing.new_span() if tracing.exporter is not None else None
+        disp = tracing.Dispatch(
+            self.dispatches, sum(e.rows for e in batch), disp_span
+        )
         fused = False
         try:
-            t0 = time.perf_counter()
             oldest = min(e.t_enq for e in batch)
-            if self.metrics is not None:
-                self.metrics.stage_duration.labels(stage="queue").observe(
-                    t0 - oldest,
-                    exemplar=(
-                        {"trace_id": disp_span.trace_id} if disp_span else None
-                    ),
-                )
-                # per-enqueue queue wait (the shed policy's p99 story):
-                # "queue" above is per-CHUNK (its oldest member); these are
-                # per admitted batch, the distribution deadlines cut into
-                qw = self.metrics.stage_duration.labels(stage="queue_wait")
-                for e in batch:
-                    wait = t0 - e.t_enq
-                    qw.observe(wait)
-                    self.metrics.queue_wait_seconds.observe(wait)
-            if disp_span is not None:
-                q_ns = time.time_ns()
-                tracing.record_span(
-                    "queue", tracing.new_span(disp_span), disp_span.span_id,
-                    q_ns - int((t0 - oldest) * 1e9), q_ns,
-                )
+            tracing.observe("queue", self.metrics, t0 - oldest, disp_span)
+            # per-enqueue queue wait (the shed policy's p99 story): "queue"
+            # above is per-CHUNK (its oldest member); these are per admitted
+            # batch, the distribution deadlines cut into
+            for e in batch:
+                tracing.observe("queue_wait", self.metrics, t0 - e.t_enq)
             payloads = [e.payload for e in batch]
             rc = None
             if all(isinstance(p, WireBatch) for p in payloads):
@@ -656,7 +682,7 @@ class Batcher:
                     from gubernator_tpu.service.ring import RingClosed
 
                     try:
-                        rc = await self.ring.submit(payloads, span=disp_span)
+                        rc = await self.ring.submit(payloads, disp=disp)
                         self.ring_dispatches += 1
                         fused = True
                     except RingClosed:
@@ -666,9 +692,7 @@ class Batcher:
                     # into one staged compact grid
                     # (ops/engine.prepare_check_wire) — the request bytes
                     # are traversed exactly once end to end
-                    rc = await self.runner.check_wire(
-                        payloads, span=disp_span
-                    )
+                    rc = await self.runner.check_wire(payloads, disp=disp)
                     if rc is not None:
                         self.fused_dispatches += 1
                         fused = True
@@ -676,12 +700,13 @@ class Batcher:
                         self.wire_fallbacks += 1
             if rc is None:
                 cat = concat_columns([_payload_cols(p) for p in payloads])
-                rc = await self.runner.check(cat, span=disp_span)
+                rc = await self.runner.check(cat, disp=disp)
                 self.column_dispatches += 1
         except Exception as exc:  # pragma: no cover - defensive
             for e in batch:
                 if not e.fut.done():
                     e.fut.set_exception(exc)
+            self._observe_dispatch(t0, disp)
             return
         finally:
             self._inflight -= 1
@@ -709,7 +734,8 @@ class Batcher:
                 "dispatch", disp_span, "",
                 end_ns - int((time.perf_counter() - oldest) * 1e9), end_ns,
                 attributes={
-                    "batch.rows": sum(e.rows for e in batch),
+                    "batch.seq": disp.seq,
+                    "batch.rows": disp.rows,
                     "batch.requests": len(batch),
                     "batch.fused": fused,
                 },
@@ -731,6 +757,7 @@ class Batcher:
                     )
                 )
             off += n
+        self._observe_dispatch(t0, disp)
 
     def arm_overload(self, deadline_ms: float) -> None:
         """(Re)arm or disarm the overload door at runtime. The scenario
@@ -761,6 +788,8 @@ class Batcher:
             "close_rows": self.close_rows,
             "close_bytes": self.close_bytes,
             "max_queue_rows": self.max_queue_rows,
+            "dispatches": self.dispatches,
+            "requests": self.requests,
             "fused_dispatches": self.fused_dispatches,
             "column_dispatches": self.column_dispatches,
             "wire_fallbacks": self.wire_fallbacks,

@@ -667,6 +667,9 @@ class Daemon:
                 from gubernator_tpu.parallel.global_sync import GlobalStats
 
                 self.engine.global_stats = GlobalStats()
+        # the /metrics scrape and the grow tick count live keys on the device:
+        # compile that program now, not under the first scrape
+        await self.runner.live_count()
         # warm-up is not traffic: reset counters so tests and metrics see
         # only real requests. The pipelined warms above apply their stats
         # deltas fire-and-forget on the engine executor — flush it first or
@@ -1025,17 +1028,14 @@ class Daemon:
         needs full request objects."""
         from gubernator_tpu.service.wire import wire_batch_from_wire
 
+        t_req = time.perf_counter()
         parsed = None
-        parse_s = 0.0
+        parse_s = door_wait_s = 0.0
         if self.event_channel is None:
-            t0 = time.perf_counter()
-            if len(data) >= self.DOOR_OFFLOAD_BYTES:
-                parsed = await asyncio.get_running_loop().run_in_executor(
-                    self._door, wire_batch_from_wire, data
-                )
-            else:
-                parsed = wire_batch_from_wire(data)
-            parse_s = time.perf_counter() - t0
+            parsed, parse_s, door_wait_s = await self._through_door(
+                "parse", len(data) >= self.DOOR_OFFLOAD_BYTES,
+                wire_batch_from_wire, data,
+            )
         if parsed is None:
             req = pb.GetRateLimitsReq.FromString(data)
             resps = await self.get_rate_limits(list(req.requests))
@@ -1051,20 +1051,55 @@ class Daemon:
         # observed under the request span so its exemplar resolves to the
         # request's own trace; the child span makes "where did my p99 go"
         # decomposable per request
-        self._observe_request_stage("parse", parse_s, token.span)
+        tracing.observe("parse", self.metrics, parse_s, token.span)
         try:
-            return await self._route_raw(data, wb, ring, spans)
+            out, encode_wait_s = await self._route_raw(data, wb, ring, spans)
+            door_wait_s += encode_wait_s
+            return out
         finally:
+            # the request's budget (docs/observability.md): first byte in
+            # hand to response bytes returned, and the part of it spent
+            # waiting for a door-pool worker and for the loop to resume us
+            tracing.observe("door_wait", self.metrics, door_wait_s, token.span)
+            tracing.observe(
+                "request", self.metrics, time.perf_counter() - t_req, token.span
+            )
             tracing.end_scope(token)
             self.metrics.concurrent_checks.dec()
 
-    async def _route_raw(self, data, wb, ring, spans) -> bytes:
+    async def _through_door(self, stage: str, offload: bool, fn, *args):
+        """Run the native parser or encoder, on the door pool when the
+        buffer is big enough to pay for the hop. Returns (result, wall
+        seconds around the call, the part of that which was not the work):
+        the work is timed where it runs (a gub:<stage> profiler span on the
+        door thread); an inline call has no wait by definition."""
+
+        def work():
+            with tracing.stage(stage) as st:
+                out = fn(*args)
+            return out, st.dt
+
+        t0 = time.perf_counter()
+        if not offload:
+            out, _ = work()
+            return out, time.perf_counter() - t0, 0.0
+        out, work_s = await asyncio.get_running_loop().run_in_executor(
+            self._door, work
+        )
+        wall = time.perf_counter() - t0
+        return out, wall, max(0.0, wall - work_s)
+
+    async def _route_raw(self, data, wb, ring, spans) -> "tuple[bytes, float]":
+        """The response bytes, and what the encode hop waited for the door
+        pool (the caller's `door_wait` line)."""
         from gubernator_tpu.service.wire import (
             encode_response_columns,
             item_from_span,
             subset_wire,
         )
 
+        t_route = time.perf_counter()
+        t_answered = 0.0  # when the last batcher.check handed its rows back
         cols = wb.cols
         n = cols.fp.shape[0]
         force_global = self.conf.behaviors.force_global
@@ -1126,11 +1161,25 @@ class Daemon:
                 if rc.err[j]:
                     errors[int(i)] = ERROR_STRINGS[int(rc.err[j])]
 
+        def routed():
+            """`route` ends where the first batcher.check begins."""
+            nonlocal t_route
+            if t_route:
+                tracing.observe(
+                    "route", self.metrics, time.perf_counter() - t_route,
+                    tracing.current_span(),
+                )
+                t_route = 0.0
+
         async def run_local():
             # the WireBatch subset keeps the parser's pre-packed lanes with
             # the columns — an all-local encodable batch stages straight
             # into the dispatch grid (fused path, service/batcher.py)
-            rc = await self.batcher.check(subset_wire(wb, local_rows))
+            nonlocal t_answered
+            local = subset_wire(wb, local_rows)
+            routed()
+            rc = await self.batcher.check(local)
+            t_answered = time.perf_counter()
             place(local_rows, rc)
 
         async def run_global():
@@ -1150,7 +1199,10 @@ class Daemon:
                 self.global_manager.queue_hit(
                     item.name + "_" + item.unique_key, item
                 )
+            nonlocal t_answered
+            routed()
             rc = await self.batcher.check(g)
+            t_answered = time.perf_counter()
             place(global_rows, rc)
 
         degraded_rows: set = set()
@@ -1212,41 +1264,26 @@ class Daemon:
                 if i in degraded_rows:
                     r.metadata["degraded"] = "true"
                 resps.append(r)
-            return pb.GetRateLimitsResp(responses=resps).SerializeToString()
-        t0 = time.perf_counter()
+            return pb.GetRateLimitsResp(responses=resps).SerializeToString(), 0.0
         now = self.now_ms()  # retry_after_ms metadata basis (denied rows)
-        if n * 8 >= self.DOOR_OFFLOAD_BYTES:
-            # native encode drops the GIL — responder workers encode big
-            # batches in parallel off the event loop
-            out_bytes = await asyncio.get_running_loop().run_in_executor(
-                self._door,
-                encode_response_columns,
-                status, limit, remaining, reset, errors, now,
+        if t_answered:
+            # answer in hand → encoder's start: placing the rows, the
+            # gather's wake-up of this coroutine, GLOBAL/region queueing
+            tracing.observe(
+                "respond", self.metrics, time.perf_counter() - t_answered,
+                tracing.current_span(),
             )
-        else:
-            out_bytes = encode_response_columns(
-                status, limit, remaining, reset, errors, now
-            )
-        self._observe_request_stage(
-            "encode", time.perf_counter() - t0, tracing.current_span()
+        # native encode drops the GIL — responder workers encode big
+        # batches in parallel off the event loop
+        out_bytes, encode_s, wait_s = await self._through_door(
+            "encode", n * 8 >= self.DOOR_OFFLOAD_BYTES,
+            encode_response_columns,
+            status, limit, remaining, reset, errors, now,
         )
-        return out_bytes
-
-    def _observe_request_stage(self, stage: str, dt_s: float, span) -> None:
-        """One request-scoped stage (parse/encode — stages that belong to a
-        single request, unlike the per-flush queue/put/issue/fetch): the
-        histogram sample carries the REQUEST trace as its exemplar and the
-        child span hangs under the request span."""
-        self.metrics.stage_duration.labels(stage=stage).observe(
-            dt_s,
-            exemplar={"trace_id": span.trace_id} if span is not None else None,
+        tracing.observe(
+            "encode", self.metrics, encode_s, tracing.current_span()
         )
-        if span is not None and tracing.exporter is not None:
-            end_ns = time.time_ns()
-            tracing.record_span(
-                stage, tracing.new_span(span), span.span_id,
-                end_ns - int(dt_s * 1e9), end_ns,
-            )
+        return out_bytes, wait_s
 
     def _emit_event(self, item, resp) -> None:
         if resp is None:  # pragma: no cover - defensive
@@ -1660,15 +1697,8 @@ class Daemon:
                 "table_bytes": int(eng.table.rows.nbytes),
                 "wire": getattr(eng, "wire", None),
                 "write_mode": getattr(eng, "write_mode", None),
-                # table-walk kernel (GUBER_PROBE_KERNEL) + the modeled HBM
-                # bytes/decision at the current layout × write × geometry —
-                # the live view of gubernator_table_hbm_bytes_per_decision
+                # table-walk kernel (GUBER_PROBE_KERNEL)
                 "probe_kernel": getattr(eng, "probe_mode", None),
-                "hbm_bytes_per_decision": (
-                    round(eng.hbm_bytes_per_decision_estimate(), 1)
-                    if hasattr(eng, "hbm_bytes_per_decision_estimate")
-                    else None
-                ),
                 "n_shards": getattr(eng, "n_shards", 1),
                 "n_hosts": getattr(eng, "n_hosts", 1),
                 "devices_per_host": getattr(eng, "devices_per_host", None),

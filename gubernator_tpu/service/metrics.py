@@ -235,12 +235,6 @@ class DaemonMetrics:
             registry=r,
             buckets=(1, 2, 4, 8, 16, 32, 64),
         )
-        self.dispatch_duration = Histogram(
-            "gubernator_tpu_dispatch_duration",
-            "Seconds per decision-kernel dispatch (host-observed)",
-            registry=r,
-            buckets=(0.0005, 0.001, 0.002, 0.005, 0.01, 0.025, 0.05, 0.1, 0.5, 2.5),
-        )
         self.stage_duration = Histogram(
             "gubernator_tpu_stage_duration",
             "Seconds per serving-pipeline stage",
@@ -264,6 +258,9 @@ class DaemonMetrics:
             registry=r,
             buckets=LATENCY_BUCKETS,
         )
+        # stage → its labelled child: a dozen samples an RPC come through
+        # tracing.observe, and labels() takes the family's lock every time
+        self._stage_children: dict = {}
         self.decisions_total = Counter(
             # renders as gubernator_tpu_decisions_total
             "gubernator_tpu_decisions",
@@ -354,15 +351,6 @@ class DaemonMetrics:
             "(0 = best-effort .. 3 = shed last)",
             ["reason", "tier"],
             registry=r,
-        )
-        self.queue_wait_seconds = Histogram(
-            "gubernator_tpu_queue_wait_seconds",
-            "Seconds each admitted front-door batch waited in the coalesce "
-            "queue before its dispatch began (per enqueued batch, not per "
-            "chunk — the p99 of this series is the queueing half of the "
-            "overload story; shed items never appear here)",
-            registry=r,
-            buckets=LATENCY_BUCKETS,
         )
         self.batch_send_retries = Counter(
             "gubernator_batch_send_retries",
@@ -639,15 +627,6 @@ class DaemonMetrics:
             registry=r,
             buckets=(0.001, 0.005, 0.01, 0.05, 0.1, 0.5, 1.0, 5.0),
         )
-        self.table_hbm_bytes_per_decision = Gauge(
-            "gubernator_table_hbm_bytes_per_decision",
-            "Modeled HBM bytes the decide path's table walk moves per "
-            "decision (worst case) at the engine's current slot layout, "
-            "write mode, probe kernel and last dispatch geometry "
-            "(ops/pallas_probe.hbm_bytes_per_decision) — the roofline "
-            "denominator behind the decisions/s record (docs/kernel.md)",
-            registry=r,
-        )
         # --- durability plane (service/checkpoint.py; docs/durability.md):
         # the incremental checkpoint loop's cost, volume, and freshness —
         # kind=delta for epoch frames, kind=base for compactions/shutdown
@@ -814,6 +793,15 @@ class DaemonMetrics:
             self.table_remaining_frac.labels(le=str(e)).set(v)
         self.table_remaining_frac.labels(le="+Inf").set(snap.live_keys)
         self.table_scan_duration.observe(snap.scan_ms / 1e3)
+
+    def stage_child(self, stage: str):
+        """gubernator_tpu_stage_duration{stage}, resolved once."""
+        child = self._stage_children.get(stage)
+        if child is None:
+            child = self._stage_children[stage] = self.stage_duration.labels(
+                stage=stage
+            )
+        return child
 
     def render(self, openmetrics: bool = False) -> bytes:
         """Prometheus exposition (the /metrics body). `openmetrics=True`

@@ -75,6 +75,7 @@ from typing import List, Optional, Tuple
 
 import numpy as np
 
+from gubernator_tpu import tracing
 from gubernator_tpu.ops.batch import ResponseColumns
 from gubernator_tpu.service.wire import concat_columns
 
@@ -119,7 +120,7 @@ class RequestRing:
         self.seq_in = np.zeros(self.slots, dtype=np.int64)
         self.seq_out = np.zeros(self.slots, dtype=np.int64)
         # slot payload staging (the emulation's stand-in for the DMA'd
-        # wire grids): (parts, span) per slot, cleared on consume
+        # wire grids): (parts, disp) per slot, cleared on consume
         self._staged: List[Optional[Tuple[list, object]]] = (
             [None] * self.slots
         )
@@ -171,7 +172,7 @@ class RequestRing:
             self.metrics.ring_occupancy.set(occ)
 
     # -------------------------------------------------------------- submit
-    async def submit(self, parts, span=None) -> ResponseColumns:
+    async def submit(self, parts, disp=None) -> ResponseColumns:
         """Claim a ticket, stage the payload, publish the ingress fence,
         and poll the egress fence for the coalesced response. `parts` is
         the all-WireBatch chunk the batcher formed — the same value the
@@ -200,32 +201,35 @@ class RequestRing:
             self._done[ticket] = fut
             # STAGE before PUBLISH — the store-fence ordering: the payload
             # must be slot-resident before seq_in makes it claimable
-            self._staged[slot] = (parts, span)
+            self._staged[slot] = (parts, disp)
             self.seq_in[slot] = ticket + 1
             self._published.set()
         self._set_occupancy()
-        self.runner._observe_stage("ring_put", t0, span)
+        # both ring legs are waits (for the lock and a slot, then for the
+        # serving loop), not work: samples and child spans only
+        span = disp.span if disp is not None else None
+        t1 = time.perf_counter()
+        tracing.observe("ring_put", self.metrics, t1 - t0, span)
         # egress-fence poll: resolve when the serving loop publishes
         # seq_out[slot] == ticket + 1
-        t1 = time.perf_counter()
         try:
             rc = await fut
         finally:
             self._done.pop(ticket, None)
-        self.runner._observe_stage("ring_poll", t1, span)
+        tracing.observe("ring_poll", self.metrics, time.perf_counter() - t1, span)
         return rc
 
     # ------------------------------------------------------- serving loop
-    async def _dispatch(self, parts, span):
+    async def _dispatch(self, parts, disp):
         """One slot's dispatch: the exact runner surface the direct path
         drives. Non-fusable chunks (duplicate keys, non-encodable rows)
         fall back to the columns path, same as Batcher._dispatch."""
-        rc = await self.runner.check_wire(parts, span=span,
+        rc = await self.runner.check_wire(parts, disp=disp,
                                           launch_path="ring")
         if rc is None:
             self.fallbacks += 1
             cat = concat_columns([p.cols for p in parts])
-            rc = await self.runner.check(cat, span=span, launch_path="ring")
+            rc = await self.runner.check(cat, disp=disp, launch_path="ring")
         return rc
 
     async def _issue_loop(self) -> None:
@@ -249,21 +253,19 @@ class RequestRing:
                 f"ring fence violation: slot {slot} has seq "
                 f"{int(self.seq_in[slot])}, expected {t + 1}"
             )
-            parts, span = self._staged[slot]
+            parts, disp = self._staged[slot]
             self._staged[slot] = None
             await self._inorder.put(
-                ([t], loop.create_task(self._dispatch(parts, span)))
+                ([t], loop.create_task(self._dispatch(parts, disp)))
             )
             t += 1
 
     # ------------------------------------------------- fused consume tier
-    def _prepare_slot(self, parts, span):
+    def _prepare_slot(self, parts, disp):
         """Prep-pool half of one fused slot: assemble the fixed-width wire
         grid + PendingCheck (ops/engine.prepare_ring_slot). None routes
         the chunk to the per-slot host path. Auto-sizes the device ring on
         the first fusable chunk when GUBER_RING_SLOT_WIDTH=0."""
-        import time as _time
-
         from gubernator_tpu.ops.engine import _pad_size, prepare_ring_slot
 
         engine = self.runner.engine
@@ -272,10 +274,11 @@ class RequestRing:
             # padded dispatch, floored so ordinary coalesced flushes fit
             n = sum(p.cols.fp.shape[0] for p in parts)
             self.slot_width = max(64, _pad_size(n))
-        t0 = _time.perf_counter()
-        prep = prepare_ring_slot(engine, parts, self.slot_width)
+        with tracing.stage("put", self.metrics, disp=disp) as st:
+            prep = prepare_ring_slot(engine, parts, self.slot_width)
+            if prep is None:
+                st.name = "put_miss"  # as in EngineRunner.check_wire
         if prep is not None:
-            self.runner._observe_stage("put", t0, span)
             for p in parts:
                 self.runner._count_decisions(p.cols.algo)
         return prep
@@ -323,15 +326,15 @@ class RequestRing:
                     f"ring fence violation: slot {slot} has seq "
                     f"{int(self.seq_in[slot])}, expected {t + 1}"
                 )
-                parts, span = self._staged[slot]
+                parts, disp = self._staged[slot]
                 self._staged[slot] = None
-                todo.append((t, parts, span))
+                todo.append((t, parts, disp))
                 t += 1
             preps = await asyncio.gather(*(
                 loop.run_in_executor(
-                    self.runner._prep, self._prepare_slot, parts, span
+                    self.runner._prep, self._prepare_slot, parts, disp
                 )
-                for _t, parts, span in todo
+                for _t, parts, disp in todo
             ))
             i = 0
             while i < len(todo):
@@ -340,9 +343,9 @@ class RequestRing:
                     # full (not pipelined) so a following fused drain can
                     # never launch before this earlier ticket's dispatch —
                     # strict launch order is what byte-parity rests on.
-                    tk, parts, span = todo[i]
+                    tk, parts, disp = todo[i]
                     self.host_slots += 1
-                    task = loop.create_task(self._dispatch(parts, span))
+                    task = loop.create_task(self._dispatch(parts, disp))
                     await asyncio.wait({task})
                     await self._inorder.put(([tk], task))
                     i += 1
@@ -362,10 +365,10 @@ class RequestRing:
                     j += 1
                 group = [preps[x] for x in range(i, j)]
                 tickets = [todo[x][0] for x in range(i, j)]
-                span = todo[i][2]
+                disp = todo[i][2]  # the group's launch rides its head's
                 try:
                     bank, n = await self.runner.drain_ring_issue(
-                        self._ensure_dring(), group, tickets[0], span=span
+                        self._ensure_dring(), group, tickets[0], disp=disp
                     )
                 except Exception as exc:
                     if self.issue_mode == "persistent":
@@ -381,7 +384,7 @@ class RequestRing:
                         try:
                             bank, n = await self.runner.drain_ring_issue(
                                 self._ensure_dring(), group, tickets[0],
-                                span=span,
+                                disp=disp,
                             )
                         except Exception as exc2:
                             await self._inorder.put(
@@ -398,7 +401,7 @@ class RequestRing:
                 self.drain_launches += 1
                 self.drained_slots += len(group)
                 task = loop.create_task(
-                    self.runner.drain_ring_finish(group, bank, n, span=span)
+                    self.runner.drain_ring_finish(group, bank, n, disp=disp)
                 )
                 await self._inorder.put((tickets, task))
                 i = j

@@ -11,7 +11,6 @@ contract hold.
 from __future__ import annotations
 
 import asyncio
-import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Optional
 
@@ -20,14 +19,6 @@ import numpy as np
 from gubernator_tpu import tracing
 from gubernator_tpu.ops.batch import RequestColumns, ResponseColumns
 from gubernator_tpu.ops.engine import LocalEngine
-
-
-def _exemplar(span) -> Optional[dict]:
-    """OpenMetrics exemplar payload for a stage observation: the dispatch
-    span's trace_id, so a p99 bucket is one click from its trace. None when
-    the dispatch is untraced (no exporter) — prometheus_client treats None
-    as no-exemplar."""
-    return {"trace_id": span.trace_id} if span is not None else None
 
 
 # gubernator_tpu_decisions_total label values (types.Algorithm order)
@@ -100,7 +91,7 @@ class EngineRunner:
                     ).inc(int(c))
 
     async def check(
-        self, cols: RequestColumns, now_ms: Optional[int] = None, span=None,
+        self, cols: RequestColumns, now_ms: Optional[int] = None, disp=None,
         launch_path: str = "xla",
     ) -> ResponseColumns:
         """Pipelined check when the engine supports the prepare/issue/finish
@@ -113,10 +104,10 @@ class EngineRunner:
         their own pending type through the prepare_columns/issue_pending/
         finish_pending hooks instead of vetoing.
 
-        `span` is the batcher's dispatch SpanContext: each pipeline stage
-        emits a child span under it (and stage_duration exemplars carry its
-        trace_id), so a coalesced flush decomposes per-stage in the trace
-        view."""
+        `disp` is the batcher's tracing.Dispatch: each pipeline stage is a
+        tracing.stage under it (histogram sample, `gub:<stage>` profiler
+        span carrying its `dispatch` number, child span under its trace), so
+        a coalesced flush decomposes per stage in every view."""
         can = getattr(self.engine, "can_pipeline", None)
         if (
             not getattr(self.engine, "supports_pipeline", False)
@@ -132,20 +123,21 @@ class EngineRunner:
         loop = asyncio.get_running_loop()
 
         def prepare():
-            t0 = time.perf_counter()
-            prepared = prepare_check_columns(self.engine, cols, now_ms=now_ms)
-            self._observe_stage("put", t0, span)
+            with tracing.stage("put", self.metrics, disp=disp):
+                prepared = prepare_check_columns(
+                    self.engine, cols, now_ms=now_ms
+                )
             if self.metrics is not None:
                 self._observe_shard_stages()
             return prepared
 
         prepared = await loop.run_in_executor(self._prep, prepare)
         return await self._issue_and_finish(
-            prepared, span=span, launch_path=launch_path
+            prepared, disp=disp, launch_path=launch_path
         )
 
     async def check_wire(
-        self, parts, now_ms=None, span=None, launch_path: str = "xla"
+        self, parts, now_ms=None, disp=None, launch_path: str = "xla"
     ) -> Optional[ResponseColumns]:
         """Fused front-door check: pre-parsed WireBatch pieces
         (service/wire.py — native-parser lanes) staged straight into ONE
@@ -165,10 +157,13 @@ class EngineRunner:
         loop = asyncio.get_running_loop()
 
         def prepare():
-            t0 = time.perf_counter()
-            prepared = prepare_check_wire(engine, parts, now_ms=now_ms)
-            if prepared is not None:
-                self._observe_stage("put", t0, span)
+            with tracing.stage("put", self.metrics, disp=disp) as st:
+                prepared = prepare_check_wire(engine, parts, now_ms=now_ms)
+                if prepared is None:
+                    # the chunk cannot fuse and check() stages it again:
+                    # wasted work under a label of its own, so that `put`
+                    # stays the staging that was used
+                    st.name = "put_miss"
             return prepared
 
         prepared = await loop.run_in_executor(self._prep, prepare)
@@ -177,33 +172,16 @@ class EngineRunner:
         for p in parts:
             self._count_decisions(p.cols.algo)
         return await self._issue_and_finish(
-            prepared, span=span, launch_path=launch_path
+            prepared, disp=disp, launch_path=launch_path
         )
 
-    def _observe_stage(self, stage: str, t0: float, span) -> None:
-        """One pipeline-stage observation: histogram sample (with the
-        dispatch trace_id as its OpenMetrics exemplar) plus a child span
-        under the dispatch span. Wall-clock ns for the span are derived
-        from the same perf_counter interval the histogram measured."""
-        dt = time.perf_counter() - t0
-        if stage == "issue":
-            self.issue_ewma = (
-                dt if self.issue_ewma == 0.0
-                else 0.9 * self.issue_ewma + 0.1 * dt
-            )
-        if self.metrics is not None:
-            self.metrics.stage_duration.labels(stage=stage).observe(
-                dt, exemplar=_exemplar(span)
-            )
-        if span is not None and tracing.exporter is not None:
-            end_ns = time.time_ns()
-            tracing.record_span(
-                stage, tracing.new_span(span), span.span_id,
-                end_ns - int(dt * 1e9), end_ns,
-            )
+    def _note_issue(self, dt: float) -> None:
+        self.issue_ewma = (
+            dt if self.issue_ewma == 0.0 else 0.9 * self.issue_ewma + 0.1 * dt
+        )
 
     async def _issue_and_finish(
-        self, prepared, span=None, launch_path: str = "xla"
+        self, prepared, disp=None, launch_path: str = "xla"
     ) -> ResponseColumns:
         """Shared issue/finish halves of the pipelined dispatch: ISSUE on
         the engine thread (enqueue kernel launches, no fetch), FINISH on a
@@ -217,9 +195,9 @@ class EngineRunner:
         loop = asyncio.get_running_loop()
 
         def issue(prepared):
-            t0 = time.perf_counter()
-            pending = issue_check_columns(self.engine, prepared)
-            self._observe_stage("issue", t0, span)
+            with tracing.stage("issue", self.metrics, disp=disp) as st:
+                pending = issue_check_columns(self.engine, prepared)
+            self._note_issue(st.dt)
             if self.metrics is not None:
                 # feed-path accounting (docs/latency.md "Dispatch budget"):
                 # ring = launched from the device-resident request ring's
@@ -234,26 +212,10 @@ class EngineRunner:
             return self._exec.submit(fn).result()
 
         def finish(pending):
-            t0 = time.perf_counter()
-            rc, delta = finish_check_columns(self.engine, pending, fixup)
-            self._observe_stage("fetch", t0, span)
-
-            def apply():
-                self.engine.stats.merge(delta)
-                if self.metrics is not None:
-                    self.metrics.dispatch_duration.observe(
-                        time.perf_counter() - t0
-                    )
-                    self.metrics.observe_engine(self.engine.stats)
-                    self._observe_probe_bytes()
-                    # GLOBAL batches ride the pipeline too: without this the
-                    # queue-length gauge would only ever be observed post-
-                    # drain (sync_global) and read 0 forever
-                    gs = getattr(self.engine, "global_stats", None)
-                    if gs is not None:
-                        self.metrics.observe_global(gs)
-
-            self._exec.submit(apply)  # fire-and-forget, engine thread
+            with tracing.stage("fetch", self.metrics, disp=disp):
+                rc, delta = finish_check_columns(self.engine, pending, fixup)
+            # fire-and-forget, engine thread
+            self._exec.submit(self._apply, [delta], disp)
             return rc
 
         pending = await loop.run_in_executor(self._exec, lambda: issue(prepared))
@@ -267,7 +229,25 @@ class EngineRunner:
     # serialize LAUNCH order across groups while group j's finish overlaps
     # group j+1's issue — the same pipelining shape the host issue loop has.
 
-    async def drain_ring_issue(self, dring, group, start: int, span=None):
+    def _apply(self, deltas, disp=None) -> None:
+        """Engine-thread tail of a dispatch: fold its stats deltas into the
+        engine's (single writer) and refresh the gauges that mirror them.
+        Not in the dispatch's budget: its caller has already been answered."""
+        with tracing.stage(
+            "apply", self.metrics, dispatch=disp.seq if disp else 0
+        ):
+            for delta in deltas:
+                self.engine.stats.merge(delta)
+            if self.metrics is not None:
+                self.metrics.observe_engine(self.engine.stats)
+                # GLOBAL batches ride the pipeline too: without this the
+                # queue-length gauge would only ever be observed post-
+                # drain (sync_global) and read 0 forever
+                gs = getattr(self.engine, "global_stats", None)
+                if gs is not None:
+                    self.metrics.observe_global(gs)
+
+    async def drain_ring_issue(self, dring, group, start: int, disp=None):
         """ENGINE-THREAD half of one fused drain: per-slot issue-time work
         (shadow promote for the group head, checkpoint marks) in ticket
         order, stage each slot's grid + ingress fence into the device ring,
@@ -276,7 +256,15 @@ class EngineRunner:
         loop = asyncio.get_running_loop()
 
         def issue():
-            t0 = time.perf_counter()
+            with tracing.stage("issue", self.metrics, disp=disp) as st:
+                bank, n = launch()
+            self._note_issue(st.dt)
+            if self.metrics is not None:
+                self.metrics.dispatch_launches.labels(path="fused").inc()
+                self.metrics.ring_drain_slots.observe(len(group))
+            return bank, n
+
+        def launch():
             from gubernator_tpu.ops.engine import promote_rows
 
             engine = self.engine
@@ -300,21 +288,15 @@ class EngineRunner:
             if engine._batch_needs_full(head.math):
                 engine.migrate_layout_full()
             engine._seen_pad_sizes.add(dring.width)
-            engine.last_dispatch_rows = dring.width
             for i, prep in enumerate(group):
                 dring.stage((start + i) % dring.slots, prep.grid, start + i)
-            bank, n = dring.drain(
+            return dring.drain(
                 engine, start, len(group), head.math, head.cascade
             )
-            self._observe_stage("issue", t0, span)
-            if self.metrics is not None:
-                self.metrics.dispatch_launches.labels(path="fused").inc()
-                self.metrics.ring_drain_slots.observe(len(group))
-            return bank, n
 
         return await loop.run_in_executor(self._exec, issue)
 
-    async def drain_ring_finish(self, group, bank, n, span=None):
+    async def drain_ring_finish(self, group, bank, n, disp=None):
         """FETCH-THREAD half of one fused drain: ONE bank fetch covers the
         whole group; each slot's PendingCheck then runs the standard
         finish (dropped-claim retries and shadow rehydrates via the engine
@@ -326,7 +308,13 @@ class EngineRunner:
             return self._exec.submit(fn).result()
 
         def finish():
-            t0 = time.perf_counter()
+            with tracing.stage("fetch", self.metrics, disp=disp):
+                done = fetch()
+            # fire-and-forget, engine thread
+            self._exec.submit(self._apply, [delta for _rc, delta in done], disp)
+            return [rc for rc, _delta in done]
+
+        def fetch():
             from gubernator_tpu.ops.engine import finish_check_columns
 
             fetched = np.asarray(bank)
@@ -341,23 +329,7 @@ class EngineRunner:
                 pending = prep.pending
                 pending.passes[0][3] = fetched[i]
                 done.append(finish_check_columns(self.engine, pending, fixup))
-            self._observe_stage("fetch", t0, span)
-
-            def apply():
-                for _rc, delta in done:
-                    self.engine.stats.merge(delta)
-                if self.metrics is not None:
-                    self.metrics.dispatch_duration.observe(
-                        time.perf_counter() - t0
-                    )
-                    self.metrics.observe_engine(self.engine.stats)
-                    self._observe_probe_bytes()
-                    gs = getattr(self.engine, "global_stats", None)
-                    if gs is not None:
-                        self.metrics.observe_global(gs)
-
-            self._exec.submit(apply)  # fire-and-forget, engine thread
-            return [rc for rc, _delta in done]
+            return done
 
         return await loop.run_in_executor(self._fetch, finish)
 
@@ -392,14 +364,6 @@ class EngineRunner:
             if rows > 0:
                 self.metrics.a2a_overflow.labels(impl=impl).inc(rows)
 
-    def _observe_probe_bytes(self) -> None:
-        """Refresh the gubernator_table_hbm_bytes_per_decision gauge from
-        the engine's current layout × write-mode × probe-kernel × dispatch
-        geometry (a few integer ops — the model, not a measurement)."""
-        est = getattr(self.engine, "hbm_bytes_per_decision_estimate", None)
-        if est is not None:
-            self.metrics.table_hbm_bytes_per_decision.set(est())
-
     async def check_columns(
         self, cols: RequestColumns, now_ms: Optional[int] = None,
         launch_path: str = "xla",
@@ -408,14 +372,11 @@ class EngineRunner:
         loop = asyncio.get_running_loop()
 
         def run():
-            t0 = time.perf_counter()
             rc = self.engine.check_columns(cols, now_ms=now_ms)
             if self.metrics is not None:
                 self.metrics.dispatch_launches.labels(path=launch_path).inc()
-                self.metrics.dispatch_duration.observe(time.perf_counter() - t0)
                 self._observe_shard_stages()
                 self.metrics.observe_engine(self.engine.stats)
-                self._observe_probe_bytes()
                 gs = getattr(self.engine, "global_stats", None)
                 if gs is not None:
                     self.metrics.observe_global(gs)
@@ -444,19 +405,25 @@ class EngineRunner:
         loop = asyncio.get_running_loop()
 
         def run():
-            t0 = time.perf_counter()
-            self.engine.sync()
+            with tracing.stage("global_sync", self.metrics) as st:
+                self.engine.sync()
             if self.metrics is not None:
-                self.metrics.global_send_duration.observe(time.perf_counter() - t0)
+                self.metrics.global_send_duration.observe(st.dt)
                 self.metrics.observe_global(self.engine.global_stats)
 
         await loop.run_in_executor(self._exec, run)
 
     async def live_count(self) -> int:
         """Table live-key count, serialized onto the engine thread — reading
-        engine.table from another thread races the donated-buffer dispatch."""
+        engine.table from another thread races the donated-buffer dispatch.
+        The count is made on the device; one integer comes back."""
         loop = asyncio.get_running_loop()
-        return await loop.run_in_executor(self._exec, self.engine.live_count)
+
+        def run():
+            with tracing.stage("live_count", self.metrics):
+                return self.engine.live_count()
+
+        return await loop.run_in_executor(self._exec, run)
 
     async def table_telemetry(self, now_ms: Optional[int] = None):
         """One background table-telemetry scan (ops/telemetry.py), split
@@ -474,12 +441,17 @@ class EngineRunner:
             self._telemetry = ThreadPoolExecutor(
                 max_workers=1, thread_name_prefix="telemetry"
             )
-        pending = await loop.run_in_executor(
-            self._exec, lambda: self.engine.telemetry_begin(now_ms)
-        )
-        return await loop.run_in_executor(
-            self._telemetry, lambda: finish_scan(pending)
-        )
+
+        def launch():
+            with tracing.stage("scan_launch", self.metrics):
+                return self.engine.telemetry_begin(now_ms)
+
+        def fetch(pending):
+            with tracing.stage("scan_fetch", self.metrics):
+                return finish_scan(pending)
+
+        pending = await loop.run_in_executor(self._exec, launch)
+        return await loop.run_in_executor(self._telemetry, fetch, pending)
 
     async def snapshot(self) -> np.ndarray:
         loop = asyncio.get_running_loop()
@@ -531,11 +503,17 @@ class EngineRunner:
         loop = asyncio.get_running_loop()
 
         def begin():
-            tracker = self.engine.ckpt
-            epoch, gids = tracker.take()
-            if gids.shape[0] == 0:
-                return epoch, gids, None
-            return epoch, gids, self.engine.checkpoint_begin(gids, now_ms)
+            with tracing.stage("ckpt_launch", self.metrics) as st:
+                tracker = self.engine.ckpt
+                epoch, gids = tracker.take()
+                st.note(blocks=int(gids.shape[0]))
+                if gids.shape[0] == 0:
+                    return epoch, gids, None
+                return epoch, gids, self.engine.checkpoint_begin(gids, now_ms)
+
+        def fetch(pending):
+            with tracing.stage("ckpt_fetch", self.metrics):
+                return self.engine.checkpoint_finish(pending)
 
         epoch, gids, pending = await loop.run_in_executor(self._exec, begin)
         if pending is None:
@@ -549,9 +527,7 @@ class EngineRunner:
             self._ckpt = ThreadPoolExecutor(
                 max_workers=1, thread_name_prefix="ckpt"
             )
-        fps, slots = await loop.run_in_executor(
-            self._ckpt, lambda: self.engine.checkpoint_finish(pending)
-        )
+        fps, slots = await loop.run_in_executor(self._ckpt, fetch, pending)
         return epoch, gids, fps, slots
 
     async def checkpoint_snapshot(self):
@@ -637,10 +613,14 @@ class EngineRunner:
         )
 
     async def maybe_grow(self, **kw) -> bool:
+        """The maintenance tick's engine-thread job (it counts live keys)."""
         loop = asyncio.get_running_loop()
-        return await loop.run_in_executor(
-            self._exec, lambda: self.engine.maybe_grow(**kw)
-        )
+
+        def run():
+            with tracing.stage("maintenance", self.metrics):
+                return self.engine.maybe_grow(**kw)
+
+        return await loop.run_in_executor(self._exec, run)
 
     def snapshot_sync(self) -> np.ndarray:
         """Synchronous snapshot for shutdown paths with no running loop."""

@@ -14,8 +14,11 @@ from __future__ import annotations
 import contextvars
 import secrets
 import threading
+import time
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Mapping, Optional
+
+from jax.profiler import TraceAnnotation
 
 TRACEPARENT_KEY = "traceparent"
 _FLAG_SAMPLED = 0x01
@@ -129,6 +132,101 @@ def record_span(
         )
 
 
+# ------------------------------------------------------------ stage timing
+# ONE way to time a stage of the serving path (docs/tracing.md "Spans on the
+# profiler's clock"). A `stage` block takes one perf_counter interval on the
+# thread that does the work and hands it to three readers: the
+# gubernator_tpu_stage_duration{stage} histogram (exemplar = the parent
+# trace), the JAX profiler (a `gub:<stage>` host span on the device trace's
+# clock, when a profile is being taken) and the OTLP exporter (a child span,
+# when one is set). Nothing switches it: with no profile running and no
+# exporter it costs the clock pair, one flag test and the histogram sample.
+
+
+@dataclass
+class Dispatch:
+    """One batcher dispatch as its stages see it: `seq` and `rows` ride on
+    every profiler span under it (`dispatch=<seq>` joins gub:put/issue/
+    fetch of one flush across threads), `span` parents the exported child
+    spans (None without an exporter), and `work_s` adds up the intervals
+    timed under it, so the batcher can state the dispatch's self time
+    (`dispatch_wait`: executor hops and loop wake-ups)."""
+
+    seq: int
+    rows: int
+    span: Optional[SpanContext] = None
+    work_s: float = 0.0
+
+
+def observe(stage_name: str, metrics, dt_s: float,
+            span: Optional[SpanContext] = None) -> None:
+    """Record an interval that has already been measured: the histogram
+    sample and, when an exporter is set, the parent's trace_id as the
+    sample's OpenMetrics exemplar and a child span under `span` (wall-clock
+    ns derived from the same interval). Without an exporter the trace_id
+    would resolve to nothing, and a dozen exemplars an RPC are not free on
+    the event loop. Direct callers are the stages that are waits or
+    differences (queue, door_wait, dispatch_wait, ...), which wrap no work
+    and so get no profiler span."""
+    traced = span is not None and exporter is not None
+    if metrics is not None:
+        child = metrics.stage_child(stage_name)
+        if traced:
+            child.observe(dt_s, exemplar={"trace_id": span.trace_id})
+        else:
+            child.observe(dt_s)
+    if traced and dt_s > 0:
+        # (an inline parse's door_wait is 0 by definition: a sample, no span)
+        end_ns = time.time_ns()
+        record_span(
+            stage_name, new_span(span), span.span_id,
+            end_ns - int(dt_s * 1e9), end_ns,
+        )
+
+
+class stage:
+    """`with tracing.stage("put", metrics, disp=disp): ...` around work, on
+    the thread that does it. `span` (a request's) or `disp` (a dispatch's
+    context) names the parent; keyword `stats` and later `note()`s become
+    the profiler span's stats. `dt` holds the interval after the block.
+    With `metrics=None` the block is a profiler span and a clock only."""
+
+    __slots__ = ("name", "metrics", "span", "disp", "stats", "dt", "_t0", "_ann")
+
+    def __init__(self, name: str, metrics=None, span: Optional[SpanContext] = None,
+                 disp: Optional[Dispatch] = None, **stats):
+        self.name, self.metrics, self.span, self.disp = name, metrics, span, disp
+        self.stats = stats
+        self.dt = 0.0
+        self._ann = None
+
+    def __enter__(self) -> "stage":
+        if TraceAnnotation.is_enabled():
+            stats, disp = self.stats, self.disp
+            if disp is not None:
+                stats = dict(stats, dispatch=disp.seq, rows=disp.rows)
+            self._ann = TraceAnnotation("gub:" + self.name, **stats)
+            self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def note(self, **stats) -> None:
+        """Stats known only once the work is under way (a window's rows)."""
+        if self._ann is not None:
+            self._ann.set_metadata(**stats)
+
+    def __exit__(self, *exc) -> bool:
+        self.dt = dt = time.perf_counter() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        disp = self.disp
+        if disp is not None:
+            disp.work_s += dt
+        observe(self.name, self.metrics, dt,
+                disp.span if disp is not None else self.span)
+        return False
+
+
 def current_span() -> Optional[SpanContext]:
     return _current.get()
 
@@ -146,8 +244,6 @@ def start_scope(name: str, parent: Optional[SpanContext] = None):
     """Begin a scope: set the current span (child of parent or of the ambient
     span) and return a Scope to pass to end_scope. The
     tracing.StartNamedScope analog."""
-    import time
-
     eff_parent = parent if parent is not None else _current.get()
     span = new_span(eff_parent)
     if span_hook is not None:
@@ -172,8 +268,6 @@ def end_scope(scope) -> None:
         # honor the W3C sampled flag: traces sampled out upstream
         # (traceparent ...-00) must not produce orphan partial traces here
         if exporter is not None and scope.span.flags & 0x01:
-            import time
-
             exporter.record(
                 scope.name, scope.span, scope.parent_span_id,
                 scope.start_ns, time.time_ns(),

@@ -257,10 +257,10 @@ class StubRunner:
         self.gate: "asyncio.Event | None" = None
         self.dispatch_rows = []
 
-    async def check_wire(self, parts, span=None):
+    async def check_wire(self, parts, disp=None):
         return None  # force the columns path
 
-    async def check(self, cols, now_ms=None, span=None):
+    async def check(self, cols, now_ms=None, disp=None):
         self.dispatch_rows.append(cols.fp.shape[0])
         if self.gate is not None and len(self.dispatch_rows) == 1:
             await self.gate.wait()
@@ -355,6 +355,9 @@ async def test_queue_gauge_set_once_per_flush():
         def __getattr__(self, name):
             class _Noop:
                 def labels(self, **kw):
+                    return self
+
+                def __call__(self, *a):  # metrics.stage_child(stage)
                     return self
 
                 def observe(self, v, exemplar=None):

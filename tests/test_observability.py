@@ -291,6 +291,257 @@ async def test_request_spans_link_to_shared_dispatch_span():
         await d.close()
 
 
+# ------------------------------------------- one primitive, budgets, profiler
+
+
+def _stage_sums(daemon):
+    """{stage: (sum seconds, count)} of gubernator_tpu_stage_duration."""
+    from prometheus_client.parser import text_string_to_metric_families
+
+    out = {}
+    for fam in text_string_to_metric_families(daemon.metrics.render().decode()):
+        for smp in fam.samples:
+            if smp.name.startswith("gubernator_tpu_stage_duration_"):
+                kind = smp.name.rsplit("_", 1)[1]
+                if kind in ("sum", "count"):
+                    st = out.setdefault(smp.labels["stage"], [0.0, 0.0])
+                    st[kind == "count"] = smp.value
+    return out
+
+
+def _raw_request(tag, n):
+    from gubernator_tpu.proto import gubernator_pb2 as pb
+
+    return pb.GetRateLimitsReq(requests=[
+        pb.RateLimitReq(name="bud", unique_key=f"{tag}-{i}", hits=1,
+                        limit=1000, duration=60_000)
+        for i in range(n)
+    ]).SerializeToString()
+
+
+def test_stage_primitive_feeds_histogram_dispatch_and_exporter():
+    """One interval, three readers: the histogram sample (through a metrics
+    object), the Dispatch's work sum, the exporter's child span; a renamed
+    stage lands under its new label; without any of them it is a clock."""
+    from gubernator_tpu.service.metrics import DaemonMetrics
+
+    m, exp, old = DaemonMetrics(), StubExporter(), tracing.exporter
+    tracing.set_exporter(exp)
+    try:
+        disp = tracing.Dispatch(seq=7, rows=3, span=tracing.new_span())
+        with tracing.stage("put", m, disp=disp) as st:
+            pass
+        with tracing.stage("put", m, disp=disp) as miss:
+            miss.name = "put_miss"
+        assert disp.work_s == pytest.approx(st.dt + miss.dt)
+        req = tracing.new_span()
+        tracing.observe("door_wait", m, 0.25, req)
+    finally:
+        tracing.set_exporter(old)
+    with tracing.stage("bare") as bare:  # no metrics, no parent, no exporter
+        pass
+    assert bare.dt >= 0.0
+    fams = {f.name: f for f in m.registry.collect()}
+    counts = {
+        s.labels["stage"]: s.value
+        for s in fams["gubernator_tpu_stage_duration"].samples
+        if s.name.endswith("_count")
+    }
+    assert counts == {"put": 1, "put_miss": 1, "door_wait": 1}
+    by_name = {s["name"]: s for s in exp.spans}
+    assert set(by_name) == {"put", "put_miss", "door_wait"}
+    assert by_name["put"]["parent"] == disp.span.span_id
+    assert by_name["put"]["trace_id"] == disp.span.trace_id
+    assert by_name["door_wait"]["parent"] == req.span_id
+    assert by_name["door_wait"]["end"] - by_name["door_wait"]["start"] == 250_000_000
+
+
+@async_test
+async def test_request_and_dispatch_budgets_close():
+    """The stages account for the time they claim to: a raw RPC's lines
+    (parse, route, batch_wait, respond, encode) cover >= 90% of `request`
+    and never more than it, `door_wait` is a part of parse+encode, and a dispatch is
+    exactly its work stages plus its self time."""
+    from gubernator_tpu.service.daemon import Daemon
+
+    d = await Daemon.spawn(daemon_config())
+    try:
+        # small RPCs parse inline, 200-item RPCs cross the door pool twice
+        assert len(_raw_request("x", 200)) >= d.DOOR_OFFLOAD_BYTES
+        for n in (1, 200, 7, 200):  # compile the shapes outside the count
+            await d.get_rate_limits_raw(_raw_request(f"w{n}", n))
+        s0 = _stage_sums(d)
+        b0 = d.batcher.debug()
+        for wave in range(30):
+            await asyncio.gather(*(
+                d.get_rate_limits_raw(
+                    _raw_request(f"{wave}-{j}", 200 if j % 4 == 0 else 1 + j)
+                )
+                for j in range(10)
+            ))
+        s1 = _stage_sums(d)
+        b1 = d.batcher.debug()
+    finally:
+        await d.close()
+
+    def delta(stage, k=0):
+        return s1.get(stage, (0, 0))[k] - s0.get(stage, (0, 0))[k]
+
+    assert delta("request", 1) == 300
+    assert delta("door_wait", 1) == 300 and delta("route", 1) == 300
+    request = delta("request")
+    lines = sum(delta(x) for x in
+                ("parse", "route", "batch_wait", "respond", "encode"))
+    assert 0.9 * request <= lines <= request
+    assert delta("respond", 1) == 300
+    assert 0.0 < delta("door_wait") <= delta("parse") + delta("encode")
+    n_disp = b1["dispatches"] - b0["dispatches"]
+    assert n_disp >= 30 and b1["requests"] - b0["requests"] == 300
+    assert delta("dispatch", 1) == delta("dispatch_wait", 1) == n_disp
+    work = sum(delta(x) for x in ("put", "put_miss", "issue", "fetch"))
+    assert work + delta("dispatch_wait") == pytest.approx(
+        delta("dispatch"), rel=0.01
+    )
+    assert 0.0 < delta("dispatch_wait") < delta("dispatch")
+    assert delta("close", 1) >= n_disp
+
+
+@async_test
+async def test_profiler_trace_holds_stage_spans_joined_by_dispatch(tmp_path):
+    """With a jax.profiler trace running (the benchmark launcher's options)
+    the stages are host spans on the profiler's clock: put, issue and fetch
+    of one flush carry the same `dispatch` stat, on three threads, and the
+    window's close says what closed it and how long its oldest entry waited."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    from gubernator_tpu.service.daemon import Daemon
+
+    d = await Daemon.spawn(daemon_config())
+    try:
+        await d.get_rate_limits_raw(_raw_request("warm", 16))
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 1
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            for wave in range(4):
+                await asyncio.gather(*(
+                    d.get_rate_limits_raw(_raw_request(f"p{wave}-{j}", 16))
+                    for j in range(4)
+                ))
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        await d.close()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    by_stage = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("gub:"):
+                    by_stage.setdefault(ev.name[4:], []).append(
+                        (dict(ev.stats), ev.start_ns, ev.duration_ns, line.name)
+                    )
+    assert {"close", "put", "issue", "fetch", "apply", "parse",
+            "encode"} <= set(by_stage), sorted(by_stage)
+    seqs = {
+        name: {st["dispatch"]: (t0, t0 + dur) for st, t0, dur, _ln in by_stage[name]}
+        for name in ("put", "issue", "fetch")
+    }
+    joined = set(seqs["put"]) & set(seqs["issue"]) & set(seqs["fetch"])
+    assert joined, seqs
+    for seq in joined:  # one flush: put ends before issue ends before fetch
+        assert seqs["put"][seq][1] <= seqs["issue"][seq][1] <= seqs["fetch"][seq][1]
+    assert all(st["rows"] >= 16 for st, *_ in by_stage["put"])
+    close = [st for st, *_ in by_stage["close"]]
+    assert all(c["reason"] in ("rows", "bytes", "idle", "slot", "expire")
+               for c in close)
+    assert any(c.get("waited_us", -1) >= 0 and c.get("rows", 0) >= 16
+               for c in close)
+
+
+@async_test
+async def test_metrics_scrape_counts_live_keys_on_the_device(monkeypatch):
+    """GET /metrics no longer moves the table: the live-key count is one
+    device program (compiled in warm-up) whose integer is fetched, and it
+    is still exact, after inserts and after expiry. The three series that
+    repeated others are gone."""
+    import aiohttp
+
+    from gubernator_tpu.ops import table2
+    from gubernator_tpu.service.daemon import Daemon
+
+    calls = {"device": 0, "host": 0}
+    dev, slots = table2.live_count_device, table2._live_slots
+
+    def count_device(*a, **k):
+        calls["device"] += 1
+        return dev(*a, **k)
+
+    def count_slots(xp, *a, **k):
+        calls["host"] += xp is np
+        return slots(xp, *a, **k)
+
+    d = await Daemon.spawn(daemon_config(telemetry_interval_ms=0.0))
+    monkeypatch.setattr(table2, "live_count_device", count_device)
+    monkeypatch.setattr(table2, "_live_slots", count_slots)
+    client = V1Client(d.conf.grpc_address)
+
+    async def scrape(session):
+        async with session.get(f"http://{d.conf.http_address}/metrics") as r:
+            assert r.status == 200
+            text = await r.text()
+        (line,) = [ln for ln in text.splitlines()
+                   if ln.startswith("gubernator_cache_size ")]
+        return text, float(line.split()[1])
+
+    try:
+        await client.get_rate_limits(
+            [RateLimitRequest(name="lc", unique_key=f"long{i}", hits=1,
+                              limit=10, duration=60_000) for i in range(20)]
+            + [RateLimitRequest(name="lc", unique_key=f"short{i}", hits=1,
+                                limit=10, duration=1_000) for i in range(12)]
+        )
+        async with aiohttp.ClientSession() as s:
+            text, live = await scrape(s)
+            assert live == 32 and calls == {"device": 1, "host": 0}
+            await asyncio.sleep(1.2)
+            _text, live = await scrape(s)
+            assert live == 20 and calls == {"device": 2, "host": 0}
+        assert 'gubernator_tpu_stage_duration_count{stage="live_count"}' in text
+        for gone in ("gubernator_tpu_queue_wait_seconds",
+                     "gubernator_tpu_dispatch_duration",
+                     "gubernator_table_hbm_bytes_per_decision"):
+            assert gone not in text
+    finally:
+        await client.close()
+        await d.close()
+
+
+def test_live_count_on_device_matches_the_host_count_sharded():
+    """The same predicate on both sides: the device count over a mesh's
+    sharded table equals the NumPy count over a host copy of its rows."""
+    from gubernator_tpu.ops.table2 import Table2, live_count2
+    from gubernator_tpu.parallel import make_mesh
+    from gubernator_tpu.parallel.sharded import ShardedEngine
+
+    rng = np.random.default_rng(5)
+    eng = ShardedEngine(make_mesh(8), capacity_per_shard=1 << 10,
+                        write_mode="xla")
+    eng.check_columns(_mixed_cols(rng, 4000), now_ms=NOW)
+    for now in (NOW, NOW + 1_000, NOW + 200_000):
+        host = live_count2(
+            Table2(rows=np.asarray(eng.table.rows), layout=eng.table.layout), now
+        )
+        assert eng.live_count(now) == host
+    assert eng.live_count(NOW) > eng.live_count(NOW + 200_000) > 0
+
+
 # --------------------------------------------------------------- debug plane
 
 
@@ -332,8 +583,13 @@ async def test_debug_endpoints_schema():
         assert set(b) >= {
             "pending_rows", "workers", "workers_alive", "inflight",
             "fused_dispatches", "column_dispatches", "adaptive_closes",
-            "close_reasons",
+            "close_reasons", "dispatches", "requests",
         }
+        # every _dispatch and every entry it carried, beside the engine's
+        # passes; the modelled bytes-per-decision field is gone
+        assert b["dispatches"] >= 1 and b["requests"] >= b["dispatches"]
+        assert pipeline["engine"]["dispatches"] >= b["dispatches"]
+        assert "hbm_bytes_per_decision" not in pipeline["engine"]
         assert set(b["close_reasons"]) == {"rows", "bytes", "idle", "slot"}
         assert pipeline["engine"]["kind"] == "LocalEngine"
         assert peers["self"] == d.conf.advertise_address

@@ -76,10 +76,10 @@ class GatedRunner:
         self.dispatch_rows = []
         self.dispatch_tiers = []  # leading row's tier, per dispatch
 
-    async def check_wire(self, parts, span=None):
+    async def check_wire(self, parts, disp=None):
         return None
 
-    async def check(self, cols, now_ms=None, span=None):
+    async def check(self, cols, now_ms=None, disp=None):
         self.dispatch_rows.append(cols.fp.shape[0])
         self.dispatch_tiers.append(priority_tier(int(cols.behavior[0])))
         if len(self.dispatch_rows) == 1:

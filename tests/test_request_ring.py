@@ -34,9 +34,8 @@ COMPILE_EVENT = "/jax/compilation_cache/compile_requests_use_cache"
 
 
 class StubRunner:
-    """The minimal runner surface the ring drives: check_wire + the stage
-    observer. Echoes the submitted payload so reordering is detectable,
-    and tracks concurrent in-flight dispatches so the occupancy bound is
+    """The minimal runner surface the ring drives: check_wire + check.
+    Echoes the submitted payload so reordering is detectable, and tracks concurrent in-flight dispatches so the occupancy bound is
     assertable."""
 
     def __init__(self, delay=0.0, fail_on=None, fuse=True):
@@ -48,10 +47,7 @@ class StubRunner:
         self.max_active = 0
         self.check_calls = 0
 
-    def _observe_stage(self, stage, t0, span=None):
-        pass
-
-    async def check_wire(self, parts, now_ms=None, span=None,
+    async def check_wire(self, parts, now_ms=None, disp=None,
                          launch_path="xla"):
         assert launch_path == "ring"
         if not self.fuse:
@@ -68,7 +64,7 @@ class StubRunner:
         finally:
             self.active -= 1
 
-    async def check(self, cols, now_ms=None, span=None, launch_path="xla"):
+    async def check(self, cols, now_ms=None, disp=None, launch_path="xla"):
         assert launch_path == "ring"
         self.check_calls += 1
         return ("cols-rc", cols)
