@@ -14,9 +14,10 @@ Three serving-plane mechanics live here (docs/latency.md "Serving plane"):
 * **Bounded ring.** Enqueues append to a deque capped at `max_queue_rows`;
   past the cap, callers await drain progress (backpressure) instead of
   growing an unbounded queue whose tail latency nobody sees until OOM.
-* **N workers.** Each worker forms a chunk, dispatches it, and slices the
-  coalesced response back onto its callers' futures — so chunk formation
-  and response fan-out for dispatch K run in parallel with dispatch K+1's,
+* **N workers.** Each worker forms a chunk and hands it to the runner; the
+  dispatch's one crossing back onto the loop (EngineRunner._run_chain)
+  slices the coalesced response onto its callers' futures, and the worker
+  goes on to the next chunk — so dispatch K+1 forms while K is in flight,
   keeping the engine's depth-N pipeline saturated instead of starving it
   behind one event-loop task.
 * **Adaptive window.** Under load the window closes on accumulated
@@ -41,8 +42,10 @@ Three serving-plane mechanics live here (docs/latency.md "Serving plane"):
   traffic by staying under a raw row budget. With the knob unset and no
   inbound deadline, behavior is exactly the legacy unbounded backpressure.
 
-NO_BATCHING items bypass the window (reference peer_client.go:126-162's fast
-path) by calling the runner directly.
+NO_BATCHING (reference peer_client.go:126-162's fast path) has a meaning
+only toward peers: `service/peer_client.py` sends such an item on its own
+instead of holding it for a peer batch. At this door every check goes through
+the window, which an idle engine closes at once.
 """
 
 from __future__ import annotations
@@ -62,7 +65,7 @@ from gubernator_tpu.ops.batch import (
 )
 from gubernator_tpu.ops.engine import ms_now
 from gubernator_tpu.service import deadline as deadline_mod
-from gubernator_tpu.service.wire import WireBatch, concat_columns
+from gubernator_tpu.service.wire import WireBatch
 from gubernator_tpu.types import (
     CASCADE_LEVEL_MASK,
     CASCADE_LEVEL_SHIFT,
@@ -638,8 +641,8 @@ class Batcher:
     # ------------------------------------------------------------ dispatch
     def _observe_dispatch(self, t0: float, disp) -> None:
         """The dispatch's budget lines: the whole of it, and its self time
-        (what put/issue/fetch did not cover: three executor hops and the
-        loop's wake-ups between them)."""
+        (what put/issue/fetch did not cover: the hand-offs prep → engine →
+        fetch thread, and the one crossing back onto the loop)."""
         dt = time.perf_counter() - t0
         tracing.observe("dispatch", self.metrics, dt)
         tracing.observe(
@@ -661,103 +664,120 @@ class Batcher:
         disp = tracing.Dispatch(
             self.dispatches, sum(e.rows for e in batch), disp_span
         )
-        fused = False
-        try:
-            oldest = min(e.t_enq for e in batch)
-            tracing.observe("queue", self.metrics, t0 - oldest, disp_span)
-            # per-enqueue queue wait (the shed policy's p99 story): "queue"
-            # above is per-CHUNK (its oldest member); these are per admitted
-            # batch, the distribution deadlines cut into
-            for e in batch:
-                tracing.observe("queue_wait", self.metrics, t0 - e.t_enq)
-            payloads = [e.payload for e in batch]
-            rc = None
-            if all(isinstance(p, WireBatch) for p in payloads):
-                if self.ring is not None:
-                    # ring path: stage the chunk into a request-ring slot;
-                    # the persistent serving loop consumes it in ticket
-                    # order through the SAME runner surface (byte-identical
-                    # responses). A ring racing drain falls through to the
-                    # direct path below — zero loss.
-                    from gubernator_tpu.service.ring import RingClosed
+        oldest = min(e.t_enq for e in batch)
+        payloads = [e.payload for e in batch]
+        wire = all(isinstance(p, WireBatch) for p in payloads)
+        answered = ringed = False
 
-                    try:
-                        rc = await self.ring.submit(payloads, disp=disp)
-                        self.ring_dispatches += 1
-                        fused = True
-                    except RingClosed:
-                        rc = None
-                if rc is None:
-                    # fused path: pre-packed parser lanes scatter straight
-                    # into one staged compact grid
-                    # (ops/engine.prepare_check_wire) — the request bytes
-                    # are traversed exactly once end to end
-                    rc = await self.runner.check_wire(payloads, disp=disp)
-                    if rc is not None:
-                        self.fused_dispatches += 1
-                        fused = True
-                    else:
-                        self.wire_fallbacks += 1
-            if rc is None:
-                cat = concat_columns([_payload_cols(p) for p in payloads])
-                rc = await self.runner.check(cat, disp=disp)
-                self.column_dispatches += 1
-        except Exception as exc:  # pragma: no cover - defensive
-            for e in batch:
-                if not e.fut.done():
-                    e.fut.set_exception(exc)
-            self._observe_dispatch(t0, disp)
-            return
-        finally:
+        def answer(rc, exc, fused) -> None:
+            """The dispatch's end, on the loop thread. The runner calls it
+            from the dispatch's one crossing back (EngineRunner._run_chain),
+            so the callers' futures resolve in that same callback, before
+            this worker's coroutine is resumed."""
+            nonlocal answered
+            if answered:
+                return
+            answered = True
             self._inflight -= 1
             self._note_drained(sum(e.cost for e in batch))
             if self._full is not None:
                 # a slot freed: a worker holding its window open should
                 # re-evaluate — refilling the pipeline beats waiting
                 self._full.set()
-        if self.metrics is not None:
-            self.metrics.batch_send_duration.observe(
-                time.perf_counter() - t0,
-                exemplar=(
-                    {"trace_id": disp_span.trace_id} if disp_span else None
-                ),
-            )
-        if disp_span is not None:
-            # request spans → dispatch span links (registered while their
-            # scopes are still open: the futures resolve after this), and
-            # the dispatch span itself links back to every distinct request
-            req_spans = [e.span for e in batch if e.span is not None]
-            for rs in req_spans:
-                tracing.add_span_link(rs, disp_span)
-            end_ns = time.time_ns()
-            tracing.record_span(
-                "dispatch", disp_span, "",
-                end_ns - int((time.perf_counter() - oldest) * 1e9), end_ns,
-                attributes={
-                    "batch.seq": disp.seq,
-                    "batch.rows": disp.rows,
-                    "batch.requests": len(batch),
-                    "batch.fused": fused,
-                },
-                links=req_spans,
-            )
-        off = 0
-        for e in batch:
-            payload, fut = e.payload, e.fut
-            n = e.rows
-            sl = slice(off, off + n)
-            if not fut.done():
-                fut.set_result(
-                    ResponseColumns(
-                        status=rc.status[sl],
-                        limit=rc.limit[sl],
-                        remaining=rc.remaining[sl],
-                        reset_time=rc.reset_time[sl],
-                        err=rc.err[sl],
-                    )
+            if exc is not None:
+                for e in batch:
+                    if not e.fut.done():
+                        e.fut.set_exception(exc)
+                self._observe_dispatch(t0, disp)
+                return
+            if ringed:
+                self.ring_dispatches += 1
+            elif fused:
+                self.fused_dispatches += 1
+            else:
+                self.column_dispatches += 1
+                if wire:
+                    self.wire_fallbacks += 1
+            if self.metrics is not None:
+                self.metrics.batch_send_duration.observe(
+                    time.perf_counter() - t0,
+                    exemplar=(
+                        {"trace_id": disp_span.trace_id} if disp_span else None
+                    ),
                 )
-            off += n
-        self._observe_dispatch(t0, disp)
+            if disp_span is not None:
+                # request spans → dispatch span links (registered while
+                # their scopes are still open: the futures resolve after
+                # this), and the dispatch span itself links back to every
+                # distinct request
+                req_spans = [e.span for e in batch if e.span is not None]
+                for rs in req_spans:
+                    tracing.add_span_link(rs, disp_span)
+                end_ns = time.time_ns()
+                tracing.record_span(
+                    "dispatch", disp_span, "",
+                    end_ns - int((time.perf_counter() - oldest) * 1e9), end_ns,
+                    attributes={
+                        "batch.seq": disp.seq,
+                        "batch.rows": disp.rows,
+                        "batch.requests": len(batch),
+                        "batch.fused": fused,
+                    },
+                    links=req_spans,
+                )
+            off = 0
+            for e in batch:
+                sl = slice(off, off + e.rows)
+                if not e.fut.done():
+                    e.fut.set_result(
+                        ResponseColumns(
+                            status=rc.status[sl],
+                            limit=rc.limit[sl],
+                            remaining=rc.remaining[sl],
+                            reset_time=rc.reset_time[sl],
+                            err=rc.err[sl],
+                        )
+                    )
+                off += e.rows
+            self._observe_dispatch(t0, disp)
+
+        try:
+            tracing.observe("queue", self.metrics, t0 - oldest, disp_span)
+            # per-enqueue queue wait (the shed policy's p99 story): "queue"
+            # above is per-CHUNK (its oldest member); these are per admitted
+            # batch, the distribution deadlines cut into
+            for e in batch:
+                tracing.observe("queue_wait", self.metrics, t0 - e.t_enq)
+            if wire and self.ring is not None:
+                # ring path: stage the chunk into a request-ring slot; the
+                # persistent serving loop consumes it in ticket order
+                # through the SAME runner surface (byte-identical
+                # responses). A ring racing drain falls through to the
+                # direct path below — zero loss.
+                from gubernator_tpu.service.ring import RingClosed
+
+                try:
+                    rc = await self.ring.submit(payloads, disp=disp)
+                    ringed = True
+                    return answer(rc, None, True)
+                except RingClosed:
+                    pass
+            if wire:
+                # fused path: pre-packed parser lanes scatter straight into
+                # one staged compact grid (ops/engine.prepare_check_wire) —
+                # the request bytes are traversed exactly once end to end;
+                # a chunk that cannot fuse is staged as columns by the same
+                # prep job
+                await self.runner.check_wire(payloads, disp=disp, done=answer)
+            else:
+                await self.runner.check(
+                    [_payload_cols(p) for p in payloads], disp=disp,
+                    done=answer,
+                )
+        except Exception as exc:
+            # raised before the runner's chain took the chunk; one raised in
+            # the chain has been answered by its crossing back already
+            answer(None, exc, False)
 
     def arm_overload(self, deadline_ms: float) -> None:
         """(Re)arm or disarm the overload door at runtime. The scenario

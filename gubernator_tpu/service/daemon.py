@@ -1687,6 +1687,10 @@ class Daemon:
         eng = self.engine
         return {
             "batcher": self.batcher.debug(),
+            # event-loop callbacks run for runner dispatches: one each, its
+            # completion (EngineRunner._run_chain); over batcher.dispatches
+            # it says how often a dispatch came back to the loop
+            "runner": {"loop_trips": self.runner.loop_trips},
             # which request parser serves the door: "built"/"reused" = the
             # native extension (compiled by this process / found with a
             # matching source hash), None = the pure-Python fallback

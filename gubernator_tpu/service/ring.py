@@ -77,7 +77,6 @@ import numpy as np
 
 from gubernator_tpu import tracing
 from gubernator_tpu.ops.batch import ResponseColumns
-from gubernator_tpu.service.wire import concat_columns
 
 log = logging.getLogger("gubernator_tpu.ring")
 
@@ -222,15 +221,17 @@ class RequestRing:
     # ------------------------------------------------------- serving loop
     async def _dispatch(self, parts, disp):
         """One slot's dispatch: the exact runner surface the direct path
-        drives. Non-fusable chunks (duplicate keys, non-encodable rows)
-        fall back to the columns path, same as Batcher._dispatch."""
-        rc = await self.runner.check_wire(parts, disp=disp,
-                                          launch_path="ring")
-        if rc is None:
-            self.fallbacks += 1
-            cat = concat_columns([p.cols for p in parts])
-            rc = await self.runner.check(cat, disp=disp, launch_path="ring")
-        return rc
+        drives. Non-fusable chunks (duplicate keys, non-encodable rows) are
+        staged as columns inside the same runner dispatch, same as for
+        Batcher._dispatch; `fallbacks` counts them."""
+
+        def note(_rc, _exc, fused):
+            if not fused:
+                self.fallbacks += 1
+
+        return await self.runner.check_wire(
+            parts, disp=disp, launch_path="ring", done=note
+        )
 
     async def _issue_loop(self) -> None:
         """Walk tickets strictly in order (the persistent kernel's slot

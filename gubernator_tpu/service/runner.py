@@ -19,6 +19,7 @@ import numpy as np
 from gubernator_tpu import tracing
 from gubernator_tpu.ops.batch import RequestColumns, ResponseColumns
 from gubernator_tpu.ops.engine import LocalEngine
+from gubernator_tpu.service.wire import concat_columns
 
 
 # gubernator_tpu_decisions_total label values (types.Algorithm order)
@@ -70,6 +71,9 @@ class EngineRunner:
         # estimate denominated in what a launch actually costs on THIS
         # deployment, not a hand-tuned wall-clock guess.
         self.issue_ewma = 0.0
+        # event-loop callbacks run on behalf of runner dispatches: one per
+        # dispatch, its completion (_run_chain; /v1/debug/pipeline "runner")
+        self.loop_trips = 0
 
     def _count_decisions(self, algo_col) -> None:
         """Per-algorithm decision accounting (the
@@ -90,89 +94,150 @@ class EngineRunner:
                         algorithm=_ALGO_LABELS[v]
                     ).inc(int(c))
 
+    def _run_chain(self, links, parts, done, fused=lambda: False):
+        """One dispatch's way through the worker threads: it leaves the
+        event loop once and comes back once. `links` is a sequence of
+        (executor, fn): the first link runs fn(None), each later one the
+        result of the link before it, and the thread that finished a link
+        submits the next one itself — nothing returns to the loop between
+        stages. The thread that ran the last link makes the dispatch's one
+        `call_soon_threadsafe`. What that runs on the loop thread counts the
+        trip (`loop_trips`) and the decisions of `parts` (the chunk's
+        columns; `algo_counts` is a plain dict, so here and on no worker),
+        calls `done(rc, exc, fused())` if there is one — where the batcher
+        answers its callers, before any coroutine is resumed — and resolves
+        the returned future. An exception in any link (a shut-down executor
+        included) skips the links after it and arrives the same way."""
+        loop = asyncio.get_running_loop()
+        fut = loop.create_future()
+
+        def land(rc, exc):
+            self.loop_trips += 1
+            try:
+                if exc is None:
+                    for cols in parts:
+                        self._count_decisions(cols.algo)
+                if done is not None:
+                    done(rc, exc, fused())
+            finally:
+                if fut.cancelled():  # its awaiter was; nobody is left to tell
+                    pass
+                elif exc is not None:
+                    fut.set_exception(exc)
+                else:
+                    fut.set_result(rc)
+
+        def step(i, value):
+            try:
+                value = links[i][1](value)
+                if i + 1 < len(links):
+                    links[i + 1][0].submit(step, i + 1, value)
+                    return
+            except BaseException as exc:  # all an executor's future carries
+                loop.call_soon_threadsafe(land, None, exc)
+                return
+            loop.call_soon_threadsafe(land, value, None)
+
+        try:
+            links[0][0].submit(step, 0, None)
+        except RuntimeError as exc:  # the first executor is shut down
+            land(None, exc)
+        return fut
+
+    def _stage_columns(self, parts, now_ms, disp):
+        """The `put` stage of a chunk staged as columns (a prep thread)."""
+        from gubernator_tpu.ops.engine import prepare_check_columns
+
+        with tracing.stage("put", self.metrics, disp=disp):
+            prepared = prepare_check_columns(
+                self.engine, concat_columns(parts), now_ms=now_ms
+            )
+        if self.metrics is not None:
+            self._observe_shard_stages()
+        return prepared
+
     async def check(
-        self, cols: RequestColumns, now_ms: Optional[int] = None, disp=None,
-        launch_path: str = "xla",
+        self, cols, now_ms: Optional[int] = None, disp=None,
+        launch_path: str = "xla", done=None,
     ) -> ResponseColumns:
         """Pipelined check when the engine supports the prepare/issue/finish
-        split, else the serial path. Store-configured engines stay serial:
-        write-through ordering and miss-rehydrates must serialize against
-        every same-key dispatch, which interleaved pipelined chunks cannot
-        guarantee — durability trades pipeline throughput. Engines may also
-        veto per batch via `can_pipeline(cols)`; engines whose batches need
-        a custom split (the mesh-global engine's replica/owner fork) provide
-        their own pending type through the prepare_columns/issue_pending/
-        finish_pending hooks instead of vetoing.
+        split, else the serial path. `cols` is one RequestColumns or a list
+        of them that the prep job concatenates (a coalesced chunk). Store-
+        configured engines stay serial: write-through ordering and miss-
+        rehydrates must serialize against every same-key dispatch, which
+        interleaved pipelined chunks cannot guarantee — durability trades
+        pipeline throughput. Engines whose batches need a custom split (the
+        mesh-global engine's replica/owner fork) provide their own pending
+        type through the prepare_columns/issue_pending/finish_pending hooks.
 
         `disp` is the batcher's tracing.Dispatch: each pipeline stage is a
         tracing.stage under it (histogram sample, `gub:<stage>` profiler
         span carrying its `dispatch` number, child span under its trace), so
-        a coalesced flush decomposes per stage in every view."""
-        can = getattr(self.engine, "can_pipeline", None)
+        a coalesced flush decomposes per stage in every view.
+
+        `done(rc, exc, fused)`, when given, is called on the loop thread by
+        the dispatch's one crossing back (`_run_chain`), before this
+        coroutine is resumed: the batcher answers its callers there.
+        `fused` says whether the fused wire staging served the chunk."""
+        parts = [cols] if isinstance(cols, RequestColumns) else cols
         if (
             not getattr(self.engine, "supports_pipeline", False)
             or getattr(self.engine, "store", None) is not None
-            or (can is not None and not can(cols))
         ):
             return await self.check_columns(
-                cols, now_ms=now_ms, launch_path=launch_path
+                concat_columns(parts), now_ms=now_ms, launch_path=launch_path,
+                done=done,
             )
-        self._count_decisions(cols.algo)
-        from gubernator_tpu.ops.engine import prepare_check_columns
-
-        loop = asyncio.get_running_loop()
-
-        def prepare():
-            with tracing.stage("put", self.metrics, disp=disp):
-                prepared = prepare_check_columns(
-                    self.engine, cols, now_ms=now_ms
-                )
-            if self.metrics is not None:
-                self._observe_shard_stages()
-            return prepared
-
-        prepared = await loop.run_in_executor(self._prep, prepare)
-        return await self._issue_and_finish(
-            prepared, disp=disp, launch_path=launch_path
+        return await self._run_chain(
+            ((self._prep, lambda _: self._stage_columns(parts, now_ms, disp)),
+             *self._issue_and_finish(disp, launch_path)),
+            parts, done,
         )
 
     async def check_wire(
-        self, parts, now_ms=None, disp=None, launch_path: str = "xla"
-    ) -> Optional[ResponseColumns]:
+        self, parts, now_ms=None, disp=None, launch_path: str = "xla",
+        done=None,
+    ) -> ResponseColumns:
         """Fused front-door check: pre-parsed WireBatch pieces
         (service/wire.py — native-parser lanes) staged straight into ONE
-        compact ingress grid, no column concat and no HostBatch pack.
-        Returns None when the batch cannot ride the fused path (engine not
-        wire-capable, duplicate keys, non-encodable rows, Store attached) —
-        the caller falls back to the columns path, which is semantically
-        identical."""
+        compact ingress grid, no column concat and no HostBatch pack. A
+        chunk that cannot ride the fused path (duplicate keys, non-encodable
+        rows) is staged again as columns by the same prep job that found it
+        out, which is semantically identical; an engine that is not
+        wire-capable, or has a Store, takes `check` from here. `done` as in
+        `check`: its `fused` says which staging served the chunk."""
         engine = self.engine
+        cols = [p.cols for p in parts]
         if (
             not getattr(engine, "supports_wire_ingress", False)
             or getattr(engine, "store", None) is not None
         ):
-            return None
+            return await self.check(
+                cols, now_ms=now_ms, disp=disp, launch_path=launch_path,
+                done=done,
+            )
         from gubernator_tpu.ops.engine import prepare_check_wire
 
-        loop = asyncio.get_running_loop()
+        fused = True
 
-        def prepare():
+        def prepare(_):
+            nonlocal fused
             with tracing.stage("put", self.metrics, disp=disp) as st:
                 prepared = prepare_check_wire(engine, parts, now_ms=now_ms)
                 if prepared is None:
-                    # the chunk cannot fuse and check() stages it again:
+                    # the chunk cannot fuse and is staged again below:
                     # wasted work under a label of its own, so that `put`
                     # stays the staging that was used
                     st.name = "put_miss"
+            if prepared is None:
+                fused = False
+                prepared = self._stage_columns(cols, now_ms, disp)
             return prepared
 
-        prepared = await loop.run_in_executor(self._prep, prepare)
-        if prepared is None:
-            return None
-        for p in parts:
-            self._count_decisions(p.cols.algo)
-        return await self._issue_and_finish(
-            prepared, disp=disp, launch_path=launch_path
+        return await self._run_chain(
+            ((self._prep, prepare),
+             *self._issue_and_finish(disp, launch_path)),
+            cols, done, lambda: fused,
         )
 
     def _note_issue(self, dt: float) -> None:
@@ -180,19 +245,16 @@ class EngineRunner:
             dt if self.issue_ewma == 0.0 else 0.9 * self.issue_ewma + 0.1 * dt
         )
 
-    async def _issue_and_finish(
-        self, prepared, disp=None, launch_path: str = "xla"
-    ) -> ResponseColumns:
-        """Shared issue/finish halves of the pipelined dispatch: ISSUE on
-        the engine thread (enqueue kernel launches, no fetch), FINISH on a
-        fetch worker (materialize outputs, rare fixups back on the engine
-        thread), stats folded in on the engine thread."""
+    def _issue_and_finish(self, disp=None, launch_path: str = "xla"):
+        """The issue and finish links of a pipelined dispatch (`_run_chain`
+        runs them after the prepare link): ISSUE on the engine thread
+        (enqueue kernel launches, no fetch), FINISH on a fetch worker
+        (materialize outputs, rare fixups back on the engine thread), stats
+        folded in on the engine thread."""
         from gubernator_tpu.ops.engine import (
             finish_check_columns,
             issue_check_columns,
         )
-
-        loop = asyncio.get_running_loop()
 
         def issue(prepared):
             with tracing.stage("issue", self.metrics, disp=disp) as st:
@@ -218,8 +280,7 @@ class EngineRunner:
             self._exec.submit(self._apply, [delta], disp)
             return rc
 
-        pending = await loop.run_in_executor(self._exec, lambda: issue(prepared))
-        return await loop.run_in_executor(self._fetch, lambda: finish(pending))
+        return (self._exec, issue), (self._fetch, finish)
 
     # ------------------------------------------------- fused ring drain
     # (ops/ring_drain.py) — the multi-slot twin of _issue_and_finish: one
@@ -366,12 +427,12 @@ class EngineRunner:
 
     async def check_columns(
         self, cols: RequestColumns, now_ms: Optional[int] = None,
-        launch_path: str = "xla",
+        launch_path: str = "xla", done=None,
     ) -> ResponseColumns:
-        self._count_decisions(cols.algo)
-        loop = asyncio.get_running_loop()
+        """The serial path: the whole check is one engine-thread job, a
+        chain of one link. `done` as in `check`."""
 
-        def run():
+        def run(_):
             rc = self.engine.check_columns(cols, now_ms=now_ms)
             if self.metrics is not None:
                 self.metrics.dispatch_launches.labels(path=launch_path).inc()
@@ -382,7 +443,7 @@ class EngineRunner:
                     self.metrics.observe_global(gs)
             return rc
 
-        return await loop.run_in_executor(self._exec, run)
+        return await self._run_chain(((self._exec, run),), [cols], done)
 
     async def install_columns(self, **kw) -> int:
         loop = asyncio.get_running_loop()

@@ -150,7 +150,8 @@ class Dispatch:
     fetch of one flush across threads), `span` parents the exported child
     spans (None without an exporter), and `work_s` adds up the intervals
     timed under it, so the batcher can state the dispatch's self time
-    (`dispatch_wait`: executor hops and loop wake-ups)."""
+    (`dispatch_wait`: the hand-offs from thread to thread and the one
+    crossing back onto the loop)."""
 
     seq: int
     rows: int

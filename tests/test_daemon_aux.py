@@ -607,9 +607,9 @@ async def test_coalesce_limit_caps_dispatch_size():
     sizes = []
     orig = runner.check  # the batcher's (pipelined) entry point
 
-    async def spy(cols, now_ms=None, disp=None):
-        sizes.append(cols.fp.shape[0])
-        return await orig(cols, now_ms=now_ms, disp=disp)
+    async def spy(cols, **kw):  # a chunk: the list of its entries' columns
+        sizes.append(sum(c.fp.shape[0] for c in cols))
+        return await orig(cols, **kw)
 
     runner.check = spy
     b = Batcher(runner, batch_wait_ms=5.0, coalesce_limit=32)

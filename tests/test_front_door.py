@@ -20,7 +20,11 @@ from gubernator_tpu.ops.engine import LocalEngine, ms_now
 from gubernator_tpu.proto import gubernator_pb2 as pb
 from gubernator_tpu.service.batcher import Batcher
 from gubernator_tpu.service.daemon import Daemon
-from gubernator_tpu.service.wire import WireBatch, wire_batch_from_wire
+from gubernator_tpu.service.wire import (
+    WireBatch,
+    concat_columns,
+    wire_batch_from_wire,
+)
 from gubernator_tpu.types import Behavior
 
 from tests.cluster import daemon_config
@@ -257,21 +261,22 @@ class StubRunner:
         self.gate: "asyncio.Event | None" = None
         self.dispatch_rows = []
 
-    async def check_wire(self, parts, disp=None):
-        return None  # force the columns path
-
-    async def check(self, cols, now_ms=None, disp=None):
+    async def check(self, cols, now_ms=None, disp=None, done=None):
+        cols = concat_columns(cols)
         self.dispatch_rows.append(cols.fp.shape[0])
         if self.gate is not None and len(self.dispatch_rows) == 1:
             await self.gate.wait()
         n = cols.fp.shape[0]
-        return ResponseColumns(
+        rc = ResponseColumns(
             status=np.zeros(n, dtype=np.int32),
             limit=cols.limit.copy(),
             remaining=cols.limit - cols.hits,
             reset_time=np.zeros(n, dtype=np.int64),
             err=np.zeros(n, dtype=np.int8),
         )
+        if done is not None:
+            done(rc, None, False)
+        return rc
 
 
 @async_test
@@ -402,9 +407,17 @@ async def test_runner_check_wire_matches_columns():
             np.testing.assert_array_equal(
                 getattr(rc1, f), getattr(rc2, f), err_msg=f
             )
-        # full-width engine declines
+        # a full-width engine takes the columns path, and says so
         r_full = EngineRunner(LocalEngine(capacity=4096, wire="full"))
-        assert await r_full.check_wire([wb], now_ms=now) is None
+        paths = []
+        rc3 = await r_full.check_wire(
+            [wb], now_ms=now, done=lambda _rc, _exc, fused: paths.append(fused)
+        )
+        assert paths == [False]
+        for f in ResponseColumns._fields:
+            np.testing.assert_array_equal(
+                getattr(rc3, f), getattr(rc2, f), err_msg=f
+            )
         r_full.close()
     finally:
         r_wire.close()

@@ -294,12 +294,12 @@ async def test_request_spans_link_to_shared_dispatch_span():
 # ------------------------------------------- one primitive, budgets, profiler
 
 
-def _stage_sums(daemon):
+def _stage_sums(metrics):
     """{stage: (sum seconds, count)} of gubernator_tpu_stage_duration."""
     from prometheus_client.parser import text_string_to_metric_families
 
     out = {}
-    for fam in text_string_to_metric_families(daemon.metrics.render().decode()):
+    for fam in text_string_to_metric_families(metrics.render().decode()):
         for smp in fam.samples:
             if smp.name.startswith("gubernator_tpu_stage_duration_"):
                 kind = smp.name.rsplit("_", 1)[1]
@@ -370,7 +370,7 @@ async def test_request_and_dispatch_budgets_close():
         assert len(_raw_request("x", 200)) >= d.DOOR_OFFLOAD_BYTES
         for n in (1, 200, 7, 200):  # compile the shapes outside the count
             await d.get_rate_limits_raw(_raw_request(f"w{n}", n))
-        s0 = _stage_sums(d)
+        s0 = _stage_sums(d.metrics)
         b0 = d.batcher.debug()
         for wave in range(30):
             await asyncio.gather(*(
@@ -379,7 +379,7 @@ async def test_request_and_dispatch_budgets_close():
                 )
                 for j in range(10)
             ))
-        s1 = _stage_sums(d)
+        s1 = _stage_sums(d.metrics)
         b1 = d.batcher.debug()
     finally:
         await d.close()

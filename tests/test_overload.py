@@ -33,6 +33,7 @@ from gubernator_tpu.proto import gubernator_pb2 as pb
 from gubernator_tpu.service import deadline as deadline_mod
 from gubernator_tpu.service.batcher import Batcher
 from gubernator_tpu.service.daemon import Daemon
+from gubernator_tpu.service.wire import concat_columns
 from gubernator_tpu.types import priority_tier, with_cascade_level, with_priority
 
 from tests.cluster import daemon_config
@@ -76,22 +77,23 @@ class GatedRunner:
         self.dispatch_rows = []
         self.dispatch_tiers = []  # leading row's tier, per dispatch
 
-    async def check_wire(self, parts, disp=None):
-        return None
-
-    async def check(self, cols, now_ms=None, disp=None):
+    async def check(self, cols, now_ms=None, disp=None, done=None):
+        cols = concat_columns(cols)
         self.dispatch_rows.append(cols.fp.shape[0])
         self.dispatch_tiers.append(priority_tier(int(cols.behavior[0])))
         if len(self.dispatch_rows) == 1:
             await self.gate.wait()
         n = cols.fp.shape[0]
-        return ResponseColumns(
+        rc = ResponseColumns(
             status=np.zeros(n, dtype=np.int32),
             limit=cols.limit.copy(),
             remaining=cols.limit - cols.hits,
             reset_time=np.zeros(n, dtype=np.int64),
             err=np.zeros(n, dtype=np.int8),
         )
+        if done is not None:
+            done(rc, None, False)
+        return rc
 
 
 def _shed_all(rc: ResponseColumns) -> bool:

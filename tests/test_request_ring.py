@@ -34,24 +34,27 @@ COMPILE_EVENT = "/jax/compilation_cache/compile_requests_use_cache"
 
 
 class StubRunner:
-    """The minimal runner surface the ring drives: check_wire + check.
+    """The minimal runner surface the ring drives: check_wire (which stages
+    a chunk that cannot fuse as columns itself, and tells `done` so).
     Echoes the submitted payload so reordering is detectable, and tracks concurrent in-flight dispatches so the occupancy bound is
     assertable."""
 
     def __init__(self, delay=0.0, fail_on=None, fuse=True):
         self.delay = delay
         self.fail_on = fail_on  # payload value that raises
-        self.fuse = fuse  # False => check_wire returns None (fallback)
+        self.fuse = fuse  # False => the chunk rides the columns path
         self.launch_order = []
         self.active = 0
         self.max_active = 0
         self.check_calls = 0
 
     async def check_wire(self, parts, now_ms=None, disp=None,
-                         launch_path="xla"):
+                         launch_path="xla", done=None):
         assert launch_path == "ring"
         if not self.fuse:
-            return None
+            rc = await self.check(parts[0].cols, launch_path=launch_path)
+            done(rc, None, False)
+            return rc
         self.active += 1
         self.max_active = max(self.max_active, self.active)
         self.launch_order.append(parts[0])
@@ -172,25 +175,20 @@ def test_ring_drain_without_traffic():
 
 
 def test_ring_nonfusable_chunk_falls_back_to_columns_path():
-    """A chunk check_wire rejects rides runner.check (the columns path)
-    INSIDE the ring dispatch — same as Batcher._dispatch's fallback."""
-    import gubernator_tpu.service.ring as ring_mod
+    """A chunk the fused staging refuses rides the columns path INSIDE the
+    same runner dispatch — same as for Batcher._dispatch — and the ring
+    counts it."""
+    async def go():
+        r = StubRunner(fuse=False)
+        ring = RequestRing(r, slots=2)
 
-    async def go(monkey_concat):
-        ring_mod.concat_columns, orig = monkey_concat, ring_mod.concat_columns
-        try:
-            r = StubRunner(fuse=False)
-            ring = RequestRing(r, slots=2)
+        class P:
+            cols = "c0"
 
-            class P:
-                cols = "c0"
+        out = await ring.submit([P()])
+        return r, ring, out
 
-            out = await ring.submit([P()])
-            return r, ring, out
-        finally:
-            ring_mod.concat_columns = orig
-
-    r, ring, out = asyncio.run(go(lambda cols_list: cols_list[0]))
+    r, ring, out = asyncio.run(go())
     assert r.check_calls == 1
     assert out == ("cols-rc", "c0")
     assert ring.fallbacks == 1
