@@ -27,6 +27,7 @@ Prints exactly ONE JSON line on stdout:
 plus human-readable detail on stderr.
 """
 
+import contextlib
 import json
 import sys
 import time
@@ -38,6 +39,7 @@ import jax
 import jax.numpy as jnp
 
 from gubernator_tpu.bench_guard import (
+    StageTotals,
     WorkMismatchError,
     check_dropped,
     check_transport,
@@ -1269,7 +1271,7 @@ def dispatch_case(rng, now) -> dict:
     return out
 
 
-def _pipelined_checks(eng, cols_iter, now, depth=2):
+def _pipelined_checks(eng, cols_iter, now, depth=2, totals=None):
     """Drive check batches through the engine's prepare/issue/finish split
     with a depth-`depth` software pipeline — the serving loop the daemon's
     EngineRunner runs across threads, single-threaded here. At the default
@@ -1289,18 +1291,20 @@ def _pipelined_checks(eng, cols_iter, now, depth=2):
 
     fixup = lambda fn: fn()
     pend = deque()
-    for cols in cols_iter:
-        pend.append(
-            issue_check_columns(
-                eng, prepare_check_columns(eng, cols, now_ms=now)
+    # `totals` (bench_guard.StageTotals) sums the mesh engine's host stages
+    with totals.watch() if totals is not None else contextlib.nullcontext():
+        for cols in cols_iter:
+            pend.append(
+                issue_check_columns(
+                    eng, prepare_check_columns(eng, cols, now_ms=now)
+                )
             )
-        )
-        if len(pend) > depth:
+            if len(pend) > depth:
+                _rc, delta = finish_check_columns(eng, pend.popleft(), fixup)
+                eng.stats.merge(delta)
+        while pend:
             _rc, delta = finish_check_columns(eng, pend.popleft(), fixup)
             eng.stats.merge(delta)
-    while pend:
-        _rc, delta = finish_check_columns(eng, pend.popleft(), fixup)
-        eng.stats.merge(delta)
 
 
 def pod_scaling_case(rng, now) -> dict:
@@ -1372,13 +1376,14 @@ def pod_scaling_case(rng, now) -> dict:
             _pipelined_checks(
                 eng, (cols_for(staged[i % 4]) for i in range(3)), now
             )  # compile + seed
-            eng.take_stage_deltas()
+            totals = StageTotals()  # the timed dispatches' host stages
             eng.take_wire_deltas()
 
-            def timed(k, eng=eng):
+            def timed(k, eng=eng, totals=totals):
                 t0 = time.perf_counter()
                 _pipelined_checks(
-                    eng, (cols_for(staged[i % 4]) for i in range(k)), now
+                    eng, (cols_for(staged[i % 4]) for i in range(k)), now,
+                    totals=totals,
                 )
                 return time.perf_counter() - t0
 
@@ -1392,7 +1397,7 @@ def pod_scaling_case(rng, now) -> dict:
                 rec["decisions_per_sec"] = round(s.rate, 1)
             else:
                 rec["invalid"] = s.reason
-            stage = eng.take_stage_deltas()
+            stage = totals.stage_ms
             wire = eng.take_wire_deltas()
             bad = check_transport(
                 stage["put"] / 1e3, wire["put"], label=f"pod-D{D}-{impl}-put"
@@ -1547,20 +1552,21 @@ def sharded_ingress_case(rng, now, batch=1 << 17) -> dict:
             _pipelined_checks(eng, (cols_for(staged[i % len(staged)])
                                     for i in range(2)), now)  # warm
 
-            def timed(k, eng=eng):
+            # the mesh engine's host stages over the timed window
+            totals = StageTotals() if eng is sharded else None
+
+            def timed(k, eng=eng, totals=totals):
                 t0 = time.perf_counter()
                 _pipelined_checks(
                     eng,
                     (cols_for(staged[i % len(staged)]) for i in range(k)),
-                    now,
+                    now, totals=totals,
                 )
                 return time.perf_counter() - t0
 
             n_short, n_long = 2, 2 + n_disp
-            if hasattr(eng, "take_stage_deltas"):
-                eng.take_stage_deltas()  # reset the split to the timed window
+            if totals is not None:
                 eng.take_wire_deltas()
-                d0 = eng.stage_dispatches
             t_short = min(timed(n_short) for _ in range(3))
             t_long = min(timed(n_long) for _ in range(3))
             s = slope(t_short, t_long, n_short, n_long, batch, min_ratio=1.0)
@@ -1570,10 +1576,10 @@ def sharded_ingress_case(rng, now, batch=1 << 17) -> dict:
                 rec["decisions_per_sec"] = round(s.rate, 1)
             else:
                 rec["invalid"] = s.reason
-            if hasattr(eng, "take_stage_deltas"):
-                stage = eng.take_stage_deltas()
+            if totals is not None:
+                stage = totals.stage_ms
                 wire = eng.take_wire_deltas()
-                nd = max(1, eng.stage_dispatches - d0)
+                nd = max(1, totals.stage_dispatches)
                 rec["host_stage_ms"] = {
                     k: round(v / nd, 3) for k, v in stage.items()
                 }
@@ -1612,15 +1618,14 @@ def sharded_ingress_case(rng, now, batch=1 << 17) -> dict:
         # batch/8 — in-trace dedup + persistent staging must make staging
         # scale with rows shipped, not with the keyspace or a host sort
         small = batch // 8
-        sharded.take_stage_deltas()
-        d0 = sharded.stage_dispatches
+        totals = StageTotals()
         _pipelined_checks(
             sharded,
             (cols_for(staged[i % len(staged)][:small]) for i in range(6)),
-            now,
+            now, totals=totals,
         )
-        stage_small = sharded.take_stage_deltas()
-        nd = max(1, sharded.stage_dispatches - d0)
+        stage_small = totals.stage_ms
+        nd = max(1, totals.stage_dispatches)
         small_ms = sum(stage_small.values()) / nd
         entry["host_stage_small_ms"] = round(small_ms, 3)
         big_ms = entry["sharded"].get("host_stage_total_ms")
@@ -1735,20 +1740,21 @@ def config3_global_case(rng, now, live=10_000_000, batch=1 << 17,
         fixup = lambda fn: fn()
         prev = None
         t0 = time.perf_counter()
-        for i in range(k):
-            pending = issue_check_columns(
-                eng,
-                prepare_check_columns(
-                    eng, cols_for(staged[i % 8], behavior), now_ms=now
-                ),
-            )
-            drain_queue(eng)
-            if prev is not None:
-                _rc, delta = finish_check_columns(eng, prev, fixup)
-                eng.stats.merge(delta)
-            prev = pending
-        _rc, delta = finish_check_columns(eng, prev, fixup)
-        eng.stats.merge(delta)
+        with totals[name].watch():
+            for i in range(k):
+                pending = issue_check_columns(
+                    eng,
+                    prepare_check_columns(
+                        eng, cols_for(staged[i % 8], behavior), now_ms=now
+                    ),
+                )
+                drain_queue(eng)
+                if prev is not None:
+                    _rc, delta = finish_check_columns(eng, prev, fixup)
+                    eng.stats.merge(delta)
+                prev = pending
+            _rc, delta = finish_check_columns(eng, prev, fixup)
+            eng.stats.merge(delta)
         return time.perf_counter() - t0
 
     # INTERLEAVED timing: host-side conditions drift on the minutes scale
@@ -1758,11 +1764,12 @@ def config3_global_case(rng, now, live=10_000_000, batch=1 << 17,
     # path measured 175s vs 107s across two phases). Alternating runs give
     # both engines the same weather distribution; min-of-3 per point.
     n_short, n_long = 2, 14
+    totals = {name: StageTotals() for name in engines}
     for name in engines:
         timed(name, 2)  # warm residual shapes
         # scope the wire-byte and stage-delta windows to the timed phase
         engines[name].take_wire_deltas()
-        engines[name].take_stage_deltas()
+        totals[name] = StageTotals()
     samples = {name: {"s": [], "l": []} for name in engines}
     for _rep in range(3):
         for name in engines:
@@ -1785,9 +1792,9 @@ def config3_global_case(rng, now, live=10_000_000, batch=1 << 17,
         # the mesh path's host-staging split (route/pack/put ms per
         # dispatch, cumulative average — the shard_* stage_duration series)
         eng = engines[name]
-        nd = max(1, eng.stage_dispatches)
+        nd = max(1, totals[name].stage_dispatches)
         out[f"{name}_host_stage_ms"] = {
-            k: round(v / nd, 3) for k, v in eng.stage_ms.items()
+            k: round(v / nd, 3) for k, v in totals[name].stage_ms.items()
         }
         out[f"{name}_route"] = eng.route
         out[f"{name}_dedup"] = eng.dedup
@@ -1795,7 +1802,7 @@ def config3_global_case(rng, now, live=10_000_000, batch=1 << 17,
         # bytes/decision over the timed phase (the acceptance surface for
         # the compact-wire reduction), plus the transport-dominance gate
         wire = eng.take_wire_deltas()
-        stage_d = eng.take_stage_deltas()
+        stage_d = totals[name].stage_ms
         # denominator = client decisions in the interleaved timed phase
         # (bytes/DECISION — retry sub-dispatch bytes stay in the numerator)
         rows_timed = 3 * (n_short + n_long) * batch

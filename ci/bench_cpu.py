@@ -30,7 +30,7 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import gubernator_tpu  # noqa: F401,E402  (x64 on)
-from gubernator_tpu.bench_guard import check_dropped
+from gubernator_tpu.bench_guard import StageTotals, check_dropped
 from gubernator_tpu.ops.batch import RequestColumns
 from gubernator_tpu.ops.engine import LocalEngine
 
@@ -80,12 +80,11 @@ def sharded_smoke() -> dict:
             eng.check_columns(cols(f), now_ms=NOW)
 
     def stage_ms_per_dispatch(n: int, k: int = 12) -> float:
-        eng.take_stage_deltas()
-        d0 = eng.stage_dispatches
-        for i in range(k):
-            eng.check_columns(cols(batches[n][i % 4]), now_ms=NOW)
-        stage = eng.take_stage_deltas()
-        return sum(stage.values()) / max(1, eng.stage_dispatches - d0)
+        totals = StageTotals()
+        with totals.watch():
+            for i in range(k):
+                eng.check_columns(cols(batches[n][i % 4]), now_ms=NOW)
+        return sum(totals.stage_ms.values()) / max(1, totals.stage_dispatches)
 
     small_ms = min(stage_ms_per_dispatch(small) for _ in range(3))
     big_ms = min(stage_ms_per_dispatch(big) for _ in range(3))
@@ -248,12 +247,13 @@ def wire_smoke() -> dict:
 
     # transport gate: only the impossible-bandwidth side is fatal on CI
     ec.take_wire_deltas()
-    ec.take_stage_deltas()
-    for i in range(6):
-        ec.check_columns(cols(rng.integers(1, (1 << 63) - 1, size=big,
-                                           dtype=np.int64)), now_ms=NOW)
+    totals = StageTotals()
+    with totals.watch():
+        for i in range(6):
+            ec.check_columns(cols(rng.integers(1, (1 << 63) - 1, size=big,
+                                               dtype=np.int64)), now_ms=NOW)
     w = ec.take_wire_deltas()
-    put_ms = ec.take_stage_deltas()["put"]
+    put_ms = totals.stage_ms["put"]
     guard = check_transport(put_ms / 1e3, w["put"], min_bandwidth=0.0)
     out["transport_guard"] = guard or "ok"
     if guard:
@@ -947,12 +947,11 @@ def mesh_smoke() -> dict:
             ring.check_columns(cols(f), now_ms=NOW)
 
     def stage_ms_per_dispatch(n: int, k: int = 12) -> float:
-        ring.take_stage_deltas()
-        d0 = ring.stage_dispatches
-        for i in range(k):
-            ring.check_columns(cols(batches[n][i % 4]), now_ms=NOW)
-        stage = ring.take_stage_deltas()
-        return sum(stage.values()) / max(1, ring.stage_dispatches - d0)
+        totals = StageTotals()
+        with totals.watch():
+            for i in range(k):
+                ring.check_columns(cols(batches[n][i % 4]), now_ms=NOW)
+        return sum(totals.stage_ms.values()) / max(1, totals.stage_dispatches)
 
     small_ms = min(stage_ms_per_dispatch(small) for _ in range(3))
     big_ms = min(stage_ms_per_dispatch(big) for _ in range(3))

@@ -193,3 +193,39 @@ def check_dropped(
             "the work its rate claims"
         )
     return None
+
+
+class StageTotals:
+    """The mesh engine's host stages, summed, for a rig that drives an
+    engine without a daemon. The engine times them as parts of the dispatch
+    stage that is open on the thread (tracing.stage.within), into that
+    stage's metrics; `watch()` opens such a stage with this object where
+    the daemon's metrics stand. ms per stage under the rigs' old names
+    (route, pack, put, wire_pack, wire_decode; shard_unroute, which holds
+    wire_decode, is left out of the sum as before), and the passes staged."""
+
+    _NAMES = {
+        "shard_route": "route", "shard_pack": "pack", "shard_put": "put",
+        "wire_pack": "wire_pack", "wire_decode": "wire_decode",
+    }
+
+    class _Sample:
+        def __init__(self, totals: "StageTotals", key: Optional[str]) -> None:
+            self.totals, self.key = totals, key
+
+        def observe(self, dt_s: float) -> None:
+            if self.key is not None:
+                self.totals.stage_ms[self.key] += dt_s * 1e3
+                self.totals.stage_dispatches += self.key == "put"
+
+    def __init__(self) -> None:
+        self.stage_ms = dict.fromkeys(self._NAMES.values(), 0.0)
+        self.stage_dispatches = 0
+
+    def stage_child(self, stage: str) -> "StageTotals._Sample":
+        return self._Sample(self, self._NAMES.get(stage))
+
+    def watch(self):
+        from gubernator_tpu import tracing
+
+        return tracing.stage("rig", self, disp=tracing.Dispatch(0, 0))

@@ -84,6 +84,20 @@ def pair_capacity(c: int, D: int) -> int:
     return p
 
 
+def exchange_traffic(c: int, D: int) -> "tuple[int, int, int]":
+    """What one pass of `make_a2a_decide(mesh, c)` is traced with, from its
+    shapes alone: (lanes the decide kernel runs on each device, row slots
+    ONE chip sends plus receives over ICI, bytes of them). Each leg moves a
+    (D, ·, C) buffer per chip, of which the chip's own block stays at home:
+    D-1 blocks of C slots out and as many in — 12 int64 lanes a request
+    slot on the way to the owners, 4 a response slot on the way back.
+    Padding slots travel like live rows, so this is what the exchange
+    moves, not what the traffic needed."""
+    C = pair_capacity(c, D)
+    slots = 2 * (D - 1) * C  # sent plus received, one leg
+    return D * C, 2 * slots, slots * (12 + 4) * 8
+
+
 def make_a2a_decide(
     mesh: Mesh, c: int, math: str = "mixed", write=None, dedup: bool = False,
     wire: bool = False, impl: "str | None" = None, probe: str = "xla",

@@ -52,7 +52,6 @@ from __future__ import annotations
 
 import os
 import threading
-import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Dict, List, NamedTuple, Optional, Sequence
 
@@ -97,6 +96,7 @@ from gubernator_tpu.parallel.mesh import (
     shard_of,
     shard_spec,
 )
+from gubernator_tpu import tracing
 from gubernator_tpu.types import RateLimitRequest, RateLimitResponse
 
 
@@ -486,7 +486,9 @@ class ShardedEngine:
         # 12-lane int64 grids (the parity oracle). Per-dispatch
         # encodability still falls compact batches back to full-width.
         self.wire = wire or default_wire_mode()
-        self._decide_fns = {}  # (kind, …, math) → jitted mesh step (lazy)
+        # (kind, …, math) → jitted mesh step (lazy); an a2a step with its
+        # exchange_traffic
+        self._decide_fns = {}
         self._install = make_sharded_install(
             mesh, write=self.write_mode, probe=self.walk_mode
         )
@@ -525,16 +527,17 @@ class ShardedEngine:
         # same-shape dispatch where XLA aliases the output allocation
         self._egress: Dict[tuple, list] = {}
         self._egress_lock = threading.Lock()
-        # host-staging cost accounting (the bench's host-stage/device split
-        # and the shard_*/wire_* stage_duration series): cumulative ms per
-        # stage — wire_pack is the compact encode, wire_decode the compact
-        # egress decode (both 0 on full-width dispatches)
-        self.stage_ms = {
-            "route": 0.0, "pack": 0.0, "put": 0.0,
-            "wire_pack": 0.0, "wire_decode": 0.0,
-        }
-        self.stage_dispatches = 0
-        self._stage_taken = dict(self.stage_ms)
+        # what the mesh steps were traced with, growing per pass (engine
+        # thread, _decide; /v1/debug/pipeline "engine"): `mesh_lanes` the
+        # lanes the decide kernel ran over all shards (D x b_local on the
+        # host grid, D x D*C after the exchange), to set beside
+        # stats.checks; `exchange_rows`/`exchange_bytes` the row slots and
+        # bytes ONE chip sends plus receives over ICI in both legs of the
+        # a2a exchange (parallel/a2a.exchange_traffic; 0 for a host-routed
+        # pass). From shapes, not measured.
+        self.mesh_lanes = 0
+        self.exchange_rows = 0
+        self.exchange_bytes = 0
         self._stage_lock = threading.Lock()
         # bytes actually crossing the host↔device boundary on the decide
         # path (the gubernator_tpu_wire_bytes_total series): ingress grid
@@ -630,22 +633,10 @@ class ShardedEngine:
         if self.ckpt is not None:
             self.ckpt.mark(np.asarray(fps))
 
-    # -------------------------------------------- staging cost accounting
-
-    def _stage_time(self, key: str, dt_s: float) -> None:
-        with self._stage_lock:
-            self.stage_ms[key] += dt_s * 1e3
-
-    def take_stage_deltas(self) -> Dict[str, float]:
-        """Host-staging ms per stage since the last take (EngineRunner
-        feeds these into the shard_*/wire_* stage_duration series)."""
-        with self._stage_lock:
-            d = {
-                k: self.stage_ms[k] - self._stage_taken[k]
-                for k in self.stage_ms
-            }
-            self._stage_taken = dict(self.stage_ms)
-        return d
+    # ------------------------------------------------ boundary accounting
+    # (the host stages themselves are tracing.stage parts of the runner's
+    # put/fetch: shard_route, shard_pack | wire_pack, shard_put in _stage*,
+    # shard_unroute with wire_decode inside it in _unroute)
 
     def _wire_count(self, direction: str, nbytes: int) -> None:
         with self._stage_lock:
@@ -1142,17 +1133,25 @@ class ShardedEngine:
             table = self._table_to_full(table)
         dedup = self.dedup == "device"
         if isinstance(staged, _StagedA2A):
-            from gubernator_tpu.parallel.a2a import make_a2a_decide
+            from gubernator_tpu.parallel.a2a import (
+                exchange_traffic,
+                make_a2a_decide,
+            )
 
+            # the built step is kept with what it was traced with: the
+            # exchange's geometry is read when the step is built
             key = ("a2a", staged.c, staged.math, staged.wire, self.a2a_impl)
-            fn = self._decide_fns.get(key)
-            if fn is None:
-                fn = self._decide_fns[key] = make_a2a_decide(
+            built = self._decide_fns.get(key)
+            if built is None:
+                built = self._decide_fns[key] = make_a2a_decide(
                     self.mesh, staged.c, math=staged.math,
                     write=self.write_mode, dedup=dedup, wire=staged.wire,
                     impl=self.a2a_impl, probe=self.probe_mode,
-                )
+                ), exchange_traffic(staged.c, self.n_shards)
+            fn, (lanes, ex_rows, ex_bytes) = built
             rows = staged.c
+            self.exchange_rows += ex_rows
+            self.exchange_bytes += ex_bytes
         else:
             key = ("host", staged.math, staged.wire)
             fn = self._decide_fns.get(key)
@@ -1161,7 +1160,8 @@ class ShardedEngine:
                     self.mesh, math=staged.math, write=self.write_mode,
                     dedup=dedup, wire=staged.wire, probe=self.probe_mode,
                 )
-            rows = staged.b_local
+            rows = lanes = staged.b_local
+        self.mesh_lanes += self.n_shards * lanes
         out_buf = self._take_egress(
             (self.n_shards, rows + 2, 4),
             np.int32 if staged.wire else np.int64,
@@ -1211,51 +1211,45 @@ class ShardedEngine:
         in arrival order and the mesh exchanges them over ICI
         (parallel/a2a.py). Explicit `shard` pins (the GLOBAL replica path)
         always take the host grid: a2a routes by ownership hash only.
-        Grids build in the persistent staging ring (_StagingPool) and each
-        phase's host cost accumulates into stage_ms (route/pack/put)."""
+        Grids build in the persistent staging ring (_StagingPool); each
+        phase is a part of the runner's `put` stage (shard_route,
+        shard_pack | wire_pack, shard_put; the compact wire's plan, which
+        decides the pack's name, is `put`'s own time)."""
         if self.route == "device" and shard is None:
             return self._stage_a2a(batch)
         D = self.n_shards
-        t0 = time.perf_counter()
-        routed = shard if shard is not None else shard_of(batch.fp, D)
-        order, rs, offset, b_local = _route_plan(routed, D)
-        t1 = time.perf_counter()
+        with tracing.stage.within("shard_route"):
+            routed = shard if shard is not None else shard_of(batch.fp, D)
+            order, rs, offset, b_local = _route_plan(routed, D)
         wired, base = self._wire_plan(batch)
-        if wired:
-            from gubernator_tpu.ops import wire as wire_mod
+        with tracing.stage.within("wire_pack" if wired else "shard_pack"):
+            if wired:
+                from gubernator_tpu.ops import wire as wire_mod
 
-            # compact grid: one trailing column per device block carries
-            # the base (decode_wire_block reads cells [0, -1], [1, -1])
-            shape = (D, wire_mod.WIRE_LANES, b_local + 1)
-            grid = (
-                self._pool.get(shape, zero=True, dtype=np.int32)
-                if self._pool is not None
-                else np.zeros(shape, dtype=np.int32)
-            )
-            packed = wire_mod.pack_wire_rows(batch, base)
-            grid[rs, :, offset] = packed[:, order].T
-            for d in range(D):
-                wire_mod.stamp_base(grid[d], base)
-            stage = "wire_pack"
-        else:
-            packed = pack_host_batch(batch)  # (12, n)
-            shape = (D, 12, b_local)
-            grid = (
-                self._pool.get(shape, zero=True)
-                if self._pool is not None
-                else np.zeros(shape, dtype=np.int64)
-            )
-            grid[rs, :, offset] = packed[:, order].T
-            stage = "pack"
-        t2 = time.perf_counter()
-        dev = self._put_grid(grid)
-        t3 = time.perf_counter()
-        self._stage_time("route", t1 - t0)
-        self._stage_time(stage, t2 - t1)
-        self._stage_time("put", t3 - t2)
+                # compact grid: one trailing column per device block carries
+                # the base (decode_wire_block reads cells [0, -1], [1, -1])
+                shape = (D, wire_mod.WIRE_LANES, b_local + 1)
+                grid = (
+                    self._pool.get(shape, zero=True, dtype=np.int32)
+                    if self._pool is not None
+                    else np.zeros(shape, dtype=np.int32)
+                )
+                packed = wire_mod.pack_wire_rows(batch, base)
+                grid[rs, :, offset] = packed[:, order].T
+                for d in range(D):
+                    wire_mod.stamp_base(grid[d], base)
+            else:
+                packed = pack_host_batch(batch)  # (12, n)
+                shape = (D, 12, b_local)
+                grid = (
+                    self._pool.get(shape, zero=True)
+                    if self._pool is not None
+                    else np.zeros(shape, dtype=np.int64)
+                )
+                grid[rs, :, offset] = packed[:, order].T
+        with tracing.stage.within("shard_put"):
+            dev = self._put_grid(grid)
         self._wire_count("put", grid.nbytes)
-        with self._stage_lock:
-            self.stage_dispatches += 1
         math = effective_math(self.table.layout, batch)
         return _Staged(
             order=order, rs=rs, offset=offset, b_local=b_local, dev=dev,
@@ -1306,48 +1300,42 @@ class ShardedEngine:
         D = self.n_shards
         n = batch.fp.shape[0]
         c = _pad_size(max(1, -(-n // D)), floor=8)
-        t0 = time.perf_counter()
         wired, base = self._wire_plan(batch)
-        if wired:
-            from gubernator_tpu.ops import wire as wire_mod
+        with tracing.stage.within("wire_pack" if wired else "shard_pack"):
+            if wired:
+                from gubernator_tpu.ops import wire as wire_mod
 
-            L = wire_mod.WIRE_LANES
-            if self._pool is not None:
-                flat = self._pool.get((L, D * c), dtype=np.int32)
-                flat[:, n:] = 0  # stale tail from the buffer's last use
-                grid = self._pool.get((D, L, c + 1), dtype=np.int32)
+                L = wire_mod.WIRE_LANES
+                if self._pool is not None:
+                    flat = self._pool.get((L, D * c), dtype=np.int32)
+                    flat[:, n:] = 0  # stale tail from the buffer's last use
+                    grid = self._pool.get((D, L, c + 1), dtype=np.int32)
+                else:
+                    flat = np.zeros((L, D * c), dtype=np.int32)
+                    grid = np.empty((D, L, c + 1), dtype=np.int32)
+                wire_mod.pack_wire_rows(batch, base, out=flat[:, :n])
+                np.copyto(
+                    grid[:, :, :c], flat.reshape(L, D, c).transpose(1, 0, 2)
+                )
+                grid[:, :, c] = 0
+                for d in range(D):
+                    wire_mod.stamp_base(grid[d], base)
             else:
-                flat = np.zeros((L, D * c), dtype=np.int32)
-                grid = np.empty((D, L, c + 1), dtype=np.int32)
-            wire_mod.pack_wire_rows(batch, base, out=flat[:, :n])
-            np.copyto(
-                grid[:, :, :c], flat.reshape(L, D, c).transpose(1, 0, 2)
-            )
-            grid[:, :, c] = 0
-            for d in range(D):
-                wire_mod.stamp_base(grid[d], base)
-            stage = "wire_pack"
-        else:
-            if self._pool is not None:
-                flat = self._pool.get((12, D * c))
-                flat[:, n:] = 0  # stale tail from the buffer's last use
-                grid = self._pool.get((D, 12, c))
-            else:
-                flat = np.zeros((12, D * c), dtype=np.int64)
-                grid = np.empty((D, 12, c), dtype=np.int64)
-            pack_host_batch(batch, out=flat[:, : n])
-            # one strided copy rearranges (12, D·c) → (D, 12, c); every grid
-            # byte is overwritten, so the pooled buffer needs no zeroing
-            np.copyto(grid, flat.reshape(12, D, c).transpose(1, 0, 2))
-            stage = "pack"
-        t1 = time.perf_counter()
-        dev = self._put_grid(grid)
-        t2 = time.perf_counter()
-        self._stage_time(stage, t1 - t0)
-        self._stage_time("put", t2 - t1)
+                if self._pool is not None:
+                    flat = self._pool.get((12, D * c))
+                    flat[:, n:] = 0  # stale tail from the buffer's last use
+                    grid = self._pool.get((D, 12, c))
+                else:
+                    flat = np.zeros((12, D * c), dtype=np.int64)
+                    grid = np.empty((D, 12, c), dtype=np.int64)
+                pack_host_batch(batch, out=flat[:, : n])
+                # one strided copy rearranges (12, D·c) → (D, 12, c); every
+                # grid byte is overwritten, so the pooled buffer needs no
+                # zeroing
+                np.copyto(grid, flat.reshape(12, D, c).transpose(1, 0, 2))
+        with tracing.stage.within("shard_put"):
+            dev = self._put_grid(grid)
         self._wire_count("put", grid.nbytes)
-        with self._stage_lock:
-            self.stage_dispatches += 1
         math = effective_math(self.table.layout, batch)
         return _StagedA2A(
             c=c, dev=dev, math=math, wire=wired, base=base,
@@ -1364,39 +1352,40 @@ class ShardedEngine:
         Flag bits shared with the single-device decoder
         (kernel2.FLAG_*/unpack_outputs). Compact-wire outputs (int32 —
         ops/wire.py) decode here with vectorized numpy: the reset lane is
-        base-relative, everything else widens to int64."""
+        base-relative, everything else widens to int64. A part of the
+        runner's `fetch` stage (shard_unroute, wire_decode inside it)."""
         self._wire_count("fetch", outh.nbytes)
-        if isinstance(staged, _StagedA2A):
-            st = outh[:, staged.c, :].astype(np.int64).sum(axis=0)
-            per = outh[:, : staged.c, :].reshape(-1, 4)[:n]
-            per = per.copy() if per.dtype == np.int64 else per
-        else:
-            st = outh[:, staged.b_local, :].astype(np.int64).sum(axis=0)
-            per = np.empty((n, 4), dtype=outh.dtype)
-            per[staged.order] = outh[staged.rs, staged.offset]
-        if staged.wire:
-            from gubernator_tpu.ops.wire import decode_wire_rows
+        with tracing.stage.within("shard_unroute"):
+            if isinstance(staged, _StagedA2A):
+                st = outh[:, staged.c, :].astype(np.int64).sum(axis=0)
+                per = outh[:, : staged.c, :].reshape(-1, 4)[:n]
+                per = per.copy() if per.dtype == np.int64 else per
+            else:
+                st = outh[:, staged.b_local, :].astype(np.int64).sum(axis=0)
+                per = np.empty((n, 4), dtype=outh.dtype)
+                per[staged.order] = outh[staged.rs, staged.offset]
+            if staged.wire:
+                from gubernator_tpu.ops.wire import decode_wire_rows
 
-            t0 = time.perf_counter()
-            per = decode_wire_rows(per, staged.base)
-            self._stage_time("wire_decode", time.perf_counter() - t0)
-        status = (per[:, 3] & FLAG_STATUS).astype(np.int32)
-        hit = (per[:, 3] & FLAG_HIT) != 0
-        dropped = (per[:, 3] & FLAG_DROPPED) != 0
-        unproc = (per[:, 3] & FLAG_UNPROCESSED) != 0
-        member = (per[:, 3] & FLAG_MEMBER) != 0
-        if isinstance(staged, _StagedA2A):
-            # capacity overflow: exchanged rows that never reached a kernel
-            # this dispatch (members inherit their carrier's flags without
-            # having been exchanged — not counted)
-            over = int((unproc & ~member).sum())
-            if over:
-                with self._stage_lock:
-                    self.a2a_overflow += over
-        return (
-            status, per[:, 0], per[:, 1], per[:, 2], dropped, hit, unproc,
-            member, int(st[3]),
-        )
+                with tracing.stage.within("wire_decode"):
+                    per = decode_wire_rows(per, staged.base)
+            status = (per[:, 3] & FLAG_STATUS).astype(np.int32)
+            hit = (per[:, 3] & FLAG_HIT) != 0
+            dropped = (per[:, 3] & FLAG_DROPPED) != 0
+            unproc = (per[:, 3] & FLAG_UNPROCESSED) != 0
+            member = (per[:, 3] & FLAG_MEMBER) != 0
+            if isinstance(staged, _StagedA2A):
+                # capacity overflow: exchanged rows that never reached a
+                # kernel this dispatch (members inherit their carrier's
+                # flags without having been exchanged — not counted)
+                over = int((unproc & ~member).sum())
+                if over:
+                    with self._stage_lock:
+                        self.a2a_overflow += over
+            return (
+                status, per[:, 0], per[:, 1], per[:, 2], dropped, hit, unproc,
+                member, int(st[3]),
+            )
 
     def _dispatch(
         self,

@@ -1715,6 +1715,14 @@ class Daemon:
                 # pair_capacity is undersized for the traffic's skew
                 # (GUBER_A2A_CAPACITY_SIGMA)
                 "a2a_overflow": getattr(eng, "a2a_overflow", 0),
+                # what the mesh steps were traced with, per pass (from
+                # shapes, parallel/a2a.exchange_traffic): row slots and
+                # bytes one chip sends plus receives over ICI, and the
+                # lanes the decide kernel ran over all shards, to set
+                # beside `checks` (0 on one device)
+                "exchange_rows": getattr(eng, "exchange_rows", 0),
+                "exchange_bytes": getattr(eng, "exchange_bytes", 0),
+                "mesh_lanes": getattr(eng, "mesh_lanes", 0),
                 "poisoned": getattr(eng, "poisoned", None),
                 "checks": eng.stats.checks,
                 "dispatches": eng.stats.dispatches,

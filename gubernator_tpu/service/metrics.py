@@ -239,9 +239,10 @@ class DaemonMetrics:
             "gubernator_tpu_stage_duration",
             "Seconds per serving-pipeline stage",
             # parse | queue | put | issue | fetch | encode, plus the mesh
-            # ingress host-staging split shard_route | shard_pack |
-            # shard_put (ShardedEngine host work per dispatch — route plan,
-            # grid pack, device transfer; docs/latency.md "mesh ingress")
+            # host stages shard_route | shard_pack | shard_put inside put
+            # and shard_unroute inside fetch (ShardedEngine host work per
+            # pass — route plan, grid pack, device transfer, un-routing the
+            # fetched grid; docs/latency.md "mesh ingress")
             # and the compact-wire codec stages wire_pack | wire_decode
             # (host encode of the 5-lane ingress grid / decode of the int32
             # egress; docs/latency.md "wire budget").

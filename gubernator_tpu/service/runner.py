@@ -395,23 +395,14 @@ class EngineRunner:
         return await loop.run_in_executor(self._fetch, finish)
 
     def _observe_shard_stages(self) -> None:
-        """Fold the mesh engine's host-staging split (route/pack/put ms
-        accumulated in ShardedEngine._stage*) into the stage_duration
-        summaries as shard_* labels — the mesh-path mirror of the local
-        pipeline's put/issue/fetch stages, and the series the ingress bench
-        reads to show staging cost ∝ batch rows. The compact-wire codec
-        stages keep their own wire_pack/wire_decode labels, and the bytes
-        the engine moved across the boundary feed the
-        gubernator_tpu_wire_bytes_total counter so bytes/decision is
-        scrapeable rather than bench-computed."""
-        take = getattr(self.engine, "take_stage_deltas", None)
-        if take is not None:
-            for k, ms in take().items():
-                if ms > 0:
-                    label = k if k.startswith("wire_") else f"shard_{k}"
-                    self.metrics.stage_duration.labels(stage=label).observe(
-                        ms / 1e3
-                    )
+        """Fold what the mesh engine counted since the last call into the
+        daemon's counters: the bytes it moved across the host↔device
+        boundary (gubernator_tpu_wire_bytes_total, so bytes/decision is
+        scrapeable rather than bench-computed) and the rows its exchange
+        capacity-dropped. The host stages themselves (shard_route,
+        shard_pack | wire_pack, shard_put, shard_unroute, wire_decode) are
+        tracing.stage parts of `put` and `fetch`, timed where the work
+        happens (parallel/sharded.py)."""
         wtake = getattr(self.engine, "take_wire_deltas", None)
         if wtake is not None:
             for direction, nbytes in wtake().items():

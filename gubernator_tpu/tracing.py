@@ -159,6 +159,13 @@ class Dispatch:
     work_s: float = 0.0
 
 
+# The dispatch stage (put, put_miss, issue, fetch) that is open on this
+# thread. Those run from start to end on one worker thread, so code they
+# call (the mesh engine's staging and un-routing) can time its own parts
+# under them without the dispatch being handed down through every call.
+_open = threading.local()
+
+
 def observe(stage_name: str, metrics, dt_s: float,
             span: Optional[SpanContext] = None) -> None:
     """Record an interval that has already been measured: the histogram
@@ -190,9 +197,16 @@ class stage:
     the thread that does it. `span` (a request's) or `disp` (a dispatch's
     context) names the parent; keyword `stats` and later `note()`s become
     the profiler span's stats. `dt` holds the interval after the block.
-    With `metrics=None` the block is a profiler span and a clock only."""
+    With `metrics=None` the block is a profiler span and a clock only.
 
-    __slots__ = ("name", "metrics", "span", "disp", "stats", "dt", "_t0", "_ann")
+    `stage.within(name)` opens a part of the dispatch stage that is open on
+    this thread (`gub:shard_route` inside `gub:put`): it samples into the
+    same metrics and carries the same `dispatch=<seq>`, and its interval is
+    already in the outer stage's, so it adds nothing to the dispatch's
+    `work_s`. Under no dispatch stage it is a profiler span and a clock."""
+
+    __slots__ = ("name", "metrics", "span", "disp", "stats", "dt", "_t0",
+                 "_ann", "_part", "_outer")
 
     def __init__(self, name: str, metrics=None, span: Optional[SpanContext] = None,
                  disp: Optional[Dispatch] = None, **stats):
@@ -200,8 +214,22 @@ class stage:
         self.stats = stats
         self.dt = 0.0
         self._ann = None
+        self._part = False
+
+    @classmethod
+    def within(cls, name: str, **stats) -> "stage":
+        outer = getattr(_open, "stage", None)
+        if outer is None:
+            st = cls(name, **stats)
+        else:
+            st = cls(name, outer.metrics, disp=outer.disp, **stats)
+        st._part = True
+        return st
 
     def __enter__(self) -> "stage":
+        if self.disp is not None and not self._part:
+            self._outer = getattr(_open, "stage", None)
+            _open.stage = self
         if TraceAnnotation.is_enabled():
             stats, disp = self.stats, self.disp
             if disp is not None:
@@ -221,7 +249,8 @@ class stage:
         if self._ann is not None:
             self._ann.__exit__(*exc)
         disp = self.disp
-        if disp is not None:
+        if disp is not None and not self._part:
+            _open.stage = self._outer
             disp.work_s += dt
         observe(self.name, self.metrics, dt,
                 disp.span if disp is not None else self.span)

@@ -222,20 +222,41 @@ def test_pipelined_dedup_matches_serial(mesh, frozen_now):
 
 
 def test_stage_timing_and_egress_recycling(mesh, frozen_now):
-    """The ingress accounting the bench and shard_* metrics read: staging
-    time accumulates per dispatch, take_stage_deltas drains it, and fetched
-    egress buffers are banked for donation reuse."""
+    """The ingress accounting the shard_* metrics read: the mesh engine's
+    host stages are parts of the dispatch stage that is open on the thread
+    (tracing.stage.within), each a sample of the stage histogram of the
+    metrics that stage was given and nothing added to the dispatch's work
+    time; and fetched egress buffers are banked for donation reuse."""
+    from gubernator_tpu import tracing
+    from gubernator_tpu.service.metrics import DaemonMetrics
+
     t = frozen_now
     eng = ShardedEngine(mesh, capacity_per_shard=1024, route="device",
                         dedup="device")
     reqs = [req(f"s{i}", created_at=t) for i in range(64)]
+    metrics = DaemonMetrics()
+    disp = tracing.Dispatch(seq=7, rows=len(reqs))
+
+    def samples():
+        return {
+            k: (c._sum.get(), sum(b.get() for b in c._buckets))
+            for k, c in metrics._stage_children.items()
+        }
+
+    with tracing.stage("put", metrics, disp=disp) as outer:
+        eng.check(reqs, now_ms=t)
+    got = samples()
+    packs = {"shard_pack", "wire_pack"} & set(got)
+    assert len(packs) == 1  # one pass, one wire format
+    assert set(got) - packs - {"wire_decode"} == {
+        "put", "shard_put", "shard_unroute"}  # route=device: no shard_route
+    assert all(n == 1 and s > 0 for s, n in got.values())
+    parts = sum(s for k, (s, _n) in got.items() if k != "put")
+    assert parts <= got["put"][0]
+    assert disp.work_s == outer.dt  # the parts are already inside it
+    # under no dispatch stage the parts are clocks only: nothing is sampled
     eng.check(reqs, now_ms=t)
-    assert eng.stage_dispatches >= 1
-    d = eng.take_stage_deltas()
-    assert set(d) == {"route", "pack", "put", "wire_pack", "wire_decode"}
-    assert d["pack"] + d["wire_pack"] >= 0 and d["put"] > 0
-    # drained: a second take with no traffic reads zero
-    assert all(v == 0.0 for v in eng.take_stage_deltas().values())
+    assert samples() == got
     # egress bank primed by the fetch; the next same-shape dispatch pops it
     assert any(len(v) for v in eng._egress.values())
     banked = {k: len(v) for k, v in eng._egress.items()}
