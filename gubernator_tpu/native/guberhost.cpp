@@ -179,6 +179,7 @@ static const int32_t BEHAVIOR_CLIENT_MASK = 255;
 // highest algorithm enum this build speaks (types.MAX_ALGORITHM); larger
 // values are per-item errors on the full path, so never fused
 static const int32_t MAX_ALGORITHM = 4;
+static const int32_t ALGO_CONCURRENCY_LEASE = 4;  // types.Algorithm
 
 struct Item {
   const uint8_t* name = nullptr; size_t name_len = 0;
@@ -260,14 +261,18 @@ static bool parse_item(Cursor& c, Item& it) {
 
 // parse_get_rate_limits(data: bytes)
 //   -> (n, fp, algo, behavior, hits, limit, burst, duration, created_at,
-//       err, ring_hash, spans, traceparent, lanes, enc, casc)
+//       err, ring_hash, spans, traceparent, lanes, enc, summary)
 // Buffer layouts (np.frombuffer): fp/hits/limit/burst/duration/created_at
 // int64; algo/behavior int32; err int8; ring_hash uint32; spans int64 pairs
 // (start, len) of each item's bytes for lazy pb materialization; lanes a
 // (5, n) row-major int32 pre-packed compact-wire image (ops/wire.py lanes,
-// created-delta field zero); enc int8 per-item compact-wire encodability;
-// casc int8 per-item "carries a cascade field" flag (such batches take the
-// pb path, where the daemon expands the levels).
+// created-delta field zero); enc int8 per-item compact-wire encodability.
+// summary is the batch reduced over its items in the fill loop
+// (service/wire.RowSummary, in field order): rows with err set, OR of the
+// behavior words, lease rows, rows with created_at 0, enc rows, highest
+// priority tier, rows that carry a cascade field (such a batch takes the pb
+// path, where the daemon expands the levels) — what the handler and the
+// enqueue would otherwise scan the columns for.
 // The scan + fill loops run with the GIL RELEASED — N front-door workers
 // parse concurrently (service/daemon.py door pool).
 static PyObject* parse_get_rate_limits(PyObject*, PyObject* args) {
@@ -336,10 +341,8 @@ static PyObject* parse_get_rate_limits(PyObject*, PyObject* args) {
   PyObject* span_b = PyBytes_FromStringAndSize(nullptr, n * 16);
   PyObject* lanes_b = PyBytes_FromStringAndSize(nullptr, n * 5 * 4);
   PyObject* enc_b = PyBytes_FromStringAndSize(nullptr, n);
-  PyObject* casc_b = PyBytes_FromStringAndSize(nullptr, n);
   if (!out || !fp_b || !algo_b || !beh_b || !hits_b || !lim_b || !burst_b ||
-      !dur_b || !ca_b || !err_b || !ring_b || !span_b || !lanes_b || !enc_b ||
-      !casc_b) {
+      !dur_b || !ca_b || !err_b || !ring_b || !span_b || !lanes_b || !enc_b) {
     PyBuffer_Release(&buf);
     Py_XDECREF(out);
     return nullptr;
@@ -357,8 +360,9 @@ static PyObject* parse_get_rate_limits(PyObject*, PyObject* args) {
   int64_t* span = (int64_t*)PyBytes_AS_STRING(span_b);
   int32_t* lanes = (int32_t*)PyBytes_AS_STRING(lanes_b);
   int8_t* enc = (int8_t*)PyBytes_AS_STRING(enc_b);
-  int8_t* casc = (int8_t*)PyBytes_AS_STRING(casc_b);
 
+  long long n_err = 0, n_lease = 0, n_unstamped = 0, n_enc = 0, n_casc = 0;
+  int32_t beh_or = 0, max_tier = 0;
   Py_BEGIN_ALLOW_THREADS;
   std::string hk;
   for (size_t i = 0; i < n; i++) {
@@ -367,7 +371,11 @@ static PyObject* parse_get_rate_limits(PyObject*, PyObject* args) {
     // client-facing flag bits only: the high bits are the internal cascade
     // level field, which must never arrive from the wire
     beh[i] = it.behavior & BEHAVIOR_CLIENT_MASK;
-    casc[i] = it.has_cascade ? 1 : 0;
+    beh_or |= beh[i];
+    if (((beh[i] >> 6) & 3) > max_tier) max_tier = (beh[i] >> 6) & 3;
+    n_lease += it.algorithm == ALGO_CONCURRENCY_LEASE;
+    n_unstamped += it.created_at == 0;
+    n_casc += it.has_cascade;
     hits[i] = it.hits;
     lim[i] = it.limit;
     burst[i] = it.burst;
@@ -379,8 +387,13 @@ static PyObject* parse_get_rate_limits(PyObject*, PyObject* args) {
     ring[i] = 0;
     lanes[i] = lanes[n + i] = lanes[2 * n + i] = lanes[3 * n + i] =
         lanes[4 * n + i] = 0;
-    if (it.key_len == 0) { err[i] = ERR_EMPTY_KEY; enc[i] = 1; continue; }
-    if (it.name_len == 0) { err[i] = ERR_EMPTY_NAME; enc[i] = 1; continue; }
+    if (it.key_len == 0 || it.name_len == 0) {
+      err[i] = it.key_len == 0 ? ERR_EMPTY_KEY : ERR_EMPTY_NAME;
+      enc[i] = 1;
+      n_err++;
+      n_enc++;
+      continue;
+    }
     err[i] = ERR_OK;
     hk.clear();
     hk.append((const char*)it.name, it.name_len);
@@ -407,6 +420,7 @@ static PyObject* parse_get_rate_limits(PyObject*, PyObject* args) {
              (it.algorithm == 0 || it.burst == 0) &&
              !it.has_cascade;
     enc[i] = e ? 1 : 0;
+    n_enc += e;
     // pre-packed 5-lane int32 row (ops/wire.pack_wire_rows layout);
     // lane 4's created-delta bits stay 0 until the flush stamps them
     uint64_t ufp = (uint64_t)fp[i];
@@ -440,7 +454,13 @@ static PyObject* parse_get_rate_limits(PyObject*, PyObject* args) {
   PyTuple_SET_ITEM(out, 12, tp);
   PyTuple_SET_ITEM(out, 13, lanes_b);
   PyTuple_SET_ITEM(out, 14, enc_b);
-  PyTuple_SET_ITEM(out, 15, casc_b);
+  PyObject* summary = Py_BuildValue("(LiLLLiL)", n_err, (int)beh_or, n_lease,
+                                    n_unstamped, n_enc, (int)max_tier, n_casc);
+  if (!summary) {
+    Py_DECREF(out);
+    return nullptr;
+  }
+  PyTuple_SET_ITEM(out, 15, summary);
   return out;
 }
 

@@ -626,7 +626,7 @@ def _assemble_wire_parts(engine, parts, now_ms=None, pad_to=None):
         return None
     if engine.store is not None or not engine.supports_pipeline:
         return None
-    if not all(bool(p.encodable.all()) for p in parts):
+    if not all(p.all_encodable for p in parts):
         return None
     cols_list = [p.cols for p in parts]
     n = sum(c.fp.shape[0] for c in cols_list)

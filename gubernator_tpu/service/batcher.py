@@ -88,14 +88,6 @@ DEFAULT_COALESCE_LIMIT = 16384
 OVERLOAD_AUTO_DEADLINE_MULT = 200
 
 
-def _payload_rows(payload) -> int:
-    return (
-        payload.rows
-        if isinstance(payload, WireBatch)
-        else payload.fp.shape[0]
-    )
-
-
 def _payload_cols(payload) -> RequestColumns:
     return payload.cols if isinstance(payload, WireBatch) else payload
 
@@ -279,28 +271,36 @@ class Batcher:
         t_in = time.perf_counter()
         now = now_ms if now_ms is not None else ms_now()
         # stamp unset created_at at ENQUEUE time (reference stamps at request
-        # entry, gubernator.go:225-227), not at flush time
-        if isinstance(payload, WireBatch):
-            cols = payload.cols
-            payload = payload._replace(
-                cols=cols._replace(
-                    created_at=np.where(cols.created_at == 0, now, cols.created_at)
-                )
-            )
+        # entry, gubernator.go:225-227), not at flush time. Where the parser
+        # reduced these rows already (wire.RowSummary), the stamp, the tier
+        # and the cost come from that, with no scan of the columns on the
+        # event-loop thread.
+        cols = _payload_cols(payload)
+        rows = cols.fp.shape[0]
+        summary = payload.summary if isinstance(payload, WireBatch) else None
+        if summary is None or 0 < summary.unstamped < rows:
+            created = np.where(cols.created_at == 0, now, cols.created_at)
+        elif summary.unstamped:
+            created = np.full(rows, now, dtype=np.int64)
         else:
-            payload = payload._replace(
-                created_at=np.where(
-                    payload.created_at == 0, now, payload.created_at
-                )
-            )
-        rows = _payload_rows(payload)
+            created = None  # the client stamped every row
+        if created is not None:
+            cols = cols._replace(created_at=created)
+            if isinstance(payload, WireBatch):
+                if summary is not None:
+                    summary = summary._replace(unstamped=0)
+                payload = payload._replace(cols=cols, summary=summary)
+            else:
+                payload = cols
+        if summary is not None:
+            tier, cost = summary.max_tier, rows + summary.leases
+        else:
+            tier, cost = _payload_tier(payload), _payload_cost(payload)
         loop = asyncio.get_running_loop()
         if self._wake is None:
             self._wake = asyncio.Event()
             self._full = asyncio.Event()
             self._space = asyncio.Event()
-        tier = _payload_tier(payload)
-        cost = _payload_cost(payload)
         bucket = _payload_bucket(payload, self.tenant_buckets)
         deadline = self._item_deadline()
         entry = _Entry(

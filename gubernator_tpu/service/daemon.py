@@ -51,6 +51,7 @@ from gubernator_tpu.service.runner import EngineRunner
 from gubernator_tpu.service.wire import (
     batch_too_large_error,
     columns_from_pb,
+    encode_response_columns,
     expand_cascades,
     pb_from_cascade_response_columns,
     pb_from_response_columns,
@@ -64,6 +65,23 @@ import logging
 log = logging.getLogger("gubernator_tpu.daemon")
 
 FORWARD_RETRIES = 5  # reference asyncRequest retries (gubernator.go:333-359)
+
+# the behavior bits that make the raw handler do something with a row
+# besides checking it here (queue a GLOBAL hit or update, replicate to a region)
+_ROUTED_BEHAVIOR = int(Behavior.GLOBAL) | int(Behavior.MULTI_REGION)
+
+
+def _encode_counted(status, limit, remaining, reset, errors, now, err=None):
+    """What the encode hop runs for an answered raw RPC (on a door-pool
+    thread for a big one): the response bytes and the OVER_LIMIT rows for
+    the counter. An engine error column `err` is folded into the sparse
+    `errors` here, where a scan of the rows does not hold the event loop."""
+    if err is not None and err.any():
+        errors = {
+            int(i): ERROR_STRINGS[int(err[i])] for i in np.flatnonzero(err)
+        }
+    out = encode_response_columns(status, limit, remaining, reset, errors, now)
+    return out, int(np.count_nonzero(status == int(pb.OVER_LIMIT)))
 
 
 def _hashkey_fp(key: str) -> int:
@@ -101,6 +119,10 @@ class Daemon:
         # lags rather than stalling the serving path
         self.event_channel = event_channel
         self.events_dropped = 0
+        # RPCs the native raw handler served, and those of them that took
+        # its plain path (_serve_plain); /v1/debug/pipeline shows both
+        self.raw_rpcs = 0
+        self.plain_rpcs = 0
         self.metrics = DaemonMetrics(metric_flags=conf.metric_flags)
         if engine is not None:
             self.engine = engine
@@ -1092,24 +1114,31 @@ class Daemon:
     async def _route_raw(self, data, wb, ring, spans) -> "tuple[bytes, float]":
         """The response bytes, and what the encode hop waited for the door
         pool (the caller's `door_wait` line)."""
-        from gubernator_tpu.service.wire import (
-            encode_response_columns,
-            item_from_span,
-            subset_wire,
-        )
+        from gubernator_tpu.service.wire import item_from_span, subset_wire
 
         t_route = time.perf_counter()
+        self.raw_rpcs += 1
+        force_global = self.conf.behaviors.force_global
+        summary = wb.summary
+        if (
+            summary is not None
+            and wb.rows
+            and not summary.errors
+            and not summary.behavior_or & _ROUTED_BEHAVIOR
+            and not force_global
+            and self._local_picker.size() == 0
+        ):
+            return await self._serve_plain(wb, t_route)
         t_answered = 0.0  # when the last batcher.check handed its rows back
         cols = wb.cols
         n = cols.fp.shape[0]
-        force_global = self.conf.behaviors.force_global
         if force_global:
             # GLOBAL is kernel-inert (dropped on the compact wire), so the
             # routing-only behavior flip leaves the parser's lanes valid
             cols = cols._replace(
                 behavior=cols.behavior | np.int32(int(Behavior.GLOBAL))
             )
-            wb = wb._replace(cols=cols)
+            wb = wb._replace(cols=cols, summary=None)
 
         def materialize(i):
             """Lazy pb item from its wire span; a forced GLOBAL bit must
@@ -1157,9 +1186,8 @@ class Daemon:
             limit[rows] = rc.limit
             remaining[rows] = rc.remaining
             reset[rows] = rc.reset_time
-            for j, i in enumerate(rows):
-                if rc.err[j]:
-                    errors[int(i)] = ERROR_STRINGS[int(rc.err[j])]
+            for j in np.flatnonzero(rc.err):
+                errors[int(rows[j])] = ERROR_STRINGS[int(rc.err[j])]
 
         def routed():
             """`route` ends where the first batcher.check begins."""
@@ -1245,13 +1273,13 @@ class Daemon:
             self.region_manager.queue_hit(
                 item.name + "_" + item.unique_key, item
             )
-        over = int((status == int(pb.OVER_LIMIT)).sum())
-        if over:
-            self.metrics.over_limit_counter.inc(over)
         if degraded_rows:
             # degraded responses carry the metadata marker, which the native
             # encoder does not emit — partitions are the rare path, so fall
             # back to pb encoding for the whole batch
+            over = int((status == int(pb.OVER_LIMIT)).sum())
+            if over:
+                self.metrics.over_limit_counter.inc(over)
             resps = []
             for i in range(n):
                 r = pb.RateLimitResp(
@@ -1265,21 +1293,51 @@ class Daemon:
                     r.metadata["degraded"] = "true"
                 resps.append(r)
             return pb.GetRateLimitsResp(responses=resps).SerializeToString(), 0.0
+        return await self._encode_raw(
+            n, t_answered, status, limit, remaining, reset, errors
+        )
+
+    async def _serve_plain(self, wb, t_route: float) -> "tuple[bytes, float]":
+        """An RPC whose rows are all valid, all this daemon's and free of
+        GLOBAL and MULTI_REGION (the parser's summary says so; no peers, no
+        force_global): the parser's batch goes to the batcher as it is, in
+        this coroutine, and the answer's columns go to the encoder as they
+        are. Nothing here depends on the number of rows."""
+        self.plain_rpcs += 1
+        tracing.observe(
+            "route", self.metrics, time.perf_counter() - t_route,
+            tracing.current_span(),
+        )
+        rc = await self.batcher.check(wb)
+        return await self._encode_raw(
+            wb.rows, time.perf_counter(), rc.status, rc.limit, rc.remaining,
+            rc.reset_time, None, rc.err,
+        )
+
+    async def _encode_raw(
+        self, n, t_answered, status, limit, remaining, reset, errors, err=None
+    ) -> "tuple[bytes, float]":
+        """The tail of both raw paths: `respond` ends, the encode hop, the
+        over-limit counter. `err` is the engine's error column where nobody
+        has folded it into `errors` yet (the plain path)."""
         now = self.now_ms()  # retry_after_ms metadata basis (denied rows)
         if t_answered:
-            # answer in hand → encoder's start: placing the rows, the
-            # gather's wake-up of this coroutine, GLOBAL/region queueing
+            # answer in hand → encoder's start: on the general path placing
+            # the rows, the gather's wake-up of this coroutine, GLOBAL/region
+            # queueing; on the plain path this call
             tracing.observe(
                 "respond", self.metrics, time.perf_counter() - t_answered,
                 tracing.current_span(),
             )
         # native encode drops the GIL — responder workers encode big
         # batches in parallel off the event loop
-        out_bytes, encode_s, wait_s = await self._through_door(
+        (out_bytes, over), encode_s, wait_s = await self._through_door(
             "encode", n * 8 >= self.DOOR_OFFLOAD_BYTES,
-            encode_response_columns,
-            status, limit, remaining, reset, errors, now,
+            _encode_counted,
+            status, limit, remaining, reset, errors, now, err,
         )
+        if over:
+            self.metrics.over_limit_counter.inc(over)
         tracing.observe(
             "encode", self.metrics, encode_s, tracing.current_span()
         )
@@ -1691,6 +1749,12 @@ class Daemon:
             # completion (EngineRunner._run_chain); over batcher.dispatches
             # it says how often a dispatch came back to the loop
             "runner": {"loop_trips": self.runner.loop_trips},
+            # natively parsed RPCs, and how many of them crossed the loop
+            # thread with no per-row work (all rows valid, local and free
+            # of GLOBAL/MULTI_REGION: _serve_plain)
+            "daemon": {
+                "raw_rpcs": self.raw_rpcs, "plain_rpcs": self.plain_rpcs,
+            },
             # which request parser serves the door: "built"/"reused" = the
             # native extension (compiled by this process / found with a
             # matching source hash), None = the pure-Python fallback

@@ -47,8 +47,12 @@ def _timed(metrics, method):
                 ).inc()
                 # the handler's request scope has already closed; its span
                 # is this context's last-ended — the request-duration bucket
-                # carries the request's trace_id as its exemplar
-                span = tracing.last_ended_span()
+                # carries the request's trace_id as its exemplar, when an
+                # exporter can resolve it (tracing.observe's rule)
+                span = (
+                    tracing.last_ended_span()
+                    if tracing.exporter is not None else None
+                )
                 metrics.grpc_request_duration.labels(method=method).observe(
                     time.perf_counter() - t0,
                     exemplar=(

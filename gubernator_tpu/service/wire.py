@@ -38,6 +38,23 @@ def batch_too_large_error(cap: int) -> str:
     return f"Requests.RateLimits list too large; max size is '{cap}'"
 
 
+class RowSummary(NamedTuple):
+    """A parsed batch reduced over its rows, in the native parser's item
+    loop (one pass, GIL dropped): what the raw handler and the enqueue need
+    to know of the rows without scanning the columns again on the
+    event-loop thread. Counts, so `x == rows` says "all". The behavior
+    words are the client-facing ones (the parser masks the cascade level),
+    so a summarised row costs the door 1, or 2 for a lease."""
+
+    errors: int  # rows with `err` set
+    behavior_or: int  # OR of the behavior words
+    leases: int  # concurrency-lease rows
+    unstamped: int  # rows whose created_at is 0
+    encodable: int  # compact-wire representable rows
+    max_tier: int  # highest priority tier among the rows
+    cascades: int  # rows carrying a cascade field (→ the pb path)
+
+
 class WireBatch(NamedTuple):
     """One parsed request batch carrying BOTH serving forms: the legacy
     column view (routing, pb fallback, non-encodable dispatches) and the
@@ -51,13 +68,23 @@ class WireBatch(NamedTuple):
     lanes: np.ndarray  # (5, n) int32, lane-4 created-delta bits zero
     encodable: np.ndarray  # (n,) bool — compact-wire representable
     nbytes: int  # request wire size (adaptive-window byte accounting)
+    # the parser's reduction of these very rows; None once rows were
+    # selected or a summarised column rewritten (readers then scan)
+    summary: Optional[RowSummary] = None
 
     @property
     def rows(self) -> int:
         return self.cols.fp.shape[0]
 
+    @property
+    def all_encodable(self) -> bool:
+        if self.summary is not None:
+            return self.summary.encodable == self.rows
+        return bool(self.encodable.all())
+
 
 def subset_wire(wb: WireBatch, rows: np.ndarray) -> WireBatch:
+    """The rows `rows` of `wb`; the summary does not survive a selection."""
     return WireBatch(
         cols=subset_columns(wb.cols, rows),
         lanes=wb.lanes[:, rows],
@@ -428,9 +455,10 @@ def wire_batch_from_wire(data: bytes):
         return None
     (
         n, fp, algo, beh, hits, lim, burst, dur, ca, err, ring, span,
-        traceparent, lanes, enc, casc,
+        traceparent, lanes, enc, summary,
     ) = m.parse_get_rate_limits(data)
-    if n and np.frombuffer(casc, np.int8).any():
+    summary = RowSummary(*summary)
+    if summary.cascades:
         return None  # cascade batch → pb path (level expansion needs items)
     # np.frombuffer over bytes is read-only; routing mutates behavior/err
     cols = RequestColumns(
@@ -449,6 +477,7 @@ def wire_batch_from_wire(data: bytes):
         lanes=np.frombuffer(lanes, np.int32).reshape(5, n),
         encodable=np.frombuffer(enc, np.int8).astype(bool),
         nbytes=len(data),
+        summary=summary,
     )
     return (
         wb,

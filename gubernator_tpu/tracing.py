@@ -12,7 +12,7 @@ optional span-event hook embedders can point at their own tracer.
 from __future__ import annotations
 
 import contextvars
-import secrets
+import random
 import threading
 import time
 from dataclasses import dataclass
@@ -257,15 +257,23 @@ class stage:
         return False
 
 
+# ids for spans: one generator of this module's own, so that a caller who
+# seeds the `random` module does not make two daemons mint the same ids
+_id_bits = random.Random().getrandbits
+
+
 def current_span() -> Optional[SpanContext]:
     return _current.get()
 
 
 def new_span(parent: Optional[SpanContext] = None) -> SpanContext:
-    """A child of `parent` (same trace), or a fresh root."""
+    """A child of `parent` (same trace), or a fresh root. A W3C id has to be
+    unique, not unguessable: the ids come from the process's Mersenne
+    Twister, seeded from the system once, and not from `os.urandom`, a
+    system call that drops the GIL twice for every RPC on the event loop."""
     return SpanContext(
-        trace_id=parent.trace_id if parent else secrets.token_hex(16),
-        span_id=secrets.token_hex(8),
+        trace_id=parent.trace_id if parent else f"{_id_bits(128):032x}",
+        span_id=f"{_id_bits(64):016x}",
         flags=parent.flags if parent else _FLAG_SAMPLED,
     )
 

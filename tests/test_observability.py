@@ -291,6 +291,134 @@ async def test_request_spans_link_to_shared_dispatch_span():
         await d.close()
 
 
+def _is_id(s, n):
+    return len(s) == n and s == s.lower() and int(s, 16) != 0
+
+
+@async_test
+async def test_ids_with_exporter_inbound_traceparent_and_forwarded_row():
+    """With a reader of the ids (an exporter, an inbound traceparent, a row
+    forwarded to its owner) they are where they always were: the ingress
+    span continues the client's trace under the client's span, its stage
+    spans hang under it, the owner's span hangs under the ingress span
+    through the forwarded row's metadata and links to the dispatch that
+    served it, and the gRPC duration bucket carries the trace as exemplar."""
+    from prometheus_client.openmetrics.parser import (
+        text_string_to_metric_families,
+    )
+
+    from tests.cluster import Cluster, wait_for
+
+    exp = StubExporter()
+    old = tracing.exporter
+    tracing.set_exporter(exp)
+    c = await Cluster.start(2)
+    trace, client_span = "5e" * 16, "c1" * 8
+    try:
+        ingress = c.non_owning_daemons("ids", "fwd")[0]
+        client = V1Client(ingress.conf.grpc_address)
+        try:
+            resp = await client.get_rate_limits([
+                RateLimitRequest(
+                    name="ids", unique_key="fwd", hits=1, limit=10,
+                    duration=60_000,
+                    metadata={"traceparent": f"00-{trace}-{client_span}-01"},
+                )
+            ])
+            assert resp.responses[0].error == ""
+        finally:
+            await client.close()
+
+        def spans(name):
+            return [s for s in exp.spans
+                    if s["name"] == name and s["trace_id"] == trace]
+
+        await wait_for(lambda: asyncio.sleep(0, spans("GetPeerRateLimits")))
+        (req_span,) = spans("GetRateLimits")
+        assert req_span["parent"] == client_span
+        assert _is_id(req_span["span_id"], 16)
+        assert req_span["span_id"] != client_span
+        # the ingress handler's stage spans are children of its span
+        kids = [s for s in exp.spans if s["parent"] == req_span["span_id"]]
+        assert {"parse", "request"} <= {s["name"] for s in kids}
+        assert all(s["trace_id"] == trace and _is_id(s["span_id"], 16)
+                   for s in kids)
+        # the forwarded row carried the ingress span to the owner
+        (peer_span,) = spans("GetPeerRateLimits")
+        assert peer_span["parent"] == req_span["span_id"]
+        # and the owner's span links to the dispatch that served the row,
+        # a trace of its own, which links back
+        assert len(peer_span["links"]) == 1
+        link = peer_span["links"][0]
+        assert _is_id(link.trace_id, 32) and link.trace_id != trace
+        (disp,) = [s for s in exp.spans if s["name"] == "dispatch"
+                   and s["span_id"] == link.span_id]
+        assert peer_span["span_id"] in {l.span_id for l in disp["links"]}
+        # the request-duration bucket of the ingress door names the trace
+        tids = [
+            smp.exemplar.labels["trace_id"]
+            for fam in text_string_to_metric_families(
+                ingress.metrics.render(openmetrics=True).decode()
+            )
+            for smp in fam.samples
+            if smp.exemplar is not None
+            and smp.name == "gubernator_grpc_request_duration_bucket"
+            and smp.labels.get("method") == "/v1.GetRateLimits"
+        ]
+        assert tids == [trace]
+    finally:
+        tracing.set_exporter(old)
+        await c.stop()
+
+
+@async_test
+async def test_rpc_without_a_reader_of_its_ids_calls_no_urandom(monkeypatch):
+    """No exporter, no inbound traceparent, no hook: nothing will read an
+    RPC's ids, and serving it makes no `os.urandom` system call (each one
+    drops the GIL on the event-loop thread); with an exporter the duration
+    bucket gets its exemplar, without one it does not."""
+    import os
+    import random
+
+    from gubernator_tpu.service.daemon import Daemon
+
+    calls = []
+    real = os.urandom
+
+    def counted(n):
+        calls.append(n)
+        return real(n)
+
+    assert tracing.exporter is None and tracing.span_hook is None
+    d = await Daemon.spawn(daemon_config())
+    client = V1Client(d.conf.grpc_address)
+    try:
+        reqs = lambda tag, n: [
+            RateLimitRequest(name="nou", unique_key=f"{tag}{i}", hits=1,
+                             limit=10, duration=60_000)
+            for i in range(n)
+        ]
+        await client.get_rate_limits(reqs("w", 300))  # connect, compile
+        await client.get_rate_limits(reqs("w", 3))
+        monkeypatch.setattr(os, "urandom", counted)
+        monkeypatch.setattr(random, "_urandom", counted)  # what `secrets` calls
+        for k in range(5):
+            big = await client.get_rate_limits(reqs(f"b{k}", 300))  # door pool
+            small = await client.get_rate_limits(reqs(f"s{k}", 3))  # inline
+            assert len(big.responses) == 300 and len(small.responses) == 3
+        await d.get_rate_limits_raw(_raw_request("raw", 200))
+        assert calls == []
+        span = tracing.new_span()
+        assert _is_id(span.trace_id, 32) and _is_id(span.span_id, 16)
+        child = tracing.new_span(span)
+        assert child.trace_id == span.trace_id and child.span_id != span.span_id
+        assert calls == []
+        assert "trace_id" not in d.metrics.render(openmetrics=True).decode()
+    finally:
+        await client.close()
+        await d.close()
+
+
 # ------------------------------------------- one primitive, budgets, profiler
 
 

@@ -1,0 +1,310 @@
+"""The raw handler's plain path (service/daemon.py:_serve_plain): an RPC whose
+rows are all valid, all local and free of GLOBAL/MULTI_REGION goes parser →
+batcher → encoder with no per-row work on the event-loop thread, on the
+strength of the summary the native parser reduced over the rows.
+
+Contract: the plain path is a pure perf change. The same bodies answered by
+the general path (forced by each thing that disqualifies an RPC) give the
+same bytes; the summary equals numpy reductions of the columns; a selection
+of rows drops it; the enqueue reads tier, cost and stamp from it exactly as
+its scans would."""
+
+import asyncio
+import functools
+
+import numpy as np
+import pytest
+
+from gubernator_tpu import native
+from gubernator_tpu.ops.engine import LocalEngine, ms_now
+from gubernator_tpu.proto import gubernator_pb2 as pb
+from gubernator_tpu.service import batcher as batcher_mod
+from gubernator_tpu.service import daemon as daemon_mod
+from gubernator_tpu.service.batcher import Batcher
+from gubernator_tpu.service.daemon import Daemon
+from gubernator_tpu.service.wire import (
+    RowSummary,
+    WireBatch,
+    subset_wire,
+    wire_batch_from_wire,
+)
+from gubernator_tpu.types import (
+    PRIORITY_MASK,
+    PRIORITY_SHIFT,
+    Algorithm,
+    Behavior,
+    PeerInfo,
+)
+
+from tests.cluster import daemon_config
+
+pytestmark = pytest.mark.skipif(
+    native.load() is None, reason="native toolchain unavailable"
+)
+
+NOW = ms_now()  # every clock the answers can see is pinned to this
+
+
+def async_test(fn):
+    @functools.wraps(fn)
+    def wrapper(*a, **k):
+        asyncio.run(fn(*a, **k))
+
+    return wrapper
+
+
+def req(i: int, **kw) -> "pb.RateLimitReq":
+    d = dict(name="pl", unique_key=f"k{i}", hits=1, limit=3 + i,
+             duration=60_000, created_at=NOW)
+    d.update(kw)
+    return pb.RateLimitReq(**d)
+
+
+def body(items) -> bytes:
+    return pb.GetRateLimitsReq(requests=items).SerializeToString()
+
+
+# ------------------------------------------------------------------ parity
+# what sends an RPC down the general path: rows appended to the plain body
+# (answered after it, so the plain rows' bytes are a prefix of the answer),
+# or the daemon's own state
+ERROR_ROW = pb.RateLimitReq(name="pl", hits=1, limit=1)  # no unique_key
+TRIGGERS = {
+    "error_row": ([ERROR_ROW], None),
+    "global_row": ([req(900, behavior=int(Behavior.GLOBAL))], None),
+    "multi_region_row": ([req(901, behavior=int(Behavior.MULTI_REGION))], None),
+    "force_global": ([], lambda d: setattr(d.conf.behaviors, "force_global", True)),
+    "peer_set_owner_self": (
+        [], lambda d: d.set_peers([PeerInfo(grpc_address=d.conf.advertise_address)])
+    ),
+}
+
+
+def plain_rounds(rc_err_row: bool):
+    """Three rounds over the same keys: under the limit, at it, over it
+    (OVER_LIMIT rows carry retry_after_ms and feed the counter); stamped and
+    unstamped created_at; a leaky row, a priority tier, a lease, a reset.
+    `rc_err_row` adds a row the parser passes and the engine refuses (limit
+    beyond int32): an error the plain path has to fold in itself."""
+    rows = [
+        req(0), req(1, hits=2), req(2, created_at=0), req(3, algorithm=1),
+        req(4, behavior=2 << PRIORITY_SHIFT),
+        req(5, behavior=int(Behavior.DRAIN_OVER_LIMIT), hits=2),
+        req(6, algorithm=int(Algorithm.CONCURRENCY_LEASE)),
+    ]
+    if rc_err_row:
+        rows.append(req(7, limit=1 << 40))
+    return [rows, rows, rows + [req(8, behavior=int(Behavior.RESET_REMAINING))]]
+
+
+async def _spawn():
+    d = await Daemon.spawn(
+        daemon_config(http_address=""),
+        engine=LocalEngine(capacity=8192, wire="compact"),
+    )
+    d.now_ms = lambda: NOW + 7  # retry_after_ms basis
+    return d
+
+
+@pytest.mark.parametrize("rc_err_row", [False, True], ids=["", "rc_err_row"])
+@pytest.mark.parametrize("trigger", sorted(TRIGGERS))
+@async_test
+async def test_plain_path_bytes_equal_general_path(trigger, rc_err_row, monkeypatch):
+    """The same rounds through a daemon that serves them on the plain path
+    and through one that is forced onto the general path: the plain rows'
+    response bytes are equal, and the counters say which path each took."""
+    monkeypatch.setattr(batcher_mod, "ms_now", lambda: NOW + 3)  # the enqueue's stamp
+    counted = []  # OVER_LIMIT rows each encode hop reported, both daemons in turn
+    encode = daemon_mod._encode_counted
+
+    def spy(*a):
+        out = encode(*a)
+        counted.append(out[1])
+        return out
+
+    monkeypatch.setattr(daemon_mod, "_encode_counted", spy)
+    extra, arrange = TRIGGERS[trigger]
+    d_plain, d_gen = await _spawn(), await _spawn()
+    try:
+        if arrange is not None:
+            arrange(d_gen)
+        rounds = plain_rounds(rc_err_row)
+        for k, rows in enumerate(rounds):
+            got_plain = await d_plain.get_rate_limits_raw(body(rows))
+            got_gen = await d_gen.get_rate_limits_raw(body(rows + extra))
+            assert got_gen[: len(got_plain)] == got_plain, (trigger, k)
+            tail = pb.GetRateLimitsResp.FromString(got_gen[len(got_plain):])
+            assert len(tail.responses) == len(extra)
+            answers = pb.GetRateLimitsResp.FromString(got_plain).responses
+            assert len(answers) == len(rows)
+            if rc_err_row:
+                assert answers[7].error and not answers[0].error
+        assert answers[1].status == pb.OVER_LIMIT  # 3 × 2 hits of limit 4
+        assert answers[1].metadata["retry_after_ms"] == str(60_000 - 7)
+        assert (d_plain.raw_rpcs, d_plain.plain_rpcs) == (3, 3)
+        assert (d_gen.raw_rpcs, d_gen.plain_rpcs) == (3, 0)
+        assert counted[0::2] == counted[1::2] and counted[-1] > 0
+        pipe = d_plain.debug_pipeline()["daemon"]
+        assert pipe == {"raw_rpcs": 3, "plain_rpcs": 3}
+    finally:
+        await d_plain.close()
+        await d_gen.close()
+
+
+@async_test
+async def test_rpcs_that_are_not_plain_are_counted_so():
+    """An empty RPC and a cascade RPC (which leaves for the pb path before
+    the raw handler) are not plain; the second is not raw either."""
+    d = await Daemon.spawn(
+        daemon_config(http_address=""),
+        engine=LocalEngine(capacity=8192, wire="compact"),
+    )
+    try:
+        assert await d.get_rate_limits_raw(body([])) == b""
+        assert (d.raw_rpcs, d.plain_rpcs) == (1, 0)
+        casc = req(1)
+        casc.cascade.add(name="pl", unique_key="tenant", limit=10, duration=60_000)
+        out = pb.GetRateLimitsResp.FromString(
+            await d.get_rate_limits_raw(body([req(0), casc]))
+        )
+        assert len(out.responses) == 2 and len(out.responses[1].cascade) == 1
+        assert (d.raw_rpcs, d.plain_rpcs) == (1, 0)
+        await d.get_rate_limits_raw(body([req(0)]))
+        assert (d.raw_rpcs, d.plain_rpcs) == (2, 1)
+    finally:
+        await d.close()
+
+
+# ----------------------------------------------------------------- summary
+
+
+def random_items(rng, n: int):
+    """An item mix that moves every field of the summary."""
+    items = []
+    for i in range(n):
+        behavior = int(rng.choice([0, 0, 0, 1, 2, 8, 16, 32, 4]))
+        behavior |= int(rng.integers(0, 4)) << PRIORITY_SHIFT
+        if rng.random() < 0.1:
+            behavior |= 1 << 9  # a forged cascade level: masked at ingress
+        it = pb.RateLimitReq(
+            name="" if rng.random() < 0.05 else "sm",
+            unique_key="" if rng.random() < 0.05 else f"k{i}",
+            hits=int(rng.choice([0, 1, 5, 1 << 19])),
+            limit=int(rng.choice([10, 1 << 20, 1 << 40])),
+            duration=60_000,
+            algorithm=int(rng.integers(0, 5)),
+            behavior=behavior,
+        )
+        if rng.random() < 0.5:
+            it.created_at = NOW + int(rng.integers(0, 100))
+        items.append(it)
+    return items
+
+
+def reduced(wb: WireBatch) -> RowSummary:
+    """The summary as numpy reductions of the parsed columns."""
+    c = wb.cols
+    return RowSummary(
+        errors=int((c.err != 0).sum()),
+        behavior_or=int(np.bitwise_or.reduce(c.behavior, initial=0)),
+        leases=int((c.algo == int(Algorithm.CONCURRENCY_LEASE)).sum()),
+        unstamped=int((c.created_at == 0).sum()),
+        encodable=int(wb.encodable.sum()),
+        max_tier=int(((c.behavior >> PRIORITY_SHIFT) & PRIORITY_MASK).max(initial=0)),
+        cascades=0,
+    )
+
+
+@pytest.mark.parametrize("n", [0, 1, 2, 37, 600])
+@pytest.mark.parametrize("seed", [1, 2, 3])
+def test_summary_equals_numpy_reductions(seed, n):
+    rng = np.random.default_rng(seed * 1000 + n)
+    wb = wire_batch_from_wire(body(random_items(rng, n)))[0]
+    assert wb.rows == n
+    assert wb.summary == reduced(wb)
+    assert wb.all_encodable == bool(wb.encodable.all())
+    assert wb.summary.behavior_or < 256  # client-facing bits only
+
+
+def test_summary_edges_all_and_none():
+    """All rows unstamped / none; the cascade count sends a batch to the pb
+    path; the raw parser's tuple ends with the summary."""
+    stamped = wire_batch_from_wire(body([req(i) for i in range(5)]))[0]
+    assert stamped.summary == RowSummary(0, 0, 0, 0, 5, 0, 0)
+    bare = wire_batch_from_wire(body([req(i, created_at=0) for i in range(5)]))[0]
+    assert bare.summary.unstamped == 5
+    casc = req(1)
+    casc.cascade.add(name="pl", unique_key="t", limit=10, duration=60_000)
+    data = body([req(0), casc])
+    assert wire_batch_from_wire(data) is None
+    raw = native.load().parse_get_rate_limits(data)
+    assert RowSummary(*raw[-1]).cascades == 1
+
+
+def test_subset_wire_drops_the_summary():
+    rng = np.random.default_rng(7)
+    wb = wire_batch_from_wire(body(random_items(rng, 20)))[0]
+    assert wb.summary is not None
+    sub = subset_wire(wb, np.array([1, 3, 5]))
+    assert sub.summary is None and sub.rows == 3
+    assert sub.all_encodable == bool(wb.encodable[[1, 3, 5]].all())
+
+
+# ----------------------------------------------------------------- enqueue
+
+
+class EchoRunner:
+    """Answers every chunk at once; keeps the payloads it was handed."""
+
+    def __init__(self):
+        self.payloads = []
+
+    async def check_wire(self, payloads, now_ms=None, disp=None, done=None):
+        from gubernator_tpu.service.wire import empty_response_columns
+
+        self.payloads.extend(payloads)
+        done(empty_response_columns(sum(p.rows for p in payloads)), None, False)
+
+
+@pytest.mark.parametrize("stamps", ["none", "some", "all"])
+@async_test
+async def test_enqueue_reads_the_summary_as_it_scans(stamps, monkeypatch):
+    """Tier, cost and the created_at stamp of an enqueue: from the summary
+    and from the scans of a summary-less payload, the same."""
+    entries = []
+
+    class Spy(batcher_mod._Entry):
+        def __init__(self, *a):
+            super().__init__(*a)
+            entries.append(self)
+
+    monkeypatch.setattr(batcher_mod, "_Entry", Spy)
+    rng = np.random.default_rng(11)
+    items = [it for it in random_items(rng, 600) if it.name and it.unique_key]
+    for i, it in enumerate(items):
+        if stamps == "none" or (stamps == "some" and i % 3):
+            it.ClearField("created_at")
+        else:
+            it.created_at = NOW + i % 50
+    wb = wire_batch_from_wire(body(items))[0]
+    assert (wb.summary.unstamped == 0) == (stamps == "all")
+    assert (wb.summary.unstamped == wb.rows) == (stamps == "none")
+    runner = EchoRunner()
+    b = Batcher(runner, batch_wait_ms=0.0, workers=1)
+    try:
+        await b.check(wb, now_ms=NOW + 3)
+        await b.check(wb._replace(summary=None), now_ms=NOW + 3)
+    finally:
+        await b.drain()
+    with_summary, scanned = entries
+    assert (with_summary.tier, with_summary.cost, with_summary.rows) == (
+        scanned.tier, scanned.cost, scanned.rows
+    )
+    assert with_summary.cost > with_summary.rows  # leases cost 2
+    a, c = (p.cols.created_at for p in runner.payloads)
+    np.testing.assert_array_equal(a, c)
+    assert (a != 0).all()
+    if stamps == "all":  # nothing to stamp: the parser's column itself
+        assert runner.payloads[0].cols.created_at is wb.cols.created_at
+    # stamped once: handing the stamped payload in again changes nothing
+    assert runner.payloads[0].summary.unstamped == 0
