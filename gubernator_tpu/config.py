@@ -185,9 +185,7 @@ class BehaviorConfig:
     # consume tier (docs/latency.md "Launch budget"): "auto" resolves per
     # backend (fused on TPU, host on CPU); "host" = one XLA launch per
     # published slot; "fused" = ONE jitted while_loop launch drains up to
-    # ring_drain_k published slots (ops/ring_drain.py); "persistent" =
-    # staged Pallas fence-claim tier (runs the fused drain with a watchdog
-    # until the device run validates the resident loop)
+    # ring_drain_k published slots (ops/ring_drain.py)
     ring_issue: str = "auto"
     # max published slots one fused drain launch retires (the launch-
     # amortization factor; clamped to ring_slots)
@@ -315,34 +313,12 @@ class DaemonConfig:
     # "device" (in-trace aggregation — hits summed, RESET OR-ed, newest
     # config wins; O(1) host planning, kernel2.dedup_packed_cols)
     shard_dedup: str = "auto"
-    # ownership-exchange schedule for route="device" dispatches
-    # (parallel/ring.py): "auto" (= collective) | "collective" (one
-    # lax.all_to_all per direction) | "ring" (hand-rolled per-hop
-    # schedule: ppermute shifts on CPU meshes; its TPU remote-DMA kernel
-    # is refused by the compiler and raises when selected).
-    # Byte-identical results either way; GUBER_A2A_IMPL.
-    a2a_impl: str = "auto"
     # fold the mesh's devices into this many (simulated) host rows — the
     # 2-D (host, device) topology used by multi-host tests/CI on one
     # machine (GUBER_MESH_HOSTS; 0 = from the runtime: process_count on a
     # real pod slice, 1 host otherwise). Read by parallel/mesh.make_mesh
     # through the environment, surfaced here for validation + visibility.
     mesh_hosts: int = 0
-    # table-walk kernel for decide dispatches (ops/plan.default_probe_kernel;
-    # GUBER_PROBE_KERNEL): "auto" (= xla until the device record flips it) |
-    # "xla" (row gather + sweep/sparse write) | "pallas" (the fused
-    # double-buffered probe→decide→write megakernel, ops/pallas_probe.py —
-    # interpret-mode on CPU backends)
-    probe_kernel: str = "auto"
-    # table-walk kernel for the NON-decide walks — GLOBAL installs,
-    # region/handoff merges, tiering promotes (ops/plan.default_walk_kernel;
-    # GUBER_WALK_KERNEL): "auto" (= xla until the device bench's fused-vs-
-    # two-pass wall flips it) | "xla" (two-pass gather + sweep/sparse
-    # write) | "pallas" (the fused probe→install/merge→write walk,
-    # ops/pallas_probe.walk2_pallas_impl). Independent of probe_kernel so
-    # the latency-critical decide path and the throughput walks can flip
-    # separately.
-    walk_kernel: str = "auto"
     workers: int = 0  # 0 = auto; host-side executor width
 
     behaviors: BehaviorConfig = field(default_factory=BehaviorConfig)
@@ -560,24 +536,9 @@ class DaemonConfig:
                 f"GUBER_SHARD_DEDUP: must be auto, host or device, got "
                 f"{self.shard_dedup!r}"
             )
-        if self.a2a_impl not in ("auto", "ring", "collective"):
-            raise ConfigError(
-                f"GUBER_A2A_IMPL: must be auto, ring or collective, got "
-                f"{self.a2a_impl!r}"
-            )
         if self.mesh_hosts < 0:
             raise ConfigError(
                 "GUBER_MESH_HOSTS must be >= 0 (0 = topology from the runtime)"
-            )
-        if self.probe_kernel not in ("auto", "xla", "pallas"):
-            raise ConfigError(
-                f"GUBER_PROBE_KERNEL: must be auto, xla or pallas, got "
-                f"{self.probe_kernel!r}"
-            )
-        if self.walk_kernel not in ("auto", "xla", "pallas"):
-            raise ConfigError(
-                f"GUBER_WALK_KERNEL: must be auto, xla or pallas, got "
-                f"{self.walk_kernel!r}"
             )
         if self.cache_size <= 0:
             raise ConfigError("GUBER_CACHE_SIZE must be positive")
@@ -624,11 +585,9 @@ class DaemonConfig:
                 "GUBER_RING_SLOTS must be >= 2 (a 1-slot ring serializes "
                 "staging against consumption — no overlap to buy)"
             )
-        if self.behaviors.ring_issue not in (
-            "auto", "host", "fused", "persistent"
-        ):
+        if self.behaviors.ring_issue not in ("auto", "host", "fused"):
             raise ConfigError(
-                "GUBER_RING_ISSUE must be auto, host, fused or persistent, "
+                "GUBER_RING_ISSUE must be auto, host or fused, "
                 f"got {self.behaviors.ring_issue!r}"
             )
         if self.behaviors.ring_drain_k < 1:
@@ -770,10 +729,7 @@ def setup_daemon_config(
         engine=_get(env, "GUBER_ENGINE", "local"),
         shard_route=_get(env, "GUBER_SHARD_ROUTE", "auto"),
         shard_dedup=_get(env, "GUBER_SHARD_DEDUP", "auto"),
-        a2a_impl=_get(env, "GUBER_A2A_IMPL", "auto"),
         mesh_hosts=_get_int(env, "GUBER_MESH_HOSTS", 0),
-        probe_kernel=_get(env, "GUBER_PROBE_KERNEL", "auto"),
-        walk_kernel=_get(env, "GUBER_WALK_KERNEL", "auto"),
         workers=_get_int(env, "GUBER_WORKER_COUNT", 0),
         behaviors=BehaviorConfig(
             batch_timeout_ms=_get_float_ms(env, "GUBER_BATCH_TIMEOUT", 500.0),

@@ -6,8 +6,7 @@ service/lease_manager.py is the server half). One LocalLimiter guards one
 
 * ``allow(hits)`` is the SYNCHRONOUS hot path: a lock-guarded counter
   decrement against the leased budget — no RPC, no event loop, safe from
-  any thread. This is what turns ~10⁵ checks/s of per-RPC fan-in into
-  ~10⁷ local admissions/s (the bench.py ``leases`` phase records it).
+  any thread: an admission costs no round trip to the limiter.
 * A background task renews ahead of expiry with ADAPTIVE grant sizing:
   exhaustion before renewal doubles the next grant; a mostly-unused grant
   (returned-unused fraction above ``waste_fraction``) halves it — so a hot
@@ -17,9 +16,8 @@ service/lease_manager.py is the server half). One LocalLimiter guards one
   the server's ``retry_after_ms`` (denials short-circuit locally until the
   conforming instant, so a denied edge never hammers the daemon).
 
-Honesty bounds (asserted by tests/test_edge_lease.py and the CI
-``lease_smoke``): local admissions never exceed tokens granted; a limiter
-stops admitting the instant its lease expires (an unreachable daemon
+Honesty bounds (asserted by tests/test_edge_lease.py): local admissions
+never exceed tokens granted; a limiter stops admitting the instant its lease expires (an unreachable daemon
 degrades, never over-admits); across a daemon crash + restart, total
 admissions ≤ limit + outstanding-at-crash.
 """

@@ -985,14 +985,8 @@ class LocalEngine:
         store=None,
         wire: Optional[str] = None,
         layout: Optional[str] = None,
-        probe: Optional[str] = None,
-        walk: Optional[str] = None,
     ):
         from gubernator_tpu.ops.layout import resolve_layout
-        from gubernator_tpu.ops.plan import (
-            default_probe_kernel,
-            default_walk_kernel,
-        )
         from gubernator_tpu.ops.wire import default_wire_mode
 
         # slot layout (ops/layout.py): "full" (bit-compatible default),
@@ -1028,25 +1022,6 @@ class LocalEngine:
         # at EVERY batch size 2K-16K vs sweep 4.1-4.9 ms), so it picked a
         # 13× slower path exactly where latency mattered.
         self.write_mode = write_mode or default_write_mode()
-        # table-walk kernel for decide dispatches (GUBER_PROBE_KERNEL /
-        # probe=): "xla" — the row gather + sweep/sparse write every PR
-        # before the megakernel shipped — or "pallas", the fused
-        # probe→decide→write kernel (ops/pallas_probe.py) streaming the
-        # touched rows through VMEM with double-buffered DMA. A static jit
-        # arg like write/math, so both kernels can serve side by side.
-        if probe is not None and probe not in ("xla", "pallas"):
-            raise ValueError(f"probe must be 'xla' or 'pallas', got {probe!r}")
-        self.probe_mode = probe or default_probe_kernel()
-        # table-walk kernel for the NON-decide walks — GLOBAL installs,
-        # region/handoff merges, tiering promotes (GUBER_WALK_KERNEL /
-        # walk=): "xla" two-pass gather + write, or "pallas" — the fused
-        # probe→install/merge→write megakernel sharing the decide
-        # kernel's claim/carry/write machinery (ops/pallas_probe.py).
-        # Independent of probe_mode: serving latency and sync throughput
-        # flip separately.
-        if walk is not None and walk not in ("xla", "pallas"):
-            raise ValueError(f"walk must be 'xla' or 'pallas', got {walk!r}")
-        self.walk_mode = walk or default_walk_kernel()
         self._decide_fn = decide_fn
         # oracle engines return unpacked outputs; the begin/finish split
         # assumes the packed single-fetch layout
@@ -1227,12 +1202,12 @@ class LocalEngine:
 
             self.table, packed = decide2_wire_cols(
                 self.table, dev_arr, write=self.write_mode, math=math,
-                cascade=cascade, probe=self.probe_mode, evictees=ev,
+                cascade=cascade, evictees=ev,
             )
             return packed
         self.table, packed = decide2_packed_cols(
             self.table, dev_arr, write=self.write_mode, math=math,
-            cascade=cascade, probe=self.probe_mode, evictees=ev,
+            cascade=cascade, evictees=ev,
         )
         return packed
 
@@ -1480,7 +1455,7 @@ class LocalEngine:
             ),
         )
         self.table, installed = install2(
-            self.table, inst, write=self.write_mode, probe=self.walk_mode
+            self.table, inst, write=self.write_mode
         )
         self.stats.dispatches += 1
         return int(np.asarray(installed).sum())
@@ -1588,8 +1563,7 @@ class LocalEngine:
         )
         if collect:
             self.table, merged, ev = merge2(
-                *args, write=self.write_mode, evictees=True,
-                probe=self.walk_mode,
+                *args, write=self.write_mode, evictees=True
             )
             self.stats.dispatches += 1
             mask = np.asarray(merged)[:n].copy()
@@ -1600,9 +1574,7 @@ class LocalEngine:
             return (
                 int(mask.sum()), mask, ev_fp[keep], ev_h[keep].copy()
             )
-        self.table, merged = merge2(
-            *args, write=self.write_mode, probe=self.walk_mode
-        )
+        self.table, merged = merge2(*args, write=self.write_mode)
         self.stats.dispatches += 1
         return int(np.asarray(merged).sum())
 

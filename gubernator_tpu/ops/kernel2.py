@@ -629,13 +629,10 @@ def _write_xla(rows_tbl, new16, c: Claim2, layout=None):
 
 
 def decide_payload(lane16, req: ReqBatch, owns, *, math: str):
-    """The per-row DECIDE stage, shared VERBATIM by the XLA path below and
-    the fused Pallas probe kernel (ops/pallas_probe.py): the chosen lane's
-    canonical (B, 16) stored fields + the request rows → (exists, Decision,
-    canonical (B, 16) write-payload rows). Factoring it out is what makes
-    the two probe kernels bit-identical by construction on everything
-    downstream of the claim — algorithm math, payload packing, response
-    fields — instead of by parallel maintenance."""
+    """The per-row DECIDE stage: the chosen lane's canonical (B, 16) stored
+    fields + the request rows → (exists, Decision, canonical (B, 16)
+    write-payload rows) — algorithm math and payload packing, everything
+    downstream of the claim except the response assembly."""
     now = req.created_at
     B = req.fp.shape[0]
     g = lambda f: lane16[:, f]
@@ -709,8 +706,8 @@ def decide_payload(lane16, req: ReqBatch, owns, *, math: str):
 
 
 def assemble_resp(req: ReqBatch, d, exists, written, evict_live):
-    """Response + stats assembly shared by both probe kernels: the Decision
-    rows plus the claim outcome flags → (RespBatch, BatchStats)."""
+    """Response + stats assembly: the Decision rows plus the claim outcome
+    flags → (RespBatch, BatchStats)."""
     active = req.active
     OVER = jnp.int32(int(Status.OVER_LIMIT))
     UNDER = jnp.int32(int(Status.UNDER_LIMIT))
@@ -741,7 +738,7 @@ def assemble_resp(req: ReqBatch, d, exists, written, evict_live):
 
 def decide2_impl(
     table: Table2, req: ReqBatch, *, write: str = "sweep", math: str = "mixed",
-    probe: str = "xla", evictees: bool = False,
+    evictees: bool = False,
 ) -> Tuple[Table2, RespBatch, BatchStats]:
     """Un-jitted v2 kernel body — call through `decide2` / `decide2_xla`.
 
@@ -755,12 +752,6 @@ def decide2_impl(
     layouts only serve their own math mode — the engine migrates a packed
     table to full before dispatching off-family traffic, so this guard
     firing means a caller skipped the engine layer.
-
-    `probe="pallas"` routes the WHOLE decide path — bucket-row fetch,
-    layout unpack, claim, algorithm math and dirty-row write-back — through
-    the fused double-buffered Pallas megakernel (ops/pallas_probe.py,
-    GUBER_PROBE_KERNEL) instead of the XLA gather + separate sweep/sparse
-    write; `write` is then moot (the megakernel writes its own dirty rows).
 
     `evictees=True` (static — compiled only when a shadow tier is attached,
     gubernator_tpu/tier/) additionally returns the EVICTEE SIDECAR: a
@@ -778,14 +769,6 @@ def decide2_impl(
             "migrate the table to the full layout first (engine does this "
             "automatically)"
         )
-    if probe not in ("xla", "pallas"):
-        raise ValueError(
-            f"unknown probe kernel {probe!r}; expected 'xla' or 'pallas'"
-        )
-    if probe == "pallas":
-        from gubernator_tpu.ops.pallas_probe import decide2_pallas_impl
-
-        return decide2_pallas_impl(table, req, math=math, evictees=evictees)
     B = req.fp.shape[0]
     NB = table.rows.shape[0]
     write = resolve_write(write, NB, B, layout)
@@ -820,7 +803,7 @@ def decide2_impl(
 
 decide2 = functools.partial(
     jax.jit, donate_argnums=(0,),
-    static_argnames=("write", "math", "probe", "evictees"),
+    static_argnames=("write", "math", "evictees"),
 )(decide2_impl)
 
 
@@ -979,18 +962,16 @@ def unpack_outputs(arr, n: int):
 
 def decide2_packed_impl(
     table: Table2, req: ReqBatch, *, write: str = "sweep", math: str = "mixed",
-    probe: str = "xla", evictees: bool = False,
+    evictees: bool = False,
 ):
     """(table', packed (B+2, 4) i64[, evictee sidecar (B, 16) i32]) — the
     sidecar element exists only under evictees=True (see decide2_impl)."""
     if evictees:
         table, resp, stats, ev16 = decide2_impl(
-            table, req, write=write, math=math, probe=probe, evictees=True
+            table, req, write=write, math=math, evictees=True
         )
         return table, pack_outputs(resp, stats, req.behavior), ev16
-    table, resp, stats = decide2_impl(
-        table, req, write=write, math=math, probe=probe
-    )
+    table, resp, stats = decide2_impl(table, req, write=write, math=math)
     return table, pack_outputs(resp, stats, req.behavior)
 
 
@@ -1016,29 +997,25 @@ def req_from_arr(arr: jnp.ndarray) -> ReqBatch:
 
 def decide2_packed_cols_impl(
     table: Table2, arr: jnp.ndarray, *, write: str = "sweep",
-    math: str = "mixed", cascade: bool = False, probe: str = "xla",
-    evictees: bool = False,
+    math: str = "mixed", cascade: bool = False, evictees: bool = False,
 ) -> Tuple[Table2, jnp.ndarray]:
     """Single-transfer serving entry: packed ingress array in, packed
     output array out — one host→device put and one device→host fetch per
     dispatch regardless of column count. `cascade=True` folds cascade
     groups' combined verdicts into their carrier rows in-trace (set by the
     engine for order-preserving single-device dispatches whose batch
-    carries level bits — see fold_cascade_packed). `probe` selects the
-    table-walk kernel (GUBER_PROBE_KERNEL): the XLA gather + sweep write,
-    or the fused Pallas megakernel (ops/pallas_probe.py). `evictees=True`
+    carries level bits — see fold_cascade_packed). `evictees=True`
     rides the evictee sidecar home in the same fetched array
     (attach_evictees; decoded host-side by unpack_evictees)."""
     if evictees:
         table, packed, ev16 = decide2_packed_impl(
-            table, req_from_arr(arr), write=write, math=math, probe=probe,
-            evictees=True,
+            table, req_from_arr(arr), write=write, math=math, evictees=True
         )
         if cascade:
             packed = fold_cascade_packed(packed, arr)
         return table, attach_evictees(packed, ev16)
     table, packed = decide2_packed_impl(
-        table, req_from_arr(arr), write=write, math=math, probe=probe
+        table, req_from_arr(arr), write=write, math=math
     )
     if cascade:
         packed = fold_cascade_packed(packed, arr)
@@ -1047,7 +1024,7 @@ def decide2_packed_cols_impl(
 
 decide2_packed_cols = functools.partial(
     jax.jit, donate_argnums=(0,),
-    static_argnames=("write", "math", "cascade", "probe", "evictees"),
+    static_argnames=("write", "math", "cascade", "evictees"),
 )(decide2_packed_cols_impl)
 
 
@@ -1232,7 +1209,7 @@ def fold_cascade_packed(packed: jnp.ndarray, arr: jnp.ndarray) -> jnp.ndarray:
 
 def decide2_packed_dedup_impl(
     table: Table2, arr: jnp.ndarray, *, write: str = "sweep",
-    math: str = "mixed", cascade: bool = False, probe: str = "xla",
+    math: str = "mixed", cascade: bool = False,
 ) -> Tuple[Table2, jnp.ndarray]:
     """Single-transfer serving entry with IN-TRACE duplicate aggregation:
     raw (possibly duplicate-keyed) packed ingress in, packed outputs out
@@ -1244,7 +1221,7 @@ def decide2_packed_dedup_impl(
     (order-preserving traces only — see fold_cascade_packed)."""
     ded, carrier, member = dedup_packed_cols(arr)
     table, packed = decide2_packed_cols_impl(
-        table, ded, write=write, math=math, probe=probe
+        table, ded, write=write, math=math
     )
     packed = fanout_packed(packed, carrier, member, arr.shape[1])
     if cascade:
@@ -1258,12 +1235,7 @@ def decide2_packed_dedup_impl(
 def install_payload16(inst) -> jnp.ndarray:
     """The per-row INSTALL payload stage: InstallBatch columns → canonical
     (B, 16) i32 slot rows. A pure function of the incoming batch — it never
-    reads table state — shared VERBATIM by the two-pass XLA path
-    (install2_impl below) and the fused Pallas walk
-    (ops/pallas_probe.walk2_pallas_impl, which precomputes these rows in
-    its prologue and DMAs them through the megakernel). Factoring it out is
-    what makes the two install paths bit-identical by construction, the
-    same contract decide_payload discharges for the probe kernels."""
+    reads table state."""
     from gubernator_tpu.types import Algorithm
 
     B = inst.fp.shape[0]
@@ -1351,25 +1323,11 @@ def install_payload16(inst) -> jnp.ndarray:
 
 
 def install2_impl(
-    table: Table2, inst, *, write: str = "xla", probe: str = "xla"
+    table: Table2, inst, *, write: str = "xla"
 ) -> Tuple[Table2, jnp.ndarray]:
     """v2 analog of kernel.install_impl — install owner-authoritative GLOBAL
     statuses as fresh items (reference UpdatePeerGlobals, gubernator.go:434-474).
-    Returns (table', installed_mask).
-
-    `probe` (static) selects the table walk, mirroring decide2_impl:
-    "xla" = the two-pass gather + sweep/sparse write below, "pallas" = the
-    fused probe→install→write megakernel (ops/pallas_probe), which
-    consumes the same install_payload16 rows and skips the `write` plan
-    entirely (one coalesced DMA per distinct bucket per block)."""
-    if probe == "pallas":
-        from gubernator_tpu.ops.pallas_probe import walk2_pallas_impl
-
-        return walk2_pallas_impl(
-            table, inst.fp, install_payload16(inst), inst.now, inst.active,
-            stage="install",
-        )
-
+    Returns (table', installed_mask)."""
     layout = table.layout
     B = inst.fp.shape[0]
     NB = table.rows.shape[0]
@@ -1391,7 +1349,7 @@ def install2_impl(
 
 
 install2 = functools.partial(
-    jax.jit, donate_argnums=(0,), static_argnames=("write", "probe")
+    jax.jit, donate_argnums=(0,), static_argnames=("write",)
 )(install2_impl)
 
 
@@ -1403,10 +1361,7 @@ def merge_payload16(fp, slots, lane16, owns, now):
     stored lane, ownership mask, receiver clock) → (exists_mask, merged
     (B, 16) i32 slot rows). Implements every conservatism rule documented
     on merge2_impl — remaining=min, raw aux=max, expiry=max, OVER sticks,
-    newest-stamp config — and is shared VERBATIM by the two-pass XLA path
-    and the fused Pallas walk (ops/pallas_probe.walk2_pallas_impl calls it
-    in-kernel against the VMEM-resident lane). Factoring it out is what
-    makes the two merge paths bit-identical by construction."""
+    newest-stamp config."""
     g_i = lambda f: slots[:, f]
     g_s = lambda f: lane16[:, f]
     i_exp = _join64(g_i(EXP_LO), g_i(EXP_HI))
@@ -1499,7 +1454,7 @@ def merge_payload16(fp, slots, lane16, owns, now):
 
 def merge2_impl(
     table: Table2, fp, slots, now, active, *, write: str = "xla",
-    evictees: bool = False, probe: str = "xla",
+    evictees: bool = False,
 ):
     """Conservative merge of transferred table slots (the TransferState
     receive path, docs/robustness.md "Topology change & drain").
@@ -1530,23 +1485,10 @@ def merge2_impl(
     returns the (B, 16) i32 canonical rows of LIVE entries this merge's
     installs displaced, so a shadow fault-back that lands in a full
     bucket demotes the victim instead of silently destroying it — the
-    invariant that makes HBM + shadow a closed state set.
-
-    `probe` (static) selects the table walk, mirroring decide2_impl:
-    "xla" = the two-pass gather + sweep/sparse write below, "pallas" = the
-    fused probe→merge→write megakernel (ops/pallas_probe), which calls
-    merge_payload16 in-kernel against the VMEM-resident lane and skips the
-    `write` plan entirely."""
+    invariant that makes HBM + shadow a closed state set."""
     g_i = lambda f: slots[:, f]
     i_exp = _join64(g_i(EXP_LO), g_i(EXP_HI))
     active = active & (i_exp >= now)
-
-    if probe == "pallas":
-        from gubernator_tpu.ops.pallas_probe import walk2_pallas_impl
-
-        return walk2_pallas_impl(
-            table, fp, slots, now, active, stage="merge", evictees=evictees,
-        )
 
     layout = table.layout
     B = fp.shape[0]
@@ -1575,5 +1517,5 @@ def merge2_impl(
 
 
 merge2 = functools.partial(
-    jax.jit, donate_argnums=(0,), static_argnames=("write", "evictees", "probe")
+    jax.jit, donate_argnums=(0,), static_argnames=("write", "evictees")
 )(merge2_impl)

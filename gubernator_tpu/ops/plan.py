@@ -15,75 +15,16 @@ passes:
   OR RESET_REMAINING) and bounds worst-case passes under Zipf-skewed traffic.
 
 For the common all-unique batch this is a single pass with zero copies.
-
-This module also owns the PROBE-KERNEL plan (`probe_kernel_env` /
-`default_probe_kernel`): which table-walk kernel a dispatch compiles —
-the XLA gather + sweep/sparse write, or the fused double-buffered Pallas
-megakernel (ops/pallas_probe.py). Like the pass plan it is a host-side,
-per-engine decision that every dispatch path (local, mesh, wire) inherits
-through the engine's resolved mode.
 """
 
 from __future__ import annotations
 
-import os
 from dataclasses import dataclass
 from typing import List
 
 import numpy as np
 
 from gubernator_tpu.ops.batch import HostBatch
-
-
-def probe_kernel_env() -> str:
-    """The GUBER_PROBE_KERNEL knob: auto | xla | pallas. Read per engine
-    construction (like GUBER_SLOT_LAYOUT) so a daemon restart picks up a
-    flip without code changes."""
-    v = os.environ.get("GUBER_PROBE_KERNEL", "auto")
-    if v not in ("auto", "xla", "pallas"):
-        raise ValueError(
-            f"GUBER_PROBE_KERNEL must be auto, xla or pallas, got {v!r}"
-        )
-    return v
-
-
-def default_probe_kernel() -> str:
-    """Resolve the probe-kernel plan: "xla" (the gather + sweep path every
-    PR before this one shipped) unless GUBER_PROBE_KERNEL=pallas opts into
-    the fused megakernel. "auto" stays on xla until the bench `probe`
-    phase records the Pallas path ≥1.3× at the 100M-key config on a real
-    device run (ROADMAP; the CPU interpret path is a parity surface, not
-    a perf one)."""
-    v = probe_kernel_env()
-    return "xla" if v == "auto" else v
-
-
-def walk_kernel_env() -> str:
-    """The GUBER_WALK_KERNEL knob: auto | xla | pallas — which kernel the
-    NON-decide table walks (GLOBAL installs, region/handoff merges,
-    tiering promotes) compile: the two-pass gather + sweep/sparse write,
-    or the fused probe→install/merge→write megakernel
-    (ops/pallas_probe.walk2_pallas_impl). Deliberately independent of
-    GUBER_PROBE_KERNEL: the decide path is latency-critical per request
-    while the walks are throughput paths on the sync/maintenance planes,
-    so a deployment can flip either without the other. Read per engine
-    construction, like the probe knob."""
-    v = os.environ.get("GUBER_WALK_KERNEL", "auto")
-    if v not in ("auto", "xla", "pallas"):
-        raise ValueError(
-            f"GUBER_WALK_KERNEL must be auto, xla or pallas, got {v!r}"
-        )
-    return v
-
-
-def default_walk_kernel() -> str:
-    """Resolve the walk-kernel plan: "xla" unless GUBER_WALK_KERNEL=pallas
-    opts the install/merge walks into the fused megakernel — same
-    conservative default-flip policy as default_probe_kernel (the bench
-    `dispatch` phase's fused-vs-two-pass wall on a real device gates any
-    auto flip)."""
-    v = walk_kernel_env()
-    return "xla" if v == "auto" else v
 
 
 @dataclass

@@ -4,32 +4,17 @@ The request ring (service/ring.py) removed the per-batch *enqueue* cost
 from the serving plane, but its host issue loop still paid one full XLA
 launch round-trip per published slot — steady-state serving throughput was
 launch-bound, not kernel-bound. This module moves the CONSUME side onto the
-device in two tiers:
-
-**Tier A — fused multi-slot drain (this file's `drain_ring`, live on every
-backend).** The whole ring of compact wire-grid slots plus the
-`seq_in`/`seq_out` fence words stays device-resident (`DeviceRing`), and
-one jitted bounded `lax.while_loop` launch reads the ingress fences
-IN-TRACE, decodes and decides up to `k` published slots through the
-existing `decide2_wire_cols` walk (the donated table threaded through the
-carry), writes each slot's compact egress bank, and publishes `seq_out` —
-exactly the pattern ops/loop.py proved for the bench harness, applied to
-the serving path. The launch round-trip amortizes k× and the per-launch
-cost is ∝ published work: an unpublished slot is a fence compare and a
-no-op branch (the loop exits). `k` and the start ticket are *traced*
-scalars, so one compile per (ring geometry × math mode) serves every
-group size.
-
-**Tier B — persistent issue kernel (`fence_claim`, staged for the TPU
-run).** A Pallas kernel that polls `seq_in` and claims published slots
-with the async-copy/DMA-semaphore pattern — the device-side half of the
-protocol that makes steady state pay ZERO XLA launches (the kernel never
-exits; the host only stages grids and polls egress fences). The CPU build
-validates the fence protocol in interpreter mode
-(tests/test_ring_drain.py) against `fence_claim_ref`; the service keeps
-`GUBER_RING_ISSUE=persistent` on the fused drain launches until the
-device run validates the resident loop (watchdog re-launch on preemption
-is the service's job — service/ring.py counts `watchdog_relaunches`).
+device as a fused multi-slot drain (`drain_ring`, live on every backend).
+The whole ring of compact wire-grid slots plus the `seq_in`/`seq_out`
+fence words stays device-resident (`DeviceRing`), and one jitted bounded
+`lax.while_loop` launch reads the ingress fences IN-TRACE, decodes and
+decides up to `k` published slots through the existing `decide2_wire_cols`
+walk (the donated table threaded through the carry), writes each slot's
+compact egress bank, and publishes `seq_out`. The launch round-trip
+amortizes k× and the per-launch cost is ∝ published work: an unpublished
+slot is a fence compare and a no-op branch (the loop exits). `k` and the
+start ticket are *traced* scalars, so one compile per (ring geometry × math
+mode) serves every group size.
 
 Threading contract: every `DeviceRing` mutation (slot staging, fence
 publish, drain launch) happens on the ENGINE THREAD — the buffers are
@@ -70,7 +55,7 @@ def egress_rows(width: int, evictees: bool) -> int:
 
 def _drain_impl(
     table, grids, seq_in, seq_out, start, k, *,
-    k_max, write, math, cascade, probe, evictees,
+    k_max, write, math, cascade, evictees,
 ):
     """One fused drain launch: walk tickets from `start`, decide every
     published slot (≤ k ≤ k_max), publish egress fences. Returns
@@ -98,12 +83,10 @@ def _drain_impl(
         grid = jax.lax.dynamic_index_in_dim(grids, slot, 0, keepdims=False)
         table, out = decide2_wire_cols_impl(
             table, grid, write=write, math=math, cascade=cascade,
-            probe=probe, evictees=evictees,
+            evictees=evictees,
         )
         # dense egress bank indexed by drain POSITION, not slot: one fetch
-        # covers the whole launch. (The true device ring / persistent tier
-        # writes per-slot banks the host polls individually; the dense
-        # bank is the pipelined-fetch shape the CPU-provable tier wants.)
+        # covers the whole launch.
         bank = jax.lax.dynamic_update_index_in_dim(bank, out, n, 0)
         # egress fence AFTER the slot's outputs exist in the bank — same
         # store ordering the host finish loop keeps
@@ -122,8 +105,7 @@ def _drain_impl(
 # buffer a fetch thread is still reading from launch j.
 drain_ring = functools.partial(
     jax.jit, donate_argnums=(0, 3),
-    static_argnames=("k_max", "write", "math", "cascade", "probe",
-                     "evictees"),
+    static_argnames=("k_max", "write", "math", "cascade", "evictees"),
 )(_drain_impl)
 
 
@@ -183,118 +165,7 @@ class DeviceRing:
             engine.table, self.grids, self.seq_in, self.seq_out,
             np.int64(start), np.int64(k),
             k_max=self.drain_k, write=engine.write_mode, math=math,
-            cascade=cascade, probe=engine.probe_mode,
-            evictees=bool(engine._evictees),
+            cascade=cascade, evictees=bool(engine._evictees),
         )
         engine.table = table
         return bank, n
-
-
-# --------------------------------------------------------------------------
-# Tier B: persistent issue kernel (staged for the TPU run)
-# --------------------------------------------------------------------------
-
-
-def _fence_claim_kernel(seq_in_ref, _seq_out_in, grids_ref, ctl_ref,
-                        seq_out_ref, bank_ref, n_ref, sem):
-    """Pallas fence-claim loop: the persistent issue kernel's inner step.
-
-    Walks tickets from ctl[0], and for each CONTIGUOUSLY published slot
-    (seq_in[t%S] == t+1 — a gap stops the claim, preserving strict ticket
-    order) async-copies the slot's wire grid into the claim bank and bumps
-    the egress-side fence, up to ctl[1] claims. This is the Pallas
-    async-copy/DMA-semaphore recipe applied to slot claiming; the resident
-    production loop wraps this step in an outer poll that never exits.
-    Fence words are int32 here (tickets wrap at 2^31 — years of uptime at
-    serving rates; the host remaps before wrap)."""
-    import jax.experimental.pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    start = ctl_ref[0]
-    k = ctl_ref[1]
-    S = seq_in_ref.shape[0]
-
-    def body(i, n):
-        t = start + i
-        # i32(S): a bare python int promotes to i64 under jax_enable_x64,
-        # and lax.rem refuses mixed-width operands
-        slot = jax.lax.rem(t, i32(S))
-        published = seq_in_ref[slot] == t + 1
-        live = (i < k) & (i == n) & published
-
-        @pl.when(live)
-        def _claim():
-            cp = pltpu.make_async_copy(
-                grids_ref.at[slot], bank_ref.at[i], sem
-            )
-            cp.start()
-            cp.wait()
-            # egress fence AFTER the DMA completed — the claim ordering
-            # the host's result poll relies on
-            seq_out_ref[slot] = t + 1
-
-        return n + live.astype(i32)
-
-    n = jax.lax.fori_loop(0, bank_ref.shape[0], body, i32(0))
-    n_ref[0] = n
-
-
-def make_fence_claim(slots: int, width: int, k_max: int, *,
-                     interpret: bool = False):
-    """Build the fence-claim pallas_call for one ring geometry. Returns
-    fn(seq_in i32 (S,), seq_out i32 (S,), grids i32 (S, 5, W+1),
-    ctl i32 (2,)=[start, k]) → (seq_out', bank (k_max, 5, W+1), n (1,)).
-    `interpret=True` runs the CPU interpreter — the parity surface
-    tests/test_ring_drain.py pins against `fence_claim_ref`."""
-    import jax.experimental.pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    out_shape = (
-        jax.ShapeDtypeStruct((slots,), jnp.int32),
-        jax.ShapeDtypeStruct((k_max, WIRE_LANES, width + 1), jnp.int32),
-        jax.ShapeDtypeStruct((1,), jnp.int32),
-    )
-    return pl.pallas_call(
-        _fence_claim_kernel,
-        out_shape=out_shape,
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),  # seq_in
-            pl.BlockSpec(memory_space=pltpu.SMEM),  # seq_out (aliased)
-            pl.BlockSpec(memory_space=pl.ANY),      # grids (HBM)
-            pl.BlockSpec(memory_space=pltpu.SMEM),  # ctl [start, k]
-        ],
-        out_specs=(
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-        ),
-        scratch_shapes=[pltpu.SemaphoreType.DMA(())],
-        input_output_aliases={1: 0},
-        interpret=interpret,
-    )
-
-
-def fence_claim_ref(seq_in: np.ndarray, seq_out: np.ndarray,
-                    grids: np.ndarray, start: int, k: int):
-    """Numpy reference of the fence-claim protocol — the oracle the
-    interpreter-mode kernel test compares against. Claims contiguously
-    published tickets from `start` (a gap or k stops it), copies each
-    claimed slot's grid, bumps its egress fence."""
-    S = seq_in.shape[0]
-    seq_out = seq_out.copy()
-    claimed = []
-    n = 0
-    while n < k:
-        t = start + n
-        slot = t % S
-        if int(seq_in[slot]) != t + 1:
-            break
-        claimed.append(grids[slot].copy())
-        seq_out[slot] = t + 1
-        n += 1
-    bank = (
-        np.stack(claimed)
-        if claimed
-        else np.zeros((0,) + grids.shape[1:], dtype=grids.dtype)
-    )
-    return n, bank, seq_out

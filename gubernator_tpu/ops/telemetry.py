@@ -15,7 +15,7 @@ on a BACKGROUND cadence from EngineRunner.table_telemetry (issue on the
 engine thread, fetch off it — it overlaps serving dispatches and never sits
 on the serving path). Output is one small int64 stats vector; the host
 decodes it into a `TableSnapshot` that feeds the `gubernator_tpu_table_*`
-Prometheus families, the `/v1/debug/table` endpoint, and the bench JSON.
+Prometheus families and the `/v1/debug/table` endpoint.
 
 `host_telemetry` is the numpy oracle the parity tests (and skeptical
 operators) check the device scan against.

@@ -399,7 +399,7 @@ def decode_wire_host(lanes: np.ndarray, base: int) -> dict:
     hits as one lane image instead of n proto messages; the owner daemon
     decodes them here before applying). The in-trace twin is
     decode_wire_block; the two must agree field-for-field, which
-    tests/test_ring_exchange.py pins by round-tripping through both."""
+    tests/test_pod_mesh.py pins by round-tripping through both."""
     lanes = np.asarray(lanes, dtype=np.int32)
     l0, l1, l2, l3, l4 = (lanes[i].astype(np.int64) for i in range(WIRE_LANES))
     fp = (l0 & 0xFFFFFFFF) | (l1 << 32)
@@ -459,16 +459,16 @@ def unpack_wire_out(arr: np.ndarray, n: int):
 
 
 def decide2_wire_cols_impl(
-    table, carr, *, write="sweep", math="mixed", cascade=False, probe="xla",
+    table, carr, *, write="sweep", math="mixed", cascade=False,
     evictees=False,
 ):
     """Compact single-transfer serving entry: (5, B+1) int32 wire block in,
     (B+2, 4) int32 compact outputs out — the narrow-wire twin of
     kernel2.decide2_packed_cols_impl. `cascade=True` folds cascade verdicts
-    in-trace on the wide packed array BEFORE the egress narrowing; `probe`
-    selects the table-walk kernel (GUBER_PROBE_KERNEL). `evictees=True`
-    appends the raw int32 evictee sidecar AFTER the narrowing (slot fields
-    are bit patterns, never clamped — kernel2.attach_evictees_wire)."""
+    in-trace on the wide packed array BEFORE the egress narrowing.
+    `evictees=True` appends the raw int32 evictee sidecar AFTER the
+    narrowing (slot fields are bit patterns, never clamped —
+    kernel2.attach_evictees_wire)."""
     arr12, base = decode_wire_block(carr)
     if evictees:
         from gubernator_tpu.ops.kernel2 import (
@@ -479,31 +479,30 @@ def decide2_wire_cols_impl(
         )
 
         table, packed, ev16 = decide2_packed_impl(
-            table, req_from_arr(arr12), write=write, math=math, probe=probe,
-            evictees=True,
+            table, req_from_arr(arr12), write=write, math=math, evictees=True
         )
         if cascade:
             packed = fold_cascade_packed(packed, arr12)
         return table, attach_evictees_wire(encode_wire_out(packed, base), ev16)
     table, packed = decide2_packed_cols_impl(
-        table, arr12, write=write, math=math, cascade=cascade, probe=probe
+        table, arr12, write=write, math=math, cascade=cascade
     )
     return table, encode_wire_out(packed, base)
 
 
 def decide2_wire_dedup_impl(
-    table, carr, *, write="sweep", math="mixed", cascade=False, probe="xla"
+    table, carr, *, write="sweep", math="mixed", cascade=False
 ):
     """Compact entry with in-trace duplicate aggregation (the mesh
     engines' dedup="device" program built on the narrow wire)."""
     arr12, base = decode_wire_block(carr)
     table, packed = decide2_packed_dedup_impl(
-        table, arr12, write=write, math=math, cascade=cascade, probe=probe
+        table, arr12, write=write, math=math, cascade=cascade
     )
     return table, encode_wire_out(packed, base)
 
 
 decide2_wire_cols = functools.partial(
     jax.jit, donate_argnums=(0,),
-    static_argnames=("write", "math", "cascade", "probe", "evictees"),
+    static_argnames=("write", "math", "cascade", "evictees"),
 )(decide2_wire_cols_impl)

@@ -20,9 +20,7 @@ scaling-book recipe: annotate, exchange, let ICI do the work):
     retry re-dispatches them, the MoE "token dropping" analog);
  3. ONE exchange delivers every row to its owning device over the
     interconnect (the reference's N×N gRPC forwarding mesh, peer_client.go,
-    collapsed into a collective) — either a monolithic `lax.all_to_all` or
-    the hand-rolled per-hop ring schedule (parallel/ring.py,
-    GUBER_A2A_IMPL), byte-identical by contract;
+    collapsed into one `lax.all_to_all` over the shard axes);
  4. the owner runs the decision kernel on its received (D·C) rows;
  5. a second all_to_all returns responses to each row's arrival device,
     which un-sorts them to arrival order.
@@ -50,8 +48,7 @@ from gubernator_tpu.ops.kernel2 import (
 )
 from gubernator_tpu.ops.engine import default_write_mode
 from gubernator_tpu.ops.table2 import Table2
-from gubernator_tpu.parallel.mesh import shard_of, shard_spec
-from gubernator_tpu.parallel.ring import a2a_impl, exchange
+from gubernator_tpu.parallel.mesh import shard_axes, shard_of, shard_spec
 
 i32 = jnp.int32
 i64 = jnp.int64
@@ -98,9 +95,20 @@ def exchange_traffic(c: int, D: int) -> "tuple[int, int, int]":
     return D * C, 2 * slots, slots * (12 + 4) * 8
 
 
+def _exchange(block: jnp.ndarray, mesh: Mesh) -> jnp.ndarray:
+    """Deliver per-destination blocks (leading axis = destination device)
+    and return per-source blocks (leading axis = source device): one
+    all_to_all over the shard axes. Called INSIDE a shard_map over `mesh`."""
+    if int(mesh.devices.size) == 1:
+        return block
+    return jax.lax.all_to_all(
+        block, shard_axes(mesh), split_axis=0, concat_axis=0
+    )
+
+
 def make_a2a_decide(
     mesh: Mesh, c: int, math: str = "mixed", write=None, dedup: bool = False,
-    wire: bool = False, impl: "str | None" = None, probe: str = "xla",
+    wire: bool = False,
 ):
     """Jitted all-shards decide with ON-DEVICE routing: (Table2[D,·],
     (D, 12, c) arrival-order grid, (D, c+2, 4) recycled egress buffer) →
@@ -128,16 +136,8 @@ def make_a2a_decide(
     HOST boundary is what the narrow layout shrinks — the decode runs
     before the exchange, so the ICI legs still move the full 12-lane rows
     (ICI bandwidth is not the bottleneck the wire budget targets) and the
-    exchange/dedup machinery below is shared byte-for-byte.
-
-    `impl` picks the exchange schedule (parallel/ring.py): "collective" =
-    one lax.all_to_all per direction (the seed path — and the parity
-    oracle), "ring" = the hand-rolled per-hop schedule with double-buffered
-    remote DMA on TPU / ppermute shifts elsewhere; None resolves through
-    GUBER_A2A_IMPL (auto = collective). The two produce byte-identical
-    grids — impl is a schedule knob, never a semantics one."""
+    exchange/dedup machinery below is shared byte-for-byte."""
     write = write or default_write_mode()
-    impl = a2a_impl(impl)
     D = int(mesh.devices.size)
     C = pair_capacity(c, D)
 
@@ -178,7 +178,7 @@ def make_a2a_decide(
         send3 = send.reshape(12, D, C).transpose(1, 0, 2)  # (D, 12, C)
 
         # ---- ICI: deliver rows to owners; leading axis src↔dst swaps
-        recv = exchange(send3, mesh, impl)  # (D, 12, C), leading = source
+        recv = _exchange(send3, mesh)  # (D, 12, C), leading = source
         local = recv.transpose(1, 0, 2).reshape(12, D * C)
 
         if dedup:
@@ -186,17 +186,17 @@ def make_a2a_decide(
             # carriers; aggregate them before the kernel (its unique-fp
             # contract) and fan the response back to every received row
             table, packed = decide2_packed_dedup_impl(
-                table, local, write=write, math=math, probe=probe
+                table, local, write=write, math=math
             )
         else:
             table, packed = decide2_packed_cols_impl(
-                table, local, write=write, math=math, probe=probe
+                table, local, write=write, math=math
             )
         resp = packed[: D * C].reshape(D, C, 4)
         stats_rows = packed[D * C :]  # (2, 4)
 
         # ---- ICI: responses ride back to each row's arrival device
-        back = exchange(resp, mesh, impl).reshape(D * C, 4)
+        back = _exchange(resp, mesh).reshape(D * C, 4)
 
         # un-sort to arrival order: arrival row idx_s[p] sat in slot
         # o_s[p]*C + rank[p]

@@ -161,8 +161,8 @@ class PendingHits:
         return out
 
     def clear(self) -> None:
-        """Drop every pending entry (bench/test harness reset — modeling a
-        steady state where the sync tick keeps the accumulator drained)."""
+        """Drop every pending entry (test reset — modeling a steady state
+        where the sync tick keeps the accumulator drained)."""
         self.hb = self.hits = self.reset = None
         self.oldest_ts = None
 
@@ -430,10 +430,7 @@ class GlobalShardedEngine(ShardedEngine):
         write_mode: Optional[str] = None,
         dedup: Optional[str] = None,
         wire: Optional[str] = None,
-        a2a: Optional[str] = None,
         layout: Optional[str] = None,
-        probe: Optional[str] = None,
-        walk: Optional[str] = None,
     ):
         super().__init__(
             mesh,
@@ -445,10 +442,7 @@ class GlobalShardedEngine(ShardedEngine):
             write_mode=write_mode,
             dedup=dedup,
             wire=wire,
-            a2a=a2a,
             layout=layout,
-            probe=probe,
-            walk=walk,
         )
         # the replica table + collective step materialize on first GLOBAL
         # use: clustered daemons route GLOBAL over the host peer plane and

@@ -129,7 +129,7 @@ def default_shard_dedup() -> str:
 
 def make_sharded_decide(
     mesh: Mesh, math: str = "mixed", write: Optional[str] = None,
-    dedup: bool = False, wire: bool = False, probe: str = "xla",
+    dedup: bool = False, wire: bool = False,
 ):
     """Build the jitted all-shards decision step over the SINGLE-TRANSFER
     packed layout: (Table2[D,·], (D, 12, b) i64 ingress grid, (D, b+2, 4)
@@ -160,14 +160,10 @@ def make_sharded_decide(
         impl = decide2_packed_dedup_impl if dedup else decide2_packed_cols_impl
         if wire:
             arr12, base = decode_wire_block(arr[0])
-            table, packed = impl(
-                table, arr12, write=write, math=math, probe=probe
-            )
+            table, packed = impl(table, arr12, write=write, math=math)
             packed = encode_wire_out(packed, base)
         else:
-            table, packed = impl(
-                table, arr[0], write=write, math=math, probe=probe
-            )
+            table, packed = impl(table, arr[0], write=write, math=math)
         expand = lambda t: jax.tree.map(lambda x: x[None], t)
         return expand(table), packed[None]
 
@@ -188,21 +184,15 @@ def make_sharded_decide(
     return jax.jit(fn, donate_argnums=_staging_donate(), keep_unused=True)
 
 
-def make_sharded_install(mesh: Mesh, write: Optional[str] = None,
-                         probe: str = "xla"):
+def make_sharded_install(mesh: Mesh, write: Optional[str] = None):
     """All-shards install step for owner-authoritative GLOBAL statuses —
-    the UpdatePeerGlobals receive path on a sharded daemon. `probe`
-    (static) selects the per-shard table walk — the two-pass gather +
-    write or the fused Pallas walk (GUBER_WALK_KERNEL); like decide, the
-    megakernel composes with shard_map for free because it runs per
-    device shard."""
+    the UpdatePeerGlobals receive path on a sharded daemon."""
     write = write or default_write_mode()
 
     def per_device(table: Table2, inst: InstallBatch):
         table = jax.tree.map(lambda x: x[0], table)
         inst = jax.tree.map(lambda x: x[0], inst)
-        table, installed = install2_impl(table, inst, write=write,
-                                         probe=probe)
+        table, installed = install2_impl(table, inst, write=write)
         expand = lambda t: jax.tree.map(lambda x: x[None], t)
         return expand(table), expand(installed)
 
@@ -217,7 +207,7 @@ def make_sharded_install(mesh: Mesh, write: Optional[str] = None,
 
 
 def make_sharded_merge(mesh: Mesh, write: Optional[str] = None,
-                       evictees: bool = False, probe: str = "xla"):
+                       evictees: bool = False):
     """All-shards conservative-merge step (kernel2.merge2_impl) — the
     TransferState receive path on a sharded daemon: transferred slot rows
     are routed to their owning shard and merged with remaining=min /
@@ -234,12 +224,11 @@ def make_sharded_merge(mesh: Mesh, write: Optional[str] = None,
         if evictees:
             table, merged, ev = merge2_impl(
                 table, fp[0], slots[0], now[0], active[0], write=write,
-                evictees=True, probe=probe,
+                evictees=True,
             )
             return expand(table), expand(merged), expand(ev)
         table, merged = merge2_impl(
-            table, fp[0], slots[0], now[0], active[0], write=write,
-            probe=probe,
+            table, fp[0], slots[0], now[0], active[0], write=write
         )
         return expand(table), expand(merged)
 
@@ -409,18 +398,10 @@ class ShardedEngine:
         write_mode: Optional[str] = None,
         dedup: Optional[str] = None,
         wire: Optional[str] = None,
-        a2a: Optional[str] = None,
         layout: Optional[str] = None,
-        probe: Optional[str] = None,
-        walk: Optional[str] = None,
     ):
         from gubernator_tpu.ops.layout import resolve_layout
-        from gubernator_tpu.ops.plan import (
-            default_probe_kernel,
-            default_walk_kernel,
-        )
         from gubernator_tpu.ops.wire import default_wire_mode
-        from gubernator_tpu.parallel.ring import a2a_impl
 
         route = route or default_shard_route()
         if route not in ("host", "device"):
@@ -441,10 +422,6 @@ class ShardedEngine:
         self.n_hosts = mesh_hosts(mesh)
         self.devices_per_host = devices_per_host(mesh)
         self._live_count_fn = None  # (layout, compiled count), live_count()
-        # ownership-exchange schedule for route="device" dispatches
-        # (parallel/ring.py): "ring" | "collective", resolved once from the
-        # override / GUBER_A2A_IMPL / backend auto rule
-        self.a2a_impl = a2a_impl(a2a)
         # slot layout (ops/layout.py): full by default, packed 32 B rows
         # for single-algorithm fleets (GUBER_SLOT_LAYOUT / layout=); off-
         # family traffic migrates the shards to full in place
@@ -466,20 +443,6 @@ class ShardedEngine:
         # None = the backend default (kernel2.resolve_write still falls the
         # sparse mode back to the full sweep per dispatch shape)
         self.write_mode = write_mode or default_write_mode()
-        # table-walk kernel for decide dispatches (GUBER_PROBE_KERNEL):
-        # the per-shard programs thread it into decide2_* unchanged — the
-        # PR-8 shard_map mesh path composes with the Pallas megakernel for
-        # free because the kernel runs per device shard inside shard_map
-        if probe is not None and probe not in ("xla", "pallas"):
-            raise ValueError(f"probe must be 'xla' or 'pallas', got {probe!r}")
-        self.probe_mode = probe or default_probe_kernel()
-        # table-walk kernel for the install/merge walks (GUBER_WALK_KERNEL):
-        # threaded into the per-shard install/merge programs exactly like
-        # probe_mode into decide — the walks run per device shard inside
-        # shard_map, so the fused megakernel composes for free
-        if walk is not None and walk not in ("xla", "pallas"):
-            raise ValueError(f"walk must be 'xla' or 'pallas', got {walk!r}")
-        self.walk_mode = walk or default_walk_kernel()
         # host↔device wire format for decide dispatches and the GLOBAL sync
         # outbox: "compact" ships 5-lane int32 ingress grids + int32 egress
         # (ops/wire.py — the TPU default, GUBER_WIRE_COMPACT), "full" the
@@ -489,9 +452,7 @@ class ShardedEngine:
         # (kind, …, math) → jitted mesh step (lazy); an a2a step with its
         # exchange_traffic
         self._decide_fns = {}
-        self._install = make_sharded_install(
-            mesh, write=self.write_mode, probe=self.walk_mode
-        )
+        self._install = make_sharded_install(mesh, write=self.write_mode)
         # handoff mesh steps, built lazily (most engines never rebalance)
         self._merge_fn = None
         self._tombstone_fn = None
@@ -548,7 +509,7 @@ class ShardedEngine:
         self._wire_taken = dict(self.wire_bytes)
         # rows the a2a exchange capacity-dropped before they reached the
         # kernel (FLAG_UNPROCESSED on a device-routed dispatch) — the
-        # per-engine source of gubernator_tpu_a2a_overflow_total{impl}.
+        # per-engine source of gubernator_tpu_a2a_overflow_total.
         # Counted at every depth: a row that overflows twice was twice a
         # symptom of undersized pair capacity (GUBER_A2A_CAPACITY_SIGMA)
         self.a2a_overflow = 0
@@ -653,14 +614,14 @@ class ShardedEngine:
             self._wire_taken = dict(self.wire_bytes)
         return d
 
-    def take_a2a_overflow_delta(self) -> "tuple[str, int]":
-        """(exchange impl, overflow rows since the last take) —
-        EngineRunner feeds gubernator_tpu_a2a_overflow_total{impl} so
-        capacity pressure is scrapeable instead of test-only."""
+    def take_a2a_overflow_delta(self) -> int:
+        """Overflow rows since the last take — EngineRunner feeds
+        gubernator_tpu_a2a_overflow_total so capacity pressure is
+        scrapeable instead of test-only."""
         with self._stage_lock:
             d = self.a2a_overflow - self._a2a_overflow_taken
             self._a2a_overflow_taken = self.a2a_overflow
-        return self.a2a_impl, d
+        return d
 
     # ------------------------------------------------ egress buffer recycling
 
@@ -874,8 +835,7 @@ class ShardedEngine:
             fn = getattr(self, "_merge_ev_fn", None)
             if fn is None:
                 fn = self._merge_ev_fn = make_sharded_merge(
-                    self.mesh, write=self.write_mode, evictees=True,
-                    probe=self.walk_mode,
+                    self.mesh, write=self.write_mode, evictees=True
                 )
             self.table, merged, ev = fn(
                 self.table, put(fp_g), put(slots_g), put(now_g), put(act_g)
@@ -891,7 +851,7 @@ class ShardedEngine:
             return int(mask.sum()), mask, ev_fp[keep], ev_h[keep].copy()
         if self._merge_fn is None:
             self._merge_fn = make_sharded_merge(
-                self.mesh, write=self.write_mode, probe=self.walk_mode
+                self.mesh, write=self.write_mode
             )
         self.table, merged = self._merge_fn(
             self.table, put(fp_g), put(slots_g), put(now_g), put(act_g)
@@ -1140,13 +1100,12 @@ class ShardedEngine:
 
             # the built step is kept with what it was traced with: the
             # exchange's geometry is read when the step is built
-            key = ("a2a", staged.c, staged.math, staged.wire, self.a2a_impl)
+            key = ("a2a", staged.c, staged.math, staged.wire)
             built = self._decide_fns.get(key)
             if built is None:
                 built = self._decide_fns[key] = make_a2a_decide(
                     self.mesh, staged.c, math=staged.math,
                     write=self.write_mode, dedup=dedup, wire=staged.wire,
-                    impl=self.a2a_impl, probe=self.probe_mode,
                 ), exchange_traffic(staged.c, self.n_shards)
             fn, (lanes, ex_rows, ex_bytes) = built
             rows = staged.c
@@ -1158,7 +1117,7 @@ class ShardedEngine:
             if fn is None:
                 fn = self._decide_fns[key] = make_sharded_decide(
                     self.mesh, math=staged.math, write=self.write_mode,
-                    dedup=dedup, wire=staged.wire, probe=self.probe_mode,
+                    dedup=dedup, wire=staged.wire,
                 )
             rows = lanes = staged.b_local
         self.mesh_lanes += self.n_shards * lanes

@@ -779,17 +779,6 @@ class Batcher:
             # the chain has been answered by its crossing back already
             answer(None, exc, False)
 
-    def arm_overload(self, deadline_ms: float) -> None:
-        """(Re)arm or disarm the overload door at runtime. The scenario
-        harness (bench.py) warms XLA chunk shapes through the OPEN door and
-        only then arms it for the timed windows — a warm wave shed by the
-        armed door never dispatches, leaving its chunk shape uncompiled so
-        the compile lands inside a measured step disguised as queueing
-        latency. Per-entry deadlines are stamped at enqueue, so flipping
-        between windows never retro-affects queued items."""
-        self.overload_deadline_s = max(0.0, deadline_ms) / 1e3
-        self.armed = self.overload_deadline_s > 0 or self.overload_deadline_auto
-
     def debug(self) -> dict:
         """Live front-door state for /v1/debug/pipeline (docs/observability.md):
         ring depth, worker liveness, dispatch-path counters, and WHY the

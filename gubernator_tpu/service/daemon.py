@@ -154,27 +154,12 @@ class Daemon:
                 # dedup on TPU meshes, host grid + pass planner elsewhere)
                 route=None if conf.shard_route == "auto" else conf.shard_route,
                 dedup=None if conf.shard_dedup == "auto" else conf.shard_dedup,
-                # exchange schedule for device-routed dispatches
-                # (parallel/ring.py; "auto" = collective)
-                a2a=None if conf.a2a_impl == "auto" else conf.a2a_impl,
-                # table-walk kernel (ops/pallas_probe.py; "auto" = xla
-                # until the device bench record flips the default)
-                probe=None if conf.probe_kernel == "auto"
-                else conf.probe_kernel,
-                # install/merge walk kernel (fused vs two-pass; same
-                # default-flip policy, independent knob)
-                walk=None if conf.walk_kernel == "auto"
-                else conf.walk_kernel,
             )
         else:
             self.engine = LocalEngine(
                 capacity=conf.cache_size,
                 created_at_tolerance_ms=int(conf.created_at_tolerance_ms),
                 store=store,
-                probe=None if conf.probe_kernel == "auto"
-                else conf.probe_kernel,
-                walk=None if conf.walk_kernel == "auto"
-                else conf.walk_kernel,
             )
         self.runner = EngineRunner(
             self.engine,
@@ -593,15 +578,7 @@ class Daemon:
             duration=np.ones(1, dtype=np.int64),
             now_ms=1,
         )
-        # the install warm above already traced the install walk under the
-        # engine's resolved walk_mode (GUBER_WALK_KERNEL threads through
-        # install2/merge2 transparently). The merge walk is warmed for
-        # region daemons (the replication receive path) AND whenever the
-        # fused Pallas walks are armed — tiering promotes and handoff
-        # merges ride merge2 too, and a fused-walk graph compiling on the
-        # first promote would stall the engine thread mid-serving.
-        fused_walks = getattr(self.engine, "walk_mode", "xla") == "pallas"
-        if self.conf.data_center or fused_walks:
+        if self.conf.data_center:
             # region plane (docs/robustness.md "Multi-region active-
             # active"): pre-trace the stored-state read (the sender's
             # staging gather) and the conservative merge (the receiver's
@@ -613,18 +590,17 @@ class Daemon:
             from gubernator_tpu.ops.table2 import F as F_FULL
 
             fp1 = np.asarray([1], dtype=np.int64)
-            if self.conf.data_center:
-                await self.runner.read_state_raw(fp1)
+            await self.runner.read_state_raw(fp1)
             # an all-zero incoming row is expired at every clock: the
             # merge kernel compiles, the table keeps its bytes
             await self.runner.merge_rows(
                 fp1, np.zeros((1, F_FULL), dtype=np.int32)
             )
         # GUBER_WARM_SHAPES=pow2[-mixed]: additionally compile every pow2
-        # coalesce geometry up to the coalesce cap (like bench.py's e2e
-        # prewarm) so no production batch shape ever compiles on the
-        # request path; off by default — it multiplies spawn time by the
-        # shape count, which in-process test clusters cannot afford
+        # coalesce geometry up to the coalesce cap so no production batch
+        # shape ever compiles on the request path; off by default — it
+        # multiplies spawn time by the shape count, which in-process test
+        # clusters cannot afford
         mode = self.conf.behaviors.warm_shapes
         if mode in ("pow2", "pow2-mixed"):
             from gubernator_tpu.ops.engine import _pad_size
@@ -1765,14 +1741,15 @@ class Daemon:
                 "table_bytes": int(eng.table.rows.nbytes),
                 "wire": getattr(eng, "wire", None),
                 "write_mode": getattr(eng, "write_mode", None),
-                # table-walk kernel (GUBER_PROBE_KERNEL)
-                "probe_kernel": getattr(eng, "probe_mode", None),
+                # constants: bench/configs/*.json `expect_engine` and
+                # chip_smoke.py still compare these two keys (ROADMAP C8)
+                "probe_kernel": "xla",
                 "n_shards": getattr(eng, "n_shards", 1),
                 "n_hosts": getattr(eng, "n_hosts", 1),
                 "devices_per_host": getattr(eng, "devices_per_host", None),
                 "route": getattr(eng, "route", None),
                 "dedup": getattr(eng, "dedup", None),
-                "a2a_impl": getattr(eng, "a2a_impl", None),
+                "a2a_impl": "collective" if hasattr(eng, "mesh") else None,
                 # exchange capacity-overflow rows (FLAG_UNPROCESSED before
                 # reaching a kernel): the live view of
                 # gubernator_tpu_a2a_overflow_total — sustained growth means

@@ -306,7 +306,6 @@ class DaemonMetrics:
             "before they reached a kernel (FLAG_UNPROCESSED — retried, so "
             "not lost; sustained growth means pair_capacity is undersized "
             "for the traffic skew, GUBER_A2A_CAPACITY_SIGMA)",
-            ["impl"],  # ring | collective (GUBER_A2A_IMPL)
             registry=r,
         )
         self.global_wire_entries = Counter(

@@ -27,14 +27,14 @@ byte-identical by construction, while exercising the full ring protocol:
   consumer retires the oldest slot;
 * **drain on shutdown** — `drain()` stops intake, lets every published
   ticket complete in order, and only then parks the serving loop (zero
-  loss, the contract ci/bench_cpu.py's ring_smoke gate pins).
+  loss; tests/test_request_ring.py pins the contract).
 
-Consumption is strictly in ticket order (the persistent kernel walks slots
-in sequence), but the finish half of each dispatch overlaps the next
-ticket's issue through the runner's own prepare/issue/finish pipeline —
+Consumption is strictly in ticket order, but the finish half of each
+dispatch overlaps the next ticket's issue through the runner's own
+prepare/issue/finish pipeline —
 the ring serializes LAUNCH ORDER, not completion latency.
 
-The CONSUME side has three tiers behind GUBER_RING_ISSUE (docs/latency.md
+The CONSUME side has two tiers behind GUBER_RING_ISSUE (docs/latency.md
 "Launch budget"):
 
 * **host** — the original loop: one runner dispatch (one XLA launch) per
@@ -46,12 +46,6 @@ The CONSUME side has three tiers behind GUBER_RING_ISSUE (docs/latency.md
   the fused path can't take (duplicate keys, non-encodable rows, chunks
   wider than the slot) ride the per-slot host path in ticket order —
   byte-identical either way. The TPU default.
-* **persistent** — staged for the TPU run: the Pallas fence-claim kernel
-  (ops/ring_drain.fence_claim, interpreter-mode parity-tested) replaces
-  the host's claim loop so steady state pays zero XLA launches; until the
-  device run validates the resident loop this mode runs the fused drain
-  with a watchdog that re-launches a failed drain once (preemption cover)
-  and counts `watchdog_relaunches`.
 
 Knobs: GUBER_RING_ENABLE turns the plane on (service/daemon.py routes
 all-wire flushes here), GUBER_RING_SLOTS sizes the ring, GUBER_RING_ISSUE
@@ -69,7 +63,6 @@ submit-side staging and the egress-fence wait.
 from __future__ import annotations
 
 import asyncio
-import logging
 import time
 from typing import List, Optional, Tuple
 
@@ -77,8 +70,6 @@ import numpy as np
 
 from gubernator_tpu import tracing
 from gubernator_tpu.ops.batch import ResponseColumns
-
-log = logging.getLogger("gubernator_tpu.ring")
 
 
 class RingClosed(RuntimeError):
@@ -100,10 +91,9 @@ class RequestRing:
                  slot_width: int = 0):
         if slots < 2:
             raise ValueError("RequestRing needs at least 2 slots")
-        if issue_mode not in ("host", "fused", "persistent"):
+        if issue_mode not in ("host", "fused"):
             raise ValueError(
-                f"GUBER_RING_ISSUE must be host|fused|persistent, "
-                f"got {issue_mode!r}"
+                f"GUBER_RING_ISSUE must be host|fused, got {issue_mode!r}"
             )
         if drain_k < 1:
             raise ValueError("GUBER_RING_DRAIN_K must be >= 1")
@@ -134,16 +124,15 @@ class RequestRing:
         self._finish_task: Optional[asyncio.Task] = None
         self._inorder: Optional[asyncio.Queue] = None
         self._closed = False
-        # introspection counters (ring_smoke + /v1/debug/pipeline)
+        # introspection counters (/v1/debug/pipeline)
         self.launches = 0  # tickets retired through the ring
         self.fallbacks = 0  # non-fusable slots that rode the columns path
         self.backpressure_waits = 0  # submits that found the ring full
         self.max_occupancy = 0
-        # fused-tier counters (ring_drain_smoke + /v1/debug/pipeline)
+        # fused-tier counters (/v1/debug/pipeline)
         self.drain_launches = 0  # fused drain launches (XLA launches)
         self.drained_slots = 0  # tickets retired by fused drains
         self.host_slots = 0  # fused-ineligible tickets (per-slot path)
-        self.watchdog_relaunches = 0  # persistent-tier drain re-launches
 
     # ------------------------------------------------------------ lifecycle
     def _ensure_started(self) -> None:
@@ -234,7 +223,7 @@ class RequestRing:
         )
 
     async def _issue_loop(self) -> None:
-        """Walk tickets strictly in order (the persistent kernel's slot
+        """Walk tickets strictly in order (the device ring's slot
         walk): check the ingress fence, lift the payload, and start its
         dispatch. Completion ordering is the finish loop's job."""
         t = 0
@@ -299,7 +288,7 @@ class RequestRing:
         raise exc
 
     async def _issue_loop_fused(self) -> None:
-        """Fused consume loop (GUBER_RING_ISSUE=fused|persistent): walk
+        """Fused consume loop (GUBER_RING_ISSUE=fused): walk
         tickets strictly in order, group consecutively published fusable
         slots that share the drain graph's static modes (math, cascade),
         and retire each group with ONE device drain launch
@@ -372,33 +361,11 @@ class RequestRing:
                         self._ensure_dring(), group, tickets[0], disp=disp
                     )
                 except Exception as exc:
-                    if self.issue_mode == "persistent":
-                        # watchdog: a preempted/failed drain re-launches
-                        # once before the group is failed out. The first
-                        # failure is logged, never swallowed: a kernel the
-                        # chip refuses raises the same compiler message on
-                        # the re-launch and fails the group with it.
-                        log.warning(
-                            "ring drain failed, re-launching once: %r", exc
-                        )
-                        self.watchdog_relaunches += 1
-                        try:
-                            bank, n = await self.runner.drain_ring_issue(
-                                self._ensure_dring(), group, tickets[0],
-                                disp=disp,
-                            )
-                        except Exception as exc2:
-                            await self._inorder.put(
-                                (tickets, loop.create_task(self._fail(exc2)))
-                            )
-                            i = j
-                            continue
-                    else:
-                        await self._inorder.put(
-                            (tickets, loop.create_task(self._fail(exc)))
-                        )
-                        i = j
-                        continue
+                    await self._inorder.put(
+                        (tickets, loop.create_task(self._fail(exc)))
+                    )
+                    i = j
+                    continue
                 self.drain_launches += 1
                 self.drained_slots += len(group)
                 task = loop.create_task(
@@ -443,8 +410,8 @@ class RequestRing:
     # --------------------------------------------------------------- drain
     async def drain(self) -> None:
         """Stop intake and retire every published ticket in order before
-        parking the serving loop — zero-loss shutdown (the ring_smoke
-        drain gate). Safe to call with nothing ever submitted."""
+        parking the serving loop — zero-loss shutdown. Safe to call with
+        nothing ever submitted."""
         self._closed = True
         if self._lock is None:
             return  # never started
@@ -477,5 +444,4 @@ class RequestRing:
             "drain_launches": self.drain_launches,
             "drained_slots": self.drained_slots,
             "host_slots": self.host_slots,
-            "watchdog_relaunches": self.watchdog_relaunches,
         }

@@ -412,9 +412,9 @@ class EngineRunner:
                     )
         otake = getattr(self.engine, "take_a2a_overflow_delta", None)
         if otake is not None:
-            impl, rows = otake()
+            rows = otake()
             if rows > 0:
-                self.metrics.a2a_overflow.labels(impl=impl).inc(rows)
+                self.metrics.a2a_overflow.inc(rows)
 
     async def check_columns(
         self, cols: RequestColumns, now_ms: Optional[int] = None,

@@ -8,7 +8,7 @@ re-grant the next time that key shows up. This package turns eviction
 into a tiering event instead:
 
 * **demote-on-evict** — the decide kernels return the evicted rows as a
-  sidecar riding the response fetch (kernel2/pallas_probe `evictees=`)
+  sidecar riding the response fetch (kernel2 `evictees=`)
   and the engine appends them to the shadow;
 * **demote-on-idle** — a background sweep (tier/manager.py, telemetry
   cadence) pulls rows idle past GUBER_TIER_IDLE_MS out of HBM

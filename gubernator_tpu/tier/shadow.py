@@ -9,8 +9,7 @@ idle sweep) and the fault-back source during host staging. Design points:
   packs back — so a row that lived in a packed table round-trips
   bit-exactly and cross-layout restarts stay sound.
 * **Byte bound.** `max_bytes` bounds the RAM set at the nominal
-  ROW_BYTES (64) per row — the state bytes themselves, the figure the
-  tier_smoke gate checks. Over-budget entries shed oldest-demoted-first
+  ROW_BYTES (64) per row — the state bytes themselves. Over-budget entries shed oldest-demoted-first
   (LRU over demote/refresh time): to the spill file when one is
   configured (lossless), else dropped and counted — exactly today's
   eviction loss, never worse.

@@ -421,7 +421,7 @@ def test_cascade_deny_if_any_single_dispatch(wire, frozen_now):
 
 def test_cascade_compact_wire_encodable(frozen_now):
     """An encodable 3-level cascade rides the compact wire — zero
-    full-width fallbacks (the CI algo_smoke gate's unit twin)."""
+    full-width fallbacks."""
     from gubernator_tpu.ops import wire as wire_mod
     from gubernator_tpu.ops.batch import pack_columns
 

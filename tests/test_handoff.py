@@ -172,8 +172,7 @@ def test_merge_newest_config_wins_and_expired_dropped():
 def test_sharded_extract_merge_tombstone_parity():
     """Same surface on the 8-device CPU mesh: extract from a sharded source,
     conservative-merge into a sharded destination, tombstone at the source
-    — zero rows lost (the ci/bench_cpu.py handoff smoke's correctness
-    half)."""
+    — zero rows lost."""
     from gubernator_tpu.parallel import make_mesh
     from gubernator_tpu.parallel.sharded import ShardedEngine
 

@@ -1,7 +1,7 @@
 """The deployment `bench/configs/mesh4-sharded.json` defines — the cluster as
 the four shards of one host, GUBER_ENGINE=sharded — at a size the CPU holds:
 4,096 slots a shard on four virtual devices, the selectors a TPU resolves
-`auto` to forced (route=device, dedup=device, compact wire, a2a=collective).
+`auto` to forced (route=device, dedup=device, compact wire).
 
 The served half runs the benchmark's own server child (`bench/launcher.py`:
 `python -m gubernator_tpu` plus a control thread for the profiler) with the
@@ -43,7 +43,7 @@ SLOTS_PER_SHARD = 4096
 # what `auto` resolves to on a TPU (a CPU backend takes the host paths)
 TPU_SELECTORS = {
     "GUBER_SHARD_ROUTE": "device", "GUBER_SHARD_DEDUP": "device",
-    "GUBER_WIRE_COMPACT": "1", "GUBER_A2A_IMPL": "collective",
+    "GUBER_WIRE_COMPACT": "1",
 }
 LIMIT = int(CONFIG["keyspace"]["limit"])
 DURATION = int(CONFIG["keyspace"]["duration_ms"])
@@ -328,7 +328,7 @@ def test_the_shards_add_up_to_the_whole(shards, frozen_now):
     whole = LocalEngine(capacity=SLOTS_PER_SHARD * shards)
     mesh = ShardedEngine(
         make_mesh(shards), capacity_per_shard=SLOTS_PER_SHARD,
-        route="device", dedup="device", wire="compact", a2a="collective",
+        route="device", dedup="device", wire="compact",
     )
     # three rounds over overlapping halves: installs, then hits on live keys
     for r in range(3):

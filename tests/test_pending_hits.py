@@ -94,8 +94,8 @@ def test_empty_accumulator():
 
 
 def test_clear_drops_everything():
-    """clear() is the harness-reset entry point (bench.py's steady-state
-    queue drain) — no more reaching into __slots__ private fields."""
+    """clear() is the reset entry point for a test that needs an empty
+    queue — no reaching into __slots__ private fields."""
     p = PendingHits()
     hb = hb_for([(f"k{i}", 1, 10, 0) for i in range(5)])
     p.merge(hb, np.arange(5), hb.hits.copy(), np.zeros(5, dtype=np.int32))

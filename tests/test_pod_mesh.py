@@ -1,11 +1,10 @@
-"""Pod-scale mesh suite: ring-exchange parity, (host, device) topology, and
-the hierarchical GLOBAL sync.
+"""Pod-scale mesh suite: the (host, device) topology and the hierarchical
+GLOBAL sync.
 
-The ring schedule (parallel/ring.py) must be BYTE-identical to the
-`lax.all_to_all` oracle it replaces — at every mesh width, under both dedup
-modes, through capacity overflow, and on the 2-D (host, device) topology.
-The inter-slice compact sync codec (service/wire.sync_wire_pb) must
-round-trip exactly and engage on the real gRPC peer plane.
+Re-meshing the same devices into (host, device) rows moves no keys, the
+in-mesh GLOBAL reconcile is topology-invariant, and the inter-slice compact
+sync codec (service/wire.sync_wire_pb) round-trips exactly and engages on
+the real gRPC peer plane.
 """
 
 import asyncio
@@ -16,7 +15,6 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding
 
 from gubernator_tpu.ops.batch import columns_from_requests
 from gubernator_tpu.parallel import ShardedEngine, make_mesh
@@ -26,9 +24,7 @@ from gubernator_tpu.parallel.mesh import (
     host_of_shard,
     mesh_hosts,
     shard_axes,
-    shard_spec,
 )
-from gubernator_tpu.parallel.ring import a2a_impl, make_exchange_probe
 from gubernator_tpu.types import Algorithm, Behavior, RateLimitRequest, MINUTE
 
 
@@ -114,142 +110,15 @@ def test_make_mesh_topology():
         del os.environ["GUBER_MESH_HOSTS"]
 
 
-def test_a2a_impl_resolution(monkeypatch):
-    assert a2a_impl("ring") == "ring"
-    assert a2a_impl("collective") == "collective"
-    monkeypatch.setenv("GUBER_A2A_IMPL", "ring")
-    assert a2a_impl() == "ring"
-    monkeypatch.setenv("GUBER_A2A_IMPL", "auto")
-    # auto = collective on every backend (the ring TPU kernel is refused)
-    assert a2a_impl() == "collective"
-    monkeypatch.setenv("GUBER_A2A_IMPL", "bogus")
-    with pytest.raises(ValueError):
-        a2a_impl()
-
-
-# --------------------------------------------------- exchange-level parity
-
-
-@pytest.mark.parametrize("D", [2, 4, 8])
-def test_exchange_parity_vs_collective(D):
-    """ring.exchange == lax.all_to_all byte-for-byte at every mesh width,
-    for both the 1-D and the (host, device) topology."""
-    rng = np.random.default_rng(D)
-    meshes = [make_mesh(D)]
-    if D % 2 == 0:
-        meshes.append(make_mesh(D, hosts=2))
-    for mesh in meshes:
-        block = (D, 5, 64)
-        x = jnp.asarray(
-            rng.integers(-(1 << 31), 1 << 31, size=(D,) + block, dtype=np.int64)
-        )
-        x = jax.device_put(x, NamedSharding(mesh, shard_spec(mesh)))
-        got = np.asarray(make_exchange_probe(mesh, block, "ring")(x))
-        want = np.asarray(make_exchange_probe(mesh, block, "collective")(x))
-        np.testing.assert_array_equal(got, want, err_msg=f"D={D} {mesh.axis_names}")
-
-
-def test_exchange_probe_truncated_hops():
-    """A k-hop ring prefix delivers exactly the blocks within k hops (the
-    per-hop bench probe's contract): hop slots outside the prefix are zero,
-    inside it equal the full exchange."""
-    D = 8
-    mesh = make_mesh(D)
-    rng = np.random.default_rng(3)
-    block = (D, 4, 16)
-    x = jnp.asarray(rng.integers(1, 1 << 30, size=(D,) + block, dtype=np.int64))
-    x = jax.device_put(x, NamedSharding(mesh, shard_spec(mesh)))
-    full = np.asarray(make_exchange_probe(mesh, block, "collective")(x))
-    for hops in (1, 3):
-        part = np.asarray(make_exchange_probe(mesh, block, "ring", hops=hops)(x))
-        for d in range(D):
-            for s in range(D):
-                lag = (d - s) % D
-                want = full[d, s] if lag <= hops else np.zeros_like(full[d, s])
-                np.testing.assert_array_equal(part[d, s], want)
-
-
-# ----------------------------------------------------- engine-level parity
-
-
-@pytest.mark.parametrize("D", [2, 4, 8])
-@pytest.mark.parametrize("dedup", ["host", "device"])
-def test_ring_engine_parity(D, dedup, frozen_now):
-    """route="device" through the ring schedule vs the collective oracle:
-    responses, stats, and canonical live state identical over multi-step
-    mixed traffic at every mesh width × dedup mode."""
-    t = frozen_now
-    mesh = make_mesh(D)
-    ring = ShardedEngine(mesh, capacity_per_shard=2048, route="device",
-                         dedup=dedup, a2a="ring")
-    coll = ShardedEngine(mesh, capacity_per_shard=2048, route="device",
-                         dedup=dedup, a2a="collective")
-    rng = np.random.default_rng(D * 7 + (dedup == "device"))
-    for step in range(3):
-        reqs = mixed_corpus(rng, t, step, n=160)
-        want = coll.check(reqs, now_ms=t + step)
-        got = ring.check(reqs, now_ms=t + step)
-        assert_resp_equal(want, got, f"D={D} dedup={dedup} step={step}")
-    np.testing.assert_array_equal(canon(coll.snapshot()), canon(ring.snapshot()))
-    assert coll.stats.cache_hits == ring.stats.cache_hits
-    assert coll.stats.cache_misses == ring.stats.cache_misses
-    assert coll.stats.over_limit == ring.stats.over_limit
-
-
-def test_ring_zipf_overflow_parity(frozen_now):
-    """Skewed batches through the exchange: Zipf duplicate traffic (route
-    parity under dedup) plus a hash-concentrated batch that genuinely
-    overflows one destination's pair capacity — the retry chain must make
-    the schedule invisible (identical responses, zero errors) and the
-    overflow must be OBSERVABLE via the engine's a2a_overflow counter (the
-    gubernator_tpu_a2a_overflow_total source)."""
-    from gubernator_tpu.hashing import fingerprint
-    from gubernator_tpu.parallel.mesh import shard_of
-
-    t = frozen_now
-    mesh = make_mesh(8)
-    ring = ShardedEngine(mesh, capacity_per_shard=4096, route="device",
-                         dedup="device", a2a="ring")
-    coll = ShardedEngine(mesh, capacity_per_shard=4096, route="device",
-                         dedup="device", a2a="collective")
-    rng = np.random.default_rng(17)
-    z = np.minimum(rng.zipf(1.1, size=2048) - 1, 1023)
-    reqs = [req(f"z{k}", hits=1, limit=1 << 20, created_at=t) for k in z]
-    want = coll.check(reqs, now_ms=t)
-    got = ring.check(reqs, now_ms=t)
-    assert_resp_equal(want, got, "zipf")
-    assert all(r.error == "" for r in got)
-
-    # distinct keys all OWNED BY SHARD 0: every source block concentrates on
-    # one destination, far past pair_capacity's 5σ multinomial bound
-    hot = []
-    i = 0
-    while len(hot) < 800:
-        if shard_of(np.int64(fingerprint("ring", f"h{i}")), 8) == 0:
-            hot.append(f"h{i}")
-        i += 1
-    reqs = [req(k, hits=1, limit=1 << 20, created_at=t) for k in hot]
-    want = coll.check(reqs, now_ms=t)
-    got = ring.check(reqs, now_ms=t)
-    assert_resp_equal(want, got, "hot-shard")
-    assert all(r.error == "" for r in got)
-    np.testing.assert_array_equal(canon(coll.snapshot()), canon(ring.snapshot()))
-    # both schedules overflowed identically — and the take-delta drains once
-    assert ring.a2a_overflow == coll.a2a_overflow > 0
-    impl, d = ring.take_a2a_overflow_delta()
-    assert impl == "ring" and d == ring.a2a_overflow
-    assert ring.take_a2a_overflow_delta() == ("ring", 0)
-
-
 def test_multihost_mesh_state_parity(frozen_now):
     """Re-meshing the same 8 devices from 1 host to 2 (host, device) rows
-    moves no keys: identical responses and canonical state, ring exchange
-    included — the ownership-stability contract of the host-major layout."""
+    moves no keys: identical responses and canonical state — the
+    ownership-stability contract of the host-major layout."""
     t = frozen_now
     one = ShardedEngine(make_mesh(8), capacity_per_shard=2048,
-                        route="device", dedup="device", a2a="ring")
+                        route="device", dedup="device")
     two = ShardedEngine(make_mesh(8, hosts=2), capacity_per_shard=2048,
-                        route="device", dedup="device", a2a="ring")
+                        route="device", dedup="device")
     assert two.n_hosts == 2 and two.devices_per_host == 4
     rng = np.random.default_rng(29)
     for step in range(2):
@@ -266,11 +135,9 @@ def test_multihost_global_sync_convergence(frozen_now):
     1-D mesh — in-mesh reconcile is topology-invariant."""
     t = frozen_now
     one = GlobalShardedEngine(make_mesh(8), capacity_per_shard=2048,
-                              sync_out=64, route="device", dedup="device",
-                              a2a="collective")
+                              sync_out=64, route="device", dedup="device")
     two = GlobalShardedEngine(make_mesh(8, hosts=2), capacity_per_shard=2048,
-                              sync_out=64, route="device", dedup="device",
-                              a2a="ring")
+                              sync_out=64, route="device", dedup="device")
     rng = np.random.default_rng(31)
     for step in range(2):
         ks = rng.integers(0, 40, size=120)

@@ -11,9 +11,8 @@ The acceptance surface of the always-on-chip tentpole's serving half:
 * the daemon integration is byte-identical to the direct dispatch path
   (same runner surface by construction) and feeds the
   `dispatch_launches_total{path="ring"}` / `ring_occupancy` telemetry;
-* `Daemon.warm_up` leaves ZERO compiles for the warmed shapes — including
-  the fused install/merge walk graphs when GUBER_WALK_KERNEL=pallas —
-  verified through jax.monitoring compile events, so no production
+* `Daemon.warm_up` leaves ZERO compiles for the warmed shapes, verified
+  through jax.monitoring compile events, so no production
   dispatch of a warmed shape ever pays a trace on the request path.
 """
 
@@ -287,11 +286,48 @@ def test_ring_config_env_plumbing():
     conf = setup_daemon_config(env={
         "GUBER_GRPC_ADDRESS": "127.0.0.1:0", "GUBER_HTTP_ADDRESS": "",
         "GUBER_RING_ENABLE": "1", "GUBER_RING_SLOTS": "8",
-        "GUBER_WALK_KERNEL": "pallas",
     })
     assert conf.behaviors.ring_enable is True
     assert conf.behaviors.ring_slots == 8
-    assert conf.walk_kernel == "pallas"
+
+
+def test_ring_config_validation_and_engine_block_constants():
+    """GUBER_RING_ISSUE is auto|host|fused — `persistent` named a kernel
+    the chip refused and went with it — a 1-slot ring is refused, and
+    /v1/debug/pipeline still reports the two engine keys the benchmark's
+    configurations and chip_smoke.py compare, as constants."""
+    from gubernator_tpu.config import (
+        BehaviorConfig,
+        ConfigError,
+        DaemonConfig,
+        setup_daemon_config,
+    )
+    from gubernator_tpu.service.daemon import Daemon
+
+    for issue in ("auto", "host", "fused"):
+        DaemonConfig(behaviors=BehaviorConfig(
+            ring_enable=True, ring_slots=2, ring_issue=issue
+        )).validate()
+    with pytest.raises(ConfigError, match="GUBER_RING_ISSUE"):
+        setup_daemon_config(env={
+            "GUBER_GRPC_ADDRESS": "127.0.0.1:0", "GUBER_HTTP_ADDRESS": "",
+            "GUBER_RING_ENABLE": "1", "GUBER_RING_ISSUE": "persistent",
+        })
+    with pytest.raises(ValueError, match="GUBER_RING_ISSUE"):
+        RequestRing(StubRunner(), issue_mode="persistent")
+    with pytest.raises(ConfigError):
+        DaemonConfig(behaviors=BehaviorConfig(ring_slots=1)).validate()
+
+    async def go():
+        d = await Daemon.spawn(_conf())
+        try:
+            return d.debug_pipeline()
+        finally:
+            await d.close()
+
+    dbg = asyncio.run(go())
+    assert dbg["engine"]["probe_kernel"] == "xla"
+    assert dbg["engine"]["a2a_impl"] is None  # "collective" on a mesh engine
 
 
 # ------------------------------------------------------ warm_up zero compiles
@@ -299,11 +335,9 @@ def test_ring_config_env_plumbing():
 
 def _warm_shapes_again(d):
     """Re-drive the exact dispatch surface warm_up traced, with DIFFERENT
-    values (shape-cache, not value-cache): the decide variants, the
-    1-row install, and — when the fused walks are armed — the 1-row
-    merge."""
+    values (shape-cache, not value-cache): the decide variants and the
+    1-row install."""
     from gubernator_tpu.ops.batch import RequestColumns
-    from gubernator_tpu.ops.table2 import F as F_FULL
 
     async def go():
         for algos in ([0], [2], [2, 3], [1]):
@@ -329,25 +363,17 @@ def _warm_shapes_again(d):
             duration=np.full(1, 2, dtype=np.int64),
             now_ms=2,
         )
-        if getattr(d.engine, "walk_mode", "xla") == "pallas":
-            await d.runner.merge_rows(
-                np.asarray([11], dtype=np.int64),
-                np.zeros((1, F_FULL), dtype=np.int32),
-            )
 
     return go()
 
 
-@pytest.mark.parametrize("walk", ["xla", "pallas"])
-def test_warm_up_leaves_zero_compiles(monkeypatch, walk):
+def test_warm_up_leaves_zero_compiles():
     """After Daemon.spawn (which runs warm_up), re-dispatching every warmed
-    shape triggers ZERO fresh XLA compiles — including the fused
-    install/merge walk graphs under GUBER_WALK_KERNEL=pallas (the
-    always-on contract: no production dispatch of a warmed shape ever
-    traces on the request path)."""
+    shape triggers ZERO fresh XLA compiles (the always-on contract: no
+    production dispatch of a warmed shape ever traces on the request
+    path)."""
     import jax.monitoring as jm
 
-    monkeypatch.setenv("GUBER_WALK_KERNEL", walk)
     from gubernator_tpu.service.daemon import Daemon
 
     compiles = []
@@ -362,8 +388,6 @@ def test_warm_up_leaves_zero_compiles(monkeypatch, walk):
         import jax.numpy as jnp
 
         d = await Daemon.spawn(_conf())
-        if walk == "pallas":
-            assert d.engine.walk_mode == "pallas"
         jm.register_event_listener(listener)
         armed[0] = True
         try:

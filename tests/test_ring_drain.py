@@ -13,9 +13,9 @@ fused issue loop) — the kill-the-launch-tax tentpole's acceptance surface.
   every submitter resolves (served or RingClosed→direct fallback).
 * **Backpressure.** K < occupancy just means more drains per window — the
   slot-count bound still holds, nothing drops or reorders.
-* **Fence protocol.** The staged persistent-kernel claim loop (tier B)
-  matches the numpy oracle in Pallas interpreter mode: publish gaps, ring
-  wrap, and the K bound all honored.
+* **Fence protocol.** `DeviceRing.drain` claims published tickets in
+  order, stops at the first unpublished one, takes at most k, wraps the
+  ring and publishes `seq_out` for exactly what it claimed.
 """
 
 import asyncio
@@ -170,13 +170,7 @@ def test_backpressure_when_drain_k_below_occupancy(monkeypatch):
         assert dbg["drain_launches"] >= dbg["drained_slots"] / 2
 
 
-# -------------------------------------------------- persistent fence kernel
-
-
-def _publish(seq_in, tickets):
-    for t in tickets:
-        seq_in[t % seq_in.shape[0]] = t + 1
-    return seq_in
+# ----------------------------------------------------------- fence protocol
 
 
 @pytest.mark.parametrize(
@@ -191,33 +185,55 @@ def _publish(seq_in, tickets):
         (4, [1, 2], 0, 4),             # head not published: claim nothing
     ],
 )
-def test_fence_claim_kernel_matches_oracle(case):
-    """Tier B's claim loop (interpreter mode) against the numpy oracle:
-    identical claimed count, identical claimed payload, identical seq_out
-    fence words — publish gaps stop the claim, the ring wraps, K bounds."""
-    from gubernator_tpu.ops.ring_drain import fence_claim_ref, make_fence_claim
+def test_device_ring_drain_fence_protocol(case):
+    """The fused drain's in-trace claim loop: tickets claimed contiguously
+    from `start` (a gap or k stops it), each claimed slot decided exactly
+    like a direct compact-wire dispatch in ticket order, `seq_out` bumped
+    for the claimed slots and no other."""
+    import jax
+
+    from gubernator_tpu.ops import wire
+    from gubernator_tpu.ops.batch import RequestColumns, pack_columns, pad_batch
+    from gubernator_tpu.ops.engine import LocalEngine
+    from gubernator_tpu.ops.ring_drain import DeviceRing
 
     slots, tickets, start, k = case
-    width = 6
+    width, now = 8, 1_700_000_000_000
     rng = np.random.default_rng(42 + slots + len(tickets))
-    grids = rng.integers(-5, 100, size=(slots, 5, width + 1), dtype=np.int32)
-    seq_in = _publish(np.zeros(slots, dtype=np.int32), tickets)
-    seq_out = np.zeros(slots, dtype=np.int32)
+    eng = LocalEngine(capacity=1 << 10, wire="compact")
+    ref = LocalEngine(capacity=1 << 10, wire="compact")
+    ring = DeviceRing(slots, width, drain_k=slots)
+    grids = {}
+    for t in tickets:
+        n = width - 2  # two padding rows a slot
+        hb, _ = pack_columns(RequestColumns(
+            fp=rng.integers(1, 1 << 62, size=n, dtype=np.int64),
+            algo=np.zeros(n, np.int32), behavior=np.zeros(n, np.int32),
+            hits=rng.integers(0, 4, size=n).astype(np.int64),
+            limit=np.full(n, 10, np.int64), burst=np.zeros(n, np.int64),
+            duration=np.full(n, 60_000, np.int64),
+            created_at=np.zeros(n, np.int64), err=np.zeros(n, np.int8),
+        ), now)
+        grids[t] = wire.pack_wire_full(pad_batch(hb, width), now)
+        ring.stage(t % slots, grids[t], t)
 
-    n_ref, bank_ref, seq_out_ref = fence_claim_ref(
-        seq_in, seq_out.copy(), grids, start, k
-    )
-    fn = make_fence_claim(slots, width, k_max=k, interpret=True)
-    ctl = np.asarray([start, k], dtype=np.int32)
-    seq_out_dev, bank_dev, n_dev = fn(
-        seq_in, seq_out.copy(), grids, ctl
-    )
+    claimed = []  # the protocol, in plain Python
+    while len(claimed) < k and start + len(claimed) in grids:
+        claimed.append(start + len(claimed))
 
-    assert int(n_dev[0]) == n_ref
-    np.testing.assert_array_equal(np.asarray(seq_out_dev), seq_out_ref)
-    # only the claimed prefix of the bank is defined
+    bank, n = ring.drain(eng, start, k, "token", False)
+    assert int(n) == len(claimed)
+    want_out = np.zeros(slots, dtype=np.int64)
+    for i, t in enumerate(claimed):
+        want_out[t % slots] = t + 1
+        ref.table, out = wire.decide2_wire_cols(
+            ref.table, jax.device_put(grids[t]), write=ref.write_mode,
+            math="token", cascade=False, evictees=False,
+        )
+        np.testing.assert_array_equal(np.asarray(bank)[i], np.asarray(out))
+    np.testing.assert_array_equal(np.asarray(ring.seq_out), want_out)
     np.testing.assert_array_equal(
-        np.asarray(bank_dev)[:n_ref], bank_ref[:n_ref]
+        np.asarray(eng.table.rows), np.asarray(ref.table.rows)
     )
 
 

@@ -11,7 +11,10 @@ the host-planned paths it replaces:
   Zipf-skewed batches that force per-pair exchange overflow (retries +
   terminal host fallback);
 * the GLOBAL owner/replica fork (GlobalShardedEngine) behaves identically
-  whichever side of the mesh does routing and dedup.
+  whichever side of the mesh does routing and dedup;
+* route="device" on a 2-, 4- and 8-device mesh answers like ONE LocalEngine
+  fed the same traffic — the mesh and its all_to_all exchange are invisible,
+  through a hash-concentrated batch that overflows a pair's capacity too.
 
 Tables are compared CANONICALLY (slots sorted within each bucket): lane
 assignment follows batch row order, and the dedup paths legitimately place
@@ -25,6 +28,7 @@ import pytest
 import jax
 
 from gubernator_tpu.ops.batch import columns_from_requests
+from gubernator_tpu.ops.engine import LocalEngine
 from gubernator_tpu.parallel import ShardedEngine, make_mesh
 from gubernator_tpu.parallel.global_sync import GlobalShardedEngine
 from gubernator_tpu.types import Algorithm, Behavior, RateLimitRequest, MINUTE
@@ -135,6 +139,74 @@ def test_route_parity_zipf_overflow(mesh, frozen_now):
         assert r.remaining == (1 << 20) - c, f"key z{k}"
     np.testing.assert_array_equal(canon(host_eng.snapshot()),
                                   canon(dev_eng.snapshot()))
+
+
+def _local_oracle(dedup, capacity):
+    """One table, no mesh: the host pass planner for dedup="host", its
+    aggregate-everything plan (max_exact=1) for the in-trace dedup."""
+    return LocalEngine(
+        capacity=capacity, max_exact_passes=8 if dedup == "host" else 1
+    )
+
+
+@pytest.mark.parametrize("D", [2, 4, 8])
+@pytest.mark.parametrize("dedup", ["host", "device"])
+def test_device_route_matches_local_engine(D, dedup, frozen_now):
+    """route="device" at every mesh width × dedup mode against one
+    LocalEngine: responses and stats over multi-step mixed traffic, then
+    every key's stored state read back with a hits=0 probe."""
+    t = frozen_now
+    dev = ShardedEngine(make_mesh(D), capacity_per_shard=2048,
+                        route="device", dedup=dedup)
+    local = _local_oracle(dedup, 2048 * D)
+    rng = np.random.default_rng(D * 7 + (dedup == "device"))
+    for step in range(3):
+        reqs = mixed_corpus(rng, t, step, n=160)
+        want = local.check(reqs, now_ms=t + step)
+        got = dev.check(reqs, now_ms=t + step)
+        assert_resp_equal(want, got, f"D={D} dedup={dedup} step={step}")
+    assert local.stats.cache_hits == dev.stats.cache_hits
+    assert local.stats.cache_misses == dev.stats.cache_misses
+    assert local.stats.over_limit == dev.stats.over_limit
+    probe = [
+        req(f"m{k}", hits=0, limit=1000,
+            algorithm=(Algorithm.TOKEN_BUCKET if k % 3
+                       else Algorithm.LEAKY_BUCKET),
+            created_at=t + 3)
+        for k in range(70)
+    ]
+    assert_resp_equal(local.check(probe, now_ms=t + 3),
+                      dev.check(probe, now_ms=t + 3), "probe")
+    assert dev.a2a_overflow == 0
+
+
+def test_device_route_overflow_matches_local_engine(mesh, frozen_now):
+    """Distinct keys all OWNED BY SHARD 0: every source block concentrates
+    on one destination, far past pair_capacity's 5σ bound. The retry chain
+    makes the overflow invisible in the answers (LocalEngine's, zero
+    errors) and OBSERVABLE in the engine's a2a_overflow counter, which
+    take_a2a_overflow_delta (the gubernator_tpu_a2a_overflow_total source)
+    drains exactly once."""
+    from gubernator_tpu.hashing import fingerprint
+    from gubernator_tpu.parallel.mesh import shard_of
+
+    t = frozen_now
+    dev = ShardedEngine(mesh, capacity_per_shard=4096, route="device",
+                        dedup="device")
+    local = _local_oracle("device", 4096 * 8)
+    hot = []
+    i = 0
+    while len(hot) < 800:
+        if shard_of(np.int64(fingerprint("rd", f"h{i}")), 8) == 0:
+            hot.append(f"h{i}")
+        i += 1
+    reqs = [req(k, hits=1, limit=1 << 20, created_at=t) for k in hot]
+    got = dev.check(reqs, now_ms=t)
+    assert_resp_equal(local.check(reqs, now_ms=t), got, "hot-shard")
+    assert all(r.error == "" for r in got)
+    assert dev.a2a_overflow > 0
+    assert dev.take_a2a_overflow_delta() == dev.a2a_overflow
+    assert dev.take_a2a_overflow_delta() == 0
 
 
 def test_global_fork_parity_device_route_and_dedup(mesh, frozen_now):

@@ -289,3 +289,27 @@ def test_sparse_geometry_matches_probe_window_contract():
             assert u >= min(64, batch)
             _dblk, du = sweep_geometry(nb, batch)
             assert du >= min(64, batch)
+
+
+def test_sparse_crossover_is_layout_aware(monkeypatch):
+    """The crossover is byte-denominated: a geometry whose worst-case dirty
+    coverage sits just past the FULL-layout bound still resolves sparse on
+    a 32 B packed layout (half the bytes per row → twice the row budget)."""
+    from gubernator_tpu.ops.layout import FULL, GCRA32, TOKEN32
+
+    monkeypatch.setenv("GUBER_WRITE_SPARSE_BLK", "64")
+    monkeypatch.setenv("GUBER_WRITE_SPARSE_CROSSOVER", "4")
+    # batch 128 → g = 128 grid steps × blk = 64 rows = 8192 rows worst-case
+    # dirty coverage. With crossover 4 the sweep fallback fires when
+    # scaled_coverage·4 ≥ NB: full scales ×1 → fires for NB ≤ 32768; packed
+    # ×0.5 → fires only for NB ≤ 16384. NB = 24576 (12 × 2048) sits in the
+    # boundary band where the two layouts DECIDE DIFFERENTLY.
+    nb, batch = 12 * 2048, 128
+    assert resolve_write("sparse", nb, batch, FULL) == "sweep"
+    assert resolve_write("sparse", nb, batch, GCRA32) == "sparse"
+    assert resolve_write("sparse", nb, batch, TOKEN32) == "sparse"
+    # defaulted layout keeps the pre-layout behavior bit-for-bit
+    assert resolve_write("sparse", nb, batch) == "sweep"
+    # far side of the boundary: both layouts agree again
+    assert resolve_write("sparse", 1 << 21, 128, FULL) == "sparse"
+    assert resolve_write("sparse", 1 << 11, 1 << 17, GCRA32) == "sweep"

@@ -105,9 +105,9 @@ def test_evictee_sidecar_absent_without_flag():
     assert np.asarray(out).shape == (18, 4)
 
 
-def test_evictee_sidecar_parity_xla_vs_pallas():
-    """The Pallas megakernel's sidecar (deferred-inserter patches and all)
-    is bit-identical to the XLA path's — outputs AND table bytes."""
+def test_evictee_sidecar_parity_xla_vs_sweep():
+    """Under the Pallas sweep write the sidecar is bit-identical to the
+    XLA scatter's — outputs AND table bytes."""
     rng = np.random.default_rng(11)
     t0 = new_table2(64)
     seed = rng.integers(1, 1 << 60, size=64, dtype=np.int64)
@@ -122,7 +122,7 @@ def test_evictee_sidecar_parity_xla_vs_pallas():
         tx, batch, write="xla", math="token", evictees=True
     )
     tp, op = decide2_packed_cols(
-        tp, batch, write="xla", math="token", evictees=True, probe="pallas"
+        tp, batch, write="sweep", math="token", evictees=True
     )
     assert np.array_equal(np.asarray(ox), np.asarray(op))
     assert np.array_equal(np.asarray(tx.rows), np.asarray(tp.rows))

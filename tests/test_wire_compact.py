@@ -253,6 +253,34 @@ def test_sharded_parity(mesh, route, dedup):
     assert 0 < w["put"] < wf["put"] and 0 < w["fetch"] < wf["fetch"]
 
 
+@pytest.mark.parametrize("fmt,put_row,fetch_row", [
+    ("compact", 20, 16),  # 5 int32 lanes in, 4 int32 lanes out
+    ("full", 96, 32),  # 12 int64 lanes in, 4 int64 lanes out
+])
+def test_wire_bytes_a_row_and_one_dispatch_a_batch(mesh, fmt, put_row,
+                                                   fetch_row):
+    """What one more row costs across the host↔device boundary, exactly,
+    from the engine's own byte accounting (the wire_bytes_total source):
+    the margin between a 512-row and a 4,096-row dispatch. A batch of
+    distinct keys is ONE engine dispatch at either size."""
+    rng = np.random.default_rng(31)
+    eng = ShardedEngine(mesh, capacity_per_shard=1 << 12, write_mode="xla",
+                        route="device", dedup="device", wire=fmt)
+
+    def one(n):
+        eng.take_wire_deltas()
+        before = eng.stats.dispatches
+        eng.check_columns(
+            mk_cols(n, rng, leaky_frac=0.0, behavior_pool=(0,)), now_ms=NOW
+        )
+        assert eng.stats.dispatches == before + 1
+        return eng.take_wire_deltas()
+
+    small, big = one(512), one(4096)
+    assert (big["put"] - small["put"]) / (4096 - 512) == put_row
+    assert (big["fetch"] - small["fetch"]) / (4096 - 512) == fetch_row
+
+
 def test_sharded_fallback_on_skew(mesh):
     """A batch with created_at beyond the delta budget ships full-width
     (byte-counted) and still matches the oracle row-for-row."""
