@@ -211,6 +211,15 @@ class RequestColumns(NamedTuple):
     err: np.ndarray  # int8 error codes (ERR_*)
 
 
+def concat_columns(parts: Sequence[RequestColumns]) -> RequestColumns:
+    """One RequestColumns over a chunk's pieces, in arrival order."""
+    if len(parts) == 1:
+        return parts[0]
+    return RequestColumns(
+        *[np.concatenate([p[k] for p in parts]) for k in range(len(parts[0]))]
+    )
+
+
 def fingerprint_columns(names, keys) -> "tuple[np.ndarray, np.ndarray]":
     """Fingerprint parallel name/key string sequences; returns (fp, err).
     The per-item xxhash call is the one irreducible Python loop on the ingress

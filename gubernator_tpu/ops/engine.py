@@ -13,7 +13,7 @@ from __future__ import annotations
 
 import time
 from dataclasses import dataclass
-from typing import Callable, List, Optional, Sequence
+from typing import Callable, List, NamedTuple, Optional, Sequence
 
 import numpy as np
 
@@ -28,6 +28,7 @@ from gubernator_tpu.ops.batch import (
     RequestColumns,
     ResponseColumns,
     columns_from_requests,
+    concat_columns,
     pack_columns,
     pack_host_batch,
     pad_batch,
@@ -85,6 +86,16 @@ def _occurrence_rank(fps: np.ndarray) -> np.ndarray:
     rank = np.empty(n, dtype=np.int64)
     rank[order] = idx - start
     return rank
+
+
+def _later_copies(fps: np.ndarray) -> np.ndarray:
+    """Positions, ascending, of every row whose fingerprint came earlier in
+    `fps` (occurrence rank ≥ 1; empty when no fingerprint repeats). In a
+    stable sort a key's copies stand together in arrival order, so every
+    element equal to the one before it is a later copy."""
+    order = np.argsort(fps, kind="stable")
+    s = fps[order]
+    return np.sort(order[1:][s[1:] == s[:-1]])
 
 
 def _math_mode(hb: HostBatch) -> str:
@@ -564,31 +575,31 @@ class PendingCheck:
 class _LazyWireBatch:
     """Padded HostBatch materialized ONLY if the rare dropped-claim retry
     needs it — the fused wire path stages pre-packed lanes directly and
-    skips pack_columns entirely on the common path. Duck-types the two
-    HostBatch uses inside the pipelined retry: field iteration
-    (`HostBatch(*[f[rows] for f in batch])`) and the padded row count."""
+    skips pack_columns entirely on the common path. Duck-types the
+    HostBatch uses inside the pipelined finish half: field iteration
+    (`HostBatch(*[f[rows] for f in batch])`), the padded row count, and
+    `active` — the rows the staged grid holds live lanes for (no error, and
+    the first occurrence of their key in the chunk)."""
 
-    __slots__ = ("_parts", "_now", "_tol", "rows", "_hb")
+    __slots__ = ("_parts", "_now", "_tol", "rows", "active", "_hb")
 
-    def __init__(self, parts, now, tol, rows):
+    def __init__(self, parts, now, tol, rows, active):
         self._parts = parts  # RequestColumns pieces, concat on demand
         self._now = now
         self._tol = tol
         self.rows = rows  # padded dispatch rows
+        self.active = active  # (n,) bool, unpadded
         self._hb = None
 
     def _materialize(self) -> HostBatch:
         if self._hb is None:
-            if len(self._parts) == 1:
-                cols = self._parts[0]
-            else:
-                cols = RequestColumns(
-                    *[
-                        np.concatenate([p[k] for p in self._parts])
-                        for k in range(len(self._parts[0]))
-                    ]
-                )
-            hb, _ = pack_columns(cols, self._now, tolerance_ms=self._tol)
+            hb, _ = pack_columns(
+                concat_columns(self._parts), self._now, tolerance_ms=self._tol
+            )
+            # as staged: a later copy of a key has no lane in this grid
+            hb = hb._replace(
+                fp=np.where(self.active, hb.fp, 0), active=self.active
+            )
             self._hb = pad_batch(hb, self.rows)
         return self._hb
 
@@ -612,14 +623,39 @@ def _padded_rows(batch) -> int:
     return batch.rows
 
 
+class _WireAssembly(NamedTuple):
+    """What `_assemble_wire_parts` hands the two fused stagings."""
+
+    grid: np.ndarray  # (5, pad+1) int32 compact ingress, pass 0
+    cols_list: list  # the parts' RequestColumns
+    err: np.ndarray  # (n,) validation codes, a copy the finish half owns
+    now: int
+    n: int
+    act_fp: np.ndarray  # every active fingerprint, later copies included
+    clamped: int
+    casc: bool
+    tol: int
+    pad: int
+    first: np.ndarray  # (n,) bool: rows with a live lane in `grid`
+    later: "np.ndarray | None"  # rows whose key came earlier in the chunk
+
+
 def _assemble_wire_parts(engine, parts, now_ms=None, pad_to=None):
     """Shared gating + single-scatter grid assembly of the fused wire
     paths (direct front door and ring slots): pre-packed native lanes are
     scattered into ONE padded compact ingress grid. Returns None when the
     batch needs the general columns path (engine not wire-capable,
-    non-encodable rows, duplicate fingerprints, created_at skew beyond the
-    ±511 ms delta budget, Store attached, or rows exceeding `pad_to`),
-    else (grid, cols_list, err, now, n, act_fp, clamped, casc, tol, pad).
+    non-encodable rows, created_at skew beyond the ±511 ms delta budget,
+    Store attached, or rows exceeding `pad_to`), else a `_WireAssembly`.
+
+    Copies of one key need the planner's sequential passes, and the grid is
+    its pass 0: occurrence 0 of every key rides its parser lane, and every
+    later copy has its lane zeroed (fp == 0, inactive on decode, as an error
+    row) and is named in `later` for the caller to stage as columns. A ring
+    slot holds one grid, so with `pad_to` a repeated key still means None;
+    so does one next to cascade level bits (the in-trace fold needs a
+    single pass).
+
     `pad_to` fixes the padded width (the ring's static slot shape); the
     default pads to the bucketed dispatch size."""
     if not getattr(engine, "supports_wire_ingress", False):
@@ -640,14 +676,22 @@ def _assemble_wire_parts(engine, parts, now_ms=None, pad_to=None):
         else np.concatenate([c.err for c in cols_list])
     )
     active = err == 0
-    n_act = int(active.sum())
-    if n_act == 0:
+    if not active.any():
         return None  # all-error batch: let the columns path produce it
     act_fp = fp[active]
-    # unique-fingerprint kernel contract: duplicate keys need the host pass
-    # planner (sequential same-key semantics) — general path
-    if np.unique(act_fp).size != n_act:
+    # unique-fingerprint kernel contract: the grid takes the first of a
+    # key's copies, the rest follow it as the planner's later passes
+    first, later = active, _later_copies(act_fp)
+    if later.size == 0:
+        later = None
+    elif pad_to is not None or engine.max_exact_passes < 2:
+        # a ring slot holds one grid; with max_exact 1 the planner has no
+        # exact pass and aggregates from occurrence 0
         return None
+    else:
+        later = np.nonzero(active)[0][later]
+        first = active.copy()
+        first[later] = False
     from gubernator_tpu.ops import wire as wire_mod
     from gubernator_tpu.ops.batch import created_at_tolerance_ms
 
@@ -664,34 +708,67 @@ def _assemble_wire_parts(engine, parts, now_ms=None, pad_to=None):
     clipped = np.clip(stamped, now - tol, now + tol)
     clamped = int((clipped != stamped).sum())
     base = int(clipped[int(np.argmax(active))])
-    delta = clipped - base
-    if (
-        (delta[active] < -wire_mod.DELTA_BIAS)
-        | (delta[active] > wire_mod.DELTA_BIAS - 1)
-    ).any():
+    delta = clipped[first] - base
+    if delta.min() < -wire_mod.DELTA_BIAS or delta.max() > wire_mod.DELTA_BIAS - 1:
         return None
     pad = pad_to if pad_to is not None else _pad_size(n)
     grid = wire_mod.assemble_wire_grid(
-        [p.lanes for p in parts], clipped, base, pad, active
+        [p.lanes for p in parts], clipped, base, pad, first
     )
     # cascade batches normally take the pb path (the native parser routes
     # them there), but an engine-level caller may assemble level-bit lanes
-    # directly — the unique-fp contract above makes them single-pass, so
-    # the in-trace fold is always sound here
+    # directly — a single pass, so the in-trace fold is sound here
     casc = wire_mod.grid_has_cascade(grid, n)
-    return grid, cols_list, err, now, n, act_fp, clamped, casc, tol, pad
+    if later is not None:
+        if casc:
+            return None
+        grid[:, later] = 0
+    return _WireAssembly(
+        grid, cols_list, err, now, n, act_fp, clamped, casc, tol, pad,
+        first, later,
+    )
 
 
-def _wire_pending(engine, assembled, staged):
+def _later_passes(engine, a: _WireAssembly) -> list:
+    """The passes that follow a fused grid: the chunk's later copies of a
+    key (a handful among thousands of rows), packed and planned as columns.
+    The grid was occurrence 0, so the planner gets one exact pass fewer:
+    exact passes hold original occurrences 1…max_exact−2 and the aggregate
+    starts at max_exact−1, where `plan_passes` puts it for the whole chunk.
+    Pass rows come back as rows of the chunk."""
+    # picked out of the part that holds them: no whole column is concatenated
+    starts = np.cumsum([0] + [c.fp.shape[0] for c in a.cols_list[:-1]])
+    part = np.searchsorted(starts, a.later, side="right") - 1
+    local = a.later - starts[part]
+    cols = concat_columns([
+        RequestColumns(*[f[local[part == i]] for f in a.cols_list[i]])
+        for i in np.unique(part)
+    ])
+    hb, err = pack_columns(cols, a.now, tolerance_ms=a.tol)
+    a.err[a.later] = err
+    passes = []
+    for p in plan_passes(hb, max_exact=engine.max_exact_passes - 1):
+        n = len(p.rows)
+        batch, staged = engine.stage_pass(p.batch, n)
+        p.rows = a.later[p.rows]
+        p.member_rows = [a.later[m] for m in p.member_rows]
+        passes.append([p, n, batch, staged])
+    return passes
+
+
+def _wire_pending(engine, a: _WireAssembly, staged):
     """PendingCheck over one assembled wire grid (direct or ring slot) —
-    the object both finish halves consume unchanged."""
-    _grid, cols_list, err, now, n, act_fp, clamped, casc, tol, pad = assembled
-    lazy = _LazyWireBatch(cols_list, now, tol, pad)
-    p = Pass(rows=np.arange(n), batch=lazy, member_rows=[])
+    the object both finish halves consume unchanged. Later copies of a key
+    (`a.later`, direct path only) are its passes after the grid's."""
+    lazy = _LazyWireBatch(a.cols_list, a.now, a.tol, a.pad, a.first)
+    p = Pass(rows=np.arange(a.n), batch=lazy, member_rows=[])
+    passes = [[p, a.n, lazy, staged]]
+    if a.later is not None:
+        passes += _later_passes(engine, a)
     return PendingCheck(
-        hb=lazy, err=err, now=now, passes=[[p, n, lazy, staged]],
-        clamped=clamped, rows=n, mark=act_fp, casc=casc, casc_intrace=casc,
-        promote=shadow_probe(engine, act_fp, now),
+        hb=lazy, err=a.err, now=a.now, passes=passes, clamped=a.clamped,
+        rows=a.n, mark=a.act_fp, casc=a.casc, casc_intrace=a.casc,
+        promote=shadow_probe(engine, a.act_fp, a.now),
     )
 
 
@@ -699,20 +776,21 @@ def prepare_check_wire(engine, parts, now_ms=None) -> "PendingCheck | None":
     """Fused front-door preparation: pre-packed native wire lanes
     (service/wire.WireBatch pieces) are scattered into ONE staged compact
     ingress grid — the request bytes were traversed once by the parser and
-    this scatter is the only further touch. Returns a PendingCheck for the
-    standard issue/finish halves, or None when the batch needs the general
-    columns path — the fallback is semantically identical, it just pays
-    the full pack."""
-    assembled = _assemble_wire_parts(engine, parts, now_ms=now_ms)
-    if assembled is None:
+    this scatter is the only further touch. A key sent more than once in
+    the chunk keeps the grid for its first copy; the later ones are staged
+    as columns in the passes behind it (`_later_passes`). Returns a
+    PendingCheck for the standard issue/finish halves, or None when the
+    batch needs the general columns path — the fallback is semantically
+    identical, it just pays the full pack."""
+    a = _assemble_wire_parts(engine, parts, now_ms=now_ms)
+    if a is None:
         return None
     from gubernator_tpu.ops import wire as wire_mod
 
-    grid, n = assembled[0], assembled[4]
     staged = engine.stage_wire(
-        grid, wire_mod.grid_math_mode(grid, n), cascade=assembled[7]
+        a.grid, wire_mod.grid_math_mode(a.grid, a.n), cascade=a.casc
     )
-    return _wire_pending(engine, assembled, staged)
+    return _wire_pending(engine, a, staged)
 
 
 class RingSlotPrep:
@@ -741,16 +819,15 @@ def prepare_ring_slot(
     assembly, but padded to the ring's fixed `width`. None routes the
     chunk to the host per-slot path (which pays a launch but is
     byte-identical) — including chunks wider than the slot."""
-    assembled = _assemble_wire_parts(engine, parts, now_ms=now_ms,
-                                     pad_to=width)
-    if assembled is None:
+    a = _assemble_wire_parts(engine, parts, now_ms=now_ms, pad_to=width)
+    if a is None:
         return None
     from gubernator_tpu.ops import wire as wire_mod
 
-    grid, n, casc = assembled[0], assembled[4], assembled[7]
-    pending = _wire_pending(engine, assembled, None)
-    return RingSlotPrep(grid, wire_mod.grid_math_mode(grid, n), casc,
-                        pending)
+    pending = _wire_pending(engine, a, None)
+    return RingSlotPrep(
+        a.grid, wire_mod.grid_math_mode(a.grid, a.n), a.casc, pending
+    )
 
 
 def prepare_check_columns(engine, cols, now_ms=None) -> PendingCheck:
@@ -920,16 +997,12 @@ def finish_check_columns(
         if pi == 0 and getattr(engine, "shadow", None) is not None:
             # tiering miss re-check: promote + re-dispatch run on the
             # engine thread through the same fixup the dropped-claim
-            # retries use. Fused wire batches carry no HostBatch activity
-            # mask — their staged-inactive rows are exactly the error
-            # rows (prepare_check_wire), so err==0 is the mask.
-            if isinstance(batch, HostBatch):
-                act = np.asarray(batch.active[:np_])
-            else:
-                act = (err == 0)[:np_]
+            # retries use. A fused wire batch's mask is the rows its grid
+            # holds live: no error row, no later copy of a key.
             (s, l, r, t, dropped, hit), changed = _shadow_rehydrate(
-                engine, batch, np_, (s, l, r, t, dropped, hit), act,
-                pending.now, fixup, pending.promote_putback,
+                engine, batch, np_, (s, l, r, t, dropped, hit),
+                np.asarray(batch.active[:np_]), pending.now, fixup,
+                pending.promote_putback,
             )
             retried_any = retried_any or changed
         if p.member_rows:

@@ -15,6 +15,12 @@ passes:
   OR RESET_REMAINING) and bounds worst-case passes under Zipf-skewed traffic.
 
 For the common all-unique batch this is a single pass with zero copies.
+
+The fused front door (ops/engine.prepare_check_wire) takes pass 0 — occurrence
+0 of every key — from the lanes the parser already packed, and plans only the
+later copies here, with `max_exact` one lower, so that the exact passes and
+the aggregate hold the occurrences they would hold in a plan of the whole
+chunk.
 """
 
 from __future__ import annotations

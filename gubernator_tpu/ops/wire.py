@@ -272,11 +272,8 @@ def assemble_wire_grid(
     concatenated rows; callers verify the delta budget (±511 ms of `base`)
     before assembling."""
     grid = np.zeros((WIRE_LANES, pad + 1), dtype=np.int32)
-    off = 0
-    for lanes in lane_parts:
-        w = lanes.shape[1]
-        grid[:, off : off + w] = lanes
-        off += w
+    off = sum(lanes.shape[1] for lanes in lane_parts)
+    np.concatenate(lane_parts, axis=1, out=grid[:, :off])
     delta32 = (
         ((created - base + DELTA_BIAS) & _DELTA_MASK) << HITS_BITS
     ).astype(np.int32)

@@ -245,7 +245,12 @@ class Batcher:
         self.requests = 0  # entries (enqueued batches) dispatched
         self.fused_dispatches = 0  # rode the fused wire→grid path
         self.column_dispatches = 0  # generic columns path
-        self.wire_fallbacks = 0  # all-wire chunk that could NOT fuse
+        # all-wire chunk that could NOT fuse: a non-encodable row or
+        # created_at skew (a repeated key fuses: split_dispatches)
+        self.wire_fallbacks = 0
+        # fused dispatches that carried at least one follow-on pass: the
+        # later copies of a key sent more than once in the chunk
+        self.split_dispatches = 0
         self.ring_dispatches = 0  # all-wire chunk staged into the ring
         self.adaptive_closes = 0  # window closed on rows/bytes/idle engine
         self.window_expires = 0  # window closed on the wall-clock ceiling
@@ -694,6 +699,7 @@ class Batcher:
                 self.ring_dispatches += 1
             elif fused:
                 self.fused_dispatches += 1
+                self.split_dispatches += fused > 1
             else:
                 self.column_dispatches += 1
                 if wire:
@@ -721,7 +727,7 @@ class Batcher:
                         "batch.seq": disp.seq,
                         "batch.rows": disp.rows,
                         "batch.requests": len(batch),
-                        "batch.fused": fused,
+                        "batch.fused": bool(fused),
                     },
                     links=req_spans,
                 )
@@ -759,7 +765,7 @@ class Batcher:
                 try:
                     rc = await self.ring.submit(payloads, disp=disp)
                     ringed = True
-                    return answer(rc, None, True)
+                    return answer(rc, None, 1)
                 except RingClosed:
                     pass
             if wire:
@@ -777,7 +783,7 @@ class Batcher:
         except Exception as exc:
             # raised before the runner's chain took the chunk; one raised in
             # the chain has been answered by its crossing back already
-            answer(None, exc, False)
+            answer(None, exc, 0)
 
     def debug(self) -> dict:
         """Live front-door state for /v1/debug/pipeline (docs/observability.md):
@@ -802,6 +808,7 @@ class Batcher:
             "fused_dispatches": self.fused_dispatches,
             "column_dispatches": self.column_dispatches,
             "wire_fallbacks": self.wire_fallbacks,
+            "split_dispatches": self.split_dispatches,
             "ring_dispatches": self.ring_dispatches,
             "ring": self.ring.debug() if self.ring is not None else None,
             "adaptive_closes": self.adaptive_closes,

@@ -210,8 +210,8 @@ class RequestRing:
     # ------------------------------------------------------- serving loop
     async def _dispatch(self, parts, disp):
         """One slot's dispatch: the exact runner surface the direct path
-        drives. Non-fusable chunks (duplicate keys, non-encodable rows) are
-        staged as columns inside the same runner dispatch, same as for
+        drives. Non-fusable chunks (a non-encodable row, created_at skew)
+        are staged as columns inside the same runner dispatch, same as for
         Batcher._dispatch; `fallbacks` counts them."""
 
         def note(_rc, _exc, fused):

@@ -94,7 +94,7 @@ class EngineRunner:
                         algorithm=_ALGO_LABELS[v]
                     ).inc(int(c))
 
-    def _run_chain(self, links, parts, done, fused=lambda: False):
+    def _run_chain(self, links, parts, done, fused=lambda: 0):
         """One dispatch's way through the worker threads: it leaves the
         event loop once and comes back once. `links` is a sequence of
         (executor, fn): the first link runs fn(None), each later one the
@@ -178,7 +178,9 @@ class EngineRunner:
         `done(rc, exc, fused)`, when given, is called on the loop thread by
         the dispatch's one crossing back (`_run_chain`), before this
         coroutine is resumed: the batcher answers its callers there.
-        `fused` says whether the fused wire staging served the chunk."""
+        `fused` counts the passes the fused wire staging issued for the
+        chunk (`check_wire`); 0 here, where every chunk is staged as
+        columns."""
         parts = [cols] if isinstance(cols, RequestColumns) else cols
         if (
             not getattr(self.engine, "supports_pipeline", False)
@@ -201,11 +203,14 @@ class EngineRunner:
         """Fused front-door check: pre-parsed WireBatch pieces
         (service/wire.py — native-parser lanes) staged straight into ONE
         compact ingress grid, no column concat and no HostBatch pack. A
-        chunk that cannot ride the fused path (duplicate keys, non-encodable
-        rows) is staged again as columns by the same prep job that found it
-        out, which is semantically identical; an engine that is not
-        wire-capable, or has a Store, takes `check` from here. `done` as in
-        `check`: its `fused` says which staging served the chunk."""
+        key sent more than once keeps the grid for its first copy, and the
+        later copies follow as passes of the same dispatch. A chunk that
+        cannot ride the fused path (a non-encodable row, `created_at` skew
+        beyond the wire's budget) is staged again as columns by the same
+        prep job that found it out, which is semantically identical; an
+        engine that is not wire-capable, or has a Store, takes `check` from
+        here. `done` as in `check`: its `fused` is the number of passes the
+        fused staging issued, 0 when the columns staging served the chunk."""
         engine = self.engine
         cols = [p.cols for p in parts]
         if (
@@ -218,7 +223,7 @@ class EngineRunner:
             )
         from gubernator_tpu.ops.engine import prepare_check_wire
 
-        fused = True
+        fused = 0
 
         def prepare(_):
             nonlocal fused
@@ -230,8 +235,9 @@ class EngineRunner:
                     # stays the staging that was used
                     st.name = "put_miss"
             if prepared is None:
-                fused = False
                 prepared = self._stage_columns(cols, now_ms, disp)
+            else:
+                fused = len(prepared.passes)
             return prepared
 
         return await self._run_chain(

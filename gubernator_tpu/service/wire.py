@@ -20,6 +20,7 @@ from gubernator_tpu.ops.batch import (
     ERROR_STRINGS,
     RequestColumns,
     ResponseColumns,
+    concat_columns,  # noqa: F401  (the service layer imports it from here)
 )
 from gubernator_tpu.proto import gubernator_pb2 as pb
 from gubernator_tpu.proto import peers_pb2 as peers_pb
@@ -172,14 +173,6 @@ def pb_from_response_columns(
 
 def subset_columns(cols: RequestColumns, rows: np.ndarray) -> RequestColumns:
     return RequestColumns(*[f[rows] for f in cols])
-
-
-def concat_columns(parts: Sequence[RequestColumns]) -> RequestColumns:
-    if len(parts) == 1:
-        return parts[0]
-    return RequestColumns(
-        *[np.concatenate([p[k] for p in parts]) for k in range(len(parts[0]))]
-    )
 
 
 def empty_response_columns(n: int) -> ResponseColumns:

@@ -161,12 +161,13 @@ class Cluster:
 
     async def settle_handoffs(self) -> None:
         """Wait for every daemon's in-flight rebalance handoff tasks (the
-        set_peers diff launches them fire-and-forget)."""
+        set_peers diff launches them fire-and-forget). Only tasks still
+        running are waited for: once in ≈50 runs under load a finished task
+        is still in `_handoff_tasks` (parent and change alike; cause not
+        found), and waiting on the set itself then never ends."""
         for d in self.daemons:
-            while d._handoff_tasks:
-                await asyncio.gather(
-                    *list(d._handoff_tasks), return_exceptions=True
-                )
+            while running := [t for t in d._handoff_tasks if not t.done()]:
+                await asyncio.gather(*running, return_exceptions=True)
 
     async def stop(self) -> None:
         await asyncio.gather(*(d.close() for d in self.daemons))

@@ -4,9 +4,9 @@
 thread to the fetch pool without a stop on the loop in between; the one
 crossing back answers the batcher's callers. These tests hold it to that:
 the loop-trip counter, which thread runs which stage, where an exception in
-any link ends up, what a chunk the fused staging refuses is answered, and
-the stage identity `dispatch` = put + put_miss + issue + fetch +
-`dispatch_wait`.
+any link ends up, what a chunk the fused staging refuses is answered (and
+that a repeated key is not such a chunk), and the stage identity
+`dispatch` = put + put_miss + issue + fetch + `dispatch_wait`.
 """
 
 import asyncio
@@ -50,16 +50,20 @@ def async_test(fn):
     return wrapper
 
 
-def wire_batch(keys, now, tag="rc"):
+def wire_batch(keys, now, tag="rc", gregorian=()):
+    """One parsed RPC of one hit a key. Keys in `gregorian` ask for a
+    calendar day (DURATION_IS_GREGORIAN, GregorianDays): rows the compact
+    wire cannot carry, so their chunk cannot ride the fused staging."""
     data = pb.GetRateLimitsReq(requests=[
         pb.RateLimitReq(
-            name=tag, unique_key=f"k{k}", hits=1, limit=10, duration=60_000,
-            created_at=now,
+            name=tag, unique_key=f"k{k}", hits=1, limit=10, created_at=now,
+            **({"behavior": 4, "duration": 4} if k in gregorian
+               else {"duration": 60_000}),
         )
         for k in keys
     ]).SerializeToString()
     wb = wire_batch_from_wire(data)[0]
-    assert wb.encodable.all()
+    assert wb.encodable.all() == (not gregorian)
     return wb
 
 
@@ -79,18 +83,21 @@ def assert_same(a: ResponseColumns, b: ResponseColumns):
         assert got.dtype == want.dtype and got.tobytes() == want.tobytes(), f
 
 
-@pytest.mark.parametrize("path", ["wire", "columns", "wire_miss", "serial"])
+@pytest.mark.parametrize(
+    "path", ["wire", "wire_split", "columns", "wire_miss", "serial"]
+)
 @async_test
 async def test_a_dispatch_is_one_loop_trip(path):
     """Whatever staging serves it, a dispatch's completion is the one
     callback the loop runs for it, and `done` hears which staging it was
-    before the awaiting coroutine goes on."""
+    (the passes the fused one issued, 0 for columns) before the awaiting
+    coroutine goes on."""
     now = ms_now()
     runner = new_runner()
     order = []
     try:
-        keys = [1, 2, 2, 3] if path == "wire_miss" else list(range(8))
-        wb = wire_batch(keys, now)
+        keys = [1, 2, 2, 3] if path == "wire_split" else list(range(8))
+        wb = wire_batch(keys, now, gregorian=[3] if path == "wire_miss" else ())
 
         def done(rc, exc, fused):
             order.append(("done", exc, fused))
@@ -105,7 +112,8 @@ async def test_a_dispatch_is_one_loop_trip(path):
             )
         order.append(("resumed", None, None))
         assert runner.loop_trips - before == 1
-        assert order == [("done", None, path == "wire"), ("resumed", None, None)]
+        fused = {"wire": 1, "wire_split": 2}.get(path, 0)
+        assert order == [("done", None, fused), ("resumed", None, None)]
         assert rc.status.shape == (len(keys),)
         assert sum(runner.algo_counts.values()) == len(keys)
     finally:
@@ -205,17 +213,20 @@ async def test_a_shut_down_executor_reaches_the_caller(pool):
         runner.close()
 
 
+@pytest.mark.parametrize("chunk", ["gregorian_row", "repeated_key"])
 @async_test
-async def test_a_wire_miss_is_restaged_where_it_was_found():
-    """A chunk with a duplicate key cannot fuse. The prep job that finds
-    that out stages it as columns itself: the answer is byte for byte what
-    `check` gives on the concatenated columns, the first staging is one
-    `put_miss` sample and the second one `put`, and the batcher counts one
-    wire fallback."""
+async def test_a_wire_miss_is_restaged_where_it_was_found(chunk):
+    """A chunk with a row the compact wire cannot carry cannot fuse. The
+    prep job that finds that out stages it as columns itself: the answer is
+    byte for byte what `check` gives on the concatenated columns, the first
+    staging is one `put_miss` sample and the second one `put`, and the
+    batcher counts one wire fallback. A key sent more than once is no such
+    chunk: it fuses, in one `put`, as a split dispatch."""
     now = ms_now()
     metrics = DaemonMetrics()
     r_wire, r_cols = new_runner(metrics), new_runner()
     b = Batcher(r_wire, batch_wait_ms=0.5, workers=1, metrics=metrics)
+    missed = chunk == "gregorian_row"
     try:
         # warm both engines with the same history, so both answer from it
         first = wire_batch(range(6), now)
@@ -223,7 +234,10 @@ async def test_a_wire_miss_is_restaged_where_it_was_found():
             await r_wire.check_wire([first], now_ms=now),
             await r_cols.check(first.cols, now_ms=now),
         )
-        parts = [wire_batch([1, 2, 3], now), wire_batch([3, 4, 1, 9], now)]
+        parts = [
+            wire_batch([1, 2, 3], now),
+            wire_batch([3, 4, 1, 9], now, gregorian=[9] if missed else ()),
+        ]
         s0, trips = _stage_sums(metrics), r_wire.loop_trips
         got = await asyncio.gather(*(b.check(p, now_ms=now) for p in parts))
         s1 = _stage_sums(metrics)
@@ -235,14 +249,18 @@ async def test_a_wire_miss_is_restaged_where_it_was_found():
             ResponseColumns(*(np.concatenate(f) for f in zip(*got))), want
         )
         assert (want.remaining == [8, 8, 8, 7, 8, 7, 9]).all()
-        assert (b.wire_fallbacks, b.column_dispatches, b.fused_dispatches) == (1, 1, 0)
+        assert (
+            b.wire_fallbacks, b.column_dispatches, b.fused_dispatches,
+            b.split_dispatches,
+        ) == ((1, 1, 0, 0) if missed else (0, 0, 1, 1))
+        assert b.debug()["split_dispatches"] == b.split_dispatches
         assert r_wire.loop_trips - trips == 1
 
         def delta(stage, k):
             return s1.get(stage, (0, 0))[k] - s0.get(stage, (0, 0))[k]
 
         assert {s: delta(s, 1) for s in ("put_miss", "put", "issue", "fetch")} == {
-            "put_miss": 1, "put": 1, "issue": 1, "fetch": 1,
+            "put_miss": int(missed), "put": 1, "issue": 1, "fetch": 1,
         }
     finally:
         await b.drain()
@@ -250,7 +268,7 @@ async def test_a_wire_miss_is_restaged_where_it_was_found():
         r_cols.close()
 
 
-@pytest.mark.parametrize("path", ["fused", "miss", "columns"])
+@pytest.mark.parametrize("path", ["fused", "split", "miss", "columns"])
 @async_test
 async def test_dispatch_is_its_stages_plus_its_self_time(path):
     """`dispatch` = put + put_miss + issue + fetch + `dispatch_wait`, to the
@@ -263,7 +281,8 @@ async def test_dispatch_is_its_stages_plus_its_self_time(path):
     try:
         for i in range(5):
             wb = wire_batch(
-                [7, 7, 8] if path == "miss" else range(8 * i, 8 * i + 8), now
+                [7, 7, 8] if path == "split" else range(8 * i, 8 * i + 8), now,
+                gregorian=[8 * i] if path == "miss" else (),
             )
             s0 = _stage_sums(metrics)
             await b.check(wb.cols if path == "columns" else wb, now_ms=now)

@@ -1,0 +1,248 @@
+"""A chunk with a key sent more than once keeps the fused wire staging.
+
+`prepare_check_wire` stages the first occurrence of every key from the
+parser's lanes (the planner's pass 0) and only the later copies as columns,
+in the passes behind it. These tests hold the split to the columns path on
+the same rows: answers byte for byte, the table row for row, the
+`EngineStats` delta, through the dropped-claim retry and the shadow's miss
+re-check; and hold the ring slot and the cascade fold, which need a single
+pass, to their refusal.
+"""
+
+import dataclasses
+
+import numpy as np
+import pytest
+
+from gubernator_tpu import native
+from gubernator_tpu.ops import wire as wire_mod
+from gubernator_tpu.ops.engine import (
+    LocalEngine,
+    ms_now,
+    prepare_check_wire,
+    prepare_ring_slot,
+)
+from gubernator_tpu.proto import gubernator_pb2 as pb
+from gubernator_tpu.service.runner import EngineRunner
+from gubernator_tpu.service.wire import concat_columns, wire_batch_from_wire
+from gubernator_tpu.tier import ShadowTable
+
+from tests.test_runner_chain import assert_same, async_test
+
+pytestmark = pytest.mark.skipif(
+    native.load() is None, reason="native toolchain unavailable"
+)
+
+RESET = 8  # Behavior.RESET_REMAINING
+EMPTY_KEY, EMPTY_NAME = object(), object()  # rows the parser marks as errors
+
+
+def rpc(rows, now):
+    """One parsed RPC. A row is a key, or (key, created_at offset in ms,
+    behavior); EMPTY_KEY / EMPTY_NAME make the two validation errors."""
+    reqs = []
+    for row in rows:
+        key, off, beh = row if isinstance(row, tuple) else (row, 0, 0)
+        reqs.append(pb.RateLimitReq(
+            name="" if key is EMPTY_NAME else "split",
+            unique_key="" if key is EMPTY_KEY else f"k{key}",
+            hits=1, limit=10, duration=60_000, created_at=now + off,
+            behavior=beh,
+        ))
+    wb = wire_batch_from_wire(
+        pb.GetRateLimitsReq(requests=reqs).SerializeToString()
+    )[0]
+    assert wb.all_encodable
+    return wb
+
+
+def pair(capacity=4096, shadow=False):
+    """Two runners over equal engines: one for the wire, one for columns."""
+    runners = []
+    for _ in range(2):
+        eng = LocalEngine(capacity=capacity, wire="compact")
+        if shadow:
+            eng.attach_shadow(ShadowTable(max_bytes=1 << 22))
+        runners.append(EngineRunner(eng))
+    return runners
+
+
+async def wire_against_columns(r_wire, r_cols, parts, now):
+    """Serve `parts` as one chunk through the wire on one engine and as
+    concatenated columns on the other; hold answers, stats delta and every
+    touched key's stored row to equality. Returns (passes the fused staging
+    issued, the answer, the stats delta)."""
+    def stats():
+        # a dispatch's stats delta lands on the engine thread after its
+        # answer: a no-op job behind it on that one thread says it has
+        for r in (r_wire, r_cols):
+            r._exec.submit(lambda: None).result()
+        return [dataclasses.asdict(r.engine.stats) for r in (r_wire, r_cols)]
+
+    before = stats()
+    fused = []
+    got = await r_wire.check_wire(
+        parts, now_ms=now, done=lambda _rc, _exc, f: fused.append(f)
+    )
+    cols = concat_columns([p.cols for p in parts])
+    want = await r_cols.check(cols, now_ms=now)
+    assert_same(got, want)
+    deltas = [
+        {k: a[k] - b[k] for k in a} for a, b in zip(stats(), before)
+    ]
+    assert deltas[0] == deltas[1]
+    fps = np.unique(cols.fp[cols.err == 0])
+    (found_w, rows_w), (found_c, rows_c) = (
+        r.engine.read_state(fps) for r in (r_wire, r_cols)
+    )
+    assert (found_w == found_c).all() and (rows_w == rows_c).all()
+    (n_fused,) = fused
+    return n_fused, got, deltas[0]
+
+
+# parts of one chunk; passes the split must issue; remaining of the rows
+# named, after a history in which keys 0..5 were hit once
+SHAPES = {
+    "pair_inside_one_rpc": ([[1, 2, 1, 7]], 2, {0: 8, 2: 7, 3: 9}),
+    "pair_across_two_parts": ([[1, 2, 3], [3, 4, 1, 9]], 2, {2: 8, 3: 7, 5: 7}),
+    "one_key_3_times": ([[6, 7, 6], [6, 8]], 3, {0: 9, 2: 8, 3: 7}),
+    # max_exact 8: occurrences 0..6 exact, 7 and up one aggregate
+    "one_key_8_times": ([[7] * 5 + [9], [7] * 3], 8, {4: 5, 6: 4, 8: 2}),
+    "one_key_9_times": ([[7] * 9], 8, {6: 3, 7: 1, 8: 1}),
+    # 13 copies left for 3 remaining: the aggregate is refused whole
+    "one_key_20_times": ([[7] * 12, [8] + [7] * 8], 8, {6: 3, 7: 3, 20: 3}),
+    "two_keys_past_the_aggregate": (
+        [[7, 8] * 9, [7] * 2], 8, {12: 3, 13: 3, 17: 1, 19: 3},
+    ),
+    "next_to_error_rows": (
+        [[EMPTY_KEY, 1, EMPTY_NAME, 1], [EMPTY_KEY, 2, 1]], 3,
+        {1: 8, 3: 7, 5: 8, 6: 6},
+    ),
+    # the wire's delta budget is ±511 ms of the first active row's stamp;
+    # a later copy is its own pass with its own base
+    "stamps_at_the_edge_of_the_budget": (
+        [[1, (2, 511, 0), (3, -512, 0)], [(1, 511, 0), (2, -512, 0), (1, 900, 0)]],
+        3, {3: 7, 4: 7, 5: 6},
+    ),
+    "reset_remaining_in_the_tail": (
+        [[7] * 9 + [(7, 0, RESET)] + [7]], 8, {6: 3, 8: 10, 9: 10, 10: 10},
+    ),
+    "reset_remaining_in_an_exact_pass": (
+        [[1, 1, (1, 0, RESET), 1]], 4, {0: 8, 1: 7, 2: 10, 3: 9},
+    ),
+}
+
+
+@pytest.mark.parametrize("shape", SHAPES)
+@async_test
+async def test_a_split_chunk_is_the_columns_path_byte_for_byte(shape):
+    parts, passes, remaining = SHAPES[shape]
+    now = ms_now()
+    r_wire, r_cols = pair()
+    try:
+        # both engines answer from the same history
+        first = rpc(range(6), now)
+        assert_same(
+            await r_wire.check_wire([first], now_ms=now),
+            await r_cols.check(first.cols, now_ms=now),
+        )
+        n_fused, got, delta = await wire_against_columns(
+            r_wire, r_cols, [rpc(p, now) for p in parts], now
+        )
+        assert n_fused == passes
+        assert {i: int(got.remaining[i]) for i in remaining} == remaining
+        assert delta["checks"] == sum(len(p) for p in parts)
+        assert delta["dispatches"] == passes
+    finally:
+        r_wire.close()
+        r_cols.close()
+
+
+@async_test
+async def test_a_clamped_stamp_is_counted_once_on_a_split_chunk():
+    """A client stamp beyond the engine's skew tolerance is clamped and
+    counted, on the first copy of a key and on a later one, once each."""
+    now = ms_now()
+    r_wire, r_cols = pair()
+    for r in (r_wire, r_cols):
+        r.engine.created_at_tolerance_ms = 300
+    try:
+        n_fused, _got, delta = await wire_against_columns(
+            r_wire, r_cols, [rpc([1, (2, 400, 0), (1, -400, 0), 2], now)], now
+        )
+        assert n_fused == 2 and delta["created_at_clamped"] == 2
+    finally:
+        r_wire.close()
+        r_cols.close()
+
+
+@pytest.mark.parametrize("shadow", [False, True], ids=["retry", "shadow"])
+@async_test
+async def test_a_split_chunk_through_the_feedback_paths(shadow, monkeypatch):
+    """One bucket of eight slots and 24 keys: pass 0's claims drop and are
+    retried on the engine thread; with a shadow attached the promote hands
+    rows back and the miss re-check re-dispatches them. Both select rows of
+    pass 0 by its mask, which leaves out the later copies of a key: a copy
+    taken for a miss would be applied twice."""
+    calls = []
+    redispatch = LocalEngine._redispatch_rows
+    monkeypatch.setattr(
+        LocalEngine, "_redispatch_rows",
+        lambda self, *a, **k: calls.append(self) or redispatch(self, *a, **k),
+    )
+    now = ms_now()
+    r_wire, r_cols = pair(capacity=8, shadow=shadow)
+    try:
+        for lo in (0, 8, 16):
+            fill = rpc(range(lo, lo + 8), now)
+            assert_same(
+                await r_wire.check(fill.cols, now_ms=now),
+                await r_cols.check(fill.cols, now_ms=now),
+            )
+        calls.clear()
+        parts = [
+            rpc(list(range(12)) + [3, 3, 20], now + 5),
+            rpc(list(range(12, 24)) + [20, 3, 1], now + 5),
+        ]
+        n_fused, got, _delta = await wire_against_columns(
+            r_wire, r_cols, parts, now + 5
+        )
+        assert n_fused == 4 and not got.err.any()
+        want_calls = 2 if shadow else 1  # the retry, and the miss re-check
+        assert calls.count(r_wire.engine) == want_calls
+        assert calls.count(r_cols.engine) == want_calls
+    finally:
+        r_wire.close()
+        r_cols.close()
+
+
+def test_what_needs_a_single_pass_still_refuses_a_repeated_key():
+    """A ring slot holds one grid and the in-trace cascade fold one pass:
+    with a repeated key in the chunk both stagings are refused whole, as
+    before; without one, both are served."""
+    now = ms_now()
+    eng = LocalEngine(capacity=4096, wire="compact")
+    unique, repeated = [rpc([1, 2, 3], now)], [rpc([1, 2], now), rpc([3, 1], now)]
+    assert prepare_ring_slot(eng, unique, 64, now_ms=now) is not None
+    assert prepare_ring_slot(eng, repeated, 64, now_ms=now) is None
+    split = prepare_check_wire(eng, repeated, now_ms=now)
+    assert [n for _p, n, _b, _s in split.passes] == [4, 1]
+    assert split.hb.active.tolist() == [True, True, True, False]
+
+    def with_level_bits(parts):
+        # what an engine-level caller assembles: level 1 on the second row
+        wb = parts[0]
+        lanes, behavior = wb.lanes.copy(), wb.cols.behavior.copy()
+        lanes[3, 1] |= np.int32(1 << wire_mod.LEVEL_SHIFT)
+        behavior[1] |= 1 << 8
+        return [
+            wb._replace(lanes=lanes, cols=wb.cols._replace(behavior=behavior)),
+            *parts[1:],
+        ]
+
+    cascade = prepare_check_wire(eng, with_level_bits(unique), now_ms=now)
+    assert cascade.casc and cascade.casc_intrace and len(cascade.passes) == 1
+    assert prepare_check_wire(eng, with_level_bits(repeated), now_ms=now) is None
+    # and an engine with no exact pass for the grid to be
+    eng.max_exact_passes = 1
+    assert prepare_check_wire(eng, [rpc([1, 1], now)], now_ms=now) is None
