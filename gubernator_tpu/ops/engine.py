@@ -20,6 +20,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from gubernator_tpu import tracing
 from gubernator_tpu.ops.batch import (
     ERR_DROPPED,
     InstallBatch,
@@ -217,6 +218,12 @@ class EngineStats:
     # traffic arrived (ops/layout.py selection contract) — a nonzero count
     # on a single-algorithm fleet means GUBER_SLOT_LAYOUT is misconfigured
     layout_migrations: int = 0
+    # rows of a pipelined chunk that were a later copy of a key sent earlier
+    # in it and were decided in the passes behind the first (the fused path
+    # stages them as columns, `_later_passes`); of those, the members of
+    # the aggregate pass (copy max_exact−1 and up of their key)
+    later_rows: int = 0
+    aggregate_rows: int = 0
 
     def accumulate(self, stats, count_dropped: bool = True) -> None:
         self.cache_hits += int(stats.cache_hits)
@@ -239,6 +246,8 @@ class EngineStats:
         self.created_at_clamped += d.created_at_clamped
         self.unprocessed_dropped += d.unprocessed_dropped
         self.layout_migrations += d.layout_migrations
+        self.later_rows += d.later_rows
+        self.aggregate_rows += d.aggregate_rows
 
 
 def _plan(engine, hb):
@@ -762,14 +771,17 @@ def _wire_pending(engine, a: _WireAssembly, staged):
     (`a.later`, direct path only) are its passes after the grid's."""
     lazy = _LazyWireBatch(a.cols_list, a.now, a.tol, a.pad, a.first)
     p = Pass(rows=np.arange(a.n), batch=lazy, member_rows=[])
-    passes = [[p, a.n, lazy, staged]]
-    if a.later is not None:
-        passes += _later_passes(engine, a)
-    return PendingCheck(
-        hb=lazy, err=a.err, now=a.now, passes=passes, clamped=a.clamped,
-        rows=a.n, mark=a.act_fp, casc=a.casc, casc_intrace=a.casc,
-        promote=shadow_probe(engine, a.act_fp, a.now),
+    pending = PendingCheck(
+        hb=lazy, err=a.err, now=a.now, passes=[[p, a.n, lazy, staged]],
+        clamped=a.clamped, rows=a.n, mark=a.act_fp, casc=a.casc,
+        casc_intrace=a.casc, promote=shadow_probe(engine, a.act_fp, a.now),
     )
+    if a.later is not None:
+        with tracing.stage.within("later_stage", rows=int(a.later.size)) as st:
+            later = _later_passes(engine, a)
+            st.note(passes=len(later))
+        pending.passes += later
+    return pending
 
 
 def prepare_check_wire(engine, parts, now_ms=None) -> "PendingCheck | None":
@@ -1013,6 +1025,9 @@ def finish_check_columns(
             remaining[members] = r[src]
             reset[members] = t[src]
             err[members[dropped[src]]] = ERR_DROPPED
+            if pi:
+                delta.later_rows += len(members)
+                delta.aggregate_rows += len(members)
         else:
             rows = p.rows
             status[rows] = s[:np_]
@@ -1020,6 +1035,8 @@ def finish_check_columns(
             remaining[rows] = r[:np_]
             reset[rows] = t[:np_]
             err[rows[dropped[:np_]]] = ERR_DROPPED
+            if pi:
+                delta.later_rows += np_
     if pending.casc and (retried_any or not pending.casc_intrace):
         # the in-trace fold (when it ran) predates any dropped-row retry;
         # the idempotent host fold makes the carriers authoritative again.

@@ -64,6 +64,7 @@ from gubernator_tpu.ops.batch import (
     ResponseColumns,
 )
 from gubernator_tpu.ops.engine import ms_now
+from gubernator_tpu.ops.wire import DELTA_BIAS
 from gubernator_tpu.service import deadline as deadline_mod
 from gubernator_tpu.service.wire import WireBatch
 from gubernator_tpu.types import (
@@ -134,10 +135,10 @@ class _Entry:
     """One enqueued batch awaiting dispatch."""
 
     __slots__ = ("payload", "fut", "t_enq", "span", "rows", "cost", "tier",
-                 "bucket", "deadline")
+                 "bucket", "deadline", "stamp_lo", "stamp_hi")
 
     def __init__(self, payload, fut, t_enq, span, rows, cost, tier, bucket,
-                 deadline):
+                 deadline, stamp_lo, stamp_hi):
         self.payload = payload
         self.fut = fut
         self.t_enq = t_enq  # perf_counter at enqueue
@@ -147,6 +148,10 @@ class _Entry:
         self.tier = tier  # 0 (best-effort) .. 3 (shed last)
         self.bucket = bucket  # tenant fingerprint bucket
         self.deadline = deadline  # absolute monotonic instant, or None
+        # the rows' created_at, earliest and latest (ms): a chunk is cut
+        # where they would leave the compact wire's delta budget
+        self.stamp_lo = stamp_lo
+        self.stamp_hi = stamp_hi
 
 
 class Batcher:
@@ -245,8 +250,9 @@ class Batcher:
         self.requests = 0  # entries (enqueued batches) dispatched
         self.fused_dispatches = 0  # rode the fused wire→grid path
         self.column_dispatches = 0  # generic columns path
-        # all-wire chunk that could NOT fuse: a non-encodable row or
-        # created_at skew (a repeated key fuses: split_dispatches)
+        # all-wire chunk that could NOT fuse: a non-encodable row, or
+        # created_at skew inside one enqueued batch (between batches a
+        # chunk is cut before it; a repeated key fuses: split_dispatches)
         self.wire_fallbacks = 0
         # fused dispatches that carried at least one follow-on pass: the
         # later copies of a key sent more than once in the chunk
@@ -279,16 +285,21 @@ class Batcher:
         # entry, gubernator.go:225-227), not at flush time. Where the parser
         # reduced these rows already (wire.RowSummary), the stamp, the tier
         # and the cost come from that, with no scan of the columns on the
-        # event-loop thread.
+        # event-loop thread (but for the range of the stamps a client set
+        # itself: `_form_chunk` keeps a chunk inside the wire's budget).
         cols = _payload_cols(payload)
         rows = cols.fp.shape[0]
         summary = payload.summary if isinstance(payload, WireBatch) else None
+        stamps = None  # the stamps that are not `now`, where a row has one
         if summary is None or 0 < summary.unstamped < rows:
-            created = np.where(cols.created_at == 0, now, cols.created_at)
+            created = stamps = np.where(cols.created_at == 0, now, cols.created_at)
         elif summary.unstamped:
             created = np.full(rows, now, dtype=np.int64)
         else:
-            created = None  # the client stamped every row
+            created, stamps = None, cols.created_at  # the client stamped every row
+        stamp_lo = stamp_hi = now
+        if stamps is not None and rows:
+            stamp_lo, stamp_hi = int(stamps.min()), int(stamps.max())
         if created is not None:
             cols = cols._replace(created_at=created)
             if isinstance(payload, WireBatch):
@@ -311,6 +322,7 @@ class Batcher:
         entry = _Entry(
             payload, loop.create_future(), time.perf_counter(),
             tracing.current_span(), rows, cost, tier, bucket, deadline,
+            stamp_lo, stamp_hi,
         )
         # per-tenant fair admission: once the queue is under pressure
         # (≥ half full), no tenant bucket may hold more than its share of
@@ -618,10 +630,21 @@ class Batcher:
             )
         chunk = []
         rows = 0
+        lo = hi = None
         now = time.monotonic()
         while self._pending:
             head = self._pending[0]
             if chunk and rows + head.rows > self.coalesce_limit:
+                break
+            # The compact wire carries created_at as a delta of −512…511 ms
+            # from the chunk's first stamp. Rows are stamped when they are
+            # enqueued, so after a stall of half a second a chunk of
+            # everything pending would leave the wire for the full-width
+            # format, whose programs warm_up does not compile (a leaky
+            # one compiles for over a minute on a TPU: PERF.md §6, PR 32).
+            # The entry that would take the chunk past that span starts
+            # the next chunk instead.
+            if chunk and max(hi, head.stamp_hi) - min(lo, head.stamp_lo) >= DELTA_BIAS:
                 break
             entry = self._pending.popleft()
             self._pending_rows -= entry.rows
@@ -632,6 +655,8 @@ class Batcher:
                 continue
             chunk.append(entry)
             rows += entry.rows
+            lo = entry.stamp_lo if lo is None else min(lo, entry.stamp_lo)
+            hi = entry.stamp_hi if hi is None else max(hi, entry.stamp_hi)
         self._pending_bytes = sum(
             e.payload.nbytes
             for e in self._pending

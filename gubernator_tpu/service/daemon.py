@@ -1768,6 +1768,14 @@ class Daemon:
                 "checks": eng.stats.checks,
                 "dispatches": eng.stats.dispatches,
                 "dropped": eng.stats.dropped,
+                # decided rows that came out OVER_LIMIT (the kernel's count:
+                # an aggregate once, `checks` counts its every member)
+                "over_limit": eng.stats.over_limit,
+                # rows that repeated a key of their chunk and were decided
+                # in the passes behind the first; of those, the members of
+                # an aggregate (ops/engine.EngineStats)
+                "later_rows": eng.stats.later_rows,
+                "aggregate_rows": eng.stats.aggregate_rows,
             },
             # per-algorithm decision counts (live view of
             # gubernator_tpu_decisions_total) — scenario breadth at a glance

@@ -245,7 +245,9 @@ class DaemonMetrics:
             # fetched grid; docs/latency.md "mesh ingress")
             # and the compact-wire codec stages wire_pack | wire_decode
             # (host encode of the 5-lane ingress grid / decode of the int32
-            # egress; docs/latency.md "wire budget").
+            # egress; docs/latency.md "wire budget"), and later_stage
+            # inside put on the local engine's fused path (a chunk's later
+            # copies of a key staged as column passes, ops/engine.py).
             # The request-ring plane adds ring_put (submit-side slot claim
             # + payload staging + ingress-fence publish) and ring_poll
             # (the egress-fence wait for the coalesced response) —

@@ -233,7 +233,8 @@ class stage:
         if TraceAnnotation.is_enabled():
             stats, disp = self.stats, self.disp
             if disp is not None:
-                stats = dict(stats, dispatch=disp.seq, rows=disp.rows)
+                # a part may state the rows it handles itself
+                stats = {"dispatch": disp.seq, "rows": disp.rows, **stats}
             self._ann = TraceAnnotation("gub:" + self.name, **stats)
             self._ann.__enter__()
         self._t0 = time.perf_counter()

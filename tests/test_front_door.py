@@ -79,6 +79,19 @@ def mixed_corpus(now: int):
     ]
 
 
+async def _global_settled(d, timeout_s: float = 30.0) -> None:
+    """On a mesh engine, the GLOBAL hits queued so far have been synced: a
+    replica answers from what the last sync tick left it, so two daemons
+    given the same RPCs agree only between ticks that both have run (on a
+    loaded host one daemon's tick came after the next RPC, the other's
+    before)."""
+    if not getattr(d.engine, "mesh_global", False):
+        return
+    deadline = time.monotonic() + timeout_s
+    while d.debug_global()["mesh"]["pending"] and time.monotonic() < deadline:
+        await asyncio.sleep(0.01)
+
+
 async def _parity_daemons(corpus, raw_conf, pb_conf, raw_engine=None,
                           pb_engine=None, reset_tol_ms: int = 0):
     """Drive the SAME request sequence through a raw-bytes daemon and a
@@ -96,6 +109,8 @@ async def _parity_daemons(corpus, raw_conf, pb_conf, raw_engine=None,
             ).SerializeToString()
             raw_bytes = await d_raw.get_rate_limits_raw(data)
             resps = await d_pb.get_rate_limits(list(items))
+            await _global_settled(d_raw)
+            await _global_settled(d_pb)
             pb_bytes = pb.GetRateLimitsResp(
                 responses=resps
             ).SerializeToString()

@@ -236,9 +236,11 @@ async def test_traced_under_load_then_sigterm_exits_0(server, tmp_path):
     rng = np.random.default_rng(40_503)
     answered = 0
 
-    async def worker(stop_at: float) -> None:
+    stop = asyncio.Event()
+
+    async def worker() -> None:
         nonlocal answered
-        while time.monotonic() < stop_at:
+        while not stop.is_set():
             keys = rng.choice(KEYS, size=RPC_ITEMS, replace=False)
             got = await client.check([_req(f"k{k}", 0, _now_ms()) for k in keys])
             assert len(got) == RPC_ITEMS and not any(r.error for r in got)
@@ -263,18 +265,31 @@ async def test_traced_under_load_then_sigterm_exits_0(server, tmp_path):
         assert 0 < 3 * RPC_ITEMS < passes * SHARDS * lanes
 
         tdir = str(tmp_path / "trace")
-        load = asyncio.gather(*(worker(time.monotonic() + 3.0) for _ in range(4)))
+        load = asyncio.gather(*(worker() for _ in range(4)))
         await asyncio.sleep(0.5)
         loop = asyncio.get_running_loop()
+
+        async def dispatches() -> int:
+            pipe = await loop.run_in_executor(None, server.get, "/v1/debug/pipeline")
+            return pipe["engine"]["dispatches"]
+
         await loop.run_in_executor(
             None, functools.partial(server.command, cmd="trace_start", dir=tdir))
-        await asyncio.sleep(1.0)
+        # The load runs on through trace_stop, and the trace stays open until
+        # dispatches have begun and ended inside it: on a loaded host
+        # trace_start alone took longer than a load of fixed length, and the
+        # trace then held no span.
+        d0, deadline = await dispatches(), time.monotonic() + 60.0
+        while await dispatches() < d0 + 6 and time.monotonic() < deadline:
+            await asyncio.sleep(0.25)
         t0 = time.monotonic()
         await loop.run_in_executor(
             None, functools.partial(server.command, cmd="trace_stop"))
         t_stop = time.monotonic() - t0
+        stop.set()
         await load
     finally:
+        stop.set()
         await client.close()
     assert answered >= 5
     assert server.get("/v1/HealthCheck")["status"] == "healthy"

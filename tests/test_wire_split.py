@@ -153,6 +153,12 @@ async def test_a_split_chunk_is_the_columns_path_byte_for_byte(shape):
         assert {i: int(got.remaining[i]) for i in remaining} == remaining
         assert delta["checks"] == sum(len(p) for p in parts)
         assert delta["dispatches"] == passes
+        keys = [r[0] if isinstance(r, tuple) else r for p in parts for r in p]
+        counts = np.unique(
+            [k for k in keys if k not in (EMPTY_KEY, EMPTY_NAME)], return_counts=True
+        )[1]
+        assert delta["later_rows"] == int((counts - 1).sum())
+        assert delta["aggregate_rows"] == int(np.maximum(counts - 7, 0).sum())
     finally:
         r_wire.close()
         r_cols.close()
