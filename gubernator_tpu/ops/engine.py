@@ -7,6 +7,11 @@ reference's WorkerPool.GetRateLimit channel machinery (workers.go:266-330);
 
 Batches are padded to bucketed static shapes so jit caches a handful of
 compiled kernels instead of one per batch size.
+
+The fused front door (`prepare_check_wire`) packs nothing: the lanes the
+native parser wrote are the ingress grid of pass 0, and the passes behind it
+(the later copies of a key sent more than once in the chunk) are gathers of
+the same lanes, split by the planner's rule (ops/plan.py).
 """
 
 from __future__ import annotations
@@ -41,7 +46,14 @@ from gubernator_tpu.ops.kernel2 import (
     pack_outputs,
     unpack_outputs,
 )
-from gubernator_tpu.ops.plan import Pass, plan_passes
+from gubernator_tpu.ops.plan import (
+    Pass,
+    aggregate_pass,
+    occurrence_rank,
+    plan_passes,
+    runs,
+    split_rows,
+)
 from gubernator_tpu.ops.table2 import Table2, new_table2
 from gubernator_tpu.types import RateLimitRequest, RateLimitResponse
 
@@ -72,31 +84,6 @@ def _pad_size(n: int, floor: int = 16) -> int:
     while size < n:
         size *= 2
     return size
-
-
-def _occurrence_rank(fps: np.ndarray) -> np.ndarray:
-    """Per-row occurrence index of its fingerprint (0 for the first, 1 for
-    the second duplicate, …) — the merge path's host-side analog of the
-    planner's same-key pass split."""
-    n = fps.shape[0]
-    order = np.argsort(fps, kind="stable")
-    sorted_f = fps[order]
-    first = np.concatenate([[True], sorted_f[1:] != sorted_f[:-1]])
-    idx = np.arange(n)
-    start = np.maximum.accumulate(np.where(first, idx, -1))
-    rank = np.empty(n, dtype=np.int64)
-    rank[order] = idx - start
-    return rank
-
-
-def _later_copies(fps: np.ndarray) -> np.ndarray:
-    """Positions, ascending, of every row whose fingerprint came earlier in
-    `fps` (occurrence rank ≥ 1; empty when no fingerprint repeats). In a
-    stable sort a key's copies stand together in arrival order, so every
-    element equal to the one before it is a later copy."""
-    order = np.argsort(fps, kind="stable")
-    s = fps[order]
-    return np.sort(order[1:][s[1:] == s[:-1]])
 
 
 def _math_mode(hb: HostBatch) -> str:
@@ -219,11 +206,13 @@ class EngineStats:
     # on a single-algorithm fleet means GUBER_SLOT_LAYOUT is misconfigured
     layout_migrations: int = 0
     # rows of a pipelined chunk that were a later copy of a key sent earlier
-    # in it and were decided in the passes behind the first (the fused path
-    # stages them as columns, `_later_passes`); of those, the members of
-    # the aggregate pass (copy max_exact−1 and up of their key)
+    # in it and were decided in the passes behind the first; of those, the
+    # members of the aggregate pass (copy max_exact−1 and up of their key),
+    # and the rows whose pass the fused path staged from the parser's lanes
+    # (`_later_passes`; a member of a lane-staged aggregate counts)
     later_rows: int = 0
     aggregate_rows: int = 0
+    later_lane_rows: int = 0
 
     def accumulate(self, stats, count_dropped: bool = True) -> None:
         self.cache_hits += int(stats.cache_hits)
@@ -248,6 +237,7 @@ class EngineStats:
         self.layout_migrations += d.layout_migrations
         self.later_rows += d.later_rows
         self.aggregate_rows += d.aggregate_rows
+        self.later_lane_rows += d.later_lane_rows
 
 
 def _plan(engine, hb):
@@ -433,10 +423,10 @@ def serve_columns(engine, cols, now_ms, dispatch) -> ResponseColumns:
                 lambda fn: fn(), promote_putback,
             )
         s, l, r, t, dropped, _hit = outs
-        if p.member_rows:
+        if p.members is not None:
             # fan the aggregate's response out to every member row
-            members = np.concatenate(p.member_rows)
-            src = np.repeat(np.arange(np_), [len(m) for m in p.member_rows])
+            members = p.members
+            src = np.repeat(np.arange(np_), p.member_counts)
             status[members] = s[src]
             limit_o[members] = l[src]
             remaining[members] = r[src]
@@ -588,27 +578,40 @@ class _LazyWireBatch:
     HostBatch uses inside the pipelined finish half: field iteration
     (`HostBatch(*[f[rows] for f in batch])`), the padded row count, and
     `active` — the rows the staged grid holds live lanes for (no error, and
-    the first occurrence of their key in the chunk)."""
+    the first occurrence of their key in the chunk). A pass behind the grid
+    is the chunk's rows `pick` (all active), folded into the planner's
+    aggregate where it has `groups` (ops/plan.runs over `pick`)."""
 
-    __slots__ = ("_parts", "_now", "_tol", "rows", "active", "_hb")
+    __slots__ = (
+        "_parts", "_now", "_tol", "rows", "active", "_pick", "_groups", "_hb",
+    )
 
-    def __init__(self, parts, now, tol, rows, active):
+    def __init__(
+        self, parts, now, tol, rows, active=None, pick=None, groups=None
+    ):
         self._parts = parts  # RequestColumns pieces, concat on demand
         self._now = now
         self._tol = tol
         self.rows = rows  # padded dispatch rows
-        self.active = active  # (n,) bool, unpadded
+        self.active = active  # (n,) bool, unpadded; the grid's batch only
+        self._pick = pick
+        self._groups = groups
         self._hb = None
 
     def _materialize(self) -> HostBatch:
         if self._hb is None:
-            hb, _ = pack_columns(
-                concat_columns(self._parts), self._now, tolerance_ms=self._tol
-            )
-            # as staged: a later copy of a key has no lane in this grid
-            hb = hb._replace(
-                fp=np.where(self.active, hb.fp, 0), active=self.active
-            )
+            cols = concat_columns(self._parts)
+            if self._pick is not None:
+                cols = RequestColumns(*[f[self._pick] for f in cols])
+            hb, _ = pack_columns(cols, self._now, tolerance_ms=self._tol)
+            if self._pick is None:
+                # as staged: a later copy of a key has no lane in this grid
+                hb = hb._replace(
+                    fp=np.where(self.active, hb.fp, 0), active=self.active
+                )
+            elif self._groups is not None:
+                members = np.arange(self._pick.size)
+                hb = aggregate_pass(hb, members, *self._groups).batch
             self._hb = pad_batch(hb, self.rows)
         return self._hb
 
@@ -647,6 +650,14 @@ class _WireAssembly(NamedTuple):
     pad: int
     first: np.ndarray  # (n,) bool: rows with a live lane in `grid`
     later: "np.ndarray | None"  # rows whose key came earlier in the chunk
+    # with `later`, what their passes are staged from: the grid before those
+    # rows were taken out of it, the chunk's rows sorted by key and each
+    # row's occurrence rank (ops/plan.occurrence_rank; an error row's is 0),
+    # and the rows whose stamp `grid`'s base carries (None: all)
+    lanes: "np.ndarray | None" = None
+    order: "np.ndarray | None" = None
+    rank: "np.ndarray | None" = None
+    fits: "np.ndarray | None" = None
 
 
 def _assemble_wire_parts(engine, parts, now_ms=None, pad_to=None):
@@ -660,10 +671,10 @@ def _assemble_wire_parts(engine, parts, now_ms=None, pad_to=None):
     Copies of one key need the planner's sequential passes, and the grid is
     its pass 0: occurrence 0 of every key rides its parser lane, and every
     later copy has its lane zeroed (fp == 0, inactive on decode, as an error
-    row) and is named in `later` for the caller to stage as columns. A ring
-    slot holds one grid, so with `pad_to` a repeated key still means None;
-    so does one next to cascade level bits (the in-trace fold needs a
-    single pass).
+    row) and is named in `later` for the caller to stage from `lanes`, the
+    grid as it was with them in it. A ring slot holds one grid, so with
+    `pad_to` a repeated key still means None; so does one next to cascade
+    level bits (the in-trace fold needs a single pass).
 
     `pad_to` fixes the padded width (the ring's static slot shape); the
     default pads to the bucketed dispatch size."""
@@ -690,15 +701,18 @@ def _assemble_wire_parts(engine, parts, now_ms=None, pad_to=None):
     act_fp = fp[active]
     # unique-fingerprint kernel contract: the grid takes the first of a
     # key's copies, the rest follow it as the planner's later passes
-    first, later = active, _later_copies(act_fp)
-    if later.size == 0:
+    first, later = active, None
+    order, rank = occurrence_rank(fp)
+    if rank is not None:
+        rank[~active] = 0  # error rows (fp 0) are no copies of one another
+        later = np.nonzero(rank)[0]
+    if later is None or later.size == 0:
         later = None
     elif pad_to is not None or engine.max_exact_passes < 2:
         # a ring slot holds one grid; with max_exact 1 the planner has no
         # exact pass and aggregates from occurrence 0
         return None
     else:
-        later = np.nonzero(active)[0][later]
         first = active.copy()
         first[later] = False
     from gubernator_tpu.ops import wire as wire_mod
@@ -717,52 +731,81 @@ def _assemble_wire_parts(engine, parts, now_ms=None, pad_to=None):
     clipped = np.clip(stamped, now - tol, now + tol)
     clamped = int((clipped != stamped).sum())
     base = int(clipped[int(np.argmax(active))])
-    delta = clipped[first] - base
-    if delta.min() < -wire_mod.DELTA_BIAS or delta.max() > wire_mod.DELTA_BIAS - 1:
+    delta = clipped - base
+    fits = (delta >= -wire_mod.DELTA_BIAS) & (delta < wire_mod.DELTA_BIAS)
+    if not fits[first].all():
         return None
     pad = pad_to if pad_to is not None else _pad_size(n)
     grid = wire_mod.assemble_wire_grid(
-        [p.lanes for p in parts], clipped, base, pad, first
+        [p.lanes for p in parts], clipped, base, pad, active
     )
     # cascade batches normally take the pb path (the native parser routes
     # them there), but an engine-level caller may assemble level-bit lanes
     # directly — a single pass, so the in-trace fold is sound here
     casc = wire_mod.grid_has_cascade(grid, n)
+    lanes = None
     if later is not None:
         if casc:
             return None
+        lanes, grid = grid, grid.copy()
         grid[:, later] = 0
+        if fits[later].all():
+            fits = None
     return _WireAssembly(
         grid, cols_list, err, now, n, act_fp, clamped, casc, tol, pad,
-        first, later,
+        first, later, lanes, order, rank, fits,
     )
 
 
-def _later_passes(engine, a: _WireAssembly) -> list:
-    """The passes that follow a fused grid: the chunk's later copies of a
-    key (a handful among thousands of rows), packed and planned as columns.
-    The grid was occurrence 0, so the planner gets one exact pass fewer:
-    exact passes hold original occurrences 1…max_exact−2 and the aggregate
-    starts at max_exact−1, where `plan_passes` puts it for the whole chunk.
-    Pass rows come back as rows of the chunk."""
-    # picked out of the part that holds them: no whole column is concatenated
-    starts = np.cumsum([0] + [c.fp.shape[0] for c in a.cols_list[:-1]])
-    part = np.searchsorted(starts, a.later, side="right") - 1
-    local = a.later - starts[part]
-    cols = concat_columns([
-        RequestColumns(*[f[local[part == i]] for f in a.cols_list[i]])
-        for i in np.unique(part)
-    ])
-    hb, err = pack_columns(cols, a.now, tolerance_ms=a.tol)
-    a.err[a.later] = err
-    passes = []
-    for p in plan_passes(hb, max_exact=engine.max_exact_passes - 1):
-        n = len(p.rows)
-        batch, staged = engine.stage_pass(p.batch, n)
-        p.rows = a.later[p.rows]
-        p.member_rows = [a.later[m] for m in p.member_rows]
-        passes.append([p, n, batch, staged])
-    return passes
+def _later_passes(engine, a: _WireAssembly) -> "tuple[list, int]":
+    """The passes that follow a fused grid, staged as it was: each a gather
+    of the chunk's own lanes (`a.lanes`) under the grid's base, so that no
+    HostBatch is built. Exact pass r holds the rows of occurrence rank r in
+    arrival order, r = 1…max_exact−2; the aggregate every row of rank
+    max_exact−1 and up, where `plan_passes` puts them for the whole chunk.
+    What the lanes cannot carry — a stamp beyond ±511 ms of the grid's
+    base, an aggregate's hits past 18 bits — is that pass alone packed and
+    staged as columns. Returns the passes and how many rode the lanes."""
+    from gubernator_tpu.ops import wire as wire_mod
+
+    exact, tail = split_rows(a.order, a.rank, engine.max_exact_passes)
+    plan = [(rows, None) for rows in exact[1:]]
+    if tail is not None:
+        plan.append((tail, runs(a.lanes[0, tail], a.lanes[1, tail])))
+    passes, blocks = [], []
+    for rows, groups in plan:
+        p, starts = Pass(rows=rows, batch=None), None
+        if groups is not None:  # answered by each group's newest member
+            starts, p.member_counts = groups
+            p.rows, p.members = rows[starts + p.member_counts - 1], rows
+        n = p.rows.size
+        p.batch = lazy = _LazyWireBatch(
+            a.cols_list, a.now, a.tol, _pad_size(n), pick=rows, groups=groups
+        )
+        block = None
+        if a.fits is None or a.fits[rows].all():
+            block = wire_mod.gather_wire_block(
+                a.lanes, p.rows, lazy.rows, p.members, starts
+            )
+        if block is None:
+            p.batch, staged = engine.stage_pass(lazy._materialize(), n)
+            passes.append([p, n, p.batch, staged])
+        else:
+            blocks.append(block)
+            passes.append([p, n, lazy, None])
+    # every row of these passes is live: where all later copies name one
+    # algorithm, all passes select the mode the first does
+    algo = a.lanes[3, a.later] >> wire_mod.DUR_BITS
+    same = int(algo.min()) == int(algo.max())
+    maths = [
+        wire_mod.grid_math_mode(b, b.shape[1] - 1)
+        for b in (blocks[:1] if same else blocks)
+    ]
+    staged = iter(engine.stage_wire_blocks(blocks, maths * len(blocks) if same else maths))
+    for entry in passes:
+        if entry[3] is None:
+            entry[3] = next(staged)
+    return passes, len(blocks)
 
 
 def _wire_pending(engine, a: _WireAssembly, staged):
@@ -770,7 +813,7 @@ def _wire_pending(engine, a: _WireAssembly, staged):
     the object both finish halves consume unchanged. Later copies of a key
     (`a.later`, direct path only) are its passes after the grid's."""
     lazy = _LazyWireBatch(a.cols_list, a.now, a.tol, a.pad, a.first)
-    p = Pass(rows=np.arange(a.n), batch=lazy, member_rows=[])
+    p = Pass(rows=np.arange(a.n), batch=lazy)
     pending = PendingCheck(
         hb=lazy, err=a.err, now=a.now, passes=[[p, a.n, lazy, staged]],
         clamped=a.clamped, rows=a.n, mark=a.act_fp, casc=a.casc,
@@ -778,8 +821,8 @@ def _wire_pending(engine, a: _WireAssembly, staged):
     )
     if a.later is not None:
         with tracing.stage.within("later_stage", rows=int(a.later.size)) as st:
-            later = _later_passes(engine, a)
-            st.note(passes=len(later))
+            later, lanes = _later_passes(engine, a)
+            st.note(passes=len(later), lane_passes=lanes)
         pending.passes += later
     return pending
 
@@ -789,8 +832,8 @@ def prepare_check_wire(engine, parts, now_ms=None) -> "PendingCheck | None":
     (service/wire.WireBatch pieces) are scattered into ONE staged compact
     ingress grid — the request bytes were traversed once by the parser and
     this scatter is the only further touch. A key sent more than once in
-    the chunk keeps the grid for its first copy; the later ones are staged
-    as columns in the passes behind it (`_later_passes`). Returns a
+    the chunk keeps the grid for its first copy; the later ones are gathers
+    of the same lanes in the passes behind it (`_later_passes`). Returns a
     PendingCheck for the standard issue/finish halves, or None when the
     batch needs the general columns path — the fallback is semantically
     identical, it just pays the full pack."""
@@ -1017,26 +1060,24 @@ def finish_check_columns(
                 pending.promote_putback,
             )
             retried_any = retried_any or changed
-        if p.member_rows:
-            members = np.concatenate(p.member_rows)
-            src = np.repeat(np.arange(np_), [len(m) for m in p.member_rows])
-            status[members] = s[src]
-            limit_o[members] = l[src]
-            remaining[members] = r[src]
-            reset[members] = t[src]
-            err[members[dropped[src]]] = ERR_DROPPED
+        if p.members is not None:
+            rows = p.members
+            src = np.repeat(np.arange(np_), p.member_counts)
+            s, l, r, t, dropped = s[src], l[src], r[src], t[src], dropped[src]
             if pi:
-                delta.later_rows += len(members)
-                delta.aggregate_rows += len(members)
+                delta.aggregate_rows += len(rows)
         else:
             rows = p.rows
-            status[rows] = s[:np_]
-            limit_o[rows] = l[:np_]
-            remaining[rows] = r[:np_]
-            reset[rows] = t[:np_]
-            err[rows[dropped[:np_]]] = ERR_DROPPED
-            if pi:
-                delta.later_rows += np_
+            s, l, r, t, dropped = s[:np_], l[:np_], r[:np_], t[:np_], dropped[:np_]
+        status[rows] = s
+        limit_o[rows] = l
+        remaining[rows] = r
+        reset[rows] = t
+        err[rows[dropped]] = ERR_DROPPED
+        if pi:
+            delta.later_rows += len(rows)
+            if not isinstance(batch, HostBatch):
+                delta.later_lane_rows += len(rows)
     if pending.casc and (retried_any or not pending.casc_intrace):
         # the in-trace fold (when it ran) predates any dropped-row retry;
         # the idempotent host fold makes the carriers authoritative again.
@@ -1337,15 +1378,19 @@ class LocalEngine:
     def stage_wire(self, grid: np.ndarray, math: str, cascade: bool = False):
         """Stage a fused front-door grid (ops/wire.assemble_wire_grid
         output) — same staged tuple as stage_pass's, issued by
-        issue_staged unchanged. Wire grids carry no Gregorian rows
-        (wire_encodable excludes them) and their algorithm family is
-        implied by the math mode, so the layout check needs no batch."""
-        import jax
+        issue_staged unchanged."""
+        return self.stage_wire_blocks([grid], [math], cascade)[0]
 
-        return (
-            jax.device_put(grid), math, True, cascade,
-            self._batch_needs_full(math),
-        )
+    def stage_wire_blocks(self, blocks, maths, cascade: bool = False):
+        """`stage_wire` of several wire blocks (a grid; the passes behind
+        one, ops/wire.gather_wire_block) in one transfer call. Wire blocks
+        carry no Gregorian rows (wire_encodable excludes them) and their
+        algorithm family is implied by the math mode, so the layout check
+        needs no batch."""
+        return [
+            (dev, math, True, cascade, self._batch_needs_full(math))
+            for dev, math in zip(jax.device_put(blocks), maths)
+        ]
 
     def issue_staged(self, staged, batch_rows: int):
         dev, math, wired, cascade, needs_full = staged
@@ -1623,8 +1668,8 @@ class LocalEngine:
                 ), np.empty((0, 16), dtype=np.int32)
             return 0
         slots = self._slots_to_full(slots, layout)
-        rank = _occurrence_rank(fps)
-        if rank.max() > 0:
+        _order, rank = occurrence_rank(fps)
+        if rank is not None:
             if collect:
                 raise ValueError(
                     "merge_rows(collect=True) requires unique fingerprints"

@@ -16,17 +16,18 @@ passes:
 
 For the common all-unique batch this is a single pass with zero copies.
 
-The fused front door (ops/engine.prepare_check_wire) takes pass 0 — occurrence
-0 of every key — from the lanes the parser already packed, and plans only the
-later copies here, with `max_exact` one lower, so that the exact passes and
-the aggregate hold the occurrences they would hold in a plan of the whole
-chunk.
+A plan is one stable sort of the fingerprints (`occurrence_rank`), the rows
+of each rank (`split_rows`) and the aggregate's groups as runs of the sorted
+tail (`runs`). `plan_passes` builds a `HostBatch` for every pass from them;
+the fused front door (ops/engine.prepare_check_wire) takes the same rows as
+gathers of the lanes the parser already packed (pass 0 is its grid) and
+builds no batch at all.
 """
 
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import List
+from typing import List, Optional
 
 import numpy as np
 
@@ -37,9 +38,12 @@ from gubernator_tpu.ops.batch import HostBatch
 class Pass:
     rows: np.ndarray  # original row indices whose response comes from this pass
     batch: HostBatch
-    # For the aggregated final pass, responses fan back out: member_rows[i]
-    # lists every original row sharing batch row i's response.
-    member_rows: List[np.ndarray]
+    # The aggregated final pass fans its responses back out: `members` lists,
+    # group after group, every original row that shares a batch row's
+    # response, and `member_counts[i]` how many share batch row i's. None on
+    # an exact pass.
+    members: Optional[np.ndarray] = None
+    member_counts: Optional[np.ndarray] = None
 
 
 def _subset(b: HostBatch, rows: np.ndarray) -> HostBatch:
@@ -49,15 +53,72 @@ def _subset(b: HostBatch, rows: np.ndarray) -> HostBatch:
 def single_pass(b: HostBatch) -> List[Pass]:
     """O(1) plan for engines that aggregate duplicate keys IN-TRACE
     (kernel2.dedup_packed_cols, ShardedEngine dedup="device"): one pass, the
-    raw batch, no host group-by. The np.unique sweep below is the host-side
-    cost the mesh path eliminates — on a 131K-row dispatch the sort alone is
-    milliseconds of single-process work while every device idles. Member
-    fan-out happens on-device too (kernel2.fanout_packed), so member_rows
-    stays empty and each row comes back with its own (aggregate) response."""
+    raw batch, no host group-by. The sort below is the host-side cost the
+    mesh path eliminates — on a 131K-row dispatch it alone is milliseconds
+    of single-process work while every device idles. Member fan-out happens
+    on-device too (kernel2.fanout_packed), so `members` stays None and each
+    row comes back with its own (aggregate) response."""
     act = np.nonzero(b.active)[0]
     if act.size == b.fp.shape[0]:
-        return [Pass(rows=act, batch=b, member_rows=[])]
-    return [Pass(rows=act, batch=_subset(b, act), member_rows=[])]
+        return [Pass(rows=act, batch=b)]
+    return [Pass(rows=act, batch=_subset(b, act))]
+
+
+def occurrence_rank(fps: np.ndarray):
+    """(order, rank): the stable argsort of `fps` — a key's copies stand
+    together in arrival order — and each row's occurrence index among the
+    rows of its fingerprint (0 for the first, 1 for the second, …). `rank`
+    is None when no fingerprint repeats."""
+    order = np.argsort(fps, kind="stable")
+    s = fps[order]
+    new = np.ones(s.shape[0], dtype=bool)
+    new[1:] = s[1:] != s[:-1]
+    if new.all():
+        return order, None
+    idx = np.arange(s.shape[0])
+    rank = np.empty(s.shape[0], dtype=np.int64)
+    rank[order] = idx - np.maximum.accumulate(np.where(new, idx, 0))
+    return order, rank
+
+
+def split_rows(order: np.ndarray, rank: np.ndarray, max_exact: int):
+    """(exact, tail) of an `occurrence_rank`: the rows of rank r, in arrival
+    order, for each r below max_exact−1 that occurs; and the rows of every
+    rank from max_exact−1 up — the aggregate's members — key after key, in
+    arrival order within a key (None when no key has that many copies)."""
+    top = int(rank.max())
+    exact = [np.nonzero(rank == r)[0] for r in range(min(top + 1, max_exact - 1))]
+    if top < max_exact - 1:
+        return exact, None
+    return exact, order[rank[order] >= max_exact - 1]
+
+
+def runs(*keys: np.ndarray):
+    """(starts, counts) of the runs of rows over which every one of `keys`
+    (parallel columns, sorted so that equal rows stand together) is equal."""
+    n = keys[0].shape[0]
+    new = np.zeros(n, dtype=bool)
+    new[0] = True
+    for k in keys:
+        new[1:] |= k[1:] != k[:-1]
+    starts = np.nonzero(new)[0]
+    return starts, np.diff(starts, append=n)
+
+
+def aggregate_pass(b: HostBatch, tail, starts, counts) -> Pass:
+    """The aggregated final pass over `tail`, rows of `b` in the groups
+    `runs` found: the newest member of each group carries the config
+    (clients send the full config with every request; latest wins) with the
+    group's summed hits. Only RESET_REMAINING survives the merge (reference
+    global.go:117-121); OR-ing other flags would desynchronize the carrier
+    row's pre-resolved fields (e.g. Gregorian rate inputs)."""
+    last = tail[starts + counts - 1]
+    agg = _subset(b, last)
+    reset = np.bitwise_or.reduceat(b.behavior[tail] & 8, starts)
+    agg = agg._replace(
+        hits=np.add.reduceat(b.hits[tail], starts), behavior=agg.behavior | reset
+    )
+    return Pass(rows=last, batch=agg, members=tail, member_counts=counts)
 
 
 def plan_passes(b: HostBatch, max_exact: int = 8) -> List[Pass]:
@@ -65,49 +126,23 @@ def plan_passes(b: HostBatch, max_exact: int = 8) -> List[Pass]:
     active=False (padding or per-request validation errors) are skipped."""
     act = np.nonzero(b.active)[0]
     fp = b.fp[act]
-    uniq, inv, counts = np.unique(fp, return_inverse=True, return_counts=True)
-    if counts.max(initial=0) <= 1:
+    order, rank = occurrence_rank(fp)
+    if rank is None:
         if act.size == b.fp.shape[0]:
-            return [Pass(rows=act, batch=b, member_rows=[])]
-        return [Pass(rows=act, batch=_subset(b, act), member_rows=[])]
+            return [Pass(rows=act, batch=b)]
+        return [Pass(rows=act, batch=_subset(b, act))]
 
-    order = np.argsort(inv, kind="stable")
-    sorted_inv = inv[order]
-    group_start = np.searchsorted(sorted_inv, sorted_inv)
-    occ = np.empty(act.size, dtype=np.int64)
-    occ[order] = np.arange(act.size) - group_start
-
-    passes: List[Pass] = []
-    for r in range(min(int(occ.max()) + 1, max_exact - 1)):
-        rows = act[np.nonzero(occ == r)[0]]
-        if rows.size == 0:
-            break
-        passes.append(Pass(rows=rows, batch=_subset(b, rows), member_rows=[]))
-
-    tail_pos = np.nonzero(occ >= max_exact - 1)[0]
-    if tail_pos.size:
-        tail = act[tail_pos]
+    exact, tail = split_rows(order, rank, max_exact)
+    passes = [Pass(rows=rows, batch=_subset(b, rows)) for rows in map(act.take, exact)]
+    if tail is not None:
         # aggregation groups key on (fp, cascade level) — two LEVELS of one
         # cascade whose keys collide on a fingerprint carry different limit
         # configs and must not merge (kernel2.dedup_packed_cols applies the
-        # same discriminator in-trace). `inv` indexes unique fps; pairing it
-        # with the level keeps the group id dense enough for np.unique.
-        tail_lvl = (b.behavior[tail].astype(np.int64) >> 8) & 0xFF
-        tail_key = inv[tail_pos].astype(np.int64) * 256 + tail_lvl
-        tuniq, tinv = np.unique(tail_key, return_inverse=True)
-        # newest member of each group carries the config (clients send the full
-        # config with every request; latest wins)
-        last_rows = np.zeros(tuniq.size, dtype=np.int64)
-        np.maximum.at(last_rows, tinv, tail)
-        agg = _subset(b, last_rows)
-        hits = np.zeros(tuniq.size, dtype=np.int64)
-        np.add.at(hits, tinv, b.hits[tail])
-        # Only RESET_REMAINING survives the merge (reference global.go:117-121);
-        # OR-ing other flags would desynchronize the carrier row's pre-resolved
-        # fields (e.g. Gregorian rate inputs).
-        reset_bit = np.zeros(tuniq.size, dtype=np.int32)
-        np.bitwise_or.at(reset_bit, tinv, b.behavior[tail] & 8)  # RESET_REMAINING
-        agg = agg._replace(hits=hits, behavior=agg.behavior | reset_bit)
-        member_rows = [tail[tinv == g] for g in range(tuniq.size)]
-        passes.append(Pass(rows=last_rows, batch=agg, member_rows=member_rows))
+        # same discriminator in-trace)
+        key, tail = fp[tail], act[tail]
+        lvl = (b.behavior[tail] >> 8) & 0xFF
+        if lvl.any():
+            by_level = np.lexsort((lvl, key))  # stable: arrival order kept
+            key, lvl, tail = key[by_level], lvl[by_level], tail[by_level]
+        passes.append(aggregate_pass(b, tail, *runs(key, lvl)))
     return passes

@@ -503,3 +503,32 @@ decide2_wire_cols = functools.partial(
     jax.jit, donate_argnums=(0,),
     static_argnames=("write", "math", "cascade", "evictees"),
 )(decide2_wire_cols_impl)
+
+
+# ------------------------------------------------- passes behind a grid
+
+
+def gather_wire_block(
+    lanes: np.ndarray, rows: np.ndarray, pad: int, members=None, starts=None
+) -> "np.ndarray | None":
+    """One pass behind a fused grid as a gather of the chunk's own lanes:
+    columns `rows` of `lanes` (an assembled grid, every copy of a key still
+    in it) in a zeroed (5, pad+1) block under the same base column. The
+    planner's aggregate names its `members` too, group after group from
+    `starts` on, a group's newest member being its row: lane 4 then takes
+    the group's summed hits and RESET_REMAINING OR-ed over the group — None
+    when a sum passes the lane's 18 bits."""
+    n = rows.size
+    block = np.zeros((WIRE_LANES, pad + 1), dtype=np.int32)
+    block[:, -1] = lanes[:, -1]
+    block[:, :n] = lanes[:, rows]
+    if members is not None:
+        l4 = lanes[4, members]
+        hits = np.add.reduceat((l4 & _HITS_MASK).astype(np.int64), starts)
+        if hits.max() > _HITS_MASK:
+            return None
+        reset = np.bitwise_or.reduceat(l4 & np.int32(1 << 30), starts)
+        block[4, :n] = (
+            (block[4, :n] & ~np.int32(_HITS_MASK)) | hits.astype(np.int32) | reset
+        )
+    return block

@@ -771,11 +771,9 @@ class GlobalShardedEngine(ShardedEngine):
                 s[rows], l[rows], r[rows], t[rows] = s2, l2, r2, t2
                 dropped[rows] = d2
                 hit[rows] = h2
-            if p.member_rows:
-                members = rowmap[np.concatenate(p.member_rows)]
-                src = np.repeat(
-                    np.arange(np_), [len(m) for m in p.member_rows]
-                )
+            if p.members is not None:
+                members = rowmap[p.members]
+                src = np.repeat(np.arange(np_), p.member_counts)
                 status[members] = s[src]
                 limit_o[members] = l[src]
                 remaining[members] = r[src]
@@ -880,11 +878,9 @@ class GlobalShardedEngine(ShardedEngine):
                 s, l, r, t, d, _h = _rehydrate_misses(
                     self, p.batch, nrows, (s, l, r, t, d, _h), now, disp
                 )
-            if p.member_rows:
-                members = np.concatenate(p.member_rows)
-                src = np.repeat(
-                    np.arange(nrows), [len(m) for m in p.member_rows]
-                )
+            if p.members is not None:
+                members = p.members
+                src = np.repeat(np.arange(nrows), p.member_counts)
                 status[members] = s[src]
                 limit[members] = l[src]
                 remaining[members] = r[src]

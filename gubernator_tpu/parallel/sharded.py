@@ -804,12 +804,12 @@ class ShardedEngine:
                     0, dtype=np.int64
                 ), np.empty((0, 16), dtype=np.int32)
             return 0
-        from gubernator_tpu.ops.engine import _occurrence_rank
+        from gubernator_tpu.ops.plan import occurrence_rank
         from gubernator_tpu.ops.table2 import FLAGS
 
         slots = self._slots_to_full(slots, layout)
-        rank = _occurrence_rank(fps)
-        if rank.max() > 0:  # unique-fp contract (cf. LocalEngine.merge_rows)
+        _order, rank = occurrence_rank(fps)
+        if rank is not None:  # unique-fp contract (cf. LocalEngine.merge_rows)
             if collect:
                 raise ValueError(
                     "merge_rows(collect=True) requires unique fingerprints"

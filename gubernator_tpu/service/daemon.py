@@ -1773,9 +1773,11 @@ class Daemon:
                 "over_limit": eng.stats.over_limit,
                 # rows that repeated a key of their chunk and were decided
                 # in the passes behind the first; of those, the members of
-                # an aggregate (ops/engine.EngineStats)
+                # an aggregate, and the rows whose pass was staged from the
+                # parser's lanes (ops/engine.EngineStats)
                 "later_rows": eng.stats.later_rows,
                 "aggregate_rows": eng.stats.aggregate_rows,
+                "later_lane_rows": eng.stats.later_lane_rows,
             },
             # per-algorithm decision counts (live view of
             # gubernator_tpu_decisions_total) — scenario breadth at a glance

@@ -4,9 +4,9 @@ the normal path: raw gRPC handler → native parser → batcher → fused wire
 staging → engine.
 
 Under that skew every chunk repeats its hot keys: the grid carries the first
-copy of each key and `ops/engine._later_passes` stages the rest as columns,
-copies 1–6 one pass each and copies 7 and up as one aggregate (`ops/plan.py`,
-`max_exact_passes` 8). These tests hold every answer of such chunks to the
+copy of each key and `ops/engine._later_passes` stages the rest as gathers of
+the same lanes, copies 1–6 one pass each and copies 7 and up as one aggregate
+(`ops/plan.py`, `max_exact_passes` 8). These tests hold every answer of such chunks to the
 plain leaky oracle (`tests/oracle/algos.py`) applied in the plan's order,
 the counters `later_rows` / `aggregate_rows` to what the chunk holds, the
 read-back to the benchmark's rule, and the `later_stage` part of `put` to
@@ -24,12 +24,14 @@ from gubernator_tpu import native
 from gubernator_tpu.ops.engine import LocalEngine, ms_now
 from gubernator_tpu.proto import gubernator_pb2 as pb
 from gubernator_tpu.service.daemon import Daemon
+from gubernator_tpu.service.wire import wire_batch_from_wire
 
 from tests.cluster import daemon_config
 from tests.oracle.algos import LeakyOracle
 from tests.test_mesh4_deployment import _spans
 from tests.test_observability import _stage_sums
 from tests.test_runner_chain import async_test
+from tests.test_wire_split import pair, wire_against_columns
 
 pytestmark = pytest.mark.skipif(
     native.load() is None, reason="native toolchain unavailable"
@@ -191,6 +193,39 @@ async def test_a_zipf_chunk_answers_as_the_oracle_in_plan_order(copies, left):
             assert (status, remaining) == (0, room - (MAX_EXACT - 1))
     finally:
         await d.close()
+
+
+@async_test
+async def test_a_chunk_of_the_deployments_size_rides_the_lanes_as_it_rode_columns():
+    """Eight 1,000-item RPCs of Zipf(0.99) leaky keys as one chunk, twice
+    (the second past the hot keys' limit), through the fused staging on one
+    engine and as plain columns on another: eight passes, every answer, every
+    stored row and the stats delta equal, and every later copy — 4 in 10 of
+    the rows, the aggregate's hundreds of members among them — staged from
+    the parser's lanes."""
+    rng = np.random.default_rng(33_001)
+    now = ms_now()
+    r_wire, r_cols = pair(capacity=65536)
+    try:
+        for _ in range(2):
+            ranks = zipf_ranks(rng, 8 * RPC_ITEMS)
+            parts = [
+                wire_batch_from_wire(
+                    body([(int(k), 1, LIMIT) for k in ranks[lo:lo + RPC_ITEMS]], now))[0]
+                for lo in range(0, ranks.size, RPC_ITEMS)
+            ]
+            n_fused, got, delta = await wire_against_columns(r_wire, r_cols, parts, now)
+            counts = np.unique(ranks, return_counts=True)[1]
+            assert n_fused == MAX_EXACT and not got.err.any()
+            assert delta["checks"] == ranks.size and delta["dispatches"] == MAX_EXACT
+            assert delta["later_rows"] == ranks.size - counts.size > 0.4 * ranks.size
+            assert delta["aggregate_rows"] == int(np.maximum(counts - (MAX_EXACT - 1), 0).sum())
+            assert delta["aggregate_rows"] > 0.2 * ranks.size
+            assert delta["later_lane_rows"] == delta["later_rows"]
+        assert (got.status == pb.OVER_LIMIT).sum() > 0.2 * ranks.size
+    finally:
+        r_wire.close()
+        r_cols.close()
 
 
 @async_test

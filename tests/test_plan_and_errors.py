@@ -69,7 +69,7 @@ def test_hot_key_aggregation_merges_only_reset_remaining(frozen_now):
     agg = passes[-1]
     assert agg.batch.behavior[0] == int(Behavior.RESET_REMAINING)
     assert agg.batch.hits[0] == 11  # everything after occurrence 0 summed
-    assert len(agg.member_rows[0]) == 11
+    assert agg.member_counts.tolist() == [11] and agg.members.tolist() == list(range(1, 12))
 
 
 def test_aggregated_members_share_response(frozen_now):

@@ -1,10 +1,12 @@
 """A chunk with a key sent more than once keeps the fused wire staging.
 
 `prepare_check_wire` stages the first occurrence of every key from the
-parser's lanes (the planner's pass 0) and only the later copies as columns,
-in the passes behind it. These tests hold the split to the columns path on
-the same rows: answers byte for byte, the table row for row, the
-`EngineStats` delta, through the dropped-claim retry and the shadow's miss
+parser's lanes (the planner's pass 0) and the later copies as gathers of the
+same lanes, in the passes behind it; a pass the lanes cannot carry (a stamp
+off the grid's base, an aggregate's hits past 18 bits) is alone staged as
+columns. These tests hold the split to the columns path on the same rows:
+answers byte for byte, the table row for row, the `EngineStats` delta, the
+programs selected, through the dropped-claim retry and the shadow's miss
 re-check; and hold the ring slot and the cascade fold, which need a single
 pass, to their refusal.
 """
@@ -16,9 +18,11 @@ import pytest
 
 from gubernator_tpu import native
 from gubernator_tpu.ops import wire as wire_mod
+from gubernator_tpu.ops import engine as engine_mod
 from gubernator_tpu.ops.engine import (
     LocalEngine,
     ms_now,
+    prepare_check_columns,
     prepare_check_wire,
     prepare_ring_slot,
 )
@@ -39,15 +43,19 @@ EMPTY_KEY, EMPTY_NAME = object(), object()  # rows the parser marks as errors
 
 def rpc(rows, now):
     """One parsed RPC. A row is a key, or (key, created_at offset in ms,
-    behavior); EMPTY_KEY / EMPTY_NAME make the two validation errors."""
+    behavior[, hits[, algorithm]]); EMPTY_KEY / EMPTY_NAME make the two
+    validation errors."""
     reqs = []
     for row in rows:
-        key, off, beh = row if isinstance(row, tuple) else (row, 0, 0)
+        row = row if isinstance(row, tuple) else (row,)
+        key, off, beh, hits, algo = (
+            *row, *(0, 0, 1, pb.TOKEN_BUCKET)[len(row) - 1:]
+        )
         reqs.append(pb.RateLimitReq(
             name="" if key is EMPTY_NAME else "split",
             unique_key="" if key is EMPTY_KEY else f"k{key}",
-            hits=1, limit=10, duration=60_000, created_at=now + off,
-            behavior=beh,
+            hits=hits, limit=10, duration=60_000, created_at=now + off,
+            behavior=beh, algorithm=algo,
         ))
     wb = wire_batch_from_wire(
         pb.GetRateLimitsReq(requests=reqs).SerializeToString()
@@ -90,7 +98,11 @@ async def wire_against_columns(r_wire, r_cols, parts, now):
     deltas = [
         {k: a[k] - b[k] for k in a} for a, b in zip(stats(), before)
     ]
+    # the one counter only the wire can move: rows staged from its lanes
+    lane_rows = deltas[0].pop("later_lane_rows")
+    assert deltas[1].pop("later_lane_rows") == 0
     assert deltas[0] == deltas[1]
+    deltas[0]["later_lane_rows"] = lane_rows
     fps = np.unique(cols.fp[cols.err == 0])
     (found_w, rows_w), (found_c, rows_c) = (
         r.engine.read_state(fps) for r in (r_wire, r_cols)
@@ -100,8 +112,17 @@ async def wire_against_columns(r_wire, r_cols, parts, now):
     return n_fused, got, deltas[0]
 
 
+def programs(pending):
+    """What each pass of a prepared check selects: the staged ingress's
+    shape and dtype, the math mode, wire or not, cascade, layout flag."""
+    return [(st[0].shape, st[0].dtype, *st[1:]) for *_, st in pending.passes]
+
+
+LEAKY = pb.LEAKY_BUCKET
+
 # parts of one chunk; passes the split must issue; remaining of the rows
-# named, after a history in which keys 0..5 were hit once
+# named, after a history in which keys 0..5 were hit once; and, where not 0,
+# the later rows of passes the lanes cannot carry
 SHAPES = {
     "pair_inside_one_rpc": ([[1, 2, 1, 7]], 2, {0: 8, 2: 7, 3: 9}),
     "pair_across_two_parts": ([[1, 2, 3], [3, 4, 1, 9]], 2, {2: 8, 3: 7, 5: 7}),
@@ -122,7 +143,7 @@ SHAPES = {
     # a later copy is its own pass with its own base
     "stamps_at_the_edge_of_the_budget": (
         [[1, (2, 511, 0), (3, -512, 0)], [(1, 511, 0), (2, -512, 0), (1, 900, 0)]],
-        3, {3: 7, 4: 7, 5: 6},
+        3, {3: 7, 4: 7, 5: 6}, 1,
     ),
     "reset_remaining_in_the_tail": (
         [[7] * 9 + [(7, 0, RESET)] + [7]], 8, {6: 3, 8: 10, 9: 10, 10: 10},
@@ -130,13 +151,33 @@ SHAPES = {
     "reset_remaining_in_an_exact_pass": (
         [[1, 1, (1, 0, RESET), 1]], 4, {0: 8, 1: 7, 2: 10, 3: 9},
     ),
+    # the bit is OR-ed over its own group, from a member that is not the
+    # newest, and over no other group
+    "reset_remaining_on_one_group_of_two": (
+        [[7, 8] * 8, [(7, 0, RESET), 8, 7, 7], [8]], 8,
+        {12: 3, 13: 3, 14: 10, 15: 0, 16: 10, 17: 0, 18: 10, 19: 10, 20: 0},
+    ),
+    # three members of 2^17 hits: their sum does not fit lane 4's 18 bits,
+    # so the aggregate alone is staged as columns (and refused whole)
+    "an_aggregate_whose_hits_pass_the_lane": (
+        [[7] * 7 + [(7, 0, 0, 1 << 17)] * 3], 8, {5: 4, 6: 3, 7: 3, 9: 3}, 3,
+    ),
+    # the token key's fourth copy is a pass of its own with no leaky row
+    "two_algorithms_in_one_chunk": (
+        [[6, (7, 0, 0, 1, LEAKY), 6], [(7, 0, 0, 1, LEAKY), 6, (7, 0, 0, 1, LEAKY), 6]],
+        4, {0: 9, 1: 9, 4: 7, 5: 7, 6: 6},
+    ),
+    "error_rows_between_copies_past_the_aggregate": (
+        [[7] * 7 + [EMPTY_KEY, 7], [EMPTY_NAME, 7, EMPTY_KEY, EMPTY_KEY, 7]], 8,
+        {6: 3, 8: 0, 10: 0, 13: 0},
+    ),
 }
 
 
 @pytest.mark.parametrize("shape", SHAPES)
 @async_test
 async def test_a_split_chunk_is_the_columns_path_byte_for_byte(shape):
-    parts, passes, remaining = SHAPES[shape]
+    parts, passes, remaining, *off_lanes = SHAPES[shape]
     now = ms_now()
     r_wire, r_cols = pair()
     try:
@@ -159,9 +200,52 @@ async def test_a_split_chunk_is_the_columns_path_byte_for_byte(shape):
         )[1]
         assert delta["later_rows"] == int((counts - 1).sum())
         assert delta["aggregate_rows"] == int(np.maximum(counts - 7, 0).sum())
+        assert delta["later_lane_rows"] == delta["later_rows"] - sum(off_lanes)
+        # and no program the columns path would not have selected
+        chunk = [rpc(p, now) for p in parts]
+        cols = concat_columns([p.cols for p in chunk])
+        grid, *behind = programs(prepare_check_wire(r_wire.engine, chunk, now_ms=now))
+        first, *later = programs(prepare_check_columns(r_cols.engine, cols, now_ms=now))
+        # (the grid is padded for the whole chunk, pass 0 for its first
+        # copies; a pass whose stamps fit the grid's base and not its own
+        # first row's keeps the compact program where columns go full-width)
+        assert grid[1:] == first[1:] and len(behind) == len(later)
+        for lanes, columns in zip(behind, later):
+            wired = columns[3]
+            assert lanes == columns or (lanes[3] and not wired and lanes[2] == columns[2])
     finally:
         r_wire.close()
         r_cols.close()
+
+
+@async_test
+async def test_the_common_path_packs_no_batch(monkeypatch):
+    """Nine copies each of two keys: the grid, six exact passes and the
+    aggregate are all staged from the parser's lanes. `pack_columns`, which
+    every HostBatch of the serving path comes from, is not called."""
+    def packed(*_a, **_k):
+        raise AssertionError("the fused staging packed a HostBatch")
+
+    monkeypatch.setattr(engine_mod, "pack_columns", packed)
+    now = ms_now()
+    r_wire = EngineRunner(LocalEngine(capacity=4096, wire="compact"))
+    try:
+        fused = []
+        got = await r_wire.check_wire(
+            [rpc([7, 8] * 5, now), rpc([8, 7] * 4, now)], now_ms=now,
+            done=lambda _rc, _exc, f: fused.append(f),
+        )
+        r_wire._exec.submit(lambda: None).result()  # the stats delta is in
+        assert fused == [8] and not got.err.any() and not got.status.any()
+        # copies 0–6 one after another, copies 7 and 8 as one check of 2 hits
+        assert got.remaining.tolist() == (
+            [r for r in range(9, 4, -1) for _ in range(2)]
+            + [4, 4, 3, 3, 1, 1, 1, 1]
+        )
+        stats = r_wire.engine.stats
+        assert (stats.later_rows, stats.aggregate_rows, stats.later_lane_rows) == (16, 4, 16)
+    finally:
+        r_wire.close()
 
 
 @async_test
