@@ -14,13 +14,16 @@ proportional to the WRITE RATE instead:
   launch; `take()` runs on the engine thread too, immediately before the
   extract launch, so the mark→mutate / take→extract pairs interleave FIFO
   and a dirtied block can never fall between epochs.
-* **extract pass** — the PR-4 `extract_live_rows` pattern applied to only
-  the dirty blocks: one device gather of the dirty blocks' bucket rows, an
-  in-trace live filter + pack (live slots sorted to the front), and a host
-  fetch of just the live prefix. Cost ∝ dirty blocks, never table size.
-  Mesh engines run the same core per-shard under shard_map
-  (parallel/sharded.make_sharded_extract_dirty) so no slot row ever crosses
-  a device boundary.
+* **extract pass** — only the dirty blocks leave the device. On one device
+  (`extract_begin` / `finish_extract`): a gather of the dirty blocks'
+  bucket rows in grids of a fixed width (EXTRACT_GRIDS: two compiled
+  programs, warmed before the door opens), each slot's liveness computed
+  beside it, a fetch of each grid whole, and the dead slots dropped on the
+  host. Mesh engines gather, filter and pack (live slots sorted to the
+  front) per shard under shard_map (`_extract_blocks_core`,
+  parallel/sharded.make_sharded_extract_dirty), so no slot row ever crosses
+  a device boundary, and fetch the live prefix. Cost ∝ dirty blocks, never
+  table size.
 
 The extracted rows ride the table's own packed slot-field layout ((N, F)
 int32 — the same wire format TransferState chunks use), which is exactly
@@ -48,6 +51,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from gubernator_tpu import tracing
 from gubernator_tpu.ops.table2 import FP_HI, FP_LO, K
 
 
@@ -104,7 +108,9 @@ class EpochTracker:
         fps = fps[fps != 0]  # padding/inactive rows carry fp == 0
         if fps.size == 0:
             return fps
-        blkid = (fps % self.n_buckets) // self.blk
+        blkid = fps % self.n_buckets
+        if self.blk > 1:  # every array call on the serving path waits for the GIL
+            blkid //= self.blk
         if self.n_shards > 1:
             from gubernator_tpu.parallel.mesh import shard_of
 
@@ -113,12 +119,16 @@ class EpochTracker:
 
     def mark(self, fps: np.ndarray) -> None:
         """Mark the blocks holding `fps` dirty (fp == 0 entries ignored)."""
-        blkid = self._block_ids(fps)
-        if blkid.size == 0:
-            return
-        with self._lock:
-            self._dirty[blkid] = True
-            self.marked_fps += int(blkid.size)
+        # a part of the dispatch's `issue` stage when one is open on this
+        # thread (the served path marks inside the issue job), else a
+        # profiler span and a clock
+        with tracing.stage.within("ckpt_mark"):
+            blkid = self._block_ids(fps)
+            if blkid.size == 0:
+                return
+            with self._lock:
+                self._dirty[blkid] = True
+                self.marked_fps += int(blkid.size)
 
     def mark_all(self) -> None:
         """Everything is dirty (restore/resize of unknown provenance): the
@@ -166,9 +176,9 @@ class EpochTracker:
 
 
 def _extract_blocks_core(rows2d, bidx, now, blk: int, layout=None):
-    """Traced core shared by the single-device jit and the per-shard
-    shard_map body (parallel/sharded.py): gather the dirty blocks' bucket
-    rows, filter live slots, pack them to the front.
+    """Traced core of the per-shard shard_map body (parallel/sharded.py):
+    gather the dirty blocks' bucket rows, filter live slots, pack them to
+    the front.
 
     `rows2d` is (T, ROW_layout); `bidx` (g,) block ids with out-of-range
     sentinels for padding (jnp.take mode="fill" zero-fills them — fp == 0
@@ -195,31 +205,63 @@ def _extract_blocks_core(rows2d, bidx, now, blk: int, layout=None):
     return slots[order], fp[order], live.sum()
 
 
+# Grid widths (dirty blocks an execution) of the single-device extract: a
+# quiet epoch runs the small grid once, a busy one the large grid as often
+# as its dirty set needs. Two compiled programs whatever the write rate —
+# `EngineRunner.checkpoint_warm` compiles both before the door opens, so no
+# epoch compiles under load (a pow2 pad per dirty-set size compiled its steady shape
+# inside the first busy second) — and the padding of an epoch is at most
+# one grid.
+EXTRACT_GRIDS = (4096, 65536)
+
+
 @functools.partial(jax.jit, static_argnames=("blk", "layout"))
-def _extract_blocks_sorted(rows, bidx, now, *, blk: int, layout):
-    """Single-array entry: accepts any (..., ROW_layout) rows array
-    ((NB, ·) local or (D, NB, ·) sharded — the flatten folds the shard
-    axis in, exactly like table2._extract_sorted; block ids are then
-    GLOBAL, shard-major)."""
-    return _extract_blocks_core(
-        rows.reshape(-1, layout.row), bidx, now, blk, layout
+def _extract_blocks_grid(rows, bidx, now, *, blk: int, layout):
+    """One grid of the single-device extract: the dirty blocks' bucket rows
+    as they lie in the table ((g·blk, ROW_layout): a bucket row is one lane
+    row, which the chip gathers at a few ns a row and hands to the host
+    untiled) and, flat beside them, each slot's fingerprint, 0 where the
+    slot is empty or expired. `rows` is any (..., ROW_layout) array ((NB, ·)
+    local, or (D, NB, ·) with the shard axis folded in and block ids then
+    global, shard-major); `bidx` (g,) block ids, padded with an
+    out-of-range sentinel (`jnp.take` mode="fill" zero-fills it, and
+    fp == 0 is never live). Packing live slots to the front on the device
+    would add a sort over every slot and a gather of 64-byte rows (282 ms
+    an epoch of 364K buckets against 10, PERF.md section 6, PR 34), so the
+    host drops the dead slots as it copies the rows out of the fetch buffer
+    (`finish_extract`)."""
+    g = bidx.shape[0]
+    rowidx = (
+        bidx[:, None].astype(jnp.int32) * blk
+        + jnp.arange(blk, dtype=jnp.int32)[None, :]
+    ).reshape(-1)
+    blocks = jnp.take(
+        rows.reshape(-1, layout.row), rowidx, axis=0, mode="fill",
+        fill_value=0,
     )
+    slots = blocks.reshape(g * blk, K, layout.F)
+    lo = slots[:, :, FP_LO].astype(jnp.int64) & 0xFFFFFFFF
+    hi = slots[:, :, FP_HI].astype(jnp.int64)
+    exp = (slots[:, :, layout.exp_lo_i].astype(jnp.int64) & 0xFFFFFFFF) | (
+        slots[:, :, layout.exp_hi_i].astype(jnp.int64) << 32
+    )
+    return blocks, jnp.where(exp >= now, (hi << 32) | lo, 0).reshape(-1)
 
 
-def _pad_pow2(n: int, floor: int = 8) -> int:
-    p = floor
-    while p < n:
-        p *= 2
-    return p
+def _grid_for(n: int) -> int:
+    for g in EXTRACT_GRIDS:
+        if n <= g:
+            return g
+    return EXTRACT_GRIDS[-1]
 
 
 def extract_begin(rows, gids: np.ndarray, blk: int, now_ms: int, layout=None):
     """LAUNCH half of a dirty-block extract (engine thread — must read a
-    coherent table, costs only the enqueue): pads the dirty-block list to a
-    pow2 grid width (log-many compiled shapes) with an out-of-range
-    sentinel and launches the gather+filter+pack. Returns a pending handle
-    for finish_extract. `layout` is the table's slot layout (full when
-    omitted — the legacy geometry)."""
+    coherent table, costs only the enqueues): cuts the dirty-block list
+    into grids of one of EXTRACT_GRIDS' widths, pads the last with an
+    out-of-range sentinel and launches one gather per grid. Returns a
+    pending handle for finish_extract. `layout` is the table's slot layout
+    (full when omitted — the legacy geometry)."""
     if layout is None:
         from gubernator_tpu.ops.layout import layout_for_row
 
@@ -227,34 +269,36 @@ def extract_begin(rows, gids: np.ndarray, blk: int, now_ms: int, layout=None):
     # sentinel: one past the last valid block id in the flattened layout
     sentinel = int(np.prod(rows.shape[:-1])) // blk
     g = int(gids.shape[0])
-    pad = _pad_pow2(max(g, 1))
-    bidx = np.full(pad, sentinel, dtype=np.int64)
-    bidx[:g] = gids
-    slots_s, fp_s, cnt = _extract_blocks_sorted(
-        rows, jnp.asarray(bidx), jnp.asarray(np.int64(now_ms)),
-        blk=blk, layout=layout,
-    )
-    return slots_s, fp_s, cnt
+    width = _grid_for(g)
+    now = jnp.asarray(np.int64(now_ms))
+    pending = []
+    for at in range(0, max(g, 1), width):
+        bidx = np.full(width, sentinel, dtype=np.int32)
+        part = gids[at:at + width]
+        bidx[:part.shape[0]] = part
+        pending.append(_extract_blocks_grid(
+            rows, jnp.asarray(bidx), now, blk=blk, layout=layout,
+        ))
+    return pending
 
 
 def finish_extract(pending):
-    """FETCH half (any thread): materialize the live count, then fetch only
-    the live prefix padded to a pow2 so the compiled slice shapes stay
-    logarithmic in extract size (the extract_live_rows fetch rule). Slots
-    come back in the table's own layout (width = the pending arrays')."""
-    slots_s, fp_s, cnt = pending
-    n = int(cnt)
-    if n == 0:
-        width = int(slots_s.shape[-1])
-        return (
-            np.empty(0, dtype=np.int64),
-            np.empty((0, width), dtype=np.int32),
-        )
-    pad = 256
-    while pad < n:
-        pad *= 2
-    pad = min(pad, int(fp_s.shape[0]))
-    return (
-        np.asarray(fp_s[:pad])[:n].copy(),
-        np.asarray(slots_s[:pad])[:n].copy(),
-    )
+    """FETCH half (any thread): fetch each grid whole — one fixed shape, so
+    no slice program is compiled per extract size — and copy its live
+    slots out. Returns (fps (N,) i64, slots (N, F_layout) i32), slots in
+    the table's own layout and in table order."""
+    fp = [np.asarray(fp_d) for _blocks, fp_d in pending]
+    live = [f != 0 for f in fp]
+    ends = np.cumsum([int(m.sum()) for m in live])
+    F = int(pending[0][0].shape[1]) // K
+    fps = np.empty(int(ends[-1]), dtype=np.int64)
+    slots = np.empty((int(ends[-1]), F), dtype=np.int32)
+    at = 0
+    for (blocks_d, _fp_d), f, m, end in zip(pending, fp, live, ends.tolist()):
+        # each grid's live rows land where the frame wants them: no
+        # concatenate of a hundred megabytes afterwards
+        np.compress(m, f, out=fps[at:end])
+        np.compress(m, np.asarray(blocks_d).reshape(-1, F), axis=0,
+                    out=slots[at:end])
+        at = end
+    return fps, slots

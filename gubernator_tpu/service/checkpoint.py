@@ -5,13 +5,17 @@ table at graceful shutdown only (reference store.go:49-78), so a `kill -9`
 loses every counter since the last clean stop and a 100M-key cold restart
 re-seeds for minutes. This manager bounds both:
 
-* a background loop (GUBER_CHECKPOINT_INTERVAL_MS) takes the engine's dirty
-  epoch (ops/checkpoint.EpochTracker — blocks touched since the last take),
-  extracts just those blocks' live rows ON DEVICE (engine.checkpoint_begin
-  on the engine thread, fetch off it — the PR-7 telemetry overlap split, so
-  checkpointing overlaps serving), and appends one CRC-framed delta to the
-  log beside the base snapshot (store.DeltaLog). Checkpoint cost is
-  proportional to the write rate, never table size.
+* a background loop starts an epoch every GUBER_CHECKPOINT_INTERVAL_MS (a
+  fixed period: the next epoch is due one interval after the last was, not
+  one interval after it ended; an epoch that overruns its interval is
+  followed at once). An epoch takes the engine's dirty set
+  (ops/checkpoint.EpochTracker — blocks touched since the last take),
+  gathers just those blocks ON DEVICE (engine.checkpoint_begin on the
+  engine thread, fetch and live filter off it, on the checkpoint thread —
+  the PR-7 telemetry overlap split, so checkpointing overlaps serving), and
+  appends one CRC-framed delta to the log beside the base snapshot
+  (store.DeltaLog), on that thread too. Checkpoint cost is proportional to
+  the write rate, never table size.
 * every GUBER_CHECKPOINT_COMPACT_FRAMES frames the log compacts: one full
   snapshot becomes the new base (atomic rename FIRST), then the log resets
   — a crash between the two steps leaves stale deltas atop a newer base,
@@ -42,6 +46,8 @@ import numpy as np
 
 log = logging.getLogger("gubernator_tpu.checkpoint")
 
+REPLAY_ROWS = 1 << 17  # rows a merge call of a warm restart's frame replay
+
 
 class CheckpointManager:
     """One daemon's incremental-checkpoint plane. Inert (enabled=False)
@@ -69,10 +75,25 @@ class CheckpointManager:
         self.base_epoch = 0
         self.frames_since_compaction = 0
         self.last_epoch = 0  # last epoch durably persisted (frame or base)
-        self.last_epoch_ts: Optional[float] = None  # wall time of ^
+        # when the state that epoch holds was taken (clock of `_clock`): a
+        # kill -9 now loses what was admitted since then
+        self.last_epoch_ts: Optional[float] = None
+        self._clock = time.monotonic  # a test's way in, with `_sleep`
+        self._sleep = asyncio.sleep
+        # /v1/debug/pipeline "checkpoint": delta epochs that wrote a frame,
+        # what they held, compactions, the loop's epoch starts
+        self.epochs = self.dirty_blocks = self.rows = self.bytes = 0
+        self.bases = 0
+        self._age_max = 0.0  # oldest the last epoch has been since last read
+        self._starts = 0
+        self._first_start = self._last_start = 0.0
         self.last_error: Optional[str] = None
         self.replayed_frames = 0
         self.replayed_rows = 0
+        # replayed rows a merge call did not take: expired by now, or
+        # dropped by the kernel (a restart that reads more than a few here
+        # while nothing can have expired has lost state)
+        self.replay_unmerged = 0
         self.restored = "none"  # none | cold | base | base+delta
         self._lock = asyncio.Lock()  # one checkpoint/compaction at a time
 
@@ -184,8 +205,8 @@ class CheckpointManager:
                 # different GUBER_SLOT_LAYOUT) convert through the
                 # canonical full row inside merge_rows — replay stays
                 # conservative whatever the layouts
-                engine.merge_rows(
-                    fps_from_slots(slots), slots, layout=frame_layout
+                self.replay_unmerged += self._replay(
+                    engine, fps_from_slots(slots), slots, frame_layout
                 )
             except Exception as exc:
                 log.warning(
@@ -204,11 +225,39 @@ class CheckpointManager:
         if self.restored != "cold":
             log.info(
                 "warm restart: %s — base epoch %d + %d delta frames "
-                "(%d rows) in %.1f ms",
+                "(%d rows, %d not merged) in %.1f ms",
                 self.restored, self.base_epoch, self.replayed_frames,
-                self.replayed_rows, (time.perf_counter() - t0) * 1e3,
+                self.replayed_rows, self.replay_unmerged,
+                (time.perf_counter() - t0) * 1e3,
             )
-        self.last_epoch_ts = time.monotonic()
+        self.last_epoch_ts = self._clock()
+
+    @staticmethod
+    def _replay(engine, fps, slots, layout) -> int:
+        """One frame through the conservative merge, REPLAY_ROWS rows a
+        call: a busy epoch's frame holds a million rows and more, and one
+        merge of them all is a program of its own size (a compile a pow2,
+        gigabytes of gathered buckets). A long frame is shuffled first: it
+        lists its rows in table order, and a run of neighbours overflows
+        the table write's per-block window, whose overflow rows the kernel
+        drops (kernel2.sweep_geometry sizes the window for rows spread over
+        the table, as a whole frame's are and a shuffled chunk's). The last
+        chunk reaches back over the one before it, so that every chunk has
+        the one shape: merging a row a second time changes nothing.
+        Returns the rows a merge call did not take (expired at this clock,
+        or dropped by the kernel)."""
+        n = fps.shape[0]
+        if n > REPLAY_ROWS:
+            order = np.random.default_rng(n).permutation(n)
+            fps, slots = fps[order], slots[order]
+        unmerged = 0
+        for lo in range(0, n, REPLAY_ROWS):
+            lo = max(0, min(lo, n - REPLAY_ROWS))
+            part = fps[lo:lo + REPLAY_ROWS]
+            unmerged += part.shape[0] - engine.merge_rows(
+                part, slots[lo:lo + REPLAY_ROWS], layout=layout
+            )
+        return unmerged
 
     def attach(self) -> None:
         """Create the engine's epoch tracker (clean — everything restored
@@ -222,17 +271,45 @@ class CheckpointManager:
             n_shards=getattr(engine, "n_shards", 1),
             start_epoch=self.last_epoch,
         )
+        # what is on disk holds everything admitted so far, and stays that
+        # fresh until the door opens: the age counts from here, not from
+        # the restore (warm-up lies between them)
+        self.last_epoch_ts = self._clock()
 
     # ---------------------------------------------------------------- loop
     async def loop(self) -> None:
+        """An epoch every interval, on a fixed period: each is due one
+        interval after the one before it was due, whatever that one took,
+        so the documented loss bound (what is admitted in one interval,
+        plus what is admitted while the epoch that holds it is written) is
+        the code's. An epoch that overran its interval is followed at
+        once, and the period starts again from there."""
+        due = self._clock() + self.interval_s
         while not self.daemon._shutting_down:
-            await asyncio.sleep(self.interval_s)
+            await self._sleep(max(0.0, due - self._clock()))
+            now = self._clock()
+            if not self._starts:
+                self._first_start = now
+            self._starts += 1
+            self._last_start = now
             try:
                 await self.checkpoint_once()
             except asyncio.CancelledError:
                 raise
             except Exception:  # pragma: no cover - defensive
                 log.exception("checkpoint tick failed")
+            due = max(due + self.interval_s, self._clock())
+
+    def _durable(self, epoch: int, taken_at: float) -> None:
+        """`epoch`, whose state was taken at `taken_at`, is on disk: until
+        now a crash would have fallen back to the epoch before it."""
+        if self.last_epoch_ts is not None:
+            self._age_max = max(
+                self._age_max, self._clock() - self.last_epoch_ts
+            )
+        self.last_epoch = max(self.last_epoch, epoch)
+        self.last_epoch_ts = taken_at
+        self._observe_age()
 
     async def checkpoint_once(self) -> dict:
         """One delta epoch: take the dirty set + launch the extract
@@ -241,6 +318,10 @@ class CheckpointManager:
         daemon = self.daemon
         async with self._lock:
             t0 = time.perf_counter()
+            taken_at = self._clock()
+            # the frame's stamp: not later than the take, so that whatever
+            # was answered before it is in the frame
+            now_ms = daemon.now_ms()
             epoch, gids, fps, slots = await daemon.runner.checkpoint_extract()
             out = dict(
                 epoch=epoch, dirty_blocks=int(gids.shape[0]),
@@ -248,16 +329,12 @@ class CheckpointManager:
             )
             if gids.shape[0] == 0:
                 # nothing dirtied: the previous epoch is still fresh
-                self.last_epoch = epoch
-                self.last_epoch_ts = time.monotonic()
-                self._observe_age()
+                self._durable(epoch, taken_at)
                 return out
-            loop = asyncio.get_running_loop()
-            now_ms = daemon.now_ms()
             lay = daemon.engine.table.layout
             try:
-                nbytes = await loop.run_in_executor(
-                    None, lambda: self._log.append(
+                nbytes = await daemon.runner.checkpoint_write(
+                    "ckpt_append", lambda: self._log.append(
                         epoch, now_ms, slots, layout=lay
                     )
                 )
@@ -271,14 +348,16 @@ class CheckpointManager:
                 return {**out, "error": str(exc)}
             dt = time.perf_counter() - t0
             self.frames_since_compaction += 1
-            self.last_epoch = epoch
-            self.last_epoch_ts = time.monotonic()
             self.last_error = None
+            self.epochs += 1
+            self.dirty_blocks += out["dirty_blocks"]
+            self.rows += out["rows"]
+            self.bytes += nbytes
             m = daemon.metrics
             m.checkpoint_duration.labels(kind="delta").observe(dt)
             m.checkpoint_bytes.labels(kind="delta").inc(nbytes)
             m.checkpoint_rows.labels(kind="delta").inc(int(fps.shape[0]))
-            self._observe_age()
+            self._durable(epoch, taken_at)
             out["bytes"] = nbytes
         if self.frames_since_compaction >= self.compact_frames:
             await self.compact()
@@ -315,36 +394,29 @@ class CheckpointManager:
 
     async def compact(self) -> None:
         """Fold the delta log into a fresh base: full snapshot (engine
-        thread for coherence, disk write off-loop, atomic rename), THEN
-        log reset. Dirty bits marked since the snapshot stay armed — the
-        next delta may duplicate a little state, which replay's
-        conservative merge absorbs."""
+        thread for coherence; the occupied slots written plain and the log
+        reset on the checkpoint thread; atomic rename), THEN log reset.
+        Dirty bits marked since the snapshot stay armed — the next delta
+        may duplicate a little state, which replay's conservative merge
+        absorbs."""
         daemon = self.daemon
         async with self._lock:
             t0 = time.perf_counter()
+            taken_at = self._clock()
             rows, epoch, lay = await daemon.runner.checkpoint_snapshot()
-            loop = asyncio.get_running_loop()
-            from gubernator_tpu.ops.table2 import live_count2, Table2
             from gubernator_tpu.store import save_snapshot
-
-            now_ms = daemon.now_ms()
 
             def write_base():
                 # everything that touches disk stays off the event loop:
                 # snapshot write + rename, log reset, size stat
-                save_snapshot(self.base_path, rows, epoch,
-                              layout_name=lay.name)
+                n = save_snapshot(self.base_path, rows, epoch,
+                                  layout_name=lay.name)
                 self._log.reset()
-                # the rows are already host-side; the live count is one
-                # vectorized pass over memory the save just touched
-                return (
-                    live_count2(Table2(rows=rows, layout=lay), now_ms),
-                    os.path.getsize(self.base_path),
-                )
+                return n or 0, os.path.getsize(self.base_path)
 
             try:
-                base_rows, base_bytes = await loop.run_in_executor(
-                    None, write_base
+                base_rows, base_bytes = await daemon.runner.checkpoint_write(
+                    "ckpt_base", write_base
                 )
             except Exception as exc:
                 self.last_error = f"compaction: {exc}"
@@ -354,17 +426,16 @@ class CheckpointManager:
             dt = time.perf_counter() - t0
             self.base_epoch = epoch
             self.frames_since_compaction = 0
-            self.last_epoch = max(self.last_epoch, epoch)
-            self.last_epoch_ts = time.monotonic()
             self.last_error = None
+            self.bases += 1
             m = daemon.metrics
             m.checkpoint_duration.labels(kind="base").observe(dt)
             m.checkpoint_bytes.labels(kind="base").inc(base_bytes)
             m.checkpoint_rows.labels(kind="base").inc(base_rows)
-            self._observe_age()
+            self._durable(epoch, taken_at)
             log.info(
-                "delta log compacted into base (epoch %d) in %.1f ms",
-                epoch, dt * 1e3,
+                "delta log compacted into base (epoch %d, %d rows, %d "
+                "bytes) in %.1f ms", epoch, base_rows, base_bytes, dt * 1e3,
             )
 
     async def final_checkpoint(self) -> None:
@@ -381,7 +452,34 @@ class CheckpointManager:
         kill -9 would lose right now."""
         if self.last_epoch_ts is None:
             return 0.0
-        return max(0.0, time.monotonic() - self.last_epoch_ts)
+        return max(0.0, self._clock() - self.last_epoch_ts)
+
+    def pipeline(self) -> Optional[dict]:
+        """The `checkpoint` block of /v1/debug/pipeline (None while the
+        plane is off): cumulative counts a reader takes deltas of, and two
+        readings of the cadence. `epoch_age_ms_max` is the oldest the last
+        durable epoch has been since this block was last read (what a
+        kill -9 at the worst moment would have lost, in time), and reading
+        it starts it again."""
+        if not self.enabled:
+            return None
+        age = max(self._age_max, self.epoch_age_s())
+        self._age_max = 0.0
+        starts = self._starts
+        return {
+            "epochs": self.epochs,
+            "extracts": self.daemon.runner.ckpt_extracts,
+            "dirty_blocks": self.dirty_blocks,
+            "rows": self.rows,
+            "bytes": self.bytes,
+            "bases": self.bases,
+            "epoch_age_ms_max": age * 1e3,
+            # mean start-to-start distance of the loop's epochs
+            "period_ms": (
+                (self._last_start - self._first_start) / (starts - 1) * 1e3
+                if starts > 1 else None
+            ),
+        }
 
     # --------------------------------------------------------------- status
     def status(self) -> dict:
@@ -401,6 +499,7 @@ class CheckpointManager:
             "delta_log_bytes": self._log.size_bytes() if self._log else 0,
             "replayed_frames": self.replayed_frames,
             "replayed_rows": self.replayed_rows,
+            "replay_unmerged": self.replay_unmerged,
             "last_error": self.last_error,
         }
         if tracker is not None:

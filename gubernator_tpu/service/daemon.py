@@ -328,7 +328,10 @@ class Daemon:
         if d.checkpointer.enabled:
             # epoch tracker attaches BEFORE the listeners open: every
             # serving mutation from the first request onward is marked
+            # (and after warm_up, whose rows are no one's state); then the
+            # extract's programs compile, so that no epoch does under load
             d.checkpointer.attach()
+            await d.runner.checkpoint_warm()
         if d.tier.enabled:
             # AFTER the checkpoint restore (delta replay — including
             # tombstone frames — settles HBM first), before serving
@@ -1778,7 +1781,16 @@ class Daemon:
                 "later_rows": eng.stats.later_rows,
                 "aggregate_rows": eng.stats.aggregate_rows,
                 "later_lane_rows": eng.stats.later_lane_rows,
+                # buckets a dirty block of the incremental checkpoint's
+                # tracker holds: there when the plane is armed and the
+                # tracker attached (every dispatch marks), else None
+                "ckpt_blk": getattr(
+                    getattr(eng, "ckpt", None), "blk", None
+                ),
             },
+            # the incremental checkpoint plane's counts and cadence
+            # (service/checkpoint.CheckpointManager.pipeline); None when off
+            "checkpoint": self.checkpointer.pipeline(),
             # per-algorithm decision counts (live view of
             # gubernator_tpu_decisions_total) — scenario breadth at a glance
             "decisions_by_algorithm": dict(self.runner.algo_counts),

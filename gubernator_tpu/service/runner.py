@@ -553,6 +553,12 @@ class EngineRunner:
     # on the engine thread, fetch on a dedicated lazy thread so the extract
     # streams off-device WHILE serving dispatches keep issuing.
 
+    # extract programs launched for checkpoint epochs (/v1/debug/pipeline
+    # "checkpoint"); a default on the class, so that no line above the
+    # dispatch chain's functions moves (a moved line re-keys every decide
+    # program in the compile cache: PERF.md, PR 33)
+    ckpt_extracts = 0
+
     async def checkpoint_extract(self, now_ms: Optional[int] = None):
         """One checkpoint epoch's dirty-block extract: (epoch, gids, fps,
         slots). The tracker take() and the extract LAUNCH run in one
@@ -581,12 +587,54 @@ class EngineRunner:
                 np.empty(0, dtype=np.int64),
                 np.empty((0, width), dtype=np.int32),
             )
+        # device programs launched: the local engine's pending is one entry
+        # a grid (ops/checkpoint.extract_begin), a mesh engine's one step
+        self.ckpt_extracts += len(pending) if isinstance(pending, list) else 1
+        fps, slots = await loop.run_in_executor(
+            self._ckpt_thread(), fetch, pending
+        )
+        return epoch, gids, fps, slots
+
+    def _ckpt_thread(self) -> ThreadPoolExecutor:
         if self._ckpt is None:
             self._ckpt = ThreadPoolExecutor(
                 max_workers=1, thread_name_prefix="ckpt"
             )
-        fps, slots = await loop.run_in_executor(self._ckpt, fetch, pending)
-        return epoch, gids, fps, slots
+        return self._ckpt
+
+    async def checkpoint_write(self, stage_name: str, fn):
+        """Run a checkpoint's disk work (`ckpt_append`: a frame's CRC, write
+        and fsync; `ckpt_base`: a compaction's snapshot write and log
+        reset) on the checkpoint thread, timed as that stage: the thread
+        that fetched the rows writes them, and neither the event loop nor
+        the default executor's workers wait on a disk."""
+        def run():
+            with tracing.stage(stage_name, self.metrics):
+                return fn()
+
+        return await asyncio.get_running_loop().run_in_executor(
+            self._ckpt_thread(), run
+        )
+
+    async def checkpoint_warm(self) -> None:
+        """Compile the extract's programs before the door opens (engine
+        thread: it reads the table): every grid the tracker's geometry can
+        fill, through the engine's own begin/finish, so that a checkpoint
+        epoch under load compiles nothing."""
+        from gubernator_tpu.ops.checkpoint import EXTRACT_GRIDS
+
+        def run():
+            tracker = self.engine.ckpt
+            total = tracker.n_shards * tracker.nblk
+            for width in EXTRACT_GRIDS:
+                n = min(width, total)
+                self.engine.checkpoint_finish(self.engine.checkpoint_begin(
+                    np.arange(n, dtype=np.int64), 0
+                ))
+                if n == total:
+                    break  # a table this small never fills a wider grid
+
+        await asyncio.get_running_loop().run_in_executor(self._exec, run)
 
     async def checkpoint_snapshot(self):
         """(full table rows, epoch, slot layout) read atomically on the

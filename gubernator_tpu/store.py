@@ -34,30 +34,61 @@ import numpy as np
 SNAPSHOT_MAGIC = "GUBTPU1"
 
 
+def _occupied_slots(rows: np.ndarray, layout_name: str):
+    """(mask over the flat slots, the occupied slots' rows) of a table
+    image, or None when `rows` is not a table in that layout. An empty slot
+    is fp == 0 (ops/table2.py), and nothing reads its other fields."""
+    from gubernator_tpu.ops.layout import LAYOUTS
+
+    lay = LAYOUTS.get(layout_name)
+    if (
+        lay is None or rows.ndim < 2 or rows.shape[-1] != lay.row
+        or rows.dtype != np.int32
+    ):
+        return None
+    slots = rows.reshape(-1, lay.F)
+    mask = (slots[:, 0] != 0) | (slots[:, 1] != 0)
+    return mask, slots[mask]
+
+
 def save_snapshot(path: str, rows: np.ndarray, epoch: int = 0,
-                  layout_name: str = "full") -> None:
-    """Atomically write a table snapshot (tmp + rename, so a crash mid-write
-    never leaves a torn file for the next boot). `epoch` records the last
-    checkpoint epoch the snapshot includes (0 on the classic full-snapshot
-    path) so warm restart can skip already-compacted delta frames.
-    `layout_name` records the slot layout the rows bytes are in
-    (ops/layout.py) — "full" writes a file byte-identical to the
-    pre-layout format."""
+                  layout_name: str = "full") -> Optional[int]:
+    """Atomically write a table snapshot (tmp + fsync + rename, so a crash
+    mid-write never leaves a torn file for the next boot, and a delta log
+    reset after the rename never outlives its base). `epoch` records the
+    last checkpoint epoch the snapshot includes (0 on the classic
+    full-snapshot path) so warm restart can skip already-compacted delta
+    frames. `layout_name` records the slot layout the rows bytes are in
+    (ops/layout.py).
+
+    A table image is written as its occupied slots and a bitmap of their
+    positions, uncompressed: a checkpoint's cost is then the occupied rows'
+    bytes at the disk's speed (10M keys in 16.7M slots: 0.7 GB, under a
+    second), where deflating the whole image took ten (PERF.md section 6,
+    PR 34). `load_snapshot*` read both this form and the compressed whole
+    image every earlier version wrote. Returns the slot
+    rows written (None for an array that is no table image, written whole)."""
     d = os.path.dirname(os.path.abspath(path)) or "."
     os.makedirs(d, exist_ok=True)
+    fields = dict(
+        magic=np.frombuffer(SNAPSHOT_MAGIC.encode(), dtype=np.uint8),
+        epoch=np.int64(epoch),
+    )
+    if layout_name != "full":
+        fields["layout"] = np.frombuffer(layout_name.encode(), dtype=np.uint8)
+    occupied = _occupied_slots(rows, layout_name)
+    if occupied is None:
+        fields["rows"] = rows
+    else:
+        fields["shape"] = np.asarray(rows.shape, dtype=np.int64)
+        fields["occupied"] = np.packbits(occupied[0])
+        fields["slots"] = occupied[1]
     fd, tmp = tempfile.mkstemp(dir=d, prefix=".gubtpu-snap-")
     try:
         with os.fdopen(fd, "wb") as f:
-            extra = {}
-            if layout_name != "full":
-                # only non-default layouts write the key: full snapshots
-                # stay byte-identical to every pre-layout file
-                extra["layout"] = np.frombuffer(
-                    layout_name.encode(), dtype=np.uint8
-                )
-            np.savez_compressed(f, magic=np.frombuffer(
-                SNAPSHOT_MAGIC.encode(), dtype=np.uint8
-            ), rows=rows, epoch=np.int64(epoch), **extra)
+            np.savez(f, **fields)
+            f.flush()
+            os.fsync(f.fileno())
         os.replace(tmp, path)
     except BaseException:
         try:
@@ -65,14 +96,21 @@ def save_snapshot(path: str, rows: np.ndarray, epoch: int = 0,
         except OSError:
             pass
         raise
+    return None if occupied is None else int(occupied[1].shape[0])
+
+
+def _snapshot_rows(z) -> np.ndarray:
+    if "rows" in z.files:
+        return z["rows"]
+    slots = z["slots"]
+    rows = np.zeros(tuple(z["shape"]), dtype=np.int32)
+    flat = rows.reshape(-1, slots.shape[1])
+    flat[np.unpackbits(z["occupied"], count=flat.shape[0]).astype(bool)] = slots
+    return rows
 
 
 def load_snapshot(path: str) -> np.ndarray:
-    with np.load(path) as z:
-        magic = bytes(z["magic"]).decode()
-        if magic != SNAPSHOT_MAGIC:
-            raise ValueError(f"{path}: not a gubernator-tpu snapshot")
-        return z["rows"]
+    return load_snapshot_meta(path)[0]
 
 
 def load_snapshot_meta(path: str) -> "Tuple[np.ndarray, int, str]":
@@ -86,7 +124,7 @@ def load_snapshot_meta(path: str) -> "Tuple[np.ndarray, int, str]":
         layout = (
             bytes(z["layout"]).decode() if "layout" in z.files else "full"
         )
-        return z["rows"], epoch, layout
+        return _snapshot_rows(z), epoch, layout
 
 
 # ------------------------------------------------------------- delta log
@@ -162,12 +200,13 @@ def fps_from_slots(slots: np.ndarray) -> np.ndarray:
     return (hi << 32) | lo
 
 
-def encode_delta_frame(epoch: int, now_ms: int, slots: np.ndarray,
-                       layout=None) -> bytes:
-    """One CRC-framed delta: header + raw little-endian (N, F_layout) int32
-    slot rows — live rows of dirty blocks only, vs the base snapshot's
-    every-slot-of-every-bucket. 64 B/row under the full layout, 32 B/row
-    under the packed ones (the frame's version byte carries the layout)."""
+def _frame_parts(epoch: int, now_ms: int, slots: np.ndarray, layout=None):
+    """(header bytes, payload buffer) of one CRC-framed delta: raw
+    little-endian (N, F_layout) int32 slot rows — live rows of dirty blocks
+    only, vs the base snapshot's every occupied slot. 64 B/row under the
+    full layout, 32 B/row under the packed ones (the frame's version byte
+    carries the layout). The payload is a view of `slots` where that is
+    already contiguous little-endian int32."""
     if layout is None:
         from gubernator_tpu.ops.layout import FULL
 
@@ -181,12 +220,21 @@ def encode_delta_frame(epoch: int, now_ms: int, slots: np.ndarray,
             f"slot rows are {slots.shape[1]} fields wide but layout "
             f"{layout.name} has {layout.F}"
         )
-    payload = np.ascontiguousarray(slots, dtype="<i4").tobytes()
+    payload = memoryview(
+        np.ascontiguousarray(slots, dtype="<i4").reshape(-1)
+    ).cast("B")
     header = _FRAME_HEADER.pack(
         FRAME_MAGIC, 1 + layout.code, slots.shape[0], epoch, now_ms,
         zlib.crc32(payload),
     )
-    return header + payload
+    return header, payload
+
+
+def encode_delta_frame(epoch: int, now_ms: int, slots: np.ndarray,
+                       layout=None) -> bytes:
+    """One CRC-framed delta as bytes (`_frame_parts` joined)."""
+    header, payload = _frame_parts(epoch, now_ms, slots, layout)
+    return header + bytes(payload)
 
 
 def encode_tombstone_frame(epoch: int, now_ms: int,
@@ -299,8 +347,13 @@ class DeltaLog:
                layout=None) -> int:
         """Append one frame; returns bytes written (header included).
         `layout` tags the slot rows' layout (full inferred for 16-field
-        rows)."""
-        frame = encode_delta_frame(epoch, now_ms, slots, layout=layout)
+        rows). The rows are checksummed and written where they lie — a
+        busy epoch's frame is a hundred megabytes, and a copy of it into
+        one bytes object would be made twice."""
+        header, payload = _frame_parts(epoch, now_ms, slots, layout)
+        return self._append(header, payload)
+
+    def _append(self, header: bytes, payload) -> int:
         fresh = not os.path.exists(self.path) or (
             os.path.getsize(self.path) == 0
         )
@@ -309,28 +362,19 @@ class DeltaLog:
         with open(self.path, "ab") as f:
             if fresh:
                 f.write(DELTA_LOG_MAGIC)
-            f.write(frame)
+            f.write(header)
+            f.write(payload)
             f.flush()
             os.fsync(f.fileno())
-        return len(frame) + (len(DELTA_LOG_MAGIC) if fresh else 0)
+        return len(header) + len(payload) + (
+            len(DELTA_LOG_MAGIC) if fresh else 0
+        )
 
     def append_tombstones(self, epoch: int, now_ms: int,
                           fps: np.ndarray) -> int:
         """Append one tombstone frame (demote-on-idle removals — see
         TOMBSTONE_FRAME_VERSION). Returns bytes written."""
-        frame = encode_tombstone_frame(epoch, now_ms, fps)
-        fresh = not os.path.exists(self.path) or (
-            os.path.getsize(self.path) == 0
-        )
-        d = os.path.dirname(os.path.abspath(self.path)) or "."
-        os.makedirs(d, exist_ok=True)
-        with open(self.path, "ab") as f:
-            if fresh:
-                f.write(DELTA_LOG_MAGIC)
-            f.write(frame)
-            f.flush()
-            os.fsync(f.fileno())
-        return len(frame) + (len(DELTA_LOG_MAGIC) if fresh else 0)
+        return self._append(encode_tombstone_frame(epoch, now_ms, fps), b"")
 
     def scan(self) -> DeltaScan:
         return read_delta_frames(self.path)
