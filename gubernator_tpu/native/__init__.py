@@ -93,6 +93,13 @@ def load():
         )
         _mod = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(_mod)
+        # the engine's error codes as the wire says them, for the encoder
+        # that reads an `err` column (encode_responses_many)
+        from gubernator_tpu.ops.batch import ERROR_STRINGS
+
+        _mod.set_error_strings(
+            [ERROR_STRINGS.get(c, "") for c in range(max(ERROR_STRINGS) + 1)]
+        )
     except ImportError as exc:  # pragma: no cover - toolchain-specific
         log.warning("native guberhost import failed: %s", exc)
         _mod = None

@@ -26,6 +26,7 @@
 #include <cstdint>
 #include <cstdio>
 #include <cstring>
+#include <memory>
 #include <string>
 #include <vector>
 
@@ -477,6 +478,48 @@ static inline void put_tag(std::string& out, uint32_t field, uint32_t wt) {
   put_varint(out, ((uint64_t)field << 3) | wt);
 }
 
+// One RateLimitResp as item `responses` (field 1) of a GetRateLimitsResp,
+// appended to `out`; `item` is scratch. `err` is the row's error string or
+// null. With now_ms >= 0 a DENIED row additionally carries
+// metadata["retry_after_ms"] = max(0, reset_time - now_ms) — for GCRA
+// denials reset_time is the exact TAT-derived conforming instant
+// (ops/math.py), so clients honoring it back off precisely.
+static inline void put_response(std::string& out, std::string& item,
+                                int64_t st, int64_t li, int64_t re, int64_t rt,
+                                const std::string* err, long long now_ms) {
+  item.clear();
+  if (st) { put_tag(item, 1, 0); put_varint(item, (uint64_t)st); }
+  if (li) { put_tag(item, 2, 0); put_varint(item, (uint64_t)li); }
+  if (re) { put_tag(item, 3, 0); put_varint(item, (uint64_t)re); }
+  if (rt) { put_tag(item, 4, 0); put_varint(item, (uint64_t)rt); }
+  if (err && !err->empty()) {
+    put_tag(item, 5, 2);
+    put_varint(item, err->size());
+    item += *err;
+  }
+  if (now_ms >= 0 && st == 1) {
+    // metadata map entry {1: "retry_after_ms", 2: decimal-ms}
+    static const char RA_KEY[] = "retry_after_ms";
+    long long d = rt - now_ms;
+    if (d < 0) d = 0;
+    char vbuf[24];
+    int vlen = snprintf(vbuf, sizeof vbuf, "%lld", d);
+    std::string entry;
+    put_tag(entry, 1, 2);
+    put_varint(entry, sizeof(RA_KEY) - 1);
+    entry.append(RA_KEY, sizeof(RA_KEY) - 1);
+    put_tag(entry, 2, 2);
+    put_varint(entry, (uint64_t)vlen);
+    entry.append(vbuf, (size_t)vlen);
+    put_tag(item, 6, 2);
+    put_varint(item, entry.size());
+    item += entry;
+  }
+  put_tag(out, 1, 2);
+  put_varint(out, item.size());
+  out += item;
+}
+
 // encode_responses(status_i64, limit_i64, remaining_i64, reset_i64,
 //                  errors: dict[int, str], now_ms: int = -1)
 //                  -> bytes(GetRateLimitsResp)
@@ -484,10 +527,7 @@ static inline void put_tag(std::string& out, uint32_t field, uint32_t wt) {
 // object works (contiguous numpy int64 arrays pass ZERO-COPY; no .tobytes()
 // round trip). Error strings are gathered under the GIL up front; the
 // varint/field assembly then runs with the GIL RELEASED so N responder
-// workers encode concurrently. With now_ms >= 0, DENIED rows additionally
-// carry metadata["retry_after_ms"] = max(0, reset_time - now_ms) — for
-// GCRA denials reset_time is the exact TAT-derived conforming instant
-// (ops/math.py), so clients honoring it back off precisely.
+// workers encode concurrently. `now_ms` as in put_response.
 static PyObject* encode_responses(PyObject*, PyObject* args) {
   Py_buffer sb, lb, rb, tb;
   PyObject* errs;
@@ -529,45 +569,174 @@ static PyObject* encode_responses(PyObject*, PyObject* args) {
   Py_BEGIN_ALLOW_THREADS;
   out.reserve(n * 24);
   std::string item;
-  for (size_t i = 0; i < n; i++) {
-    item.clear();
-    if (st[i]) { put_tag(item, 1, 0); put_varint(item, (uint64_t)st[i]); }
-    if (li[i]) { put_tag(item, 2, 0); put_varint(item, (uint64_t)li[i]); }
-    if (re[i]) { put_tag(item, 3, 0); put_varint(item, (uint64_t)re[i]); }
-    if (rt[i]) { put_tag(item, 4, 0); put_varint(item, (uint64_t)rt[i]); }
-    if (!err_at.empty() && err_at[i]) {
-      put_tag(item, 5, 2);
-      put_varint(item, err_at[i]->size());
-      item += *err_at[i];
-    }
-    if (now_ms >= 0 && st[i] == 1) {
-      // metadata map entry {1: "retry_after_ms", 2: decimal-ms}
-      static const char RA_KEY[] = "retry_after_ms";
-      long long d = rt[i] - now_ms;
-      if (d < 0) d = 0;
-      char vbuf[24];
-      int vlen = snprintf(vbuf, sizeof vbuf, "%lld", d);
-      std::string entry;
-      put_tag(entry, 1, 2);
-      put_varint(entry, sizeof(RA_KEY) - 1);
-      entry.append(RA_KEY, sizeof(RA_KEY) - 1);
-      put_tag(entry, 2, 2);
-      put_varint(entry, (uint64_t)vlen);
-      entry.append(vbuf, (size_t)vlen);
-      put_tag(item, 6, 2);
-      put_varint(item, entry.size());
-      item += entry;
-    }
-    put_tag(out, 1, 2);
-    put_varint(out, item.size());
-    out += item;
-  }
+  for (size_t i = 0; i < n; i++)
+    put_response(out, item, st[i], li[i], re[i], rt[i],
+                 err_at.empty() ? nullptr : err_at[i], now_ms);
   Py_END_ALLOW_THREADS;
   PyBuffer_Release(&sb);
   PyBuffer_Release(&lb);
   PyBuffer_Release(&rb);
   PyBuffer_Release(&tb);
   return PyBytes_FromStringAndSize(out.data(), (Py_ssize_t)out.size());
+}
+
+// The wire strings of the engine's error codes (ops/batch.ERROR_STRINGS),
+// indexed by code; native.load() hands them in once. Replaced and read
+// (copied) under the GIL, so an encode that runs without it keeps the
+// table it started with.
+static std::shared_ptr<const std::vector<std::string>> error_strings;
+
+// set_error_strings(strings: sequence[str]) — entry i is code i's message
+static PyObject* set_error_strings(PyObject*, PyObject* arg) {
+  PyObject* seq = PySequence_Fast(arg, "a sequence of str expected");
+  if (!seq) return nullptr;
+  auto table = std::make_shared<std::vector<std::string>>();
+  for (Py_ssize_t i = 0; i < PySequence_Fast_GET_SIZE(seq); i++) {
+    Py_ssize_t len;
+    const char* p =
+        PyUnicode_AsUTF8AndSize(PySequence_Fast_GET_ITEM(seq, i), &len);
+    if (!p) { Py_DECREF(seq); return nullptr; }
+    table->emplace_back(p, (size_t)len);
+  }
+  Py_DECREF(seq);
+  error_strings = std::move(table);
+  Py_RETURN_NONE;
+}
+
+// One integer column behind the buffer protocol: one dimension, any
+// stride, 1/2/4/8-byte items, signed or not (a numpy int8/int32/int64/bool
+// array as it is, no copy). Opened and released with the GIL held; read
+// without it.
+struct IntCol {
+  Py_buffer view;
+  bool held = false, sign = true;
+  ~IntCol() { if (held) PyBuffer_Release(&view); }
+
+  bool open(PyObject* obj, const char* what) {
+    if (PyObject_GetBuffer(obj, &view, PyBUF_STRIDES | PyBUF_FORMAT) < 0)
+      return false;
+    held = true;
+    const char* f = view.format ? view.format : "B";
+    if (*f == '@' || *f == '=' || *f == '<') f++;  // this host's byte order
+    if (strchr("bhilqn", *f) && *f) sign = true;
+    else if (strchr("BHILQN?", *f) && *f) sign = false;
+    else f = nullptr;
+    Py_ssize_t w = view.itemsize;
+    if (view.ndim != 1 || !f || f[1] || (w != 1 && w != 2 && w != 4 && w != 8)) {
+      PyErr_Format(PyExc_TypeError,
+                   "%s: a one-dimensional integer column expected", what);
+      return false;
+    }
+    return true;
+  }
+  Py_ssize_t size() const { return view.shape[0]; }
+  inline int64_t at(Py_ssize_t i) const {
+    const char* p = (const char*)view.buf + i * view.strides[0];
+    switch (view.itemsize) {
+      case 8: { int64_t v; memcpy(&v, p, 8); return v; }
+      case 4: { int32_t v; memcpy(&v, p, 4);
+                return sign ? (int64_t)v : (int64_t)(uint32_t)v; }
+      case 2: { int16_t v; memcpy(&v, p, 2);
+                return sign ? (int64_t)v : (int64_t)(uint16_t)v; }
+      default: return sign ? (int64_t)*(const int8_t*)p
+                           : (int64_t)*(const uint8_t*)p;
+    }
+  }
+};
+
+// encode_responses_many(status, limit, remaining, reset, err,
+//                       offsets: sequence[int], now_ms: int = -1)
+//                       -> (list[bytes(GetRateLimitsResp)], list[int])
+// One dispatch's answers in one call: the five response columns of a
+// coalesced chunk as the engine hands them over (ResponseColumns: any
+// integer width, widened here) and E+1 ascending row offsets; entry k is
+// rows offsets[k] .. offsets[k+1]. For every entry the bytes are what
+// encode_responses writes for that slice of the columns with the error
+// strings of its non-zero `err` codes (set_error_strings), and the second
+// list counts the entry's OVER_LIMIT rows. The whole assembly runs with
+// the GIL released.
+static PyObject* encode_responses_many(PyObject*, PyObject* args) {
+  PyObject *so, *lo, *ro, *to, *eo, *offs;
+  long long now_ms = -1;
+  if (!PyArg_ParseTuple(args, "OOOOOO|L", &so, &lo, &ro, &to, &eo, &offs,
+                        &now_ms))
+    return nullptr;
+  IntCol st, li, re, rt, er;
+  if (!st.open(so, "status") || !li.open(lo, "limit") ||
+      !re.open(ro, "remaining") || !rt.open(to, "reset_time") ||
+      !er.open(eo, "err"))
+    return nullptr;
+  Py_ssize_t n = st.size();
+  if (li.size() != n || re.size() != n || rt.size() != n || er.size() != n) {
+    PyErr_SetString(PyExc_ValueError, "response columns differ in length");
+    return nullptr;
+  }
+  PyObject* seq = PySequence_Fast(offs, "offsets: a sequence of int expected");
+  if (!seq) return nullptr;
+  std::vector<Py_ssize_t> bounds((size_t)PySequence_Fast_GET_SIZE(seq));
+  for (size_t k = 0; k < bounds.size(); k++) {
+    bounds[k] = PyNumber_AsSsize_t(
+        PySequence_Fast_GET_ITEM(seq, (Py_ssize_t)k), PyExc_OverflowError);
+    if ((bounds[k] == -1 && PyErr_Occurred()) || bounds[k] < 0 ||
+        bounds[k] > n || (k && bounds[k] < bounds[k - 1])) {
+      if (!PyErr_Occurred())
+        PyErr_SetString(PyExc_ValueError,
+                        "offsets must ascend within the columns");
+      Py_DECREF(seq);
+      return nullptr;
+    }
+  }
+  Py_DECREF(seq);
+  size_t entries = bounds.empty() ? 0 : bounds.size() - 1;
+  auto strings = error_strings;  // this call's table (see above)
+
+  std::string out;  // every entry's bytes, one after another
+  std::vector<size_t> ends(entries);
+  std::vector<Py_ssize_t> over(entries, 0);
+  bool unknown_code = false;
+  Py_BEGIN_ALLOW_THREADS;
+  if (entries) out.reserve((size_t)(bounds.back() - bounds[0]) * 24);
+  std::string item;
+  for (size_t k = 0; k < entries; k++) {
+    for (Py_ssize_t i = bounds[k]; i < bounds[k + 1]; i++) {
+      int64_t code = er.at(i), status = st.at(i);
+      const std::string* msg = nullptr;
+      if (code) {
+        if (!strings || code < 0 || (size_t)code >= strings->size()) {
+          unknown_code = true;
+          continue;
+        }
+        msg = &(*strings)[(size_t)code];
+      }
+      over[k] += status == 1;
+      put_response(out, item, status, li.at(i), re.at(i), rt.at(i), msg,
+                   now_ms);
+    }
+    ends[k] = out.size();
+  }
+  Py_END_ALLOW_THREADS;
+  if (unknown_code) {
+    PyErr_SetString(PyExc_ValueError,
+                    "err column holds a code with no error string");
+    return nullptr;
+  }
+  PyObject* bodies = PyList_New((Py_ssize_t)entries);
+  PyObject* counts = PyList_New((Py_ssize_t)entries);
+  if (!bodies || !counts) { Py_XDECREF(bodies); Py_XDECREF(counts); return nullptr; }
+  size_t start = 0;
+  for (size_t k = 0; k < entries; k++) {
+    PyObject* b = PyBytes_FromStringAndSize(out.data() + start,
+                                            (Py_ssize_t)(ends[k] - start));
+    PyObject* c = PyLong_FromSsize_t(over[k]);
+    if (!b || !c) {
+      Py_XDECREF(b); Py_XDECREF(c); Py_DECREF(bodies); Py_DECREF(counts);
+      return nullptr;
+    }
+    PyList_SET_ITEM(bodies, (Py_ssize_t)k, b);
+    PyList_SET_ITEM(counts, (Py_ssize_t)k, c);
+    start = ends[k];
+  }
+  return Py_BuildValue("(NN)", bodies, counts);
 }
 
 // fingerprint64(data: bytes) -> int — parity check hook for tests
@@ -592,6 +761,11 @@ static PyMethodDef methods[] = {
      "GetRateLimitsReq wire bytes -> column buffers"},
     {"encode_responses", encode_responses, METH_VARARGS,
      "response columns -> GetRateLimitsResp wire bytes"},
+    {"encode_responses_many", encode_responses_many, METH_VARARGS,
+     "a chunk's response columns + entry offsets -> each entry's "
+     "GetRateLimitsResp wire bytes and OVER_LIMIT count"},
+    {"set_error_strings", set_error_strings, METH_O,
+     "the error code -> wire string table encode_responses_many uses"},
     {"fingerprint64", fingerprint64, METH_VARARGS, "seeded 63-bit XXH64"},
     {"fnv1a32", fnv1a32_py, METH_VARARGS, "fnv1a 32-bit"},
     {nullptr, nullptr, 0, nullptr}};

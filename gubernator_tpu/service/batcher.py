@@ -15,9 +15,12 @@ Three serving-plane mechanics live here (docs/latency.md "Serving plane"):
   past the cap, callers await drain progress (backpressure) instead of
   growing an unbounded queue whose tail latency nobody sees until OOM.
 * **N workers.** Each worker forms a chunk and hands it to the runner; the
-  dispatch's one crossing back onto the loop (EngineRunner._run_chain)
-  slices the coalesced response onto its callers' futures, and the worker
-  goes on to the next chunk — so dispatch K+1 forms while K is in flight,
+  dispatch's last worker thread encodes the response bytes of every caller
+  that asked for them (`check(..., encoded=True)`: one native call, the
+  `encode` stage), its one crossing back onto the loop
+  (EngineRunner._run_chain) hands those bytes, or slices of the coalesced
+  response, to its callers' futures, and the worker goes on to the next
+  chunk — so dispatch K+1 forms while K is in flight,
   keeping the engine's depth-N pipeline saturated instead of starving it
   behind one event-loop task.
 * **Adaptive window.** Under load the window closes on accumulated
@@ -66,7 +69,7 @@ from gubernator_tpu.ops.batch import (
 from gubernator_tpu.ops.engine import ms_now
 from gubernator_tpu.ops.wire import DELTA_BIAS
 from gubernator_tpu.service import deadline as deadline_mod
-from gubernator_tpu.service.wire import WireBatch
+from gubernator_tpu.service.wire import WireBatch, encode_responses_many
 from gubernator_tpu.types import (
     CASCADE_LEVEL_MASK,
     CASCADE_LEVEL_SHIFT,
@@ -135,10 +138,10 @@ class _Entry:
     """One enqueued batch awaiting dispatch."""
 
     __slots__ = ("payload", "fut", "t_enq", "span", "rows", "cost", "tier",
-                 "bucket", "deadline", "stamp_lo", "stamp_hi")
+                 "bucket", "deadline", "stamp_lo", "stamp_hi", "encoded")
 
     def __init__(self, payload, fut, t_enq, span, rows, cost, tier, bucket,
-                 deadline, stamp_lo, stamp_hi):
+                 deadline, stamp_lo, stamp_hi, encoded):
         self.payload = payload
         self.fut = fut
         self.t_enq = t_enq  # perf_counter at enqueue
@@ -152,6 +155,8 @@ class _Entry:
         # where they would leave the compact wire's delta budget
         self.stamp_lo = stamp_lo
         self.stamp_hi = stamp_hi
+        # the caller takes its answer as GetRateLimitsResp bytes
+        self.encoded = encoded
 
 
 class Batcher:
@@ -258,6 +263,8 @@ class Batcher:
         # later copies of a key sent more than once in the chunk
         self.split_dispatches = 0
         self.ring_dispatches = 0  # all-wire chunk staged into the ring
+        # entries answered with the bytes their dispatch's encode link wrote
+        self.encoded_requests = 0
         self.adaptive_closes = 0  # window closed on rows/bytes/idle engine
         self.window_expires = 0  # window closed on the wall-clock ceiling
         # adaptive-close reason split (the /v1/debug/pipeline payload):
@@ -275,10 +282,14 @@ class Batcher:
         self.priority_inversions = 0
 
     # ------------------------------------------------------------- enqueue
-    async def check(self, payload, now_ms: Optional[int] = None) -> ResponseColumns:
+    async def check(
+        self, payload, now_ms: Optional[int] = None, encoded: bool = False
+    ) -> "ResponseColumns | bytes":
         """Enqueue a column batch (RequestColumns) or a pre-parsed wire
         batch (service/wire.WireBatch); resolves with this batch's slice of
-        the coalesced response."""
+        the coalesced response or, with `encoded`, with those rows as
+        GetRateLimitsResp bytes: written off the loop by the dispatch that
+        answers them, and their OVER_LIMIT rows counted there."""
         t_in = time.perf_counter()
         now = now_ms if now_ms is not None else ms_now()
         # stamp unset created_at at ENQUEUE time (reference stamps at request
@@ -322,7 +333,7 @@ class Batcher:
         entry = _Entry(
             payload, loop.create_future(), time.perf_counter(),
             tracing.current_span(), rows, cost, tier, bucket, deadline,
-            stamp_lo, stamp_hi,
+            stamp_lo, stamp_hi, encoded,
         )
         # per-tenant fair admission: once the queue is under pressure
         # (≥ half full), no tenant bucket may hold more than its share of
@@ -427,7 +438,7 @@ class Batcher:
             return knob
         return min(knob, inbound)
 
-    def _shed(self, entry: _Entry, reason: str) -> ResponseColumns:
+    def _shed(self, entry: _Entry, reason: str) -> "ResponseColumns | bytes":
         """Answer an entry WITHOUT dispatching it: a fast per-item
         OVER_LIMIT-style overload row (ERR_OVERLOAD, reset_time = the
         suggested retry instant). The caller's RPC succeeds — overload is
@@ -445,6 +456,8 @@ class Batcher:
                 reason=reason, tier=str(entry.tier)
             ).inc(entry.rows)
         rc = self._overload_columns(entry.payload)
+        if entry.encoded:
+            rc = self._encode(rc, (0, entry.rows))[0]
         if not entry.fut.done():
             entry.fut.set_result(rc)
         return rc
@@ -679,6 +692,34 @@ class Batcher:
             "dispatch_wait", self.metrics, max(0.0, dt - disp.work_s)
         )
 
+    def _encode(self, rc: ResponseColumns, bounds) -> List[bytes]:
+        """The GetRateLimitsResp bytes of the entries whose rows lie between
+        consecutive `bounds` of `rc` (`retry_after_ms` counts from the clock
+        read here); their OVER_LIMIT rows reach the counter in one step."""
+        bodies, over = encode_responses_many(rc, bounds, ms_now())
+        over = sum(over)
+        if over and self.metrics is not None:
+            self.metrics.over_limit_counter.inc(over)
+        return bodies
+
+    def _encode_chunk(self, rc: ResponseColumns, batch) -> list:
+        """Per entry of a dispatched chunk, its response bytes where it asked
+        for them (None where not). Such entries that follow one another
+        share one native call: a chunk of plain RPCs is one."""
+        bodies: list = [None] * len(batch)
+        runs = []  # (index of the run's first entry, its row bounds)
+        off, open_run = 0, False
+        for i, e in enumerate(batch):
+            if e.encoded:
+                if not open_run:
+                    runs.append((i, [off]))
+                runs[-1][1].append(off + e.rows)
+            open_run = e.encoded
+            off += e.rows
+        for first, bounds in runs:
+            bodies[first:first + len(bounds) - 1] = self._encode(rc, bounds)
+        return bodies
+
     async def _dispatch(self, batch) -> None:
         t0 = time.perf_counter()
         self._inflight += 1
@@ -698,6 +739,24 @@ class Batcher:
         payloads = [e.payload for e in batch]
         wire = all(isinstance(p, WireBatch) for p in payloads)
         answered = ringed = False
+        n_encoded = sum(e.encoded for e in batch)
+        bodies = None  # per entry, what `encode` wrote for it
+
+        def encode(rc):
+            """The dispatch's last worker link (`Dispatch.tail`: the fetch
+            thread that holds the answer, after `fetch`): the bytes of
+            every entry that takes its answer encoded, before the crossing
+            back. What it raises reaches every caller of the chunk as any
+            link's exception does."""
+            nonlocal bodies
+            with tracing.stage(
+                "encode", self.metrics, disp=disp, entries=n_encoded
+            ):
+                bodies = self._encode_chunk(rc, batch)
+            return rc
+
+        if n_encoded:
+            disp.tail = encode
 
         def answer(rc, exc, fused) -> None:
             """The dispatch's end, on the loop thread. The runner calls it
@@ -708,6 +767,15 @@ class Batcher:
             if answered:
                 return
             answered = True
+            disp.tail = None  # it holds `disp`: no cycle is left to the collector
+            on_worker = bodies is not None
+            if exc is None and n_encoded and not on_worker:
+                # the chunk came back as columns with no link run behind
+                # them (the ring's fused drain): encoded here, on the loop
+                try:
+                    encode(rc)
+                except Exception as e:
+                    exc = e
             self._inflight -= 1
             self._note_drained(sum(e.cost for e in batch))
             if self._full is not None:
@@ -757,9 +825,14 @@ class Batcher:
                     links=req_spans,
                 )
             off = 0
-            for e in batch:
-                sl = slice(off, off + e.rows)
-                if not e.fut.done():
+            for i, e in enumerate(batch):
+                if e.fut.done():  # its caller was cancelled
+                    pass
+                elif e.encoded:
+                    e.fut.set_result(bodies[i])
+                    self.encoded_requests += on_worker
+                else:
+                    sl = slice(off, off + e.rows)
                     e.fut.set_result(
                         ResponseColumns(
                             status=rc.status[sl],

@@ -71,15 +71,10 @@ FORWARD_RETRIES = 5  # reference asyncRequest retries (gubernator.go:333-359)
 _ROUTED_BEHAVIOR = int(Behavior.GLOBAL) | int(Behavior.MULTI_REGION)
 
 
-def _encode_counted(status, limit, remaining, reset, errors, now, err=None):
-    """What the encode hop runs for an answered raw RPC (on a door-pool
-    thread for a big one): the response bytes and the OVER_LIMIT rows for
-    the counter. An engine error column `err` is folded into the sparse
-    `errors` here, where a scan of the rows does not hold the event loop."""
-    if err is not None and err.any():
-        errors = {
-            int(i): ERROR_STRINGS[int(err[i])] for i in np.flatnonzero(err)
-        }
+def _encode_counted(status, limit, remaining, reset, errors, now):
+    """What the encode hop runs for a raw RPC of the general path (on a
+    door-pool thread for a big one): the response bytes and the OVER_LIMIT
+    rows for the counter."""
     out = encode_response_columns(status, limit, remaining, reset, errors, now)
     return out, int(np.count_nonzero(status == int(pb.OVER_LIMIT)))
 
@@ -1022,11 +1017,13 @@ class Daemon:
         wire-encodable batches against a compact-wire local engine) no
         column re-pack either: the batcher stages the parser's lanes
         directly into the dispatch grid. Big buffers parse on the door pool
-        (the C parser drops the GIL, so N workers parse concurrently); only
-        items that must travel as messages (forwards, GLOBAL/MULTI_REGION
-        queue entries) materialize lazily from their wire spans. Falls back
-        to the pb path when the extension is unavailable or an event channel
-        needs full request objects."""
+        (the C parser drops the GIL, so N workers parse concurrently): the
+        one hop of a plain RPC, whose response bytes its dispatch writes
+        (`_serve_plain`). Only items that must travel as messages (forwards,
+        GLOBAL/MULTI_REGION queue entries) materialize lazily from their
+        wire spans, and only such an RPC's answer takes a second hop, to
+        the encoder. Falls back to the pb path when the extension is
+        unavailable or an event channel needs full request objects."""
         from gubernator_tpu.service.wire import wire_batch_from_wire
 
         t_req = time.perf_counter()
@@ -1061,6 +1058,7 @@ class Daemon:
             # the request's budget (docs/observability.md): first byte in
             # hand to response bytes returned, and the part of it spent
             # waiting for a door-pool worker and for the loop to resume us
+            # (the parse hop; on the general path the encode hop too)
             tracing.observe("door_wait", self.metrics, door_wait_s, token.span)
             tracing.observe(
                 "request", self.metrics, time.perf_counter() - t_req, token.span
@@ -1280,30 +1278,35 @@ class Daemon:
         """An RPC whose rows are all valid, all this daemon's and free of
         GLOBAL and MULTI_REGION (the parser's summary says so; no peers, no
         force_global): the parser's batch goes to the batcher as it is, in
-        this coroutine, and the answer's columns go to the encoder as they
-        are. Nothing here depends on the number of rows."""
+        this coroutine, and comes back as the response bytes: the dispatch
+        that answered it encoded them on its fetch thread, with those of
+        every other plain RPC of its chunk, and counted their OVER_LIMIT
+        rows (`Batcher._dispatch`). No second door hop, no wait for one,
+        and nothing here depends on the number of rows."""
         self.plain_rpcs += 1
         tracing.observe(
             "route", self.metrics, time.perf_counter() - t_route,
             tracing.current_span(),
         )
-        rc = await self.batcher.check(wb)
-        return await self._encode_raw(
-            wb.rows, time.perf_counter(), rc.status, rc.limit, rc.remaining,
-            rc.reset_time, None, rc.err,
+        out = await self.batcher.check(wb, encoded=True)
+        # the answer in hand is the response: `respond` keeps its line in
+        # the request's budget at what is left of it, this return
+        t_answered = time.perf_counter()
+        tracing.observe(
+            "respond", self.metrics, time.perf_counter() - t_answered,
+            tracing.current_span(),
         )
+        return out, 0.0
 
     async def _encode_raw(
-        self, n, t_answered, status, limit, remaining, reset, errors, err=None
+        self, n, t_answered, status, limit, remaining, reset, errors
     ) -> "tuple[bytes, float]":
-        """The tail of both raw paths: `respond` ends, the encode hop, the
-        over-limit counter. `err` is the engine's error column where nobody
-        has folded it into `errors` yet (the plain path)."""
+        """The tail of the general raw path: `respond` ends, the encode hop,
+        the over-limit counter."""
         now = self.now_ms()  # retry_after_ms metadata basis (denied rows)
         if t_answered:
-            # answer in hand → encoder's start: on the general path placing
-            # the rows, the gather's wake-up of this coroutine, GLOBAL/region
-            # queueing; on the plain path this call
+            # answer in hand → encoder's start: placing the rows, the
+            # gather's wake-up of this coroutine, GLOBAL/region queueing
             tracing.observe(
                 "respond", self.metrics, time.perf_counter() - t_answered,
                 tracing.current_span(),
@@ -1313,7 +1316,7 @@ class Daemon:
         (out_bytes, over), encode_s, wait_s = await self._through_door(
             "encode", n * 8 >= self.DOOR_OFFLOAD_BYTES,
             _encode_counted,
-            status, limit, remaining, reset, errors, now, err,
+            status, limit, remaining, reset, errors, now,
         )
         if over:
             self.metrics.over_limit_counter.inc(over)
@@ -1728,11 +1731,15 @@ class Daemon:
             # completion (EngineRunner._run_chain); over batcher.dispatches
             # it says how often a dispatch came back to the loop
             "runner": {"loop_trips": self.runner.loop_trips},
-            # natively parsed RPCs, and how many of them crossed the loop
+            # natively parsed RPCs, how many of them crossed the loop
             # thread with no per-row work (all rows valid, local and free
-            # of GLOBAL/MULTI_REGION: _serve_plain)
+            # of GLOBAL/MULTI_REGION: _serve_plain), and how many of those
+            # were answered with bytes that their dispatch's encode link
+            # wrote on a worker thread (all but the shed, and the chunks
+            # of the request ring's fused drain)
             "daemon": {
                 "raw_rpcs": self.raw_rpcs, "plain_rpcs": self.plain_rpcs,
+                "dispatch_encoded_rpcs": self.batcher.encoded_requests,
             },
             # which request parser serves the door: "built"/"reused" = the
             # native extension (compiled by this process / found with a

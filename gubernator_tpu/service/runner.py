@@ -188,7 +188,7 @@ class EngineRunner:
         ):
             return await self.check_columns(
                 concat_columns(parts), now_ms=now_ms, launch_path=launch_path,
-                done=done,
+                done=done, disp=disp,
             )
         return await self._run_chain(
             ((self._prep, lambda _: self._stage_columns(parts, now_ms, disp)),
@@ -255,8 +255,8 @@ class EngineRunner:
         """The issue and finish links of a pipelined dispatch (`_run_chain`
         runs them after the prepare link): ISSUE on the engine thread
         (enqueue kernel launches, no fetch), FINISH on a fetch worker
-        (materialize outputs, rare fixups back on the engine thread), stats
-        folded in on the engine thread."""
+        (materialize outputs, rare fixups back on the engine thread, then
+        the dispatch's `tail`), stats folded in on the engine thread."""
         from gubernator_tpu.ops.engine import (
             finish_check_columns,
             issue_check_columns,
@@ -284,7 +284,7 @@ class EngineRunner:
                 rc, delta = finish_check_columns(self.engine, pending, fixup)
             # fire-and-forget, engine thread
             self._exec.submit(self._apply, [delta], disp)
-            return rc
+            return rc if disp is None or disp.tail is None else disp.tail(rc)
 
         return (self._exec, issue), (self._fetch, finish)
 
@@ -424,10 +424,10 @@ class EngineRunner:
 
     async def check_columns(
         self, cols: RequestColumns, now_ms: Optional[int] = None,
-        launch_path: str = "xla", done=None,
+        launch_path: str = "xla", done=None, disp=None,
     ) -> ResponseColumns:
-        """The serial path: the whole check is one engine-thread job, a
-        chain of one link. `done` as in `check`."""
+        """The serial path: the whole check, and the dispatch's `tail`, is one
+        engine-thread job, a chain of one link. `done` as in `check`."""
 
         def run(_):
             rc = self.engine.check_columns(cols, now_ms=now_ms)
@@ -438,7 +438,7 @@ class EngineRunner:
                 gs = getattr(self.engine, "global_stats", None)
                 if gs is not None:
                     self.metrics.observe_global(gs)
-            return rc
+            return rc if disp is None or disp.tail is None else disp.tail(rc)
 
         return await self._run_chain(((self._exec, run),), [cols], done)
 

@@ -525,6 +525,26 @@ def encode_response_columns(
     )
 
 
+def encode_responses_many(
+    rc: ResponseColumns, offsets: Sequence[int], now_ms: Optional[int] = None
+) -> Tuple[List[bytes], List[int]]:
+    """One dispatch's answers in one native call: `rc` is a coalesced
+    chunk's response columns as the engine hands them over (any integer
+    width: widened in C, not copied here), `offsets` the E+1 ascending row
+    bounds of E entries. For each entry, the bytes `encode_response_columns`
+    gives for its slice of the columns with the error strings of its `err`
+    codes, and its number of OVER_LIMIT rows. The GIL is released for the
+    whole assembly, so the dispatch's fetch thread encodes beside the loop."""
+    from gubernator_tpu import native
+
+    m = native.load()
+    assert m is not None, "native module required (guarded by columns_from_wire)"
+    return m.encode_responses_many(
+        rc.status, rc.limit, rc.remaining, rc.reset_time, rc.err, offsets,
+        -1 if now_ms is None else int(now_ms),
+    )
+
+
 # ----------------------------------------- inter-slice GLOBAL sync codec
 # The PR-5 compact lane layout applied to the cross-daemon hit sync
 # (docs/architecture.md "Pod-scale topology"): numeric config rides ONE

@@ -151,12 +151,16 @@ class Dispatch:
     spans (None without an exporter), and `work_s` adds up the intervals
     timed under it, so the batcher can state the dispatch's self time
     (`dispatch_wait`: the hand-offs from thread to thread and the one
-    crossing back onto the loop)."""
+    crossing back onto the loop). `tail`, where the batcher sets one, is
+    what the worker thread that holds the dispatch's answer does with it
+    before that crossing (`tail(rc)` gives what crosses): the batcher's
+    `encode` stage."""
 
     seq: int
     rows: int
     span: Optional[SpanContext] = None
     work_s: float = 0.0
+    tail: Optional[Callable] = None
 
 
 # The dispatch stage (put, put_miss, issue, fetch) that is open on this

@@ -199,3 +199,108 @@ async def test_raw_path_force_global():
         await wait_for(installed, timeout_s=15)
     finally:
         await c.stop()
+
+
+# ------------------------------------------------ encode_responses_many
+# A dispatch's answers in one call (service/batcher.py's encode link):
+# byte for byte what encode_response_columns gives for each entry's slice
+# with the error strings of its `err` codes, and the entry's OVER_LIMIT rows.
+
+
+def _response_columns(n, seed, wide=True, over=0.0, err=0.0, leaky=False):
+    """A chunk's response columns as an engine hands them over: status
+    int32, err int8, the rest int64 (`wide`) or all of them int32."""
+    from gubernator_tpu.ops.batch import ERROR_STRINGS, ResponseColumns
+
+    rng = np.random.default_rng(seed)
+    big = np.int64 if wide else np.int32
+    top = 1 << (45 if wide else 30)
+    reset = rng.integers(1, top, n)
+    if leaky:  # a leaky bucket's reset_time: the next token, often past
+        reset = MANY_NOW + rng.integers(-5_000, 5_000, n)
+    return ResponseColumns(
+        status=(rng.random(n) < over).astype(np.int32),
+        limit=rng.integers(0, top, n).astype(big),
+        remaining=rng.integers(0, top, n).astype(big),
+        reset_time=reset.astype(big),
+        err=np.where(
+            rng.random(n) < err, rng.integers(1, len(ERROR_STRINGS), n), 0
+        ).astype(np.int8),
+    )
+
+
+MANY_NOW = 1_700_000_000_000
+MANY_CASES = {
+    # name: (columns' arguments, offsets, now_ms)
+    "one_entry": (dict(n=40, seed=1), [0, 40], None),
+    "many_entries": (dict(n=3000, seed=2), [0, 1000, 1001, 2000, 3000], None),
+    "empty_entry": (dict(n=10, seed=3), [0, 4, 4, 10], None),
+    "no_entry": (dict(n=10, seed=3), [], None),
+    "int32_columns": (dict(n=64, seed=4, wide=False, over=0.3), [0, 1, 64], 77),
+    "over_limit_without_now": (dict(n=200, seed=5, over=0.5), [0, 120, 200], None),
+    "over_limit_with_now": (dict(n=200, seed=5, over=0.5), [0, 120, 200], 1 << 44),
+    "err_codes": (dict(n=300, seed=6, over=0.2, err=0.3), [0, 7, 150, 300], 9),
+    "leaky_reset_below_now": (
+        dict(n=500, seed=7, over=0.5, leaky=True), [0, 250, 500], MANY_NOW
+    ),
+    "offsets_not_from_zero": (dict(n=100, seed=8, over=0.4), [17, 30, 90], 5),
+}
+
+
+@pytest.mark.parametrize("case", sorted(MANY_CASES))
+def test_encode_many_matches_encode_of_each_slice(case):
+    from gubernator_tpu.ops.batch import ERROR_STRINGS
+    from gubernator_tpu.service.wire import (
+        encode_response_columns,
+        encode_responses_many,
+    )
+
+    kw, offsets, now = MANY_CASES[case]
+    rc = _response_columns(**kw)
+    bodies, over = encode_responses_many(rc, offsets, now)
+    assert len(bodies) == len(over) == max(len(offsets) - 1, 0)
+    for k, (lo, hi) in enumerate(zip(offsets, offsets[1:])):
+        errors = {
+            int(i): ERROR_STRINGS[int(rc.err[lo + i])]
+            for i in np.flatnonzero(rc.err[lo:hi])
+        }
+        want = encode_response_columns(
+            rc.status[lo:hi], rc.limit[lo:hi], rc.remaining[lo:hi],
+            rc.reset_time[lo:hi], errors, now,
+        )
+        assert bodies[k] == want, (case, k)
+        assert over[k] == int((rc.status[lo:hi] == pb.OVER_LIMIT).sum())
+        assert len(pb.GetRateLimitsResp.FromString(bodies[k]).responses) == hi - lo
+    if now is not None and kw.get("over"):
+        assert b"retry_after_ms" in b"".join(bodies)
+    if kw.get("leaky"):  # a reset_time behind the clock waits 0 ms
+        waits = {
+            r.metadata["retry_after_ms"]
+            for b in bodies
+            for r in pb.GetRateLimitsResp.FromString(b).responses
+            if r.status == pb.OVER_LIMIT
+        }
+        assert "0" in waits and len(waits) > 1
+
+
+def test_encode_many_takes_strided_columns_and_refuses_bad_input():
+    """A column that is a view with a stride is read where it lies; offsets
+    that leave the columns or descend, columns of unlike length, a code
+    with no error string and a column that holds no integers are refused."""
+    from gubernator_tpu.service.wire import encode_responses_many
+
+    rc = _response_columns(n=64, seed=9, over=0.5, err=0.2)
+    strided = type(rc)(*(np.repeat(c, 2)[::2] for c in rc))
+    assert not strided.limit.flags.c_contiguous
+    assert encode_responses_many(strided, [0, 30, 64], 5) == (
+        encode_responses_many(rc, [0, 30, 64], 5)
+    )
+    for bad in ([0, 65], [10, 5], [-1, 3]):
+        with pytest.raises(ValueError):
+            encode_responses_many(rc, bad)
+    with pytest.raises(ValueError):
+        encode_responses_many(rc._replace(limit=rc.limit[:-1]), [0, 1])
+    with pytest.raises(ValueError):
+        encode_responses_many(rc._replace(err=np.full(64, 99, np.int8)), [0, 64])
+    with pytest.raises(TypeError):
+        encode_responses_many(rc._replace(limit=rc.limit.astype(float)), [0, 1])

@@ -486,15 +486,16 @@ def test_stage_primitive_feeds_histogram_dispatch_and_exporter():
 
 @async_test
 async def test_request_and_dispatch_budgets_close():
-    """The stages account for the time they claim to: a raw RPC's lines
-    (parse, route, batch_wait, respond, encode) cover >= 90% of `request`
-    and never more than it, `door_wait` is a part of parse+encode, and a dispatch is
-    exactly its work stages plus its self time."""
+    """The stages account for the time they claim to: a plain raw RPC's
+    lines (parse, route, batch_wait, respond) cover >= 90% of `request` and
+    never more than it, `door_wait` is a part of parse, and a dispatch is
+    exactly its work stages (the encode of its callers' answers among them)
+    plus its self time."""
     from gubernator_tpu.service.daemon import Daemon
 
     d = await Daemon.spawn(daemon_config())
     try:
-        # small RPCs parse inline, 200-item RPCs cross the door pool twice
+        # small RPCs parse inline, 200-item RPCs cross the door pool once
         assert len(_raw_request("x", 200)) >= d.DOOR_OFFLOAD_BYTES
         for n in (1, 200, 7, 200):  # compile the shapes outside the count
             await d.get_rate_limits_raw(_raw_request(f"w{n}", n))
@@ -518,15 +519,16 @@ async def test_request_and_dispatch_budgets_close():
     assert delta("request", 1) == 300
     assert delta("door_wait", 1) == 300 and delta("route", 1) == 300
     request = delta("request")
-    lines = sum(delta(x) for x in
-                ("parse", "route", "batch_wait", "respond", "encode"))
+    lines = sum(delta(x) for x in ("parse", "route", "batch_wait", "respond"))
     assert 0.9 * request <= lines <= request
     assert delta("respond", 1) == 300
-    assert 0.0 < delta("door_wait") <= delta("parse") + delta("encode")
+    assert 0.0 < delta("door_wait") <= delta("parse")
     n_disp = b1["dispatches"] - b0["dispatches"]
     assert n_disp >= 30 and b1["requests"] - b0["requests"] == 300
     assert delta("dispatch", 1) == delta("dispatch_wait", 1) == n_disp
-    work = sum(delta(x) for x in ("put", "put_miss", "issue", "fetch"))
+    assert delta("encode", 1) == n_disp  # one a dispatch, inside batch_wait
+    work = sum(delta(x) for x in
+               ("put", "put_miss", "issue", "fetch", "encode"))
     assert work + delta("dispatch_wait") == pytest.approx(
         delta("dispatch"), rel=0.01
     )

@@ -1,7 +1,7 @@
 """The raw handler's plain path (service/daemon.py:_serve_plain): an RPC whose
 rows are all valid, all local and free of GLOBAL/MULTI_REGION goes parser →
-batcher → encoder with no per-row work on the event-loop thread, on the
-strength of the summary the native parser reduced over the rows.
+batcher → its dispatch's encoder with no per-row work on the event-loop
+thread, on the strength of the summary the native parser reduced over the rows.
 
 Contract: the plain path is a pure perf change. The same bodies answered by
 the general path (forced by each thing that disqualifies an RPC) give the
@@ -102,8 +102,15 @@ async def _spawn():
         daemon_config(http_address=""),
         engine=LocalEngine(capacity=8192, wire="compact"),
     )
-    d.now_ms = lambda: NOW + 7  # retry_after_ms basis
+    d.now_ms = lambda: NOW + 7  # retry_after_ms basis (the general path's)
     return d
+
+
+async def _over_limit_count(d) -> float:
+    """The daemon's OVER_LIMIT counter once the engine thread has folded in
+    what it owes (a dispatch's stats follow its answer)."""
+    await d.runner.live_count()
+    return d.metrics.over_limit_counter._value.get()
 
 
 @pytest.mark.parametrize("rc_err_row", [False, True], ids=["", "rc_err_row"])
@@ -113,14 +120,13 @@ async def test_plain_path_bytes_equal_general_path(trigger, rc_err_row, monkeypa
     """The same rounds through a daemon that serves them on the plain path
     and through one that is forced onto the general path: the plain rows'
     response bytes are equal, and the counters say which path each took."""
-    monkeypatch.setattr(batcher_mod, "ms_now", lambda: NOW + 3)  # the enqueue's stamp
-    counted = []  # OVER_LIMIT rows each encode hop reported, both daemons in turn
-    encode = daemon_mod._encode_counted
+    # the enqueue's stamp, and the plain path's retry_after_ms basis
+    monkeypatch.setattr(batcher_mod, "ms_now", lambda: NOW + 7)
+    hops = []  # the general path's encode hops: the plain path takes none
 
-    def spy(*a):
-        out = encode(*a)
-        counted.append(out[1])
-        return out
+    def spy(*a, encode=daemon_mod._encode_counted):
+        hops.append(a)
+        return encode(*a)
 
     monkeypatch.setattr(daemon_mod, "_encode_counted", spy)
     extra, arrange = TRIGGERS[trigger]
@@ -143,9 +149,14 @@ async def test_plain_path_bytes_equal_general_path(trigger, rc_err_row, monkeypa
         assert answers[1].metadata["retry_after_ms"] == str(60_000 - 7)
         assert (d_plain.raw_rpcs, d_plain.plain_rpcs) == (3, 3)
         assert (d_gen.raw_rpcs, d_gen.plain_rpcs) == (3, 0)
-        assert counted[0::2] == counted[1::2] and counted[-1] > 0
+        assert len(hops) == 3  # d_gen's
+        over = await _over_limit_count(d_plain)
+        assert over == await _over_limit_count(d_gen) and over > 0
         pipe = d_plain.debug_pipeline()["daemon"]
-        assert pipe == {"raw_rpcs": 3, "plain_rpcs": 3}
+        assert pipe == {
+            "raw_rpcs": 3, "plain_rpcs": 3, "dispatch_encoded_rpcs": 3,
+        }
+        assert d_gen.debug_pipeline()["daemon"]["dispatch_encoded_rpcs"] == 0
     finally:
         await d_plain.close()
         await d_gen.close()
