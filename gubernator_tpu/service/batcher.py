@@ -545,6 +545,9 @@ class Batcher:
 
     # ------------------------------------------------------------- workers
     async def _run(self) -> None:
+        # when this worker's last dispatch was answered: a chunk whose
+        # oldest entry is older waited for a slot, not for company
+        t_free = 0.0
         while not self._closed:
             if not self._pending:
                 self._wake.clear()
@@ -555,7 +558,7 @@ class Batcher:
             chunk = self._take_chunk(await self._window())
             if chunk is None:
                 continue
-            await self._dispatch(chunk)
+            t_free = await self._dispatch(chunk, t_free)
 
     async def _window(self) -> str:
         """Hold the coalesce window open until it should close: on
@@ -682,15 +685,17 @@ class Batcher:
         return chunk
 
     # ------------------------------------------------------------ dispatch
-    def _observe_dispatch(self, t0: float, disp) -> None:
+    def _observe_dispatch(self, t0: float, disp) -> float:
         """The dispatch's budget lines: the whole of it, and its self time
         (what put/issue/fetch did not cover: the hand-offs prep → engine →
-        fetch thread, and the one crossing back onto the loop)."""
+        fetch thread, and the one crossing back onto the loop). Returns the
+        clock it read: the dispatch's end."""
         dt = time.perf_counter() - t0
         tracing.observe("dispatch", self.metrics, dt)
         tracing.observe(
             "dispatch_wait", self.metrics, max(0.0, dt - disp.work_s)
         )
+        return t0 + dt
 
     def _encode(self, rc: ResponseColumns, bounds) -> List[bytes]:
         """The GetRateLimitsResp bytes of the entries whose rows lie between
@@ -720,7 +725,12 @@ class Batcher:
             bodies[first:first + len(bounds) - 1] = self._encode(rc, bounds)
         return bodies
 
-    async def _dispatch(self, batch) -> None:
+    async def _dispatch(self, batch, t_free: float = 0.0) -> float:
+        """One chunk through the runner and back to its callers, begun in
+        the callback that closed it (`_take_chunk`): `t0` below is the
+        chunk's close. `t_free` is when the flush worker that took the
+        chunk came free: what its dispatch before returned, the clock its
+        answer read."""
         t0 = time.perf_counter()
         self._inflight += 1
         self.dispatches += 1
@@ -732,10 +742,12 @@ class Batcher:
         # it — minted only when spans actually export. Its number rides on
         # the profiler spans of its stages either way.
         disp_span = tracing.new_span() if tracing.exporter is not None else None
-        disp = tracing.Dispatch(
-            self.dispatches, sum(e.rows for e in batch), disp_span
-        )
         oldest = min(e.t_enq for e in batch)
+        disp = tracing.Dispatch(
+            self.dispatches, sum(e.rows for e in batch), disp_span,
+            origin=(oldest, t_free, t0),
+        )
+        t_answered = t0
         payloads = [e.payload for e in batch]
         wire = all(isinstance(p, WireBatch) for p in payloads)
         answered = ringed = False
@@ -763,7 +775,7 @@ class Batcher:
             from the dispatch's one crossing back (EngineRunner._run_chain),
             so the callers' futures resolve in that same callback, before
             this worker's coroutine is resumed."""
-            nonlocal answered
+            nonlocal answered, t_answered
             if answered:
                 return
             answered = True
@@ -786,7 +798,7 @@ class Batcher:
                 for e in batch:
                     if not e.fut.done():
                         e.fut.set_exception(exc)
-                self._observe_dispatch(t0, disp)
+                t_answered = self._observe_dispatch(t0, disp)
                 return
             if ringed:
                 self.ring_dispatches += 1
@@ -843,7 +855,7 @@ class Batcher:
                         )
                     )
                 off += e.rows
-            self._observe_dispatch(t0, disp)
+            t_answered = self._observe_dispatch(t0, disp)
 
         try:
             tracing.observe("queue", self.metrics, t0 - oldest, disp_span)
@@ -863,7 +875,8 @@ class Batcher:
                 try:
                     rc = await self.ring.submit(payloads, disp=disp)
                     ringed = True
-                    return answer(rc, None, 1)
+                    answer(rc, None, 1)
+                    return t_answered
                 except RingClosed:
                     pass
             if wire:
@@ -882,6 +895,7 @@ class Batcher:
             # raised before the runner's chain took the chunk; one raised in
             # the chain has been answered by its crossing back already
             answer(None, exc, 0)
+        return t_answered
 
     def debug(self) -> dict:
         """Live front-door state for /v1/debug/pipeline (docs/observability.md):

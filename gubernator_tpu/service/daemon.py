@@ -119,6 +119,10 @@ class Daemon:
         self.raw_rpcs = 0
         self.plain_rpcs = 0
         self.metrics = DaemonMetrics(metric_flags=conf.metric_flags)
+        # what the host's threads, the event loop and the collector were
+        # doing (tracing.HostClocks): read at scrape time, armed in spawn
+        self.host = tracing.HostClocks(self.metrics)
+        self.metrics.watch_host(self.host)
         if engine is not None:
             self.engine = engine
             if store is not None:
@@ -334,6 +338,7 @@ class Daemon:
         from gubernator_tpu.service.server import start_servers
 
         await start_servers(d)
+        d.host.start()  # the loop-lag ticker and the collector's callback
         d.global_manager.start()
         d.region_manager.start()
         if getattr(d.engine, "mesh_global", False):
@@ -1044,12 +1049,26 @@ class Daemon:
             raise ValueError(batch_too_large_error(self.conf.max_batch_size))
         self.metrics.concurrent_checks.inc()
         parent = tracing.parse_traceparent(traceparent) if traceparent else None
-        token = tracing.start_scope("GetRateLimits", parent)
+        # the request's span, where somebody can read it: an exporter or an
+        # embedder's hook, the client's own trace (an inbound traceparent),
+        # or a peer that a row may be forwarded to (`tracing.inject`). With
+        # none of the four, as on a lone daemon that exports nothing, an
+        # RPC mints no ids, no Scope and no contextvar token on the loop
+        # thread, and every reader below takes None.
+        token = span = None
+        if (
+            parent is not None
+            or tracing.exporter is not None
+            or tracing.span_hook is not None
+            or self._local_picker.size()
+        ):
+            token = tracing.start_scope("GetRateLimits", parent)
+            span = token.span
         # parse is a stage of THIS request (not of any batch dispatch):
         # observed under the request span so its exemplar resolves to the
         # request's own trace; the child span makes "where did my p99 go"
         # decomposable per request
-        tracing.observe("parse", self.metrics, parse_s, token.span)
+        tracing.observe("parse", self.metrics, parse_s, span)
         try:
             out, encode_wait_s = await self._route_raw(data, wb, ring, spans)
             door_wait_s += encode_wait_s
@@ -1059,11 +1078,12 @@ class Daemon:
             # hand to response bytes returned, and the part of it spent
             # waiting for a door-pool worker and for the loop to resume us
             # (the parse hop; on the general path the encode hop too)
-            tracing.observe("door_wait", self.metrics, door_wait_s, token.span)
+            tracing.observe("door_wait", self.metrics, door_wait_s, span)
             tracing.observe(
-                "request", self.metrics, time.perf_counter() - t_req, token.span
+                "request", self.metrics, time.perf_counter() - t_req, span
             )
-            tracing.end_scope(token)
+            if token is not None:
+                tracing.end_scope(token)
             self.metrics.concurrent_checks.dec()
 
     async def _through_door(self, stage: str, offload: bool, fn, *args):
@@ -1727,6 +1747,11 @@ class Daemon:
         eng = self.engine
         return {
             "batcher": self.batcher.debug(),
+            # CPU ms of the loop thread and of every worker pool from the
+            # threads' own clocks, the process's, the collector's pauses,
+            # and the monotonic clock of this reading: all monotone, so two
+            # scrapes give who was on a CPU between them (tracing.HostClocks)
+            "threads": self.host.snapshot(),
             # event-loop callbacks run for runner dispatches: one each, its
             # completion (EngineRunner._run_chain); over batcher.dispatches
             # it says how often a dispatch came back to the loop
@@ -2019,6 +2044,7 @@ class Daemon:
         if self._shutting_down:
             return
         self._shutting_down = True
+        self.host.stop()
         for t in (
             self._cert_watch_task, self._maintenance_task,
             self._global_sync_task, self._telemetry_task,
@@ -2064,6 +2090,7 @@ class Daemon:
             # this instance while its state moves
             self._leaving = True
         self._shutting_down = True  # live_check now fails → LBs de-register
+        self.host.stop()
         if self.conf.graceful_termination_delay_s > 0:
             # keep serving while load balancers notice the failing liveness
             # probe (reference daemon.go:389-391)

@@ -60,6 +60,32 @@ class _OtelSpanCollector:
             yield fam
 
 
+class _ThreadCpuCollector:
+    """gubernator_tpu_thread_cpu_seconds_total{thread}: CPU seconds of the
+    daemon's threads by pool (the event loop, door, prep, engine, fetch,
+    ... and "other": the rest of the process, gRPC's pollers and the device
+    runtime's threads among them), from the threads' own clocks, read when
+    the scrape asks (tracing.HostClocks.snapshot: the `threads` block of
+    /v1/debug/pipeline, in seconds). `process_cpu_seconds_total` cannot say
+    whether the one event loop is the busy thread; this can."""
+
+    def __init__(self, host):
+        self.host = host
+
+    def collect(self):
+        fam = CounterMetricFamily(
+            "gubernator_tpu_thread_cpu_seconds",
+            "CPU seconds of the daemon's threads, by pool",
+            labels=["thread"],
+        )
+        snap = self.host.snapshot()
+        for pool, val in snap.items():
+            if isinstance(val, dict):
+                fam.add_metric([pool], val["cpu_ms"] / 1e3)
+        fam.add_metric(["other"], snap["other_cpu_ms"] / 1e3)
+        yield fam
+
+
 class DaemonMetrics:
     """One daemon's metric family set (names mirror docs/prometheus.md).
 
@@ -695,6 +721,10 @@ class DaemonMetrics:
         # OTLP exporter health (satellite: export failures were attributes
         # nobody could scrape)
         r.register(_OtelSpanCollector())
+
+    def watch_host(self, host) -> None:
+        """Render `host` (tracing.HostClocks) as the thread CPU family."""
+        self.registry.register(_ThreadCpuCollector(host))
 
     def observe_engine(self, stats) -> None:
         """Refresh counter families from an EngineStats snapshot (engine

@@ -32,6 +32,14 @@ OPENMETRICS_CT = "application/openmetrics-text"
 
 
 def _timed(metrics, method):
+    # the method's children, bound once: `labels()` takes the family's lock
+    # and builds a key, twice an RPC on the event-loop thread
+    counts = {
+        status: metrics.grpc_request_counts.labels(method=method, status=status)
+        for status in ("ok", "error")
+    }
+    duration = metrics.grpc_request_duration.labels(method=method)
+
     def wrap(fn):
         async def run(request, context):
             t0 = time.perf_counter()
@@ -42,9 +50,7 @@ def _timed(metrics, method):
                 status = "error"
                 raise
             finally:
-                metrics.grpc_request_counts.labels(
-                    method=method, status=status
-                ).inc()
+                counts[status].inc()
                 # the handler's request scope has already closed; its span
                 # is this context's last-ended — the request-duration bucket
                 # carries the request's trace_id as its exemplar, when an
@@ -53,7 +59,7 @@ def _timed(metrics, method):
                     tracing.last_ended_span()
                     if tracing.exporter is not None else None
                 )
-                metrics.grpc_request_duration.labels(method=method).observe(
+                duration.observe(
                     time.perf_counter() - t0,
                     exemplar=(
                         {"trace_id": span.trace_id} if span is not None else None

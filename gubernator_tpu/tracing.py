@@ -12,6 +12,7 @@ optional span-event hook embedders can point at their own tracer.
 from __future__ import annotations
 
 import contextvars
+import gc
 import random
 import threading
 import time
@@ -154,13 +155,38 @@ class Dispatch:
     crossing back onto the loop). `tail`, where the batcher sets one, is
     what the worker thread that holds the dispatch's answer does with it
     before that crossing (`tail(rc)` gives what crosses): the batcher's
-    `encode` stage."""
+    `encode` stage. `origin` is how the chunk came to be dispatched, three
+    clocks the batcher had read anyway (`perf_counter`: its oldest entry's
+    enqueue, the instant the flush worker that took it came free, the
+    dispatch's start in the callback that closed the chunk): the dispatch's
+    first profiler span turns them into its `slot_us`, `window_us` and
+    `closed_us` stats (`stage.__enter__`); with no profile running nobody
+    reads them."""
 
     seq: int
     rows: int
     span: Optional[SpanContext] = None
     work_s: float = 0.0
     tail: Optional[Callable] = None
+    origin: Optional[tuple] = None
+
+
+def _origin_stats(origin: tuple, now: float) -> dict:
+    """How a dispatch got to its first stage, as that span's stats, each in
+    us and one after another back from the span's start: `closed_us`, the
+    chunk was closed and this stage had not begun (the dispatch's start on
+    the loop, the hop onto the stage's pool, the wait for one of its
+    threads); `window_us` before that, a flush worker was free and held the
+    window open; `slot_us` before that, the chunk's oldest entry lay in the
+    queue and every flush worker was in a dispatch of its own (0 for a
+    worker that was idle when the entry came)."""
+    t_enq, t_free, t_closed = origin
+    t_open = min(max(t_enq, t_free), t_closed)
+    return {
+        "closed_us": round((now - t_closed) * 1e6),
+        "window_us": round((t_closed - t_open) * 1e6),
+        "slot_us": round((t_open - min(t_enq, t_open)) * 1e6),
+    }
 
 
 # The dispatch stage (put, put_miss, issue, fetch) that is open on this
@@ -239,6 +265,11 @@ class stage:
             if disp is not None:
                 # a part may state the rows it handles itself
                 stats = {"dispatch": disp.seq, "rows": disp.rows, **stats}
+                if disp.origin is not None:
+                    # the dispatch's first span under a profile says how
+                    # its chunk came here (the idle reader's join)
+                    stats.update(_origin_stats(disp.origin, time.perf_counter()))
+                    disp.origin = None
             self._ann = TraceAnnotation("gub:" + self.name, **stats)
             self._ann.__enter__()
         self._t0 = time.perf_counter()
@@ -352,3 +383,172 @@ def extract(metadata: Mapping[str, str]) -> Optional[SpanContext]:
     metadata_carrier.go:24-31)."""
     raw = metadata.get(TRACEPARENT_KEY, "")
     return parse_traceparent(raw) if raw else None
+
+
+# ------------------------------------------------------- what the host did
+# Three things a stage's wall interval cannot say (docs/observability.md,
+# line 1 of the budget walk): which thread was on a CPU, how long a ready
+# callback waits for the event loop, and how long the collector held the
+# GIL. All three are counted here and cost nothing on the path of an RPC or
+# of a dispatch: the threads' own CPU clocks are read when somebody asks
+# (`/v1/debug/pipeline`, `/metrics`), the loop's lag is one callback every
+# 20 ms, the collector's pauses two callbacks a collection.
+
+# thread name -> pool: the event loop by its ident, executor threads by
+# their `thread_name_prefix` ("door_0"), the exporter's worker by its name
+POOLS = ("loop", "door", "prep", "engine", "fetch", "ckpt", "telemetry",
+         "put", "otel-export")
+LOOP_LAG_PERIOD_S = 0.02
+
+
+def _thread_cpu_s(native_id: int) -> float:
+    """CPU seconds of one live thread of this process from its own clock:
+    the id glibc's `pthread_getcpuclockid` builds (Linux: (~tid << 3) | 6,
+    the per-thread scheduler clock), from the kernel's thread id. Not
+    `time.pthread_getcpuclockid(ident)` itself, which reads freed memory
+    for a thread that has just exited; here the kernel looks the id up and
+    a thread that is gone is an OSError (tests hold the two to agree)."""
+    return time.clock_gettime((~native_id << 3) | 6)
+
+
+class HostClocks:
+    """One daemon's account of the host side: `snapshot()` is the `threads`
+    block of `/v1/debug/pipeline` (and what the
+    gubernator_tpu_thread_cpu_seconds_total collector renders), `start()`
+    arms the loop-lag ticker and the collector callback on the running
+    loop, `stop()` takes both away. The clocks are the process's, so two
+    daemons in one process read the same threads; each samples `loop_lag`
+    and `gc_pause` into its own metrics."""
+
+    def __init__(self, metrics=None):
+        self.metrics = metrics
+        if metrics is not None:
+            # the two stages' histogram children exist before the first
+            # sample: the collector's callback runs wherever an allocation
+            # tripped it, inside the family's `labels()` lock too, and may
+            # not ask for that lock itself (observe() then finds the child)
+            metrics.stage_child("loop_lag")
+            metrics.stage_child("gc_pause")
+        self._loop = None
+        self._loop_thread: Optional[int] = None  # native id
+        self._tick = None  # the ticker's pending TimerHandle
+        self._due = 0.0
+        # live threads: native id -> [pool, last reading]; a pool's threads
+        # that have exited keep their last reading in `_retired`, so that no
+        # pool's sum ever falls
+        self._live: Dict[int, list] = {}
+        self._retired: Dict[str, float] = {}
+        self.gc_pause_s = [0.0, 0.0, 0.0]
+        self.gc_collections = [0, 0, 0]
+        self._gc_t0 = 0.0
+
+    # ---- arming
+    def start(self) -> None:
+        """On the event-loop thread, once the loop runs."""
+        import asyncio
+
+        self._loop = loop = asyncio.get_running_loop()
+        self._loop_thread = threading.get_native_id()
+        self._due = loop.time() + LOOP_LAG_PERIOD_S
+        self._tick = loop.call_at(self._due, self._on_tick)
+        gc.callbacks.append(self._on_gc)
+
+    def stop(self) -> None:
+        if self._tick is not None:
+            self._tick.cancel()
+            self._tick = None
+        if self._on_gc in gc.callbacks:
+            gc.callbacks.remove(self._on_gc)
+
+    def _on_tick(self) -> None:
+        """`loop_lag`: how late the loop ran a callback that was due at a
+        fixed instant, which is what any ready callback waits (an RPC meets
+        it at every crossing: bytes read -> handler started, door worker
+        done -> coroutine resumed, dispatch done -> answer, bytes written).
+        The due time advances by the period whatever the lag, so a loop
+        blocked for 100 ms yields the samples 100, 80, 60, ... of the ticks
+        it missed: 50 samples a second, their mean the wait of a callback
+        that becomes ready at a random instant."""
+        loop = self._loop
+        observe("loop_lag", self.metrics, max(0.0, loop.time() - self._due))
+        self._due += LOOP_LAG_PERIOD_S
+        self._tick = loop.call_at(self._due, self._on_tick)
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        """The collector's start/stop pair, on whichever thread tripped it,
+        with the GIL held from one to the other: every thread's stall.
+        Generations 1 and 2 are `gc_pause` samples; generation 0 can run
+        hundreds of times a second and is counted and summed only."""
+        if phase == "start":
+            self._gc_t0 = time.perf_counter()
+            return
+        dt = time.perf_counter() - self._gc_t0
+        gen = info["generation"]
+        self.gc_pause_s[gen] += dt
+        self.gc_collections[gen] += 1
+        if gen:
+            observe("gc_pause", self.metrics, dt)
+
+    # ---- reading
+    def _pool_of(self, thread: threading.Thread) -> Optional[str]:
+        if thread.native_id == self._loop_thread:
+            return "loop"
+        pool = thread.name.rpartition("_")[0] or thread.name
+        return pool if pool in POOLS[1:] else None
+
+    def snapshot(self) -> dict:
+        """{"wall_ms", "process_cpu_ms", "pools_cpu_ms", "other_cpu_ms",
+        <pool>: {"cpu_ms", "threads"} for the loop and every pool that has
+        started, "gc_pause_ms", "gc_collections", "gc_generations"}: all
+        monotone, ms. `process_cpu_ms` is every thread of the process (gRPC's
+        pollers, XLA's and the device runtime's among them) and is read
+        last, so that it is never less than the pools' sum; `other_cpu_ms`
+        is the difference. Called on the event-loop thread (the HTTP
+        handlers of both endpoints run there)."""
+        seen = set()
+        for th in threading.enumerate():
+            tid = th.native_id
+            pool = self._pool_of(th) if tid is not None else None
+            if pool is None:
+                continue
+            try:
+                cpu = _thread_cpu_s(tid)
+            except OSError:  # it exited since enumerate() saw it
+                continue
+            seen.add(tid)
+            last = self._live.get(tid)
+            if last is not None and (last[0] != pool or cpu < last[1]):
+                self._retire(tid)  # the kernel gave the id to a new thread
+                last = None
+            if last is None:
+                self._live[tid] = [pool, cpu]
+            else:
+                last[1] = cpu
+        for tid in [t for t in self._live if t not in seen]:
+            self._retire(tid)
+        pools: Dict[str, dict] = {}
+        for pool, cpu in self._retired.items():
+            pools[pool] = {"cpu_ms": cpu * 1e3, "threads": 0}
+        for pool, cpu in self._live.values():
+            acc = pools.setdefault(pool, {"cpu_ms": 0.0, "threads": 0})
+            acc["cpu_ms"] += cpu * 1e3
+            acc["threads"] += 1
+        in_pools = sum(p["cpu_ms"] for p in pools.values())
+        process = max(time.process_time() * 1e3, in_pools)
+        return {
+            "wall_ms": time.monotonic() * 1e3,
+            "process_cpu_ms": process,
+            "pools_cpu_ms": in_pools,
+            "other_cpu_ms": process - in_pools,
+            **{p: pools[p] for p in POOLS if p in pools},
+            "gc_pause_ms": sum(self.gc_pause_s) * 1e3,
+            "gc_collections": sum(self.gc_collections),
+            "gc_generations": [
+                {"pause_ms": s * 1e3, "collections": n}
+                for s, n in zip(self.gc_pause_s, self.gc_collections)
+            ],
+        }
+
+    def _retire(self, tid: int) -> None:
+        pool, cpu = self._live.pop(tid)
+        self._retired[pool] = self._retired.get(pool, 0.0) + cpu
