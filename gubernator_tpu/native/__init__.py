@@ -5,6 +5,15 @@ None when no toolchain is available; callers keep a pure-Python fallback.
 The build is a single translation unit against Python.h only — no
 libprotobuf, no numpy C API (buffers cross as bytes; numpy wraps them with
 np.frombuffer zero-copy).
+
+Entry points, each one pass with the GIL released and each with a Python
+twin that the tests hold it to: `parse_get_rate_limits` (request bytes →
+columns + compact-wire lanes; service/wire.py), `encode_responses` and
+`encode_responses_many` (response columns → wire bytes, one RPC or every
+plain RPC of a dispatch), `stage_wire_chunk` (a fused chunk's lanes → its
+grid and every pass behind it, staged; ops/wire.stage_wire_chunk, twin
+ops/engine._stage_chunk_numpy), the hashes `fingerprint64` and `fnv1a32`,
+and `set_error_strings` (the encoder's table, set once by `load`).
 """
 
 from __future__ import annotations

@@ -23,11 +23,13 @@
 #define PY_SSIZE_T_CLEAN
 #include <Python.h>
 
+#include <algorithm>
 #include <cstdint>
 #include <cstdio>
 #include <cstring>
 #include <memory>
 #include <string>
+#include <utility>
 #include <vector>
 
 // ------------------------------------------------------------------ XXH64
@@ -164,8 +166,17 @@ enum { ERR_OK = 0, ERR_EMPTY_KEY = 1, ERR_EMPTY_NAME = 2 };
 // (3 bits — five in-kernel algorithms) | cascade_level << 30; the parser
 // always emits level 0 (cascade requests take the pb path — see field 11
 // below).
-static const int64_t WIRE_DUR_MASK = (1LL << 27) - 1;   // ops/wire.DUR_BITS
-static const int64_t WIRE_HITS_MASK = (1LL << 18) - 1;  // ops/wire.HITS_BITS
+static const int WIRE_LANES = 5;       // ops/wire.WIRE_LANES
+static const int WIRE_DUR_BITS = 27;   // ops/wire.DUR_BITS
+static const int WIRE_HITS_BITS = 18;  // ops/wire.HITS_BITS
+static const int64_t WIRE_DUR_MASK = (1LL << WIRE_DUR_BITS) - 1;
+static const int64_t WIRE_HITS_MASK = (1LL << WIRE_HITS_BITS) - 1;
+// lane 4's created-at delta, biased, above the hits (ops/wire.DELTA_BITS,
+// DELTA_BIAS): the staging stamps it (stage_wire_chunk), the parser leaves 0
+static const int64_t WIRE_DELTA_MASK = (1LL << 10) - 1;
+static const int64_t WIRE_DELTA_BIAS = 1LL << 9;
+static const uint32_t WIRE_RESET_BIT = 1u << 30;  // lane 4: RESET_REMAINING
+static const int WIRE_LEVEL_SHIFT = 30;  // lane 3: cascade level, 2 bits
 static const int64_t WIRE_I32_MAX = 2147483647LL;
 // RESET_REMAINING | DRAIN_OVER_LIMIT | kernel-inert bits | the 2-bit
 // priority tier (ops/wire.py _ENCODABLE_BEHAVIOR); anything else
@@ -430,10 +441,10 @@ static PyObject* parse_get_rate_limits(PyObject*, PyObject* args) {
     lanes[2 * n + i] = (int32_t)it.limit;
     lanes[3 * n + i] = (int32_t)(uint32_t)(
         ((uint64_t)(it.duration & WIRE_DUR_MASK)) |
-        ((uint64_t)(uint32_t)it.algorithm << 27));
+        ((uint64_t)(uint32_t)it.algorithm << WIRE_DUR_BITS));
     uint32_t l4 = (uint32_t)(it.hits & WIRE_HITS_MASK);
     l4 |= (uint32_t)((it.behavior >> 6) & 3) << 28;  // priority tier
-    if (it.behavior & 8) l4 |= 1u << 30;   // RESET_REMAINING
+    if (it.behavior & 8) l4 |= WIRE_RESET_BIT;
     if (it.behavior & 32) l4 |= 1u << 31;  // DRAIN_OVER_LIMIT
     lanes[4 * n + i] = (int32_t)l4;
   }
@@ -739,6 +750,364 @@ static PyObject* encode_responses_many(PyObject*, PyObject* args) {
   return Py_BuildValue("(NN)", bodies, counts);
 }
 
+// ------------------------------------------------------ fused chunk staging
+
+// One part's (5, n) int32 lane block behind the buffer protocol, any
+// strides (a selection of a parsed batch's rows is not contiguous). Opened
+// and released with the GIL held; read without it.
+struct LaneBlock {
+  Py_buffer view;
+  bool held = false;
+  ~LaneBlock() { if (held) PyBuffer_Release(&view); }
+
+  bool open(PyObject* obj) {
+    if (PyObject_GetBuffer(obj, &view, PyBUF_STRIDES | PyBUF_FORMAT) < 0)
+      return false;
+    held = true;
+    const char* f = view.format ? view.format : "B";
+    if (*f == '@' || *f == '=' || *f == '<') f++;
+    if (view.ndim != 2 || view.shape[0] != WIRE_LANES || view.itemsize != 4 ||
+        !*f || !strchr("il", *f) || f[1]) {
+      PyErr_SetString(PyExc_TypeError, "lanes: a (5, n) int32 block expected");
+      return false;
+    }
+    return true;
+  }
+  Py_ssize_t cols() const { return view.shape[1]; }
+  void copy_lane(int lane, int32_t* dst) const {
+    const char* p = (const char*)view.buf + lane * view.strides[0];
+    if (view.strides[1] == 4) {
+      memcpy(dst, p, (size_t)cols() * 4);
+      return;
+    }
+    for (Py_ssize_t j = 0; j < cols(); j++, p += view.strides[1])
+      memcpy(dst + j, p, 4);
+  }
+};
+
+struct ChunkPart {
+  LaneBlock lanes;
+  IntCol fp, err, created;
+};
+
+// One pass behind the grid: `rows` answer it; the aggregate's `members`
+// share row g's answer group after group (`starts`, `counts`). `block` is
+// its (5, pad+1) ingress image, empty when the lanes cannot carry it.
+struct LaterPass {
+  std::vector<int64_t> rows, members, starts, counts;
+  std::vector<int32_t> block;
+  bool aggregate = false, in_lanes = false;
+  int64_t pad = 0;
+  int math = -1;
+};
+
+struct ChunkStage {
+  // in
+  int64_t n = 0, now = 0, tol = 0, pad = 0, max_exact = 0, pad_floor = 0;
+  bool one_grid = false;
+  // out
+  std::vector<int32_t> grid;
+  std::vector<int8_t> err;
+  std::vector<int64_t> act_fp;
+  std::vector<uint8_t> first;
+  std::vector<LaterPass> passes;
+  int64_t clamped = 0, later = 0;
+  int math = 0;
+  bool casc = false;
+};
+
+// ops/wire.grid_math_mode over the n data columns of a (5, w) block, as an
+// index into ops/wire.MATH_MODES: token, mixed, gcra, int
+static int block_math_mode(const int32_t* b, int64_t w, int64_t n) {
+  bool leaky = false, any = false, act_gcra = true;
+  int64_t act = 0;
+  for (int64_t j = 0; j < n; j++) {
+    uint32_t algo = ((uint32_t)b[3 * w + j] >> WIRE_DUR_BITS) & 7;
+    leaky |= algo == 1;
+    any |= algo != 0;
+    if (b[j] || b[w + j]) {
+      act++;
+      act_gcra &= algo == 2;
+    }
+  }
+  if (leaky) return 1;
+  if (!any) return 0;
+  return act && act_gcra ? 2 : 3;
+}
+
+// The staging itself; touches no Python object. False: the chunk cannot
+// fuse (the cases ops/engine._assemble_wire_parts names).
+static bool stage_chunk(const ChunkPart* parts, size_t k, ChunkStage& s) {
+  const int64_t n = s.n, w = s.pad + 1;
+  std::vector<int64_t> fp(n), clipped(n);
+  std::vector<uint8_t> active(n);
+  s.err.resize(n);
+  s.grid.assign((size_t)(WIRE_LANES * w), 0);
+  int32_t* grid = s.grid.data();
+  const int64_t lo = s.now - s.tol, hi = s.now + s.tol;
+  int64_t off = 0, first_active = -1;
+  for (size_t p = 0; p < k; p++) {
+    const ChunkPart& part = parts[p];
+    const int64_t m = part.lanes.cols();
+    for (int l = 0; l < WIRE_LANES; l++)
+      part.lanes.copy_lane(l, grid + l * w + off);
+    for (int64_t j = 0; j < m; j++) {
+      const int64_t i = off + j, code = part.err.at(j);
+      fp[i] = part.fp.at(j);
+      s.err[i] = (int8_t)code;
+      active[i] = code == 0;
+      // an unset stamp is the ingress instant; a client's is held within
+      // the tolerance (ops/batch.pack_columns), and counted where it was not
+      const int64_t created = part.created.at(j);
+      const int64_t stamped = created == 0 ? s.now : created;
+      clipped[i] = std::min(std::max(stamped, lo), hi);
+      s.clamped += clipped[i] != stamped;
+      if (active[i]) {
+        if (first_active < 0) first_active = i;
+        s.act_fp.push_back(fp[i]);
+      }
+    }
+    off += m;
+  }
+  if (first_active < 0) return false;  // all-error chunk
+
+  // ops/plan.occurrence_rank without its sort: a row's rank is the number of
+  // rows before it that carry its fingerprint (what its place in a stable
+  // sort's run of that fingerprint is), counted in an open-addressed table;
+  // an error row is counted and is no copy
+  size_t cap = 16;
+  while (cap < 2 * (size_t)n) cap *= 2;
+  std::vector<int64_t> seen_fp(cap);
+  std::vector<int32_t> seen(cap, 0);
+  std::vector<int64_t> rank(n, 0);
+  int64_t top = 0;
+  for (int64_t i = 0; i < n; i++) {
+    size_t at = (size_t)(((uint64_t)fp[i] * 0x9E3779B97F4A7C15ULL) >> 20) & (cap - 1);
+    while (seen[at] && seen_fp[at] != fp[i]) at = (at + 1) & (cap - 1);
+    seen_fp[at] = fp[i];
+    const int64_t run = seen[at]++;
+    if (run && active[i]) {
+      rank[i] = run;
+      s.later++;
+      top = std::max(top, run);
+    }
+  }
+  if (s.later && (s.one_grid || s.max_exact < 2)) return false;
+
+  // the first active row's stamp is the base; lane 4 takes each active
+  // row's delta from it, which a row of the grid has to fit
+  const int64_t base = clipped[first_active];
+  std::vector<uint8_t> fits(n);
+  bool later_fit = true;
+  s.first.resize(n);
+  for (int64_t i = 0; i < n; i++) {
+    const int64_t d = clipped[i] - base;
+    fits[i] = d >= -WIRE_DELTA_BIAS && d < WIRE_DELTA_BIAS;
+    s.first[i] = active[i] && !rank[i];
+    if (s.first[i] && !fits[i]) return false;
+    later_fit &= !rank[i] || fits[i];
+    if (active[i])
+      grid[4 * w + i] |= (int32_t)(((d + WIRE_DELTA_BIAS) & WIRE_DELTA_MASK)
+                                   << WIRE_HITS_BITS);
+    s.casc |= ((uint32_t)grid[3 * w + i] >> WIRE_LEVEL_SHIFT) != 0;
+  }
+  if (s.later && s.casc) return false;  // the in-trace fold is one pass
+  // ops/wire.stamp_base
+  grid[s.pad] = (int32_t)(uint32_t)((uint64_t)base & 0xFFFFFFFFu);
+  grid[w + s.pad] = (int32_t)(uint32_t)((uint64_t)(base >> 32) & 0xFFFFFFFFu);
+
+  if (s.later) {
+    // ops/plan.split_rows: rank r below max_exact-1 is exact pass r, rows in
+    // arrival order; every rank from there up is the aggregate's, key after
+    // key, whose groups are the runs of equal fingerprint lanes
+    const int64_t n_exact = std::min(top, s.max_exact - 2);
+    const bool tail = top >= s.max_exact - 1;
+    s.passes.resize((size_t)(n_exact + tail));
+    for (int64_t i = 0; i < n; i++)
+      if (rank[i] >= 1 && rank[i] <= n_exact)
+        s.passes[rank[i] - 1].rows.push_back(i);
+    if (tail) {
+      LaterPass& t = s.passes.back();
+      t.aggregate = true;
+      // key after key as the stable sort by fingerprint leaves them
+      std::vector<std::pair<int64_t, int64_t>> order;
+      for (int64_t i = 0; i < n; i++)
+        if (rank[i] >= s.max_exact - 1) order.push_back({fp[i], i});
+      std::sort(order.begin(), order.end());
+      for (const auto& row : order) t.members.push_back(row.second);
+      const int64_t m = (int64_t)t.members.size();
+      for (int64_t q = 0; q < m; q++) {
+        const int64_t i = t.members[q], j = q ? t.members[q - 1] : 0;
+        if (!q || grid[i] != grid[j] || grid[w + i] != grid[w + j])
+          t.starts.push_back(q);
+      }
+      for (size_t g = 0; g < t.starts.size(); g++) {
+        const int64_t end = g + 1 < t.starts.size() ? t.starts[g + 1] : m;
+        t.counts.push_back(end - t.starts[g]);
+        t.rows.push_back(t.members[end - 1]);  // the newest answers
+      }
+    }
+    // ops/wire.gather_wire_block for each, under the grid's base column
+    for (LaterPass& p : s.passes) {
+      const std::vector<int64_t>& all = p.aggregate ? p.members : p.rows;
+      const int64_t m = (int64_t)p.rows.size();
+      for (p.pad = s.pad_floor; p.pad < m;) p.pad *= 2;
+      p.in_lanes = later_fit || std::all_of(all.begin(), all.end(), [&](int64_t i) {
+        return fits[i] != 0;
+      });
+      if (!p.in_lanes) continue;
+      const int64_t bw = p.pad + 1;
+      p.block.assign((size_t)(WIRE_LANES * bw), 0);
+      for (int l = 0; l < WIRE_LANES; l++) {
+        int32_t* dst = p.block.data() + l * bw;
+        const int32_t* src = grid + l * w;
+        for (int64_t j = 0; j < m; j++) dst[j] = src[p.rows[j]];
+        dst[p.pad] = src[s.pad];
+      }
+      // the aggregate: a group's summed hits and its RESET bits OR-ed
+      for (int64_t g = 0; p.aggregate && g < m; g++) {
+        int64_t hits = 0;
+        uint32_t reset = 0;
+        for (int64_t q = p.starts[g]; q < p.starts[g] + p.counts[g]; q++) {
+          const uint32_t l4 = (uint32_t)grid[4 * w + p.members[q]];
+          hits += l4 & WIRE_HITS_MASK;
+          reset |= l4 & WIRE_RESET_BIT;
+        }
+        if (hits > WIRE_HITS_MASK) {
+          p.in_lanes = false;
+          break;
+        }
+        uint32_t& cell = (uint32_t&)p.block[4 * bw + g];
+        cell = (cell & ~(uint32_t)WIRE_HITS_MASK) | (uint32_t)hits | reset;
+      }
+      if (!p.in_lanes) p.block.clear();
+    }
+    // where all later copies name one algorithm, every pass selects the
+    // mode the first does (ops/engine._later_blocks)
+    bool same = true;
+    int64_t algo0 = -1;
+    for (int64_t i = 0; i < n; i++) {
+      if (!rank[i]) continue;
+      const int64_t algo = (uint32_t)grid[3 * w + i] >> WIRE_DUR_BITS;
+      if (algo0 < 0) algo0 = algo;
+      same &= algo == algo0;
+    }
+    int first_mode = -1;
+    for (LaterPass& p : s.passes) {
+      if (!p.in_lanes) continue;
+      p.math = same && first_mode >= 0
+                   ? first_mode
+                   : block_math_mode(p.block.data(), p.pad + 1, p.pad);
+      if (first_mode < 0) first_mode = p.math;
+    }
+    // the grid is pass 0: a later copy has no lane in it
+    for (int64_t i = 0; i < n; i++)
+      if (rank[i])
+        for (int l = 0; l < WIRE_LANES; l++) grid[l * w + i] = 0;
+  }
+  s.math = block_math_mode(grid, w, n);
+  return true;
+}
+
+template <typename T>
+static PyObject* bytes_of(const std::vector<T>& v) {
+  return PyBytes_FromStringAndSize((const char*)v.data(),
+                                   (Py_ssize_t)(v.size() * sizeof(T)));
+}
+
+// stage_wire_chunk(parts: sequence[(lanes, fp, err, created_at)], now: int,
+//                  tolerance: int, pad: int, one_grid: bool, max_exact: int,
+//                  pad_floor: int)
+//   -> None | (grid, err, act_fp, first, clamped, math, cascade, later,
+//              passes)
+// The host staging of one fused chunk (ops/engine._stage_chunk_numpy and
+// its _later_blocks stay as the NumPy twin the tests hold this to, byte
+// for byte): the parts' (5, n_i) int32 lane
+// blocks and their fp / err / created_at columns become the (5, pad+1)
+// int32 pass-0 grid (later copies of a key zeroed, deltas and base
+// stamped), a bytearray copy of `err` (the finish half writes to it), the
+// active fingerprints, the (n,) bool mask of rows with a lane in the grid,
+// the clamped-stamp count, the grid's math mode (an index into
+// ops/wire.MATH_MODES), the cascade flag, the number of later copies, and
+// for each pass behind the grid (rows, block | None, pad, math, members |
+// None, starts | None, counts | None): int64 row indices, the (5, pad+1)
+// block ready to put — None where the lanes cannot carry the pass (a stamp
+// beyond the delta budget, summed hits past the lane) and the caller packs
+// that pass alone as columns — and the aggregate's fan-out. None: the chunk
+// cannot fuse. `one_grid` refuses a repeated key (a ring slot holds one
+// grid); later passes pad to pad_floor doubled until the rows fit. Holds no
+// state, and runs with the GIL released from the first row to the last.
+static PyObject* stage_wire_chunk(PyObject*, PyObject* args) {
+  PyObject* parts_o;
+  ChunkStage s;
+  long long now, tol, pad, max_exact, pad_floor;
+  int one_grid;
+  if (!PyArg_ParseTuple(args, "OLLLpLL", &parts_o, &now, &tol, &pad,
+                        &one_grid, &max_exact, &pad_floor))
+    return nullptr;
+  s.now = now; s.tol = tol; s.pad = pad; s.one_grid = one_grid != 0;
+  s.max_exact = max_exact; s.pad_floor = pad_floor;
+  PyObject* seq = PySequence_Fast(parts_o, "parts: a sequence expected");
+  if (!seq) return nullptr;
+  const size_t k = (size_t)PySequence_Fast_GET_SIZE(seq);
+  std::unique_ptr<ChunkPart[]> parts(new ChunkPart[k]);
+  bool ok = true;
+  for (size_t p = 0; ok && p < k; p++) {
+    PyObject* part = PySequence_Fast_GET_ITEM(seq, (Py_ssize_t)p);
+    PyObject *lanes, *fp, *err, *created;
+    ok = PyArg_ParseTuple(part, "OOOO", &lanes, &fp, &err, &created) &&
+         parts[p].lanes.open(lanes) && parts[p].fp.open(fp, "fp") &&
+         parts[p].err.open(err, "err") &&
+         parts[p].created.open(created, "created_at");
+    if (!ok) break;
+    const Py_ssize_t m = parts[p].lanes.cols();
+    if (parts[p].fp.size() != m || parts[p].err.size() != m ||
+        parts[p].created.size() != m) {
+      PyErr_SetString(PyExc_ValueError, "a part's columns differ in length");
+      ok = false;
+    }
+    s.n += m;
+  }
+  Py_DECREF(seq);
+  if (ok && (s.n > s.pad || s.pad_floor < 1)) {
+    PyErr_SetString(PyExc_ValueError, "pad below the chunk's rows");
+    ok = false;
+  }
+  if (!ok) return nullptr;
+
+  bool fused;
+  Py_BEGIN_ALLOW_THREADS;
+  fused = stage_chunk(parts.get(), k, s);
+  Py_END_ALLOW_THREADS;
+  if (!fused) Py_RETURN_NONE;
+
+  PyObject* passes = PyList_New((Py_ssize_t)s.passes.size());
+  if (!passes) return nullptr;
+  for (size_t i = 0; i < s.passes.size(); i++) {
+    const LaterPass& p = s.passes[i];
+    PyObject* block = p.in_lanes ? bytes_of(p.block) : Py_NewRef(Py_None);
+    PyObject* item =
+        p.aggregate
+            ? Py_BuildValue("(NNLiNNN)", bytes_of(p.rows), block,
+                            (long long)p.pad, p.math, bytes_of(p.members),
+                            bytes_of(p.starts), bytes_of(p.counts))
+            : Py_BuildValue("(NNLiOOO)", bytes_of(p.rows), block,
+                            (long long)p.pad, p.math, Py_None, Py_None,
+                            Py_None);
+    if (!item) {
+      Py_DECREF(passes);
+      return nullptr;
+    }
+    PyList_SET_ITEM(passes, (Py_ssize_t)i, item);
+  }
+  return Py_BuildValue(
+      "(NNNNLiOLN)", bytes_of(s.grid),
+      PyByteArray_FromStringAndSize((const char*)s.err.data(),
+                                    (Py_ssize_t)s.err.size()),
+      bytes_of(s.act_fp), bytes_of(s.first), (long long)s.clamped, s.math,
+      s.casc ? Py_True : Py_False, (long long)s.later, passes);
+}
+
 // fingerprint64(data: bytes) -> int — parity check hook for tests
 static PyObject* fingerprint64(PyObject*, PyObject* args) {
   Py_buffer buf;
@@ -764,6 +1133,9 @@ static PyMethodDef methods[] = {
     {"encode_responses_many", encode_responses_many, METH_VARARGS,
      "a chunk's response columns + entry offsets -> each entry's "
      "GetRateLimitsResp wire bytes and OVER_LIMIT count"},
+    {"stage_wire_chunk", stage_wire_chunk, METH_VARARGS,
+     "a fused chunk's lane blocks and columns -> its grid and every pass "
+     "behind it, staged"},
     {"set_error_strings", set_error_strings, METH_O,
      "the error code -> wire string table encode_responses_many uses"},
     {"fingerprint64", fingerprint64, METH_VARARGS, "seeded 63-bit XXH64"},

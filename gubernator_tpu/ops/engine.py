@@ -213,6 +213,9 @@ class EngineStats:
     later_rows: int = 0
     aggregate_rows: int = 0
     later_lane_rows: int = 0
+    # fused dispatches whose host staging was the one native call
+    # (ops/wire.stage_wire_chunk), not the NumPy staging
+    native_staged: int = 0
 
     def accumulate(self, stats, count_dropped: bool = True) -> None:
         self.cache_hits += int(stats.cache_hits)
@@ -238,6 +241,7 @@ class EngineStats:
         self.later_rows += d.later_rows
         self.aggregate_rows += d.aggregate_rows
         self.later_lane_rows += d.later_lane_rows
+        self.native_staged += d.native_staged
 
 
 def _plan(engine, hb):
@@ -534,12 +538,12 @@ class PendingCheck:
 
     __slots__ = (
         "hb", "err", "now", "passes", "clamped", "stacked", "rows", "mark",
-        "casc", "casc_intrace", "promote", "promote_putback",
+        "casc", "casc_intrace", "promote", "promote_putback", "native",
     )
 
     def __init__(
         self, hb, err, now, passes, clamped, rows=None, mark=None,
-        casc=False, casc_intrace=False, promote=None,
+        casc=False, casc_intrace=False, promote=None, native=False,
     ):
         self.stacked = None  # same-shape pass outputs fused for ONE fetch
         self.hb = hb
@@ -569,6 +573,8 @@ class PendingCheck:
         # fps the promote handed back to the shadow (the miss re-check's
         # eligibility set — set by issue_check_columns)
         self.promote_putback = None
+        # a fused chunk staged by the native call (EngineStats.native_staged)
+        self.native = native
 
 
 class _LazyWireBatch:
@@ -638,43 +644,36 @@ def _padded_rows(batch) -> int:
 class _WireAssembly(NamedTuple):
     """What `_assemble_wire_parts` hands the two fused stagings."""
 
-    grid: np.ndarray  # (5, pad+1) int32 compact ingress, pass 0
+    chunk: "wire_mod.StagedChunk"  # the grid, its masks, the passes behind it
     cols_list: list  # the parts' RequestColumns
-    err: np.ndarray  # (n,) validation codes, a copy the finish half owns
     now: int
     n: int
-    act_fp: np.ndarray  # every active fingerprint, later copies included
-    clamped: int
-    casc: bool
     tol: int
     pad: int
-    first: np.ndarray  # (n,) bool: rows with a live lane in `grid`
-    later: "np.ndarray | None"  # rows whose key came earlier in the chunk
-    # with `later`, what their passes are staged from: the grid before those
-    # rows were taken out of it, the chunk's rows sorted by key and each
-    # row's occurrence rank (ops/plan.occurrence_rank; an error row's is 0),
-    # and the rows whose stamp `grid`'s base carries (None: all)
-    lanes: "np.ndarray | None" = None
-    order: "np.ndarray | None" = None
-    rank: "np.ndarray | None" = None
-    fits: "np.ndarray | None" = None
+    native: bool  # staged by the native call, not by NumPy
 
 
 def _assemble_wire_parts(engine, parts, now_ms=None, pad_to=None):
-    """Shared gating + single-scatter grid assembly of the fused wire
-    paths (direct front door and ring slots): pre-packed native lanes are
-    scattered into ONE padded compact ingress grid. Returns None when the
-    batch needs the general columns path (engine not wire-capable,
-    non-encodable rows, created_at skew beyond the ±511 ms delta budget,
-    Store attached, or rows exceeding `pad_to`), else a `_WireAssembly`.
+    """Shared gating + host staging of the fused wire paths (direct front
+    door and ring slots): the parts' pre-packed native lanes become ONE
+    padded compact ingress grid. Returns None when the batch needs the
+    general columns path (engine not wire-capable, non-encodable rows,
+    created_at skew beyond the ±511 ms delta budget, Store attached, or
+    rows exceeding `pad_to`), else a `_WireAssembly`.
 
     Copies of one key need the planner's sequential passes, and the grid is
     its pass 0: occurrence 0 of every key rides its parser lane, and every
     later copy has its lane zeroed (fp == 0, inactive on decode, as an error
-    row) and is named in `later` for the caller to stage from `lanes`, the
-    grid as it was with them in it. A ring slot holds one grid, so with
-    `pad_to` a repeated key still means None; so does one next to cascade
-    level bits (the in-trace fold needs a single pass).
+    row) and is a row of one of the passes behind the grid, each a gather of
+    the chunk's own lanes (`StagedChunk.passes`). A ring slot holds one
+    grid, so with `pad_to` a repeated key still means None; so does one next
+    to cascade level bits (the in-trace fold needs a single pass).
+
+    The staging is one call into the native module, GIL-free from the first
+    row to the last (ops/wire.stage_wire_chunk): on a loaded host every
+    array call queues for the GIL again, and the NumPy staging is 45 to 155
+    of them. Where the module is not loaded (`native.load()` is None: no
+    toolchain) `_stage_chunk_numpy` is that staging, byte for byte.
 
     `pad_to` fixes the padded width (the ring's static slot shape); the
     default pads to the bucketed dispatch size."""
@@ -688,6 +687,36 @@ def _assemble_wire_parts(engine, parts, now_ms=None, pad_to=None):
     n = sum(c.fp.shape[0] for c in cols_list)
     if n == 0 or (pad_to is not None and n > pad_to):
         return None
+    from gubernator_tpu import native
+    from gubernator_tpu.ops import wire as wire_mod
+    from gubernator_tpu.ops.batch import created_at_tolerance_ms
+
+    now = now_ms if now_ms is not None else ms_now()
+    tol = engine.created_at_tolerance_ms
+    if tol is None:
+        tol = created_at_tolerance_ms()
+    pad = pad_to if pad_to is not None else _pad_size(n)
+    args = (parts, now, tol, pad, pad_to is not None, engine.max_exact_passes)
+    mod = native.load()
+    if mod is None:
+        chunk = _stage_chunk_numpy(*args)
+    else:
+        chunk = wire_mod.stage_wire_chunk(mod, *args, pad_floor=_pad_size(0))
+    if chunk is None:
+        return None
+    return _WireAssembly(chunk, cols_list, now, n, tol, pad, mod is not None)
+
+
+def _stage_chunk_numpy(parts, now, tol, pad, one_grid, max_exact):
+    """ops/wire.stage_wire_chunk in NumPy, of the same arguments: what a
+    host with no toolchain runs, and what the tests hold the native staging
+    to byte for byte. None: all-error chunk (the columns path produces it),
+    a first copy's stamp outside the delta budget, a repeated key where one
+    grid is all there is (`one_grid`, or no exact pass for the grid to be),
+    or beside cascade level bits."""
+    from gubernator_tpu.ops import wire as wire_mod
+
+    cols_list = [p.cols for p in parts]
     one = len(cols_list) == 1
     fp = cols_list[0].fp if one else np.concatenate([c.fp for c in cols_list])
     err = (
@@ -695,9 +724,10 @@ def _assemble_wire_parts(engine, parts, now_ms=None, pad_to=None):
         if one
         else np.concatenate([c.err for c in cols_list])
     )
+    n = fp.shape[0]
     active = err == 0
     if not active.any():
-        return None  # all-error batch: let the columns path produce it
+        return None
     act_fp = fp[active]
     # unique-fingerprint kernel contract: the grid takes the first of a
     # key's copies, the rest follow it as the planner's later passes
@@ -708,25 +738,17 @@ def _assemble_wire_parts(engine, parts, now_ms=None, pad_to=None):
         later = np.nonzero(rank)[0]
     if later is None or later.size == 0:
         later = None
-    elif pad_to is not None or engine.max_exact_passes < 2:
-        # a ring slot holds one grid; with max_exact 1 the planner has no
-        # exact pass and aggregates from occurrence 0
+    elif one_grid or max_exact < 2:
+        # with max_exact 1 the planner aggregates from occurrence 0
         return None
     else:
         first = active.copy()
         first[later] = False
-    from gubernator_tpu.ops import wire as wire_mod
-    from gubernator_tpu.ops.batch import created_at_tolerance_ms
-
-    now = now_ms if now_ms is not None else ms_now()
     created = (
         cols_list[0].created_at
         if one
         else np.concatenate([c.created_at for c in cols_list])
     )
-    tol = engine.created_at_tolerance_ms
-    if tol is None:
-        tol = created_at_tolerance_ms()
     stamped = np.where(created == 0, now, created)
     clipped = np.clip(stamped, now - tol, now + tol)
     clamped = int((clipped != stamped).sum())
@@ -735,7 +757,6 @@ def _assemble_wire_parts(engine, parts, now_ms=None, pad_to=None):
     fits = (delta >= -wire_mod.DELTA_BIAS) & (delta < wire_mod.DELTA_BIAS)
     if not fits[first].all():
         return None
-    pad = pad_to if pad_to is not None else _pad_size(n)
     grid = wire_mod.assemble_wire_grid(
         [p.lanes for p in parts], clipped, base, pad, active
     )
@@ -743,65 +764,80 @@ def _assemble_wire_parts(engine, parts, now_ms=None, pad_to=None):
     # them there), but an engine-level caller may assemble level-bit lanes
     # directly — a single pass, so the in-trace fold is sound here
     casc = wire_mod.grid_has_cascade(grid, n)
-    lanes = None
+    passes = []
     if later is not None:
         if casc:
             return None
         lanes, grid = grid, grid.copy()
         grid[:, later] = 0
-        if fits[later].all():
-            fits = None
-    return _WireAssembly(
-        grid, cols_list, err, now, n, act_fp, clamped, casc, tol, pad,
-        first, later, lanes, order, rank, fits,
+        passes = _later_blocks(
+            lanes, order, rank, later, None if fits[later].all() else fits,
+            max_exact,
+        )
+    return wire_mod.StagedChunk(
+        grid, err, act_fp, first, clamped, wire_mod.grid_math_mode(grid, n),
+        casc, 0 if later is None else int(later.size), passes,
     )
 
 
-def _later_passes(engine, a: _WireAssembly) -> "tuple[list, int]":
-    """The passes that follow a fused grid, staged as it was: each a gather
-    of the chunk's own lanes (`a.lanes`) under the grid's base, so that no
+def _later_blocks(lanes, order, rank, later, fits, max_exact) -> list:
+    """The passes behind a fused grid (`_stage_chunk_numpy`), each a gather
+    of `lanes`, the grid as it was with every copy in it, so that no
     HostBatch is built. Exact pass r holds the rows of occurrence rank r in
     arrival order, r = 1…max_exact−2; the aggregate every row of rank
     max_exact−1 and up, where `plan_passes` puts them for the whole chunk.
-    What the lanes cannot carry — a stamp beyond ±511 ms of the grid's
-    base, an aggregate's hits past 18 bits — is that pass alone packed and
-    staged as columns. Returns the passes and how many rode the lanes."""
+    What the lanes cannot carry — a stamp beyond ±511 ms of the grid's base
+    (`fits`; None: all fit), an aggregate's hits past 18 bits — is a pass
+    with no block."""
     from gubernator_tpu.ops import wire as wire_mod
 
-    exact, tail = split_rows(a.order, a.rank, engine.max_exact_passes)
-    plan = [(rows, None) for rows in exact[1:]]
-    if tail is not None:
-        plan.append((tail, runs(a.lanes[0, tail], a.lanes[1, tail])))
-    passes, blocks = [], []
-    for rows, groups in plan:
-        p, starts = Pass(rows=rows, batch=None), None
-        if groups is not None:  # answered by each group's newest member
-            starts, p.member_counts = groups
-            p.rows, p.members = rows[starts + p.member_counts - 1], rows
-        n = p.rows.size
-        p.batch = lazy = _LazyWireBatch(
-            a.cols_list, a.now, a.tol, _pad_size(n), pick=rows, groups=groups
+    exact, tail = split_rows(order, rank, max_exact)
+    plan = [(rows, None, (None, None)) for rows in exact[1:]]
+    if tail is not None:  # answered by each group's newest member
+        starts, counts = runs(lanes[0, tail], lanes[1, tail])
+        plan.append((tail[starts + counts - 1], tail, (starts, counts)))
+    # every row of these passes is live: where all later copies name one
+    # algorithm, all passes select the mode the first does
+    algo = lanes[3, later] >> wire_mod.DUR_BITS
+    same, mode = int(algo.min()) == int(algo.max()), None
+    passes = []
+    for rows, members, (starts, counts) in plan:
+        pad, block, math = _pad_size(rows.size), None, None
+        if fits is None or fits[rows if members is None else members].all():
+            block = wire_mod.gather_wire_block(lanes, rows, pad, members, starts)
+        if block is not None:
+            if mode is None or not same:
+                mode = wire_mod.grid_math_mode(block, pad)
+            math = mode
+        passes.append(
+            wire_mod.StagedPass(rows, block, pad, math, members, starts, counts)
         )
-        block = None
-        if a.fits is None or a.fits[rows].all():
-            block = wire_mod.gather_wire_block(
-                a.lanes, p.rows, lazy.rows, p.members, starts
-            )
-        if block is None:
+    return passes
+
+
+def _later_passes(engine, a: _WireAssembly) -> "tuple[list, int]":
+    """The passes that follow a fused grid as `issue_check_columns` takes
+    them: the staged blocks (`StagedChunk.passes`) put on the device in one
+    transfer call, each behind a lazy batch that only a retry materializes.
+    A pass the lanes could not carry is that pass alone packed and staged
+    as columns. Returns the passes and how many rode the lanes."""
+    passes, blocks, maths = [], [], []
+    for sp in a.chunk.passes:
+        p = Pass(sp.rows, None, sp.members, sp.member_counts)
+        n = sp.rows.size
+        p.batch = lazy = _LazyWireBatch(
+            a.cols_list, a.now, a.tol, sp.pad,
+            pick=sp.rows if sp.members is None else sp.members,
+            groups=None if sp.members is None else (sp.starts, sp.member_counts),
+        )
+        if sp.block is None:
             p.batch, staged = engine.stage_pass(lazy._materialize(), n)
             passes.append([p, n, p.batch, staged])
         else:
-            blocks.append(block)
+            blocks.append(sp.block)
+            maths.append(sp.math)
             passes.append([p, n, lazy, None])
-    # every row of these passes is live: where all later copies name one
-    # algorithm, all passes select the mode the first does
-    algo = a.lanes[3, a.later] >> wire_mod.DUR_BITS
-    same = int(algo.min()) == int(algo.max())
-    maths = [
-        wire_mod.grid_math_mode(b, b.shape[1] - 1)
-        for b in (blocks[:1] if same else blocks)
-    ]
-    staged = iter(engine.stage_wire_blocks(blocks, maths * len(blocks) if same else maths))
+    staged = iter(engine.stage_wire_blocks(blocks, maths))
     for entry in passes:
         if entry[3] is None:
             entry[3] = next(staged)
@@ -811,16 +847,18 @@ def _later_passes(engine, a: _WireAssembly) -> "tuple[list, int]":
 def _wire_pending(engine, a: _WireAssembly, staged):
     """PendingCheck over one assembled wire grid (direct or ring slot) —
     the object both finish halves consume unchanged. Later copies of a key
-    (`a.later`, direct path only) are its passes after the grid's."""
-    lazy = _LazyWireBatch(a.cols_list, a.now, a.tol, a.pad, a.first)
+    (`chunk.later`, direct path only) are its passes after the grid's."""
+    c = a.chunk
+    lazy = _LazyWireBatch(a.cols_list, a.now, a.tol, a.pad, c.first)
     p = Pass(rows=np.arange(a.n), batch=lazy)
     pending = PendingCheck(
-        hb=lazy, err=a.err, now=a.now, passes=[[p, a.n, lazy, staged]],
-        clamped=a.clamped, rows=a.n, mark=a.act_fp, casc=a.casc,
-        casc_intrace=a.casc, promote=shadow_probe(engine, a.act_fp, a.now),
+        hb=lazy, err=c.err, now=a.now, passes=[[p, a.n, lazy, staged]],
+        clamped=c.clamped, rows=a.n, mark=c.act_fp, casc=c.casc,
+        casc_intrace=c.casc, promote=shadow_probe(engine, c.act_fp, a.now),
+        native=a.native,
     )
-    if a.later is not None:
-        with tracing.stage.within("later_stage", rows=int(a.later.size)) as st:
+    if c.later:
+        with tracing.stage.within("later_stage", rows=c.later) as st:
             later, lanes = _later_passes(engine, a)
             st.note(passes=len(later), lane_passes=lanes)
         pending.passes += later
@@ -829,22 +867,20 @@ def _wire_pending(engine, a: _WireAssembly, staged):
 
 def prepare_check_wire(engine, parts, now_ms=None) -> "PendingCheck | None":
     """Fused front-door preparation: pre-packed native wire lanes
-    (service/wire.WireBatch pieces) are scattered into ONE staged compact
-    ingress grid — the request bytes were traversed once by the parser and
-    this scatter is the only further touch. A key sent more than once in
-    the chunk keeps the grid for its first copy; the later ones are gathers
-    of the same lanes in the passes behind it (`_later_passes`). Returns a
-    PendingCheck for the standard issue/finish halves, or None when the
-    batch needs the general columns path — the fallback is semantically
-    identical, it just pays the full pack."""
+    (service/wire.WireBatch pieces) become ONE staged compact ingress grid —
+    the request bytes were traversed once by the parser, and one native
+    call over its lanes (`_assemble_wire_parts`) is the only further touch:
+    what is left of `put` is that call, one `device_put` of the grid, one
+    more of every block behind it, and the objects around them. A key sent
+    more than once in the chunk keeps the grid for its first copy; the later
+    ones are gathers of the same lanes in the passes behind it
+    (`_later_passes`). Returns a PendingCheck for the standard issue/finish
+    halves, or None when the batch needs the general columns path — the
+    fallback is semantically identical, it just pays the full pack."""
     a = _assemble_wire_parts(engine, parts, now_ms=now_ms)
     if a is None:
         return None
-    from gubernator_tpu.ops import wire as wire_mod
-
-    staged = engine.stage_wire(
-        a.grid, wire_mod.grid_math_mode(a.grid, a.n), cascade=a.casc
-    )
+    staged = engine.stage_wire(a.chunk.grid, a.chunk.math, cascade=a.chunk.casc)
     return _wire_pending(engine, a, staged)
 
 
@@ -877,12 +913,8 @@ def prepare_ring_slot(
     a = _assemble_wire_parts(engine, parts, now_ms=now_ms, pad_to=width)
     if a is None:
         return None
-    from gubernator_tpu.ops import wire as wire_mod
-
     pending = _wire_pending(engine, a, None)
-    return RingSlotPrep(
-        a.grid, wire_mod.grid_math_mode(a.grid, a.n), a.casc, pending
-    )
+    return RingSlotPrep(a.chunk.grid, a.chunk.math, a.chunk.casc, pending)
 
 
 def prepare_check_columns(engine, cols, now_ms=None) -> PendingCheck:
@@ -1018,7 +1050,10 @@ def finish_check_columns(
     limit_o = np.zeros(n, dtype=np.int64)
     remaining = np.zeros(n, dtype=np.int64)
     reset = np.zeros(n, dtype=np.int64)
-    delta = EngineStats(created_at_clamped=pending.clamped, checks=n)
+    delta = EngineStats(
+        created_at_clamped=pending.clamped, checks=n,
+        native_staged=int(pending.native),
+    )
     retried_any = False
     for pi, (p, np_, batch, pend) in enumerate(pending.passes):
         (s, l, r, t, dropped, hit), st, uncounted = engine.finish_staged(

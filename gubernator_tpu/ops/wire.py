@@ -66,6 +66,7 @@ from __future__ import annotations
 
 import functools
 import os
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -532,3 +533,86 @@ def gather_wire_block(
             (block[4, :n] & ~np.int32(_HITS_MASK)) | hits.astype(np.int32) | reset
         )
     return block
+
+
+# ------------------------------------------------ a fused chunk, staged
+
+# grid_math_mode's answers, indexed as native stage_wire_chunk names them
+MATH_MODES = ("token", "mixed", "gcra", "int")
+
+
+class StagedPass(NamedTuple):
+    """One pass behind a fused grid: its `rows` of the chunk and its
+    (5, pad+1) `block` (gather_wire_block) with the mode it selects, or
+    `block` None where the lanes cannot carry the pass and the caller packs
+    it as columns. The aggregate also names its `members`, group after
+    group from `starts` on, `member_counts` to a group (ops/plan.Pass)."""
+
+    rows: np.ndarray
+    block: "np.ndarray | None"
+    pad: int
+    math: "str | None"
+    members: "np.ndarray | None" = None
+    starts: "np.ndarray | None" = None
+    member_counts: "np.ndarray | None" = None
+
+
+class StagedChunk(NamedTuple):
+    """The host staging of one fused chunk (ops/engine._assemble_wire_parts):
+    the pass-0 `grid` with its `math` mode and cascade flag, a copy of the
+    rows' `err` the finish half owns, every active fingerprint, the rows
+    with a live lane in the grid (`first`), the clamped stamps counted, how
+    many rows are `later` copies of a key, and the passes those make."""
+
+    grid: np.ndarray
+    err: np.ndarray
+    act_fp: np.ndarray
+    first: np.ndarray
+    clamped: int
+    math: str
+    casc: bool
+    later: int
+    passes: "list[StagedPass]"
+
+
+def stage_wire_chunk(
+    mod, parts, now: int, tol: int, pad: int, one_grid: bool, max_exact: int,
+    pad_floor: int,
+) -> "StagedChunk | None":
+    """`StagedChunk` of the WireBatch pieces `parts` from ONE call into the
+    native module `mod` (native/guberhost.cpp stage_wire_chunk), which runs
+    without the GIL from the first row to the last; what comes back is
+    wrapped, not computed. None: the chunk cannot fuse. The NumPy staging
+    of ops/engine.py is the same function of the same arguments, and what
+    the tests hold this one to byte for byte."""
+    out = mod.stage_wire_chunk(
+        [(p.lanes, p.cols.fp, p.cols.err, p.cols.created_at) for p in parts],
+        now, tol, pad, one_grid, max_exact, pad_floor,
+    )
+    if out is None:
+        return None
+    grid, err, act_fp, first, clamped, math, casc, later, passes = out
+    return StagedChunk(
+        _as_block(grid, pad), np.frombuffer(err, np.int8),
+        _as_rows(act_fp), np.frombuffer(first, np.bool_),
+        clamped, MATH_MODES[math], casc, later,
+        [
+            StagedPass(
+                _as_rows(r), _as_block(b, p), p,
+                None if b is None else MATH_MODES[m],
+                _as_rows(mem), _as_rows(st), _as_rows(cnt),
+            )
+            for r, b, p, m, mem, st, cnt in passes
+        ],
+    )
+
+
+def _as_block(buf, pad: int) -> "np.ndarray | None":
+    """A (5, pad+1) int32 wire block over the native call's bytes."""
+    if buf is None:
+        return None
+    return np.frombuffer(buf, np.int32).reshape(WIRE_LANES, pad + 1)
+
+
+def _as_rows(buf) -> "np.ndarray | None":
+    return None if buf is None else np.frombuffer(buf, np.int64)

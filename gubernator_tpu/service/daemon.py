@@ -1813,6 +1813,11 @@ class Daemon:
                 "later_rows": eng.stats.later_rows,
                 "aggregate_rows": eng.stats.aggregate_rows,
                 "later_lane_rows": eng.stats.later_lane_rows,
+                # fused dispatches whose host staging was the one native
+                # call (ops/wire.stage_wire_chunk): all of
+                # batcher.fused_dispatches where the module is loaded (and
+                # the request ring's fused slots, where that is on)
+                "native_staged": eng.stats.native_staged,
                 # buckets a dirty block of the incremental checkpoint's
                 # tracker holds: there when the plane is armed and the
                 # tracker attached (every dispatch marks), else None

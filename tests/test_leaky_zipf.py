@@ -30,8 +30,8 @@ from tests.cluster import daemon_config
 from tests.oracle.algos import LeakyOracle
 from tests.test_mesh4_deployment import _spans
 from tests.test_observability import _stage_sums
-from tests.test_runner_chain import async_test
-from tests.test_wire_split import pair, wire_against_columns
+from tests.test_runner_chain import assert_same, async_test
+from tests.test_wire_split import pair, rpc, wire_against_columns
 
 pytestmark = pytest.mark.skipif(
     native.load() is None, reason="native toolchain unavailable"
@@ -226,6 +226,109 @@ async def test_a_chunk_of_the_deployments_size_rides_the_lanes_as_it_rode_column
     finally:
         r_wire.close()
         r_cols.close()
+
+
+def no_native_module(monkeypatch) -> None:
+    """A host with no toolchain, from here to the test's end: `native.load()`
+    is None, and the fused staging is NumPy's (ops/engine._stage_chunk_numpy).
+    Parse every RPC before this."""
+    monkeypatch.setattr(native, "load", lambda: None)
+
+
+def staged_natively(runner) -> int:
+    runner._exec.submit(lambda: None).result()  # the stats delta is in
+    return runner.engine.stats.native_staged
+
+
+@pytest.mark.parametrize("rpcs", [1, 8])
+@async_test
+async def test_a_zipf_chunk_answers_the_same_staged_natively_or_by_numpy(rpcs, monkeypatch):
+    """The deployment's chunk, twice (the second past the hot keys' limit),
+    on two equal engines: one staged by the native call, one with
+    `native.load()` patched to None. Every answer and every stored row are
+    the same bytes, both dispatches issue the same passes, and
+    `engine.native_staged` counts the dispatches of the first alone."""
+    rng = np.random.default_rng(39_001)
+    now = ms_now()
+    chunks = []
+    for _ in range(2):
+        ranks = zipf_ranks(rng, rpcs * RPC_ITEMS)
+        chunks.append([
+            wire_batch_from_wire(
+                body([(int(k), 1, LIMIT) for k in ranks[lo:lo + RPC_ITEMS]], now))[0]
+            for lo in range(0, ranks.size, RPC_ITEMS)
+        ])
+    r_native, r_numpy = pair(capacity=65536)
+    try:
+        fused, got = [], []
+        for parts in chunks:
+            got.append(await r_native.check_wire(
+                parts, now_ms=now, done=lambda _rc, _exc, f: fused.append(f)))
+        assert staged_natively(r_native) == 2
+        no_native_module(monkeypatch)
+        for parts, native_answer in zip(chunks, got):
+            assert_same(
+                await r_numpy.check_wire(
+                    parts, now_ms=now, done=lambda _rc, _exc, f: fused.append(f)),
+                native_answer,
+            )
+        assert staged_natively(r_numpy) == 0 and staged_natively(r_native) == 2
+        assert fused == [MAX_EXACT] * 4
+        assert (got[1].status == pb.OVER_LIMIT).sum() > 0.2 * ranks.size
+        fps = np.unique(np.concatenate([p.cols.fp for p in chunks[1]]))
+        (found_a, rows_a), (found_b, rows_b) = (
+            r.engine.read_state(fps) for r in (r_native, r_numpy)
+        )
+        assert found_a.all() and found_b.all() and (rows_a == rows_b).all()
+        for field in ("later_rows", "aggregate_rows", "later_lane_rows", "dispatches"):
+            assert getattr(r_native.engine.stats, field) == getattr(r_numpy.engine.stats, field) > 0
+    finally:
+        r_native.close()
+        r_numpy.close()
+
+
+@async_test
+async def test_chunks_staged_on_two_threads_at_once_answer_as_one_at_a_time(monkeypatch):
+    """240 Zipf chunks, each over keys of its own, in flight together through
+    one runner at a 10 µs switch interval: its prep threads run the native
+    staging side by side, which holds no state. Every chunk is answered as
+    the same chunk served alone from NumPy's staging on another engine."""
+    import sys
+
+    rng = np.random.default_rng(39_002)
+    now = ms_now()
+    chunks = []
+    for c in range(240):
+        keys = 100 * c + np.minimum(zipf_ranks(rng, 2 * 40), 11)
+        # a later copy in 50 is stamped 700 ms late and takes its pass off
+        # the lanes (a first copy so late would take the chunk off the wire)
+        rows, seen = [], set()
+        for k in map(int, keys):
+            late = k in seen and rng.random() < 0.02
+            rows.append((k, 700 if late else int(rng.integers(-200, 200)), 0))
+            seen.add(k)
+        chunks.append([rpc(rows[:40], now), rpc(rows[40:], now)])
+    r_native, r_numpy = pair(capacity=65536)
+    interval = sys.getswitchinterval()
+    try:
+        sys.setswitchinterval(1e-5)
+        try:
+            got = await asyncio.gather(
+                *(r_native.check_wire(parts, now_ms=now) for parts in chunks))
+        finally:
+            sys.setswitchinterval(interval)
+        assert staged_natively(r_native) == 240
+        no_native_module(monkeypatch)
+        for parts, native_answer in zip(chunks, got):
+            assert_same(await r_numpy.check_wire(parts, now_ms=now), native_answer)
+        assert staged_natively(r_numpy) == 0
+        a, b = r_native.engine.stats, r_numpy.engine.stats
+        assert a.later_rows == b.later_rows > 240 * 40
+        assert a.aggregate_rows == b.aggregate_rows > 0
+        assert 0 < a.later_lane_rows == b.later_lane_rows < a.later_rows
+    finally:
+        r_native.close()
+        r_numpy.close()
 
 
 @async_test

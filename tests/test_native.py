@@ -11,6 +11,8 @@ import pytest
 from gubernator_tpu import native
 from gubernator_tpu.proto import gubernator_pb2 as pb
 
+from tests.test_wire_split import EMPTY_KEY, EMPTY_NAME, LEAKY, RESET, SHAPES, rpc
+
 m = native.load()
 pytestmark = pytest.mark.skipif(m is None, reason="native toolchain unavailable")
 
@@ -304,3 +306,229 @@ def test_encode_many_takes_strided_columns_and_refuses_bad_input():
         encode_responses_many(rc._replace(err=np.full(64, 99, np.int8)), [0, 64])
     with pytest.raises(TypeError):
         encode_responses_many(rc._replace(limit=rc.limit.astype(float)), [0, 1])
+
+
+# ------------------------------------------------------ stage_wire_chunk
+# (the host staging of a fused chunk, one GIL-free call) against the NumPy
+# staging of ops/engine.py, byte for byte
+
+STAGE_NOW = 1_759_000_000_000
+GCRA, WINDOW = pb.GCRA, pb.SLIDING_WINDOW
+UNSTAMPED = -STAGE_NOW  # the offset that leaves created_at 0
+
+
+def _level_bit(parts):
+    """Cascade level 1 on the second row, as an engine-level caller packs."""
+    lanes = parts[0].lanes.copy()
+    lanes[3, 1] |= np.int32(1 << 30)
+    return [parts[0]._replace(lanes=lanes), *parts[1:]]
+
+
+def _every_other_row(parts):
+    """A selection of a parsed batch's rows: its lanes are not contiguous."""
+    from gubernator_tpu.service.wire import subset_wire
+
+    return [subset_wire(p, np.arange(0, p.rows, 2)) for p in parts]
+
+
+def _error_row_with_a_fingerprint(parts):
+    """An error row between two copies of the key whose fingerprint it is
+    given: the parser never writes one, the rank rule is held all the same
+    (the key's second copy is rank 2 and exact pass 1 is empty)."""
+    cols = parts[0].cols
+    fp = cols.fp.copy()
+    fp[1] = fp[0]
+    return [parts[0]._replace(cols=cols._replace(fp=fp))]
+
+
+def _zipf_chunk(seed, rpcs=3, rows=1000, keys=400, late=0.0):
+    """Leaky rows over Zipf(0.99) keys, stamped within 200 ms of the ingress
+    instant; a later copy of a key, one in 1/`late`, 700 ms after it."""
+    rng = np.random.default_rng(seed)
+    cdf = np.cumsum(np.arange(1, keys + 1, dtype=np.float64) ** -0.99)
+    ranks = np.searchsorted(cdf / cdf[-1], rng.random((rpcs, rows)))
+    seen, parts = set(), []
+    for part in np.minimum(ranks, keys - 1):
+        parts.append([])
+        for k in map(int, part):
+            off = 700 if k in seen and rng.random() < late else int(rng.integers(-200, 200))
+            parts[-1].append((k, off, 0, 1, LEAKY))
+            seen.add(k)
+    return parts
+
+
+# parts (rows as tests/test_wire_split.rpc takes them), then what differs
+# from: tolerance 5,000 ms, the bucketed pad, max_exact 8, lanes as parsed
+STAGINGS = {
+    **{name: (shape[0], {}) for name, shape in SHAPES.items()},
+    "one_part_no_repeat": ([[1, 2, 3]], {}),
+    "many_parts_no_repeat": ([[1, 2, 3], [4], [5, 6]], {}),
+    "unstamped_and_clamped_stamps": (
+        [[(1, UNSTAMPED, 0), (2, 400, 0), (1, -400, 0), 2, (1, UNSTAMPED, 0)],
+         [(3, 9_000, 0), (2, -9_000, 0)]],
+        {"tol": 300},
+    ),
+    "a_first_copy_outside_the_budget": ([[1, (2, 600, 0)]], {}),
+    "the_first_later_pass_outside_the_budget": ([[1, (1, 900, 0), 1, 2, 2]], {}),
+    "every_row_an_error": ([[EMPTY_KEY, EMPTY_NAME], [EMPTY_KEY]], {}),
+    "cascade_bits_and_no_repeat": ([[1, 2, 3]], {"edit": _level_bit}),
+    "cascade_bits_beside_a_repeat": ([[1, 2], [3, 1]], {"edit": _level_bit}),
+    "a_ring_slot_and_no_repeat": ([[1, 2], [3]], {"pad_to": 64}),
+    "a_ring_slot_and_a_repeat": ([[1, 2], [3, 1]], {"pad_to": 64}),
+    "a_ring_slot_filled_to_the_last_row": ([[1, 2], [3, 4]], {"pad_to": 4}),
+    "no_exact_pass_for_the_grid": ([[1, 1]], {"max_exact": 1}),
+    "every_later_copy_in_the_aggregate": ([[7, 8, 7, 7], [8, 9]], {"max_exact": 2}),
+    "one_exact_pass_then_the_aggregate": ([[7] * 5, [8, 7, 8]], {"max_exact": 3}),
+    "lanes_that_are_not_contiguous": ([[7, 1, 7, 2, 7, 3, 8, 4, 8]], {"edit": _every_other_row}),
+    "an_error_row_under_a_keys_fingerprint": (
+        [[1, EMPTY_KEY, 1, 2]], {"edit": _error_row_with_a_fingerprint},
+    ),
+    "all_gcra": ([[(1, 0, 0, 1, GCRA), (1, 0, 0, 1, GCRA), (2, 0, 0, 1, GCRA)]], {}),
+    "gcra_beside_a_window": (
+        [[(1, 0, 0, 1, GCRA), (2, 0, 0, 1, WINDOW), (1, 0, 0, 1, GCRA), (2, 0, 0, 1, WINDOW)]],
+        {},
+    ),
+    # the later copies name two algorithms and the first pass behind the
+    # grid holds only the token one: each pass selects its own mode
+    "a_token_pass_before_a_leaky_one": (
+        [[6, (7, 0, 0, 1, LEAKY), 6, (7, 0, 0, 1, LEAKY), (7, 0, 0, 1, LEAKY)]], {},
+    ),
+    "reset_remaining_beside_priority_bits": (
+        [[(7, 0, 64)] * 8 + [(7, 0, RESET | 128), (7, 0, 64), (8, 0, 192), (8, 0, RESET)]],
+        {},
+    ),
+    "zipf_3000_rows": (_zipf_chunk(11), {}),
+    "zipf_3000_rows_some_stamps_late": (_zipf_chunk(12, late=0.002), {}),
+    "zipf_3000_rows_clamped_into_the_budget": (_zipf_chunk(13, late=0.1), {"tol": 300}),
+}
+
+
+def _same_bytes(got, want, what):
+    if want is None or isinstance(want, (bool, int, str)):
+        assert got == want and type(got) is type(want), what
+        return
+    assert got.dtype == want.dtype and got.shape == want.shape, what
+    assert got.tobytes() == want.tobytes(), what
+
+
+def _same_staging(got, want):
+    assert (got is None) == (want is None)
+    if want is None:
+        return
+    for field in want._fields[:-1]:
+        _same_bytes(getattr(got, field), getattr(want, field), field)
+    assert len(got.passes) == len(want.passes)
+    for i, (g, w) in enumerate(zip(got.passes, want.passes)):
+        for field in w._fields:
+            _same_bytes(getattr(g, field), getattr(w, field), (i, field))
+
+
+def _stage_both(parts, tol=5_000, pad_to=None, max_exact=8, edit=None):
+    from gubernator_tpu.ops import engine, wire
+
+    parts = [rpc(p, STAGE_NOW) for p in parts]
+    if edit is not None:
+        parts = edit(parts)
+    pad = pad_to or engine._pad_size(sum(p.rows for p in parts))
+    args = (parts, STAGE_NOW, tol, pad, pad_to is not None, max_exact)
+    return (
+        wire.stage_wire_chunk(m, *args, engine._pad_size(0)),
+        engine._stage_chunk_numpy(*args),
+    )
+
+
+@pytest.mark.parametrize("case", STAGINGS)
+def test_stage_wire_chunk_is_the_numpy_staging_byte_for_byte(case):
+    parts, how = STAGINGS[case]
+    got, want = _stage_both(parts, **how)
+    _same_staging(got, want)
+    # what each case is there for
+    refused = case in (
+        "a_first_copy_outside_the_budget", "every_row_an_error",
+        "cascade_bits_beside_a_repeat", "a_ring_slot_and_a_repeat",
+        "no_exact_pass_for_the_grid",
+    )
+    assert (want is None) == refused
+    if refused:
+        return
+    n = sum(len(p) for p in parts) if "edit" not in how else want.first.size
+    assert want.grid.shape == (5, (how.get("pad_to") or max(16, 1 << (n - 1).bit_length())) + 1)
+    assert want.later == sum(p.rows.size if p.members is None else p.members.size for p in want.passes)
+    assert not want.grid[:, :n][:, ~want.first].any()
+    off_lanes = {
+        "stamps_at_the_edge_of_the_budget": [False, True],
+        "the_first_later_pass_outside_the_budget": [True, False],
+        "an_aggregate_whose_hits_pass_the_lane": [False] * 6 + [True],
+    }.get(case)
+    if off_lanes is not None:
+        assert [p.block is None for p in want.passes] == off_lanes
+    elif case == "zipf_3000_rows_some_stamps_late":
+        assert 0 < sum(p.block is None for p in want.passes) < len(want.passes) == 7
+    else:
+        assert all(p.block is not None for p in want.passes)
+    if case == "unstamped_and_clamped_stamps":
+        assert want.clamped == 4
+    if case == "cascade_bits_and_no_repeat":
+        assert want.casc
+    if case == "an_error_row_under_a_keys_fingerprint":
+        assert [p.rows.size for p in want.passes] == [0, 1]
+    if case == "a_token_pass_before_a_leaky_one":
+        assert [p.math for p in want.passes] == ["mixed", "mixed"]
+        assert want.math == "mixed"
+    if case == "two_algorithms_in_one_chunk":
+        assert [p.math for p in want.passes] == ["mixed", "mixed", "token"]
+    if case in ("all_gcra", "gcra_beside_a_window"):
+        assert (want.math, want.passes[0].math) == (
+            ("gcra", "gcra") if case == "all_gcra" else ("int", "int")
+        )
+
+
+def test_stage_wire_chunk_refuses_what_it_cannot_read():
+    from gubernator_tpu.ops import wire
+
+    wb = rpc([1, 2, 3], STAGE_NOW)
+    cols = wb.cols
+    good = (wb.lanes, cols.fp, cols.err, cols.created_at)
+    for bad, exc in (
+        ((wb.lanes.astype(np.int64), *good[1:]), TypeError),  # not int32
+        ((wb.lanes[:4], *good[1:]), TypeError),  # four lanes
+        ((wb.lanes, cols.fp[:2], *good[2:]), ValueError),  # a short column
+        ((wb.lanes, cols.fp.astype(np.float64), *good[2:]), TypeError),
+        (good[:3], TypeError),
+    ):
+        with pytest.raises(exc):
+            m.stage_wire_chunk([bad], STAGE_NOW, 5_000, 16, False, 8, 16)
+    with pytest.raises(ValueError):  # a pad below the rows
+        m.stage_wire_chunk([good], STAGE_NOW, 5_000, 2, False, 8, 16)
+    assert wire.stage_wire_chunk(m, [wb], STAGE_NOW, 5_000, 16, False, 8, 16) is not None
+
+
+def test_stage_wire_chunk_holds_no_state_between_threads():
+    """240 Zipf chunks staged from eight threads at a 10 µs switch interval,
+    each twice: every one is the staging NumPy makes of it alone."""
+    import sys
+    from concurrent.futures import ThreadPoolExecutor
+
+    from gubernator_tpu.ops import engine, wire
+
+    chunks = []
+    for seed in range(240):
+        rows = _zipf_chunk(seed, rpcs=2, rows=150, keys=40, late=0.01)
+        args = ([rpc(p, STAGE_NOW) for p in rows], STAGE_NOW, 5_000, 512, False, 8)
+        chunks.append((args, engine._stage_chunk_numpy(*args)))
+    assert sum(len(want.passes) == 7 for _a, want in chunks) > 100
+
+    def stage(chunk):
+        args, want = chunk
+        for _ in range(2):
+            _same_staging(wire.stage_wire_chunk(m, *args, 16), want)
+        return True
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        with ThreadPoolExecutor(8) as pool:
+            done = list(pool.map(stage, chunks, timeout=120))
+    finally:
+        sys.setswitchinterval(interval)
+    assert done == [True] * 240

@@ -98,11 +98,12 @@ async def wire_against_columns(r_wire, r_cols, parts, now):
     deltas = [
         {k: a[k] - b[k] for k in a} for a, b in zip(stats(), before)
     ]
-    # the one counter only the wire can move: rows staged from its lanes
-    lane_rows = deltas[0].pop("later_lane_rows")
-    assert deltas[1].pop("later_lane_rows") == 0
-    assert deltas[0] == deltas[1]
-    deltas[0]["later_lane_rows"] = lane_rows
+    # the counters only the wire can move: rows staged from its lanes, and
+    # the dispatch that the native call staged
+    wire_only = {k: deltas[0].pop(k) for k in ("later_lane_rows", "native_staged")}
+    assert not any(deltas[1].pop(k) for k in wire_only)
+    assert deltas[0] == deltas[1] and wire_only["native_staged"] == 1
+    deltas[0].update(wire_only)
     fps = np.unique(cols.fp[cols.err == 0])
     (found_w, rows_w), (found_c, rows_c) = (
         r.engine.read_state(fps) for r in (r_wire, r_cols)
