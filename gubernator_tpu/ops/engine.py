@@ -1213,7 +1213,10 @@ class LocalEngine:
         # cost, bit-identical dispatch graphs.
         self.shadow = None
         self.stats = EngineStats()
-        self._seen_pad_sizes: set = set()  # compiled batch shapes (for resize warm)
+        # device passes launched, by padded batch size (_issue_from_dev, the
+        # ring's fused drains): the shapes a resize compiles again, and what
+        # `passes_by_write` counts
+        self._pad_passes: dict = {}
         # reason string when a failed donated launch left device state
         # suspect (see GlobalShardedEngine._requeue_popped); surfaces as
         # health_check "unhealthy". Never set on the single-device path
@@ -1359,9 +1362,9 @@ class LocalEngine:
         self, dev_arr, batch_rows: int, math: str, wired: bool = False,
         cascade: bool = False,
     ) -> "jax.Array":
-        """Issue one dispatch from a staged ingress array WITHOUT fetching:
-        the table advances immediately; the packed output is fetched later
-        on a fetch thread while this thread launches the next dispatch."""
+        """Launch one pass from a staged ingress array WITHOUT fetching (the
+        output is fetched later, off this thread); counted by its pad."""
+        self._pad_passes[batch_rows] = self._pad_passes.get(batch_rows, 0) + 1
         ev = self._evictees
         if wired:
             from gubernator_tpu.ops.wire import decide2_wire_cols
@@ -1432,7 +1435,6 @@ class LocalEngine:
         if needs_full:
             # engine thread — the only thread allowed to swap the table
             self.migrate_layout_full()
-        self._seen_pad_sizes.add(batch_rows)
         return self._issue_from_dev(dev, batch_rows, math, wired, cascade)
 
     def finish_staged(self, pending, n: int):
@@ -1543,7 +1545,6 @@ class LocalEngine:
         bucket within a single dispatch) are re-dispatched — the decision is
         only authoritative once persisted. Rows still unpersisted after
         `max_claim_retries` surface a per-item error (`ERR_NOT_PERSISTED`)."""
-        self._seen_pad_sizes.add(int(batch.fp.shape[0]))
         (status, limit, remaining, reset, dropped, hit), st = unpack_outputs(
             self._decide_packed(batch, cascade), n
         )
@@ -1939,7 +1940,7 @@ class LocalEngine:
         # packed layouts warm only their own math graph (off-family probe
         # rows would trigger a spurious migration)
         probe_algos = (0, 2, 1) if lay is _FULL else (lay.algos[0],)
-        for size in sorted(self._seen_pad_sizes):
+        for size in sorted(self._pad_passes):
             z64 = np.zeros(size, dtype=np.int64)
             for probe_algo in probe_algos:
                 algo = np.zeros(size, dtype=np.int32)
@@ -1982,3 +1983,24 @@ class LocalEngine:
                 return False
         self.resize(new_cap, now_ms)
         return True
+
+    # ------------------------------------------------------------ pass counts
+
+    def passes_by_write(self) -> dict:
+        """Device passes launched since warm-up, by the write their padded
+        shape resolves to on this table (kernel2.resolve_write over the
+        counts by pad: `sparse`, `sweep`, or `xla` off the TPU). Read from
+        any thread; the engine thread alone counts."""
+        from gubernator_tpu.ops.kernel2 import resolve_write
+
+        nb = int(self.table.rows.shape[-2])
+        lay = getattr(self.table, "layout", None)
+        out = {"sparse": 0, "sweep": 0, "xla": 0}
+        for pad, n in list(self._pad_passes.items()):
+            out[resolve_write(self.write_mode, nb, pad, lay)] += n
+        return out
+
+    def forget_passes(self) -> None:
+        """Zero the pass counts and keep their shapes (warm-up is no traffic,
+        and a resize still has to compile what it compiled)."""
+        self._pad_passes = dict.fromkeys(self._pad_passes, 0)

@@ -24,6 +24,7 @@ operators) check the device scan against.
 from __future__ import annotations
 
 import functools
+import math
 import time
 from dataclasses import dataclass, field
 from typing import List, Optional
@@ -42,6 +43,7 @@ from gubernator_tpu.ops.table2 import (
     K,
     LIMIT,
     REM_I,
+    _COUNT_CHUNK,
 )
 
 # TTL-horizon bucket edges (ms since `now`): live keys expiring within each
@@ -192,15 +194,45 @@ def _scan_body(rows: jnp.ndarray, now: jnp.ndarray, blk: int,
     return jnp.concatenate(parts)
 
 
-_scan = functools.partial(jax.jit, static_argnames=("blk", "layout"))(
-    _scan_body
-)
-
-
 def block_width(n_buckets: int) -> int:
     """Occupancy-block width for a table geometry: the sweep's 64-bucket
     block when it divides, the whole (tiny) table otherwise."""
     return BLOCK_BUCKETS if n_buckets % BLOCK_BUCKETS == 0 else n_buckets
+
+
+def scan_chunk(n_buckets: int, blk: Optional[int] = None) -> int:
+    """Buckets the single-device scan takes a step: `_COUNT_CHUNK` where it
+    divides the table, and never less than an occupancy block."""
+    blk = block_width(n_buckets) if blk is None else blk
+    chunk = math.gcd(n_buckets, _COUNT_CHUNK)
+    return chunk if chunk % blk == 0 else n_buckets
+
+
+def _scan_chunked(rows: jnp.ndarray, now: jnp.ndarray, blk: int,
+                  layout=None) -> jnp.ndarray:
+    """`_scan_body` over one device's rows in pieces of `_COUNT_CHUNK`
+    buckets, summed (every entry of the vector is additive over disjoint
+    rows). Seeing rows as (.., K, F) makes a TPU copy them into another
+    tiling — scratch of 1.25x the table's bytes when the table goes through
+    whole, which no chip has left beside a table of half its memory (the
+    compiler refuses the program at 8 GiB of 16) — so, as the live count
+    does (ops/table2.live_count_rows), it goes through 8 MiB at a time."""
+    rows = rows.reshape(-1, rows.shape[-1])
+    nb = rows.shape[0]
+    chunk = scan_chunk(nb, blk)
+
+    def step(i, acc):
+        part = jax.lax.dynamic_slice_in_dim(rows, i * chunk, chunk)
+        return acc + _scan_body(part, now, blk, layout)
+
+    return jax.lax.fori_loop(
+        0, nb // chunk, step, jnp.zeros(VEC_LEN, dtype=jnp.int64)
+    )
+
+
+_scan = functools.partial(jax.jit, static_argnames=("blk", "layout"))(
+    _scan_chunked
+)
 
 
 class PendingScan:

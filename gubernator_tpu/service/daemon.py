@@ -123,42 +123,20 @@ class Daemon:
         # doing (tracing.HostClocks): read at scrape time, armed in spawn
         self.host = tracing.HostClocks(self.metrics)
         self.metrics.watch_host(self.host)
+        self.metrics.watch_passes(self)
         if engine is not None:
             self.engine = engine
             if store is not None:
                 engine.store = store
-        elif conf.engine == "sharded":
-            # one daemon serving a whole device mesh: the table shards over
-            # every local device, ownership = fingerprint % n_shards. The
-            # mesh-global engine additionally serves the GLOBAL behavior as
-            # collectives (replica answers + all_gather sync over ICI) when
-            # this daemon runs standalone — the BASELINE #3 topology where
-            # the mesh IS the peer group.
-            import jax
-
-            from gubernator_tpu.parallel import make_mesh
-            from gubernator_tpu.parallel.global_sync import GlobalShardedEngine
-
-            n_dev = len(jax.devices())
-            self.engine = GlobalShardedEngine(
-                # topology resolves inside make_mesh: GUBER_MESH_HOSTS (the
-                # simulated multi-host mode) or jax.process_count() fold the
-                # devices into 2-D (host, device) axes; single hosts keep
-                # the seed's 1-D "shard" axis
-                make_mesh(n_dev),
-                capacity_per_shard=max(1, conf.cache_size // n_dev),
-                created_at_tolerance_ms=int(conf.created_at_tolerance_ms),
-                store=store,
-                # "auto" = the backend default (device routing + in-trace
-                # dedup on TPU meshes, host grid + pass planner elsewhere)
-                route=None if conf.shard_route == "auto" else conf.shard_route,
-                dedup=None if conf.shard_dedup == "auto" else conf.shard_dedup,
-            )
         else:
-            self.engine = LocalEngine(
-                capacity=conf.cache_size,
-                created_at_tolerance_ms=int(conf.created_at_tolerance_ms),
-                store=store,
+            # start-up's first part, timed to the device's own end of it:
+            # the table allocated and zeroed (stage `table_alloc`)
+            with tracing.stage("table_alloc", self.metrics) as st:
+                self.engine = self._new_engine(conf, store)
+                self.engine.table.rows.block_until_ready()
+            log.info(
+                "table_alloc: %d bytes in %.1f s",
+                self.engine.table.rows.nbytes, st.dt,
             )
         self.runner = EngineRunner(
             self.engine,
@@ -284,6 +262,43 @@ class Daemon:
         self._http_ssl_contexts = []  # live HTTPS listener contexts
 
     # ---------------------------------------------------------------- spawn
+    @staticmethod
+    def _new_engine(conf: DaemonConfig, store):
+        """The engine the configuration names, its table fresh on the
+        device(s)."""
+        if conf.engine == "sharded":
+            # one daemon serving a whole device mesh: the table shards over
+            # every local device, ownership = fingerprint % n_shards. The
+            # mesh-global engine additionally serves the GLOBAL behavior as
+            # collectives (replica answers + all_gather sync over ICI) when
+            # this daemon runs standalone — the BASELINE #3 topology where
+            # the mesh IS the peer group.
+            import jax
+
+            from gubernator_tpu.parallel import make_mesh
+            from gubernator_tpu.parallel.global_sync import GlobalShardedEngine
+
+            n_dev = len(jax.devices())
+            return GlobalShardedEngine(
+                # topology resolves inside make_mesh: GUBER_MESH_HOSTS (the
+                # simulated multi-host mode) or jax.process_count() fold the
+                # devices into 2-D (host, device) axes; single hosts keep
+                # the seed's 1-D "shard" axis
+                make_mesh(n_dev),
+                capacity_per_shard=max(1, conf.cache_size // n_dev),
+                created_at_tolerance_ms=int(conf.created_at_tolerance_ms),
+                store=store,
+                # "auto" = the backend default (device routing + in-trace
+                # dedup on TPU meshes, host grid + pass planner elsewhere)
+                route=None if conf.shard_route == "auto" else conf.shard_route,
+                dedup=None if conf.shard_dedup == "auto" else conf.shard_dedup,
+            )
+        return LocalEngine(
+            capacity=conf.cache_size,
+            created_at_tolerance_ms=int(conf.created_at_tolerance_ms),
+            store=store,
+        )
+
     @classmethod
     async def spawn(
         cls,
@@ -531,6 +546,14 @@ class Daemon:
                 log.exception("certificate rotation check failed")
 
     async def warm_up(self) -> None:
+        """Start-up's second part, as stage `warm_up` (`shapes`: the warm
+        batches sent, each there to compile one shape of one program)."""
+        with tracing.stage("warm_up", self.metrics) as st:
+            shapes = await self._warm_shapes()
+            st.note(shapes=shapes)
+        log.info("warm_up: %d shapes in %.1f s", shapes, st.dt)
+
+    async def _warm_shapes(self) -> int:
         """Compile the decision + install kernels for the smallest batch shape
         BEFORE serving: the first XLA compile takes seconds, which would blow
         the 500 ms peer-RPC budgets (global_timeout, batch_timeout) and drop
@@ -553,7 +576,9 @@ class Daemon:
                 v for v in variants
                 if lay.supports_algos(np.asarray(v, dtype=np.int32))
             )
+        shapes = 1  # the install below
         for algos in variants:
+            shapes += 1
             n = len(algos)
             warm = RequestColumns(
                 fp=np.arange(1, n + 1, dtype=np.int64),
@@ -592,6 +617,7 @@ class Daemon:
             # so they skip the staging-read compile.
             from gubernator_tpu.ops.table2 import F as F_FULL
 
+            shapes += 2
             fp1 = np.asarray([1], dtype=np.int64)
             await self.runner.read_state_raw(fp1)
             # an all-zero incoming row is expired at every clock: the
@@ -633,6 +659,7 @@ class Daemon:
                         err=np.zeros(size, dtype=np.int8),
                     )
                     await self.runner.check_columns(warm)
+                    shapes += 1
                 size *= 2
             # herd geometries: a same-key batch plans j sequential passes
             # (j ≤ max_exact) whose same-shape outputs fuse into one
@@ -655,6 +682,7 @@ class Daemon:
                 # through the PIPELINED door: the stack kernel only traces
                 # on the issue path (serial check_columns never stacks)
                 await self.runner.check(warm)
+                shapes += 1
             if (
                 getattr(self.engine, "mesh_global", False)
                 and self.engine.store is None
@@ -682,6 +710,9 @@ class Daemon:
         )
         self.engine.stats = EngineStats()
         self.metrics._last_engine = None
+        if hasattr(self.engine, "forget_passes"):
+            self.engine.forget_passes()
+        return shapes
 
     async def _start_discovery(self) -> None:
         kind = self.conf.peer_discovery_type
@@ -1733,18 +1764,23 @@ class Daemon:
         devs = jax.devices()
         # per-device HBM in use (None where the backend reports no stats,
         # e.g. CPU): shows a mesh's table spread over its chips
-        mem = [(d.memory_stats() or {}).get("bytes_in_use") for d in devs]
+        # in use, at its highest since start, and the most the runtime hands out
+        mem = [d.memory_stats() or {} for d in devs]
         return {
             "platform": devs[0].platform,
             "device_kind": devs[0].device_kind,
             "device_count": len(devs),
-            "device_bytes_in_use": mem,
+            "device_bytes_in_use": [m.get("bytes_in_use") for m in mem],
+            "device_peak_bytes": [m.get("peak_bytes_in_use") for m in mem],
+            "device_bytes_limit": [m.get("bytes_limit") for m in mem],
         }
 
     def debug_pipeline(self) -> dict:
         """Front-door + engine pipeline state: ring depth, worker liveness,
         dispatch-path counters, adaptive-close reasons, engine identity."""
         eng = self.engine
+        passes = eng.passes_by_write() if hasattr(eng, "passes_by_write") else {}
+        snap = self._table_telemetry
         return {
             "batcher": self.batcher.debug(),
             # CPU ms of the loop thread and of every worker pool from the
@@ -1776,6 +1812,21 @@ class Daemon:
                 "table_bytes": int(eng.table.rows.nbytes),
                 "wire": getattr(eng, "wire", None),
                 "write_mode": getattr(eng, "write_mode", None),
+                # device passes since warm-up, and of them by the write their
+                # padded shape resolved to (kernel2.resolve_write: `write_mode`
+                # "sparse" still sweeps the whole table where a pad's worst
+                # dirty coverage passes a quarter of it); a mesh engine keeps
+                # no such counts and has none of the three keys
+                **({
+                    "passes_total": sum(passes.values()),
+                    "passes_sparse": passes["sparse"],
+                    "passes_sweep": passes["sweep"],
+                } if passes else {}),
+                # live slots / slots at the newest telemetry scan (None
+                # before the first), beside the live keys evicted since
+                # warm-up (/v1/debug/table has the same count)
+                "table_load": None if snap is None else snap.load_factor,
+                "evicted_live_total": eng.stats.evicted_unexpired,
                 # constants: bench/configs/*.json `expect_engine` and
                 # chip_smoke.py still compare these two keys (ROADMAP C8)
                 "probe_kernel": "xla",

@@ -86,6 +86,30 @@ class _ThreadCpuCollector:
         yield fam
 
 
+class _DevicePassCollector:
+    """gubernator_tpu_device_passes_total{write}: device passes since
+    warm-up by the write their padded shape resolved to (`sparse`, `sweep`;
+    `xla` off the TPU), from the engine's counts by pad when the scrape asks
+    (ops/engine.LocalEngine.passes_by_write: `engine.passes_sparse` and
+    `engine.passes_sweep` of /v1/debug/pipeline, `engine.passes_total` their
+    sum). A mesh engine keeps no
+    such counts and the family stays empty."""
+
+    def __init__(self, daemon):
+        self.daemon = daemon
+
+    def collect(self):
+        fam = CounterMetricFamily(
+            "gubernator_tpu_device_passes",
+            "Device passes by the table write their batch shape resolved to",
+            labels=["write"],
+        )
+        by_write = getattr(self.daemon.engine, "passes_by_write", dict)()
+        for write, n in by_write.items():
+            fam.add_metric([write], n)
+        yield fam
+
+
 class DaemonMetrics:
     """One daemon's metric family set (names mirror docs/prometheus.md).
 
@@ -725,6 +749,11 @@ class DaemonMetrics:
     def watch_host(self, host) -> None:
         """Render `host` (tracing.HostClocks) as the thread CPU family."""
         self.registry.register(_ThreadCpuCollector(host))
+
+    def watch_passes(self, daemon) -> None:
+        """Render the engine `daemon` holds at scrape time (a daemon's engine
+        is swapped by tests and by a restore) as the device-pass family."""
+        self.registry.register(_DevicePassCollector(daemon))
 
     def observe_engine(self, stats) -> None:
         """Refresh counter families from an EngineStats snapshot (engine

@@ -354,7 +354,10 @@ class EngineRunner:
             head = group[0]
             if engine._batch_needs_full(head.math):
                 engine.migrate_layout_full()
-            engine._seen_pad_sizes.add(dring.width)
+            # a fused drain decides one slot-wide pass a slot
+            engine._pad_passes[dring.width] = (
+                engine._pad_passes.get(dring.width, 0) + len(group)
+            )
             for i, prep in enumerate(group):
                 dring.stage((start + i) % dring.slots, prep.grid, start + i)
             return dring.drain(
@@ -500,12 +503,16 @@ class EngineRunner:
                 max_workers=1, thread_name_prefix="telemetry"
             )
 
+        # the bytes the scan streams ride on both spans: what its time is
+        # set against (the whole table goes through the device once)
+        nbytes = int(self.engine.table.rows.nbytes)
+
         def launch():
-            with tracing.stage("scan_launch", self.metrics):
+            with tracing.stage("scan_launch", self.metrics, table_bytes=nbytes):
                 return self.engine.telemetry_begin(now_ms)
 
         def fetch(pending):
-            with tracing.stage("scan_fetch", self.metrics):
+            with tracing.stage("scan_fetch", self.metrics, table_bytes=nbytes):
                 return finish_scan(pending)
 
         pending = await loop.run_in_executor(self._exec, launch)
