@@ -192,6 +192,9 @@ static const int32_t BEHAVIOR_CLIENT_MASK = 255;
 // values are per-item errors on the full path, so never fused
 static const int32_t MAX_ALGORITHM = 4;
 static const int32_t ALGO_CONCURRENCY_LEASE = 4;  // types.Algorithm
+// gubernator_tpu_decisions_total's labels (service/runner._ALGO_LABELS): one
+// an algorithm, and `invalid` last for a value that is none of them
+static const int DECISION_LABELS = 6;
 
 struct Item {
   const uint8_t* name = nullptr; size_t name_len = 0;
@@ -271,7 +274,7 @@ static bool parse_item(Cursor& c, Item& it) {
   return c.ok;
 }
 
-// parse_get_rate_limits(data: bytes)
+// parse_get_rate_limits(data: bytes, now_ms: int = 0)
 //   -> (n, fp, algo, behavior, hits, limit, burst, duration, created_at,
 //       err, ring_hash, spans, traceparent, lanes, enc, summary)
 // Buffer layouts (np.frombuffer): fp/hits/limit/burst/duration/created_at
@@ -281,15 +284,26 @@ static bool parse_item(Cursor& c, Item& it) {
 // created-delta field zero); enc int8 per-item compact-wire encodability.
 // summary is the batch reduced over its items in the fill loop
 // (service/wire.RowSummary, in field order): rows with err set, OR of the
-// behavior words, lease rows, rows with created_at 0, enc rows, highest
-// priority tier, rows that carry a cascade field (such a batch takes the pb
-// path, where the daemon expands the levels) — what the handler and the
-// enqueue would otherwise scan the columns for.
+// behavior words, lease rows, rows the client sent with created_at 0, enc
+// rows, highest priority tier, rows that carry a cascade field (such a batch
+// takes the pb path, where the daemon expands the levels), the earliest and
+// the latest created_at as served, the first row's fingerprint (the
+// batcher's tenant bucket), and the rows by decision label
+// (service/runner._ALGO_LABELS: one count an algorithm, an out-of-range value
+// under the last) — what the handler, the enqueue and the dispatch's
+// decision counters would otherwise scan the columns for on the event-loop
+// thread.
+// now_ms is the handler's clock at request entry (upstream stamps there,
+// gubernator.go:225-227): a row the client sent with created_at 0 is served
+// with it, so the column comes back stamped and stamp_lo / stamp_hi are over
+// the stamps as served. 0 stamps nothing (the rows stay 0 and are counted
+// in the range as 0); an empty batch reads now_ms for both ends.
 // The scan + fill loops run with the GIL RELEASED — N front-door workers
 // parse concurrently (service/daemon.py door pool).
 static PyObject* parse_get_rate_limits(PyObject*, PyObject* args) {
   Py_buffer buf;
-  if (!PyArg_ParseTuple(args, "y*", &buf)) return nullptr;
+  long long now_ms = 0;
+  if (!PyArg_ParseTuple(args, "y*|L", &buf, &now_ms)) return nullptr;
   const uint8_t* data = (const uint8_t*)buf.buf;
 
   std::vector<Item> items;
@@ -374,12 +388,17 @@ static PyObject* parse_get_rate_limits(PyObject*, PyObject* args) {
   int8_t* enc = (int8_t*)PyBytes_AS_STRING(enc_b);
 
   long long n_err = 0, n_lease = 0, n_unstamped = 0, n_enc = 0, n_casc = 0;
+  long long by_label[DECISION_LABELS] = {0};
+  int64_t stamp_lo = now_ms, stamp_hi = now_ms;
   int32_t beh_or = 0, max_tier = 0;
   Py_BEGIN_ALLOW_THREADS;
   std::string hk;
   for (size_t i = 0; i < n; i++) {
     const Item& it = items[i];
     algo[i] = it.algorithm;
+    by_label[it.algorithm >= 0 && it.algorithm < DECISION_LABELS - 1
+                 ? it.algorithm
+                 : DECISION_LABELS - 1]++;
     // client-facing flag bits only: the high bits are the internal cascade
     // level field, which must never arrive from the wire
     beh[i] = it.behavior & BEHAVIOR_CLIENT_MASK;
@@ -392,7 +411,9 @@ static PyObject* parse_get_rate_limits(PyObject*, PyObject* args) {
     lim[i] = it.limit;
     burst[i] = it.burst;
     dur[i] = it.duration;
-    ca[i] = it.created_at;
+    ca[i] = it.created_at ? it.created_at : now_ms;
+    if (i == 0 || ca[i] < stamp_lo) stamp_lo = ca[i];
+    if (i == 0 || ca[i] > stamp_hi) stamp_hi = ca[i];
     span[2 * i] = (int64_t)it.start;
     span[2 * i + 1] = (int64_t)it.len;
     fp[i] = 0;
@@ -466,8 +487,12 @@ static PyObject* parse_get_rate_limits(PyObject*, PyObject* args) {
   PyTuple_SET_ITEM(out, 12, tp);
   PyTuple_SET_ITEM(out, 13, lanes_b);
   PyTuple_SET_ITEM(out, 14, enc_b);
-  PyObject* summary = Py_BuildValue("(LiLLLiL)", n_err, (int)beh_or, n_lease,
-                                    n_unstamped, n_enc, (int)max_tier, n_casc);
+  static_assert(DECISION_LABELS == 6, "the summary's tuple names each label");
+  PyObject* summary = Py_BuildValue(
+      "(LiLLLiLLLL(LLLLLL))", n_err, (int)beh_or, n_lease, n_unstamped, n_enc,
+      (int)max_tier, n_casc, (long long)stamp_lo, (long long)stamp_hi,
+      (long long)(n ? fp[0] : 0), by_label[0], by_label[1], by_label[2],
+      by_label[3], by_label[4], by_label[5]);
   if (!summary) {
     Py_DECREF(out);
     return nullptr;

@@ -265,6 +265,8 @@ class Batcher:
         self.ring_dispatches = 0  # all-wire chunk staged into the ring
         # entries answered with the bytes their dispatch's encode link wrote
         self.encoded_requests = 0
+        # entries made from the parser's summary alone, no column read
+        self.summary_entries = 0
         self.adaptive_closes = 0  # window closed on rows/bytes/idle engine
         self.window_expires = 0  # window closed on the wall-clock ceiling
         # adaptive-close reason split (the /v1/debug/pipeline payload):
@@ -291,44 +293,45 @@ class Batcher:
         GetRateLimitsResp bytes: written off the loop by the dispatch that
         answers them, and their OVER_LIMIT rows counted there."""
         t_in = time.perf_counter()
-        now = now_ms if now_ms is not None else ms_now()
-        # stamp unset created_at at ENQUEUE time (reference stamps at request
-        # entry, gubernator.go:225-227), not at flush time. Where the parser
-        # reduced these rows already (wire.RowSummary), the stamp, the tier
-        # and the cost come from that, with no scan of the columns on the
-        # event-loop thread (but for the range of the stamps a client set
-        # itself: `_form_chunk` keeps a chunk inside the wire's budget).
-        cols = _payload_cols(payload)
-        rows = cols.fp.shape[0]
         summary = payload.summary if isinstance(payload, WireBatch) else None
-        stamps = None  # the stamps that are not `now`, where a row has one
-        if summary is None or 0 < summary.unstamped < rows:
-            created = stamps = np.where(cols.created_at == 0, now, cols.created_at)
-        elif summary.unstamped:
-            created = np.full(rows, now, dtype=np.int64)
+        if summary is not None and summary.stamped:
+            # The native parser stamped these very rows on the door thread,
+            # with the handler's clock at request entry (reference stamps
+            # there, gubernator.go:225-227), and reduced them in the same
+            # pass (wire.RowSummary): the entry is made of its integers, and
+            # the event-loop thread reads no column and calls no array
+            # function for an enqueue.
+            rows = payload.rows
+            stamp_lo, stamp_hi = summary.stamp_lo, summary.stamp_hi
+            tier, cost = summary.max_tier, rows + summary.leases
+            bucket = summary.first_fp & (self.tenant_buckets - 1)
+            self.summary_entries += 1
         else:
-            created, stamps = None, cols.created_at  # the client stamped every row
-        stamp_lo = stamp_hi = now
-        if stamps is not None and rows:
-            stamp_lo, stamp_hi = int(stamps.min()), int(stamps.max())
-        if created is not None:
+            # columns, rows selected from a parsed batch, the pb path (or a
+            # batch parsed with no clock): stamp unset created_at at ENQUEUE
+            # time, not at flush time, and scan the columns for the range of
+            # the stamps (`_form_chunk` keeps a chunk inside the wire's
+            # budget), the tier and the cost. A summary does not outlive the
+            # rewrite of a column it was reduced from.
+            now = now_ms if now_ms is not None else ms_now()
+            cols = _payload_cols(payload)
+            rows = cols.fp.shape[0]
+            created = np.where(cols.created_at == 0, now, cols.created_at)
+            stamp_lo = stamp_hi = now
+            if rows:
+                stamp_lo, stamp_hi = int(created.min()), int(created.max())
             cols = cols._replace(created_at=created)
             if isinstance(payload, WireBatch):
-                if summary is not None:
-                    summary = summary._replace(unstamped=0)
-                payload = payload._replace(cols=cols, summary=summary)
+                payload = payload._replace(cols=cols, summary=None)
             else:
                 payload = cols
-        if summary is not None:
-            tier, cost = summary.max_tier, rows + summary.leases
-        else:
             tier, cost = _payload_tier(payload), _payload_cost(payload)
+            bucket = _payload_bucket(payload, self.tenant_buckets)
         loop = asyncio.get_running_loop()
         if self._wake is None:
             self._wake = asyncio.Event()
             self._full = asyncio.Event()
             self._space = asyncio.Event()
-        bucket = _payload_bucket(payload, self.tenant_buckets)
         deadline = self._item_deadline()
         entry = _Entry(
             payload, loop.create_future(), time.perf_counter(),

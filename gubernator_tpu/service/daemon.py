@@ -71,6 +71,16 @@ FORWARD_RETRIES = 5  # reference asyncRequest retries (gubernator.go:333-359)
 _ROUTED_BEHAVIOR = int(Behavior.GLOBAL) | int(Behavior.MULTI_REGION)
 
 
+def _land(fut, res, exc) -> None:
+    """A door-pool hop's end, on the loop thread: its future resolved."""
+    if fut.cancelled():  # its awaiter was; nobody is left to tell
+        pass
+    elif exc is not None:
+        fut.set_exception(exc)
+    else:
+        fut.set_result(res)
+
+
 def _encode_counted(status, limit, remaining, reset, errors, now):
     """What the encode hop runs for a raw RPC of the general path (on a
     door-pool thread for a big one): the response bytes and the OVER_LIMIT
@@ -1066,9 +1076,12 @@ class Daemon:
         parsed = None
         parse_s = door_wait_s = 0.0
         if self.event_channel is None:
+            # the rows' stamp is this clock, read at request entry (the
+            # reference's, gubernator.go:225-227): the parser writes it
+            # where the client sent none, on the door thread
             parsed, parse_s, door_wait_s = await self._through_door(
                 "parse", len(data) >= self.DOOR_OFFLOAD_BYTES,
-                wire_batch_from_wire, data,
+                wire_batch_from_wire, data, self.now_ms(),
             )
         if parsed is None:
             req = pb.GetRateLimitsReq.FromString(data)
@@ -1133,9 +1146,22 @@ class Daemon:
         if not offload:
             out, _ = work()
             return out, time.perf_counter() - t0, 0.0
-        out, work_s = await asyncio.get_running_loop().run_in_executor(
-            self._door, work
-        )
+        # one submit and one call_soon_threadsafe that resolves a future of
+        # this loop: `run_in_executor` wraps the pool's future in a second
+        # one and chains the two, which costs the loop thread half as much
+        # again for every RPC
+        loop = asyncio.get_running_loop()
+        fut = loop.create_future()
+
+        def hop() -> None:
+            try:
+                res, exc = work(), None
+            except BaseException as e:  # all an executor's future carries
+                res, exc = None, e
+            loop.call_soon_threadsafe(_land, fut, res, exc)
+
+        self._door.submit(hop)
+        out, work_s = await fut
         wall = time.perf_counter() - t0
         return out, wall, max(0.0, wall - work_s)
 
@@ -1790,17 +1816,25 @@ class Daemon:
             "threads": self.host.snapshot(),
             # event-loop callbacks run for runner dispatches: one each, its
             # completion (EngineRunner._run_chain); over batcher.dispatches
-            # it says how often a dispatch came back to the loop
-            "runner": {"loop_trips": self.runner.loop_trips},
+            # it says how often a dispatch came back to the loop. That
+            # callback counts the dispatch's decisions by algorithm, once
+            # (gubernator_tpu_decisions_total reads the same sums)
+            "runner": {
+                "loop_trips": self.runner.loop_trips,
+                "algo_counts": dict(self.runner.algo_counts),
+            },
             # natively parsed RPCs, how many of them crossed the loop
             # thread with no per-row work (all rows valid, local and free
-            # of GLOBAL/MULTI_REGION: _serve_plain), and how many of those
+            # of GLOBAL/MULTI_REGION: _serve_plain), how many of those
             # were answered with bytes that their dispatch's encode link
             # wrote on a worker thread (all but the shed, and the chunks
-            # of the request ring's fused drain)
+            # of the request ring's fused drain), and how many were
+            # enqueued from the parser's summary alone, no column read and
+            # no array call on the loop thread (Batcher.check)
             "daemon": {
                 "raw_rpcs": self.raw_rpcs, "plain_rpcs": self.plain_rpcs,
                 "dispatch_encoded_rpcs": self.batcher.encoded_requests,
+                "summary_entry_rpcs": self.batcher.summary_entries,
             },
             # which request parser serves the door: "built"/"reused" = the
             # native extension (compiled by this process / found with a

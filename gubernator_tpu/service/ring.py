@@ -269,8 +269,7 @@ class RequestRing:
             if prep is None:
                 st.name = "put_miss"  # as in EngineRunner.check_wire
         if prep is not None:
-            for p in parts:
-                self.runner._count_decisions(p.cols.algo)
+            self.runner._count_decisions(parts)
         return prep
 
     def _ensure_dring(self):

@@ -29,6 +29,16 @@ _ALGO_LABELS = (
 )
 
 
+def _label_counts(algo_col) -> list:
+    """Rows of an `algo` column by decision label (`_ALGO_LABELS`' order, an
+    out-of-range value under the last): what the native parser counts for a
+    summarised batch (wire.RowSummary.algo_counts)."""
+    a = np.asarray(algo_col)
+    last = len(_ALGO_LABELS) - 1
+    lab = np.where((a >= 0) & (a < last), a, last)
+    return np.bincount(lab, minlength=len(_ALGO_LABELS)).tolist()
+
+
 class EngineRunner:
     """Serializes engine table access onto one thread; async façade.
 
@@ -75,24 +85,30 @@ class EngineRunner:
         # dispatch, its completion (_run_chain; /v1/debug/pipeline "runner")
         self.loop_trips = 0
 
-    def _count_decisions(self, algo_col) -> None:
-        """Per-algorithm decision accounting (the
-        gubernator_tpu_decisions_total{algorithm} family) — one vectorized
-        bincount per dispatch, never per row. Cascade member rows carry
-        their own algorithm, so every level counts as one decision."""
-        a = np.asarray(algo_col)
-        if a.size == 0:
-            return
-        lab = np.where((a >= 0) & (a < len(_ALGO_LABELS) - 1), a,
-                       len(_ALGO_LABELS) - 1)
-        counts = np.bincount(lab, minlength=len(_ALGO_LABELS))
-        for v, c in enumerate(counts):
+    def _count_decisions(self, parts) -> None:
+        """Per-algorithm decision accounting of one dispatch (the
+        gubernator_tpu_decisions_total{algorithm} family and its mirror,
+        `algo_counts`): one update an algorithm a dispatch, never per row
+        nor per RPC. `parts` are the chunk's pieces. One that carries the
+        parser's summary (a plain RPC's WireBatch) adds the integers the
+        parser counted on the door thread; any other (columns, rows
+        selected from a batch) is counted here from its `algo` column.
+        Cascade member rows carry their own algorithm, so every level
+        counts as one decision."""
+        total = [0] * len(_ALGO_LABELS)
+        for part in parts:
+            summary = getattr(part, "summary", None)
+            if summary is not None:
+                counts = summary.algo_counts
+            else:
+                counts = _label_counts(getattr(part, "cols", part).algo)
+            for v, c in enumerate(counts):
+                total[v] += c
+        for label, c in zip(_ALGO_LABELS, total):
             if c:
-                self.algo_counts[_ALGO_LABELS[v]] += int(c)
+                self.algo_counts[label] += c
                 if self.metrics is not None:
-                    self.metrics.decisions_total.labels(
-                        algorithm=_ALGO_LABELS[v]
-                    ).inc(int(c))
+                    self.metrics.decisions_total.labels(algorithm=label).inc(c)
 
     def _run_chain(self, links, parts, done, fused=lambda: 0):
         """One dispatch's way through the worker threads: it leaves the
@@ -103,7 +119,8 @@ class EngineRunner:
         stages. The thread that ran the last link makes the dispatch's one
         `call_soon_threadsafe`. What that runs on the loop thread counts the
         trip (`loop_trips`) and the decisions of `parts` (the chunk's
-        columns; `algo_counts` is a plain dict, so here and on no worker),
+        pieces, once a dispatch: `_count_decisions`; `algo_counts` is a
+        plain dict, so here and on no worker),
         calls `done(rc, exc, fused())` if there is one — where the batcher
         answers its callers, before any coroutine is resumed — and resolves
         the returned future. An exception in any link (a shut-down executor
@@ -115,8 +132,7 @@ class EngineRunner:
             self.loop_trips += 1
             try:
                 if exc is None:
-                    for cols in parts:
-                        self._count_decisions(cols.algo)
+                    self._count_decisions(parts)
                 if done is not None:
                     done(rc, exc, fused())
             finally:
@@ -158,7 +174,7 @@ class EngineRunner:
 
     async def check(
         self, cols, now_ms: Optional[int] = None, disp=None,
-        launch_path: str = "xla", done=None,
+        launch_path: str = "xla", done=None, counted=None,
     ) -> ResponseColumns:
         """Pipelined check when the engine supports the prepare/issue/finish
         split, else the serial path. `cols` is one RequestColumns or a list
@@ -180,7 +196,10 @@ class EngineRunner:
         coroutine is resumed: the batcher answers its callers there.
         `fused` counts the passes the fused wire staging issued for the
         chunk (`check_wire`); 0 here, where every chunk is staged as
-        columns."""
+        columns. `counted` is what the dispatch's decisions are counted
+        from where that is not `cols`: the parsed pieces these columns came
+        in (`check_wire`, for an engine that takes no lanes), whose
+        summaries hold the counts."""
         parts = [cols] if isinstance(cols, RequestColumns) else cols
         if (
             not getattr(self.engine, "supports_pipeline", False)
@@ -188,12 +207,12 @@ class EngineRunner:
         ):
             return await self.check_columns(
                 concat_columns(parts), now_ms=now_ms, launch_path=launch_path,
-                done=done, disp=disp,
+                done=done, disp=disp, counted=counted,
             )
         return await self._run_chain(
             ((self._prep, lambda _: self._stage_columns(parts, now_ms, disp)),
              *self._issue_and_finish(disp, launch_path)),
-            parts, done,
+            counted or parts, done,
         )
 
     async def check_wire(
@@ -219,7 +238,7 @@ class EngineRunner:
         ):
             return await self.check(
                 cols, now_ms=now_ms, disp=disp, launch_path=launch_path,
-                done=done,
+                done=done, counted=parts,
             )
         from gubernator_tpu.ops.engine import prepare_check_wire
 
@@ -243,7 +262,7 @@ class EngineRunner:
         return await self._run_chain(
             ((self._prep, prepare),
              *self._issue_and_finish(disp, launch_path)),
-            cols, done, lambda: fused,
+            parts, done, lambda: fused,
         )
 
     def _note_issue(self, dt: float) -> None:
@@ -427,10 +446,11 @@ class EngineRunner:
 
     async def check_columns(
         self, cols: RequestColumns, now_ms: Optional[int] = None,
-        launch_path: str = "xla", done=None, disp=None,
+        launch_path: str = "xla", done=None, disp=None, counted=None,
     ) -> ResponseColumns:
         """The serial path: the whole check, and the dispatch's `tail`, is one
-        engine-thread job, a chain of one link. `done` as in `check`."""
+        engine-thread job, a chain of one link. `done` and `counted` as in
+        `check`."""
 
         def run(_):
             rc = self.engine.check_columns(cols, now_ms=now_ms)
@@ -443,7 +463,9 @@ class EngineRunner:
                     self.metrics.observe_global(gs)
             return rc if disp is None or disp.tail is None else disp.tail(rc)
 
-        return await self._run_chain(((self._exec, run),), [cols], done)
+        return await self._run_chain(
+            ((self._exec, run),), counted or [cols], done
+        )
 
     async def install_columns(self, **kw) -> int:
         loop = asyncio.get_running_loop()

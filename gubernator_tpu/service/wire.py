@@ -45,15 +45,31 @@ class RowSummary(NamedTuple):
     to know of the rows without scanning the columns again on the
     event-loop thread. Counts, so `x == rows` says "all". The behavior
     words are the client-facing ones (the parser masks the cascade level),
-    so a summarised row costs the door 1, or 2 for a lease."""
+    so a summarised row costs the door 1, or 2 for a lease. The parser
+    stamps the rows its client left unstamped with the clock it is handed
+    (the handler's, read at request entry), so the stamps' range is of the
+    column as it is served."""
 
     errors: int  # rows with `err` set
     behavior_or: int  # OR of the behavior words
     leases: int  # concurrency-lease rows
-    unstamped: int  # rows whose created_at is 0
+    unstamped: int  # rows the client sent with created_at 0
     encodable: int  # compact-wire representable rows
     max_tier: int  # highest priority tier among the rows
     cascades: int  # rows carrying a cascade field (→ the pb path)
+    stamp_lo: int  # earliest created_at as served (ms)
+    stamp_hi: int  # latest
+    first_fp: int  # the first row's fingerprint (the tenant bucket's key)
+    # rows by decision label, in runner._ALGO_LABELS' order (one count an
+    # algorithm, an out-of-range value under the last, `invalid`)
+    algo_counts: Tuple[int, ...]
+
+    @property
+    def stamped(self) -> bool:
+        """Every row carries a stamp, its client's or the parser's: the
+        enqueue has nothing to fill and no column to read. False where the
+        parser was handed no clock and a row came unstamped."""
+        return self.stamp_lo > 0
 
 
 class WireBatch(NamedTuple):
@@ -429,12 +445,14 @@ def transfer_chunk_arrays(req):
 # ----------------------------------------------------------- native ingress
 
 
-def wire_batch_from_wire(data: bytes):
+def wire_batch_from_wire(data: bytes, now_ms: int = 0):
     """Native parse of GetRateLimitsReq wire bytes (gubernator_tpu.native):
     → (WireBatch, ring_points uint32, spans (n,2) int64, traceparent) or
     None when the extension is unavailable OR any item carries a cascade —
     cascade requests need their levels expanded from the full pb message,
     so such batches take the pb path (Daemon._route) end to end.
+    `now_ms` is the handler's clock at request entry: the parser writes it
+    into `created_at` where the client sent 0 (0: no row is stamped).
     ring_points are fnv1a_32 of each item's hash key (the ring lookup hash)
     and spans are each item's byte range in `data` for lazy pb
     materialization — only items that must travel as messages (forwards,
@@ -449,7 +467,7 @@ def wire_batch_from_wire(data: bytes):
     (
         n, fp, algo, beh, hits, lim, burst, dur, ca, err, ring, span,
         traceparent, lanes, enc, summary,
-    ) = m.parse_get_rate_limits(data)
+    ) = m.parse_get_rate_limits(data, now_ms)
     summary = RowSummary(*summary)
     if summary.cascades:
         return None  # cascade batch → pb path (level expansion needs items)

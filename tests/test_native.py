@@ -532,3 +532,78 @@ def test_stage_wire_chunk_holds_no_state_between_threads():
     finally:
         sys.setswitchinterval(interval)
     assert done == [True] * 240
+
+
+# ------------------------------------------- the parser's stamp and its sums
+STAMP_NOW = 1_790_000_000_123
+
+
+def _stamp_items(case):
+    """Item mixes for the clocked parse: which rows their client stamped,
+    and an algorithm value that is none of the five."""
+    rng = random.Random(41)
+    n = {"empty": 0}.get(case, 300)
+    items = []
+    for i in range(n):
+        r = pb.RateLimitReq(
+            name="st", unique_key=f"k{i}", hits=1, limit=10, duration=60_000,
+            algorithm=rng.choice([0, 0, 1, 2, 3, 4]),
+        )
+        if case == "out_of_range_algorithm" and i % 7 == 0:
+            r.algorithm = rng.choice([5, 9, 1 << 20, -1])
+        stamped = {
+            "all_unstamped": False, "all_stamped": True,
+        }.get(case, rng.random() < 0.5)
+        if stamped:
+            r.created_at = STAMP_NOW + rng.randrange(-700, 700)
+        items.append(r)
+    return items
+
+
+@pytest.mark.parametrize("case", [
+    "all_unstamped", "all_stamped", "mixed", "empty", "out_of_range_algorithm",
+])
+def test_parse_with_a_clock_stamps_and_reduces_as_numpy_would(case):
+    """Handed the handler's clock, the parser writes it where the client
+    sent no stamp and reduces the stamps it serves, the rows by decision
+    label and the rows it stamped: each against its NumPy form, the column
+    byte for byte, and every other buffer as the parse without a clock."""
+    from gubernator_tpu.service.runner import _ALGO_LABELS, _label_counts
+    from gubernator_tpu.service.wire import RowSummary
+
+    items = _stamp_items(case)
+    data = pb.GetRateLimitsReq(requests=items).SerializeToString()
+    bare = m.parse_get_rate_limits(data)
+    got = m.parse_get_rate_limits(data, STAMP_NOW)
+    n = got[0]
+    assert n == len(items)
+    sent = np.frombuffer(bare[8], np.int64)
+    want = np.where(sent == 0, STAMP_NOW, sent)
+    assert got[8] == want.tobytes()  # created_at as served
+    for k in range(15):  # every other buffer, and the traceparent
+        if k != 8:
+            assert got[k] == bare[k], k
+    summary, bare_summary = RowSummary(*got[15]), RowSummary(*bare[15])
+    assert summary.unstamped == bare_summary.unstamped == int((sent == 0).sum())
+    assert summary.unstamped == {
+        "all_unstamped": n, "all_stamped": 0, "empty": 0,
+    }.get(case, summary.unstamped)
+    if n:
+        assert (summary.stamp_lo, summary.stamp_hi) == (want.min(), want.max())
+        assert (bare_summary.stamp_lo, bare_summary.stamp_hi) == (sent.min(), sent.max())
+        assert summary.first_fp == np.frombuffer(got[1], np.int64)[0]
+    else:
+        assert (summary.stamp_lo, summary.stamp_hi) == (STAMP_NOW, STAMP_NOW)
+        assert (bare_summary.stamp_lo, bare_summary.stamp_hi) == (0, 0)
+        assert summary.first_fp == 0
+    assert summary.stamped and bare_summary.stamped == (n > 0 and bool((sent != 0).all()))
+    algo = np.frombuffer(got[2], np.int32)
+    assert list(summary.algo_counts) == _label_counts(algo)
+    assert len(summary.algo_counts) == len(_ALGO_LABELS) and sum(summary.algo_counts) == n
+    if case == "out_of_range_algorithm":
+        assert summary.algo_counts[-1] == sum(
+            not 0 <= it.algorithm < len(_ALGO_LABELS) - 1 for it in items
+        ) > 0
+    # what the summary had before is what it was
+    assert summary[:7] == bare_summary[:7]
+    assert bare_summary.algo_counts == summary.algo_counts

@@ -11,6 +11,8 @@ its scans would."""
 
 import asyncio
 import functools
+import sys
+import threading
 
 import numpy as np
 import pytest
@@ -22,6 +24,7 @@ from gubernator_tpu.service import batcher as batcher_mod
 from gubernator_tpu.service import daemon as daemon_mod
 from gubernator_tpu.service.batcher import Batcher
 from gubernator_tpu.service.daemon import Daemon
+from gubernator_tpu.service.runner import _label_counts
 from gubernator_tpu.service.wire import (
     RowSummary,
     WireBatch,
@@ -97,10 +100,15 @@ def plain_rounds(rc_err_row: bool):
     return [rows, rows, rows + [req(8, behavior=int(Behavior.RESET_REMAINING))]]
 
 
-async def _spawn():
+async def _spawn(window_ms: float = 0.0):
+    """`window_ms`: a fixed batch window in place of the adaptive one, so
+    that RPCs sent together are one chunk whatever the host's load."""
+    conf = daemon_config(http_address="")
+    if window_ms:
+        conf.behaviors.adaptive_batch = False
+        conf.behaviors.batch_wait_ms = window_ms
     d = await Daemon.spawn(
-        daemon_config(http_address=""),
-        engine=LocalEngine(capacity=8192, wire="compact"),
+        conf, engine=LocalEngine(capacity=8192, wire="compact"),
     )
     d.now_ms = lambda: NOW + 7  # retry_after_ms basis (the general path's)
     return d
@@ -155,7 +163,9 @@ async def test_plain_path_bytes_equal_general_path(trigger, rc_err_row, monkeypa
         pipe = d_plain.debug_pipeline()["daemon"]
         assert pipe == {
             "raw_rpcs": 3, "plain_rpcs": 3, "dispatch_encoded_rpcs": 3,
+            "summary_entry_rpcs": 3,
         }
+        assert d_gen.debug_pipeline()["daemon"]["summary_entry_rpcs"] == 0
         assert d_gen.debug_pipeline()["daemon"]["dispatch_encoded_rpcs"] == 0
     finally:
         await d_plain.close()
@@ -212,17 +222,26 @@ def random_items(rng, n: int):
     return items
 
 
-def reduced(wb: WireBatch) -> RowSummary:
-    """The summary as numpy reductions of the parsed columns."""
+def reduced(wb: WireBatch, now_ms: int = 0, sent_unstamped=None) -> RowSummary:
+    """The summary as numpy reductions of the parsed columns. `now_ms` is
+    the clock the parser was handed and `sent_unstamped` the rows the client
+    left unstamped, which a stamped column no longer shows."""
     c = wb.cols
     return RowSummary(
         errors=int((c.err != 0).sum()),
         behavior_or=int(np.bitwise_or.reduce(c.behavior, initial=0)),
         leases=int((c.algo == int(Algorithm.CONCURRENCY_LEASE)).sum()),
-        unstamped=int((c.created_at == 0).sum()),
+        unstamped=(
+            int((c.created_at == 0).sum()) if sent_unstamped is None
+            else sent_unstamped
+        ),
         encodable=int(wb.encodable.sum()),
         max_tier=int(((c.behavior >> PRIORITY_SHIFT) & PRIORITY_MASK).max(initial=0)),
         cascades=0,
+        stamp_lo=int(c.created_at.min()) if wb.rows else now_ms,
+        stamp_hi=int(c.created_at.max()) if wb.rows else now_ms,
+        first_fp=int(c.fp[0]) if wb.rows else 0,
+        algo_counts=tuple(_label_counts(c.algo)),
     )
 
 
@@ -230,9 +249,15 @@ def reduced(wb: WireBatch) -> RowSummary:
 @pytest.mark.parametrize("seed", [1, 2, 3])
 def test_summary_equals_numpy_reductions(seed, n):
     rng = np.random.default_rng(seed * 1000 + n)
-    wb = wire_batch_from_wire(body(random_items(rng, n)))[0]
+    items = random_items(rng, n)
+    wb = wire_batch_from_wire(body(items))[0]
     assert wb.rows == n
     assert wb.summary == reduced(wb)
+    # handed a clock, the parser stamps the rows and reduces what it serves
+    sent_unstamped = sum(not it.created_at for it in items)
+    wb = wire_batch_from_wire(body(items), NOW + 5)[0]
+    assert wb.summary == reduced(wb, NOW + 5, sent_unstamped)
+    assert wb.summary.stamped and (wb.cols.created_at != 0).all()
     assert wb.all_encodable == bool(wb.encodable.all())
     assert wb.summary.behavior_or < 256  # client-facing bits only
 
@@ -241,9 +266,11 @@ def test_summary_edges_all_and_none():
     """All rows unstamped / none; the cascade count sends a batch to the pb
     path; the raw parser's tuple ends with the summary."""
     stamped = wire_batch_from_wire(body([req(i) for i in range(5)]))[0]
-    assert stamped.summary == RowSummary(0, 0, 0, 0, 5, 0, 0)
+    assert stamped.summary == RowSummary(
+        0, 0, 0, 0, 5, 0, 0, NOW, NOW, int(stamped.cols.fp[0]), (5, 0, 0, 0, 0, 0)
+    )
     bare = wire_batch_from_wire(body([req(i, created_at=0) for i in range(5)]))[0]
-    assert bare.summary.unstamped == 5
+    assert bare.summary.unstamped == 5 and not bare.summary.stamped
     casc = req(1)
     casc.cascade.add(name="pl", unique_key="t", limit=10, duration=60_000)
     data = body([req(0), casc])
@@ -297,25 +324,172 @@ async def test_enqueue_reads_the_summary_as_it_scans(stamps, monkeypatch):
             it.ClearField("created_at")
         else:
             it.created_at = NOW + i % 50
-    wb = wire_batch_from_wire(body(items))[0]
+    wb = wire_batch_from_wire(body(items), NOW + 3)[0]  # stamped by the parser
+    bare = wire_batch_from_wire(body(items))[0]  # parsed with no clock
     assert (wb.summary.unstamped == 0) == (stamps == "all")
     assert (wb.summary.unstamped == wb.rows) == (stamps == "none")
+    assert wb.summary.stamped and bare.summary.stamped == (stamps == "all")
     runner = EchoRunner()
     b = Batcher(runner, batch_wait_ms=0.0, workers=1)
     try:
-        await b.check(wb, now_ms=NOW + 3)
-        await b.check(wb._replace(summary=None), now_ms=NOW + 3)
+        await b.check(wb, now_ms=NOW + 999)  # nothing is left to stamp
+        await b.check(bare._replace(summary=None), now_ms=NOW + 3)
+        await b.check(bare, now_ms=NOW + 3)
     finally:
         await b.drain()
-    with_summary, scanned = entries
-    assert (with_summary.tier, with_summary.cost, with_summary.rows) == (
-        scanned.tier, scanned.cost, scanned.rows
-    )
+    assert b.summary_entries == 1 + (stamps == "all")
+    with_summary, scanned, late = entries
+    for e in (scanned, late):
+        assert (
+            with_summary.tier, with_summary.cost, with_summary.rows,
+            with_summary.bucket, with_summary.stamp_lo, with_summary.stamp_hi,
+        ) == (e.tier, e.cost, e.rows, e.bucket, e.stamp_lo, e.stamp_hi)
     assert with_summary.cost > with_summary.rows  # leases cost 2
-    a, c = (p.cols.created_at for p in runner.payloads)
+    a, c, l = (p.cols.created_at for p in runner.payloads)
     np.testing.assert_array_equal(a, c)
+    np.testing.assert_array_equal(a, l)
     assert (a != 0).all()
-    if stamps == "all":  # nothing to stamp: the parser's column itself
-        assert runner.payloads[0].cols.created_at is wb.cols.created_at
-    # stamped once: handing the stamped payload in again changes nothing
-    assert runner.payloads[0].summary.unstamped == 0
+    # the parser's batch goes to its dispatch as it is: nothing replaced
+    assert runner.payloads[0] is wb
+    # a batch parsed with no clock is stamped by the enqueue, as columns
+    # are, and loses the summary with the column that was rewritten
+    assert (runner.payloads[2].summary is None) == (stamps != "all")
+
+
+# ------------------------------------------- the loop thread's array calls
+
+
+class ArraySpy:
+    """Which threads made the array calls that an enqueue and a dispatch's
+    decision count used to make on the event-loop thread: `numpy.full`,
+    `numpy.where`, `numpy.bincount` (patched where every module looks them
+    up) and `ndarray.min` / `.max` (a method of a built-in type cannot be
+    patched: the interpreter's profile hook of the watched thread sees the
+    call)."""
+
+    def __init__(self, monkeypatch):
+        self.calls = []  # (function, thread id)
+        for name in ("full", "where", "bincount"):
+            monkeypatch.setattr(np, name, self._wrap(name, getattr(np, name)))
+
+    def _wrap(self, name, real):
+        def spy(*a, **k):
+            self.calls.append((name, threading.get_ident()))
+            return real(*a, **k)
+
+        return spy
+
+    def profile(self, frame, event, arg):
+        if event == "c_call" and getattr(arg, "__name__", "") in ("min", "max"):
+            owner = getattr(arg, "__self__", None)
+            if isinstance(owner, np.ndarray) or getattr(
+                arg, "__objclass__", None
+            ) is np.ndarray:
+                self.calls.append((arg.__name__, threading.get_ident()))
+
+    def on(self, thread_id: int) -> set:
+        return {name for name, t in self.calls if t == thread_id}
+
+
+@pytest.mark.parametrize("rows", [3, 600], ids=["inline_parse", "door_hop"])
+@async_test
+async def test_a_plain_rpc_makes_no_array_call_on_the_loop_thread(rows, monkeypatch):
+    """Plain RPCs, stamped by their client or not, parsed inline or on the
+    door pool, with algorithms of every label: between the request bytes
+    and the response bytes the loop thread calls none of the array
+    functions that the stamp, its range and the decision count used to
+    cost it, and every entry was made from the summary. An RPC with an
+    error row takes the general path, whose scans the same spy sees."""
+    d = await _spawn()
+    me = threading.get_ident()
+    plain = [
+        body([req(i + 1000 * r, created_at=0 if (i + r) % 2 else NOW,
+                  algorithm=(i + r) % 5) for i in range(rows)])
+        for r in range(6)
+    ]
+    assert (len(plain[0]) >= d.DOOR_OFFLOAD_BYTES) == (rows == 600)
+    try:
+        await d.get_rate_limits_raw(plain[0])  # programs compiled, pools up
+        spy = ArraySpy(monkeypatch)
+        before = dict(d.runner.algo_counts)
+        sys.setprofile(spy.profile)
+        try:
+            outs = await asyncio.gather(
+                *(d.get_rate_limits_raw(b) for b in plain[1:])
+            )
+            on_plain = spy.on(me)
+            await d.get_rate_limits_raw(body([req(1), ERROR_ROW]))
+            on_general = spy.on(me) - on_plain
+        finally:
+            sys.setprofile(None)
+        assert on_plain == set(), on_plain
+        assert {"where", "min", "max", "bincount"} <= on_general, on_general
+        for out in outs:
+            assert len(pb.GetRateLimitsResp.FromString(out).responses) == rows
+        pipe = d.debug_pipeline()
+        assert pipe["daemon"]["plain_rpcs"] == 6
+        assert pipe["daemon"]["summary_entry_rpcs"] == 6
+        assert pipe["daemon"]["raw_rpcs"] == 7
+        # every decision of the five RPCs, by label, and the general RPC's
+        # one valid row (its error row reaches no dispatch)
+        grown = {k: v - before[k] for k, v in pipe["runner"]["algo_counts"].items()}
+        assert grown == {
+            "token_bucket": rows + 1, "leaky_bucket": rows, "gcra": rows,
+            "sliding_window": rows, "concurrency_lease": rows, "invalid": 0,
+        }
+    finally:
+        await d.close()
+
+
+@pytest.mark.parametrize("off_ms,dispatches", [(400, 1), (600, 2), (-600, 2)])
+@async_test
+async def test_a_chunk_is_cut_on_the_summarys_stamp_range(off_ms, dispatches):
+    """`_form_chunk` keeps a chunk's stamps inside the compact wire's ±511
+    ms from the entries' `stamp_lo` / `stamp_hi`, which for a plain RPC are
+    the summary's: an RPC the parser stamped at request entry, then one
+    whose client stamped it up to `off_ms` away. An entry that would take
+    the chunk's stamps 512 ms apart starts the next chunk, and both ride
+    the fused staging."""
+    d = await _spawn(window_ms=200.0)  # its clock: NOW + 7
+    try:
+        await d.get_rate_limits_raw(body([req(0, created_at=0)]))
+        b0 = d.batcher.debug()
+        rpcs = [
+            body([req(10 + i, created_at=0) for i in range(4)]),
+            body([req(20, created_at=NOW + 7 + off_ms),
+                  req(21, created_at=NOW + 7 + off_ms * 7 // 8)]),
+        ]
+        entries = []
+        real = batcher_mod._Entry
+
+        class Spy(real):
+            def __init__(self, *a):
+                super().__init__(*a)
+                entries.append((self.stamp_lo, self.stamp_hi))
+
+        batcher_mod._Entry = Spy
+        try:
+            tasks = []
+            for data in rpcs:  # enqueued in this order, in one window
+                n0 = d.plain_rpcs
+                tasks.append(asyncio.ensure_future(d.get_rate_limits_raw(data)))
+                while d.plain_rpcs == n0:
+                    await asyncio.sleep(0)
+            outs = await asyncio.gather(*tasks)
+        finally:
+            batcher_mod._Entry = real
+        lo, hi = sorted((NOW + 7 + off_ms, NOW + 7 + off_ms * 7 // 8))
+        assert entries == [(NOW + 7, NOW + 7), (lo, hi)]
+        b1 = d.batcher.debug()
+        grew = {k: b1[k] - b0[k] for k in (
+            "dispatches", "fused_dispatches", "wire_fallbacks", "column_dispatches"
+        )}
+        assert grew == {
+            "dispatches": dispatches, "fused_dispatches": dispatches,
+            "wire_fallbacks": 0, "column_dispatches": 0,
+        }
+        for out, n in zip(outs, (4, 2)):
+            got = pb.GetRateLimitsResp.FromString(out).responses
+            assert len(got) == n and not any(r.error for r in got)
+    finally:
+        await d.close()

@@ -23,8 +23,12 @@ from gubernator_tpu.ops.engine import LocalEngine, ms_now
 from gubernator_tpu.proto import gubernator_pb2 as pb
 from gubernator_tpu.service.batcher import Batcher
 from gubernator_tpu.service.metrics import DaemonMetrics
-from gubernator_tpu.service.runner import EngineRunner
-from gubernator_tpu.service.wire import concat_columns, wire_batch_from_wire
+from gubernator_tpu.service.runner import _ALGO_LABELS, EngineRunner, _label_counts
+from gubernator_tpu.service.wire import (
+    concat_columns,
+    subset_wire,
+    wire_batch_from_wire,
+)
 
 from tests.test_observability import _stage_sums
 
@@ -336,4 +340,111 @@ async def test_many_dispatches_under_a_short_switch_interval():
     finally:
         sys.setswitchinterval(old)
         await b.drain()
+        runner.close()
+
+
+# ------------------------------------------------- a dispatch's decision counts
+def algo_batch(algos, now, tag, gregorian=False):
+    """One parsed RPC, stamped by the parser, a row an entry of `algos`."""
+    data = pb.GetRateLimitsReq(requests=[
+        pb.RateLimitReq(
+            name=tag, unique_key=f"k{i}", hits=1, limit=10, algorithm=a,
+            **({"behavior": 4, "duration": 4} if gregorian and i == 0
+               else {"duration": 60_000}),
+        )
+        for i, a in enumerate(algos)
+    ]).SerializeToString()
+    wb = wire_batch_from_wire(data, now)[0]
+    assert wb.summary.stamped and wb.summary.unstamped == len(algos)
+    return wb
+
+
+def decision_samples(metrics) -> dict:
+    return {
+        label: metrics.decisions_total.labels(algorithm=label)._value.get()
+        for label in _ALGO_LABELS
+    }
+
+
+DECISION_KINDS = ["fused", "wire_miss", "columns", "mixed", "no_lanes", "serial"]
+
+
+@pytest.mark.parametrize("kind", DECISION_KINDS)
+@async_test
+async def test_a_dispatch_counts_its_decisions_once(kind, monkeypatch):
+    """`decisions_total{algorithm}` and `runner.algo_counts` after a dispatch
+    are the counts of its rows by algorithm, however the chunk was staged
+    and whether its pieces carry the parser's summary (their integers are
+    added up), do not (their column is counted), or both; the counter is
+    touched once an algorithm a dispatch, not once an RPC."""
+    now = ms_now()
+    metrics = DaemonMetrics()
+    runner = new_runner(metrics)
+    if kind == "no_lanes":  # as a mesh engine: it takes the parser's columns
+        runner.close()
+        runner = EngineRunner(LocalEngine(capacity=4096, wire="full"), metrics)
+        assert not runner.engine.supports_wire_ingress
+    incs = []
+    labels = metrics.decisions_total.labels
+    monkeypatch.setattr(
+        metrics.decisions_total, "labels",
+        lambda **kw: incs.append(kw["algorithm"]) or labels(**kw),
+    )
+    counted = []  # the columns that were counted by array calls
+    import gubernator_tpu.service.runner as runner_mod
+
+    monkeypatch.setattr(
+        runner_mod, "_label_counts",
+        lambda col: counted.append(len(col)) or _label_counts(col),
+    )
+    parts = [
+        algo_batch([0, 0, 1, 0], now, "a", gregorian=kind == "wire_miss"),
+        algo_batch([1, 2, 3, 0, 0], now, "b"),
+        algo_batch([0, 1, 1], now, "c"),
+    ]
+    if kind == "mixed":  # rows selected from a batch: no summary
+        parts[1] = subset_wire(parts[1], np.array([0, 1, 3]))
+        assert parts[1].summary is None
+    want = dict(zip(_ALGO_LABELS, _label_counts(
+        np.concatenate([p.cols.algo for p in parts])
+    )))
+    fused = []
+    try:
+        done = lambda rc, exc, n: fused.append(n)  # noqa: E731
+        if kind == "columns":
+            await runner.check([p.cols for p in parts], now_ms=now, done=done)
+        elif kind == "serial":
+            await runner.check_columns(
+                concat_columns([p.cols for p in parts]), now_ms=now, done=done
+            )
+        else:
+            await runner.check_wire(parts, now_ms=now, done=done)
+        assert bool(fused[0]) == (kind in ("fused", "mixed"))
+        assert runner.algo_counts == want
+        assert sorted(incs) == sorted(k for k, v in want.items() if v)
+        assert decision_samples(metrics) == want
+        assert counted == {
+            "columns": [4, 5, 3], "serial": [12], "mixed": [3],
+        }.get(kind, [])
+    finally:
+        runner.close()
+
+
+@pytest.mark.parametrize("stage", STAGES)
+@async_test
+async def test_a_dispatch_that_fails_counts_no_decision(stage, monkeypatch):
+    now = ms_now()
+    metrics = DaemonMetrics()
+    runner = new_runner(metrics)
+    try:
+        for name in STAGE_FNS[stage]:
+            def boom(*a, _name=name, **k):
+                raise ValueError(f"{_name} failed")
+
+            monkeypatch.setattr(engine_mod, name, boom)
+        with pytest.raises(ValueError, match="failed"):
+            await runner.check_wire([algo_batch([0, 1, 4], now, "x")], now_ms=now)
+        assert not any(runner.algo_counts.values())
+        assert not any(decision_samples(metrics).values())
+    finally:
         runner.close()
