@@ -19,8 +19,10 @@ gubernator_tpu`). The cache is emptied before the first run unless
 `--keep-cache`; the runs then follow one another on what they compiled.
 
 For every run it records the exit code, the wall, the last stderr lines,
-and from the context line seconds to healthy, `setup_s`, the counters the
-acceptance criteria name; for a traced run also the trace's size on disk and
+the peak resident memory of the generator (the `bench/run.py` process) and of
+the server (its `bench/launcher.py` child), read from `/proc` every two
+seconds (`rss_peak_bytes`), and from the context line seconds to healthy,
+`setup_s`, the counters the acceptance criteria name; for a traced run also the trace's size on disk and
 the wall of `bench/xplane.py --reduce` run once more over it (the harness
 gives that child 300 s). One JSON line a run in
 `chiprun_out/driver_check/<tag>.jsonl`, stdout and stderr of each run beside
@@ -73,6 +75,78 @@ def summary(ctx: dict, res: dict, trace: int) -> dict:
     return out
 
 
+def _hwm(pid: int) -> int:
+    """Resident bytes of one process: its high-water mark where `/proc` has
+    one (VmHWM), else what it holds now (VmRSS, statm); 0 once it is gone."""
+    try:
+        with open(f"/proc/{pid}/status") as f:
+            fields = dict(
+                line.split(":", 1) for line in f if line.startswith(("VmHWM", "VmRSS"))
+            )
+        for key in ("VmHWM", "VmRSS"):
+            if key in fields:
+                return int(fields[key].split()[0]) * 1024
+        with open(f"/proc/{pid}/statm") as f:
+            return int(f.read().split()[1]) * os.sysconf("SC_PAGE_SIZE")
+    except (OSError, ValueError, IndexError):
+        return 0
+
+
+def _children(pid: int) -> list:
+    try:
+        with open(f"/proc/{pid}/task/{pid}/children") as f:
+            return [int(p) for p in f.read().split()]
+    except OSError:
+        return []
+
+
+def _by_parent(pid: int) -> list:
+    """Children of `pid` found by their stat's parent field, for a /proc
+    without `children` files."""
+    out = []
+    for name in os.listdir("/proc"):
+        if name.isdigit():
+            try:
+                with open(f"/proc/{name}/stat") as f:
+                    if int(f.read().rsplit(")", 1)[1].split()[1]) == pid:
+                        out.append(int(name))
+            except (OSError, ValueError, IndexError):
+                pass
+    return out
+
+
+def _cmdline(pid: int) -> str:
+    try:
+        with open(f"/proc/{pid}/cmdline", "rb") as f:
+            return f.read().replace(b"\0", b" ").decode(errors="replace")
+    except OSError:
+        return ""
+
+
+def run_watched(cmd, cwd, out, err) -> "tuple[int, dict]":
+    """The command to its end, with the peak resident memory of the
+    generator (the command's own process) and of the server child."""
+    import resource
+
+    peak = {"generator": 0, "server": 0}
+    proc = subprocess.Popen(cmd, cwd=cwd, stdout=out, stderr=err)
+    while True:
+        peak["generator"] = max(peak["generator"], _hwm(proc.pid))
+        for child in _children(proc.pid) or _by_parent(proc.pid):
+            if "launcher.py" in _cmdline(child):
+                peak["server"] = max(peak["server"], _hwm(child))
+        try:
+            rc = proc.wait(timeout=2.0)
+        except subprocess.TimeoutExpired:
+            continue
+        # the kernel's own figure, for a machine whose /proc says less: the
+        # largest resident set of any descendant waited for so far (KiB)
+        peak["largest_descendant_so_far"] = (
+            resource.getrusage(resource.RUSAGE_CHILDREN).ru_maxrss * 1024
+        )
+        return rc, peak
+
+
 def one_run(dest: str, tag: str, i: int, workload: str, seed: int, trace: int,
             seconds: float) -> dict:
     stem = os.path.join(OUT, f"{tag}.{i}")
@@ -80,9 +154,9 @@ def one_run(dest: str, tag: str, i: int, workload: str, seed: int, trace: int,
            "--seconds", str(seconds), "--trace", str(trace)]
     t0 = time.monotonic()
     with open(stem + ".out", "wb") as out, open(stem + ".err", "wb") as err:
-        rc = subprocess.run(cmd, cwd=dest, stdout=out, stderr=err).returncode
+        rc, rss = run_watched(cmd, dest, out, err)
     rec = {"tag": tag, "workload": workload, "seed": seed, "trace": trace, "rc": rc,
-           "wall_s": round(time.monotonic() - t0, 1)}
+           "wall_s": round(time.monotonic() - t0, 1), "rss_peak_bytes": rss}
     with open(stem + ".err", errors="replace") as f:
         rec["stderr_tail"] = f.read().strip().splitlines()[-12:]
     with open(stem + ".out", errors="replace") as f:

@@ -28,6 +28,17 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 OUT = os.path.join(ROOT, "chiprun_out", "stage_walls.jsonl")
 
 
+def _growth(before, after) -> dict:
+    """after - before for every number both snapshots hold (one level)."""
+    if not isinstance(before, dict) or not isinstance(after, dict):
+        return {}
+    return {
+        k: round(after[k] - before[k], 3) for k in after
+        if isinstance(after[k], (int, float)) and not isinstance(after[k], bool)
+        and isinstance(before.get(k), (int, float))
+    }
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -78,6 +89,12 @@ def main(argv=None) -> int:
         "dispatches": dispatches, "fused": ctx["dispatches"],
         "native_staged": p1["engine"].get("native_staged"),
         "stage_ms": stage_ms, "cpu_ms_per_dispatch": cpu,
+        # the growth, over warm-up and window, of the counters of the tier
+        # block (a tiered deployment's; absent otherwise), of the engine's
+        # and of the process's clocks (`threads`: CPU, collector pauses, wall)
+        "tier": _growth(p0.get("tier"), p1.get("tier")),
+        "engine": _growth(p0["engine"], p1["engine"]),
+        "clocks": _growth(p0.get("threads"), p1.get("threads")),
     }
     os.makedirs(os.path.dirname(OUT), exist_ok=True)
     with open(OUT, "a") as f:

@@ -1133,6 +1133,108 @@ static PyObject* stage_wire_chunk(PyObject*, PyObject* args) {
       s.casc ? Py_True : Py_False, (long long)s.later, passes);
 }
 
+// ---------------------------------------------------------------------
+// The shadow tier's fingerprint index (gubernator_tpu/tier/shadow.py,
+// `_FpIndex`): one open-addressing table in two flat arrays that the caller
+// owns, `keys` (int64: the fingerprint, 0 = never used, -1 = removed) and
+// `vals` (int32: the row id), 2^bits slots, linear probing from a
+// multiplicative hash. Both entry points take the arrays through the buffer
+// protocol (contiguous, this host's byte order), hold no state and run with
+// the GIL released: the engine thread probes and fills the index inside the
+// miss path while the loop, door and fetch threads are busy, and the NumPy
+// twin's few hundred small array calls each queue for the GIL again.
+static const uint64_t FP_INDEX_MULT = 0x9E3779B97F4A7C15ull;
+
+struct FlatBuf {
+  Py_buffer view;
+  bool held = false;
+  ~FlatBuf() { if (held) PyBuffer_Release(&view); }
+  bool open(PyObject* obj, Py_ssize_t itemsize, bool writable, const char* what) {
+    if (PyObject_GetBuffer(obj, &view,
+                           writable ? PyBUF_CONTIG : PyBUF_CONTIG_RO) < 0)
+      return false;
+    held = true;
+    if (view.itemsize != itemsize) {
+      PyErr_Format(PyExc_TypeError, "%s: items of %zd bytes expected", what,
+                   itemsize);
+      return false;
+    }
+    return true;
+  }
+  Py_ssize_t size() const { return view.len / view.itemsize; }
+};
+
+// fp_index_find(keys, bits, fps, out): out[i] = the slot that holds fps[i],
+// or -1 where the table does not hold it
+static PyObject* fp_index_find(PyObject*, PyObject* args) {
+  PyObject *keys_o, *fps_o, *out_o;
+  int bits;
+  if (!PyArg_ParseTuple(args, "OiOO", &keys_o, &bits, &fps_o, &out_o))
+    return nullptr;
+  FlatBuf keys, fps, out;
+  if (!keys.open(keys_o, 8, false, "keys") || !fps.open(fps_o, 8, false, "fps") ||
+      !out.open(out_o, 8, true, "out"))
+    return nullptr;
+  if (bits < 1 || bits > 40 || keys.size() != ((Py_ssize_t)1 << bits) ||
+      out.size() != fps.size()) {
+    PyErr_SetString(PyExc_ValueError, "fp_index_find: sizes disagree");
+    return nullptr;
+  }
+  const int64_t* k = (const int64_t*)keys.view.buf;
+  const int64_t* f = (const int64_t*)fps.view.buf;
+  int64_t* o = (int64_t*)out.view.buf;
+  const Py_ssize_t n = fps.size();
+  const uint64_t mask = ((uint64_t)1 << bits) - 1;
+  Py_BEGIN_ALLOW_THREADS;
+  for (Py_ssize_t i = 0; i < n; i++) {
+    uint64_t pos = ((uint64_t)f[i] * FP_INDEX_MULT) >> (64 - bits);
+    int64_t at = -1;
+    for (;; pos = (pos + 1) & mask) {
+      if (k[pos] == f[i]) { at = (int64_t)pos; break; }
+      if (k[pos] == 0) break;
+    }
+    o[i] = at;
+  }
+  Py_END_ALLOW_THREADS;
+  Py_RETURN_NONE;
+}
+
+// fp_index_place(keys, vals, bits, fps, ids) -> removed marks reused: puts
+// fingerprints the table does not hold (each once) on the first free slot
+// of their chain. The caller keeps the table under 0.7 full.
+static PyObject* fp_index_place(PyObject*, PyObject* args) {
+  PyObject *keys_o, *vals_o, *fps_o, *ids_o;
+  int bits;
+  if (!PyArg_ParseTuple(args, "OOiOO", &keys_o, &vals_o, &bits, &fps_o, &ids_o))
+    return nullptr;
+  FlatBuf keys, vals, fps, ids;
+  if (!keys.open(keys_o, 8, true, "keys") || !vals.open(vals_o, 4, true, "vals") ||
+      !fps.open(fps_o, 8, false, "fps") || !ids.open(ids_o, 4, false, "ids"))
+    return nullptr;
+  if (bits < 1 || bits > 40 || keys.size() != ((Py_ssize_t)1 << bits) ||
+      vals.size() != keys.size() || ids.size() != fps.size()) {
+    PyErr_SetString(PyExc_ValueError, "fp_index_place: sizes disagree");
+    return nullptr;
+  }
+  int64_t* k = (int64_t*)keys.view.buf;
+  int32_t* v = (int32_t*)vals.view.buf;
+  const int64_t* f = (const int64_t*)fps.view.buf;
+  const int32_t* id = (const int32_t*)ids.view.buf;
+  const Py_ssize_t n = fps.size();
+  const uint64_t mask = ((uint64_t)1 << bits) - 1;
+  long long reused = 0;
+  Py_BEGIN_ALLOW_THREADS;
+  for (Py_ssize_t i = 0; i < n; i++) {
+    uint64_t pos = ((uint64_t)f[i] * FP_INDEX_MULT) >> (64 - bits);
+    while (k[pos] > 0) pos = (pos + 1) & mask;
+    reused += k[pos] == -1;
+    k[pos] = f[i];
+    v[pos] = id[i];
+  }
+  Py_END_ALLOW_THREADS;
+  return PyLong_FromLongLong(reused);
+}
+
 // fingerprint64(data: bytes) -> int — parity check hook for tests
 static PyObject* fingerprint64(PyObject*, PyObject* args) {
   Py_buffer buf;
@@ -1163,6 +1265,10 @@ static PyMethodDef methods[] = {
      "behind it, staged"},
     {"set_error_strings", set_error_strings, METH_O,
      "the error code -> wire string table encode_responses_many uses"},
+    {"fp_index_find", fp_index_find, METH_VARARGS,
+     "the shadow tier's index: the slots that hold a batch of fingerprints"},
+    {"fp_index_place", fp_index_place, METH_VARARGS,
+     "the shadow tier's index: a batch of absent fingerprints put in"},
     {"fingerprint64", fingerprint64, METH_VARARGS, "seeded 63-bit XXH64"},
     {"fnv1a32", fnv1a32_py, METH_VARARGS, "fnv1a 32-bit"},
     {nullptr, nullptr, 0, nullptr}};

@@ -216,6 +216,15 @@ class EngineStats:
     # fused dispatches whose host staging was the one native call
     # (ops/wire.stage_wire_chunk), not the NumPy staging
     native_staged: int = 0
+    # live rows a decide displaced whose state went to the host-RAM shadow
+    # (gubernator_tpu/tier/), each also in `evicted_unexpired`, the
+    # kernel's count of displaced live rows: the difference is state LOST
+    # (`lost_live`), all of `evicted_unexpired` where no shadow is attached
+    demoted_live: int = 0
+
+    @property
+    def lost_live(self) -> int:
+        return self.evicted_unexpired - self.demoted_live
 
     def accumulate(self, stats, count_dropped: bool = True) -> None:
         self.cache_hits += int(stats.cache_hits)
@@ -242,6 +251,7 @@ class EngineStats:
         self.aggregate_rows += d.aggregate_rows
         self.later_lane_rows += d.later_lane_rows
         self.native_staged += d.native_staged
+        self.demoted_live += d.demoted_live
 
 
 def _plan(engine, hb):
@@ -262,7 +272,10 @@ def shadow_probe(engine, fps: np.ndarray, now_ms: int):
     through the conservative merge BEFORE the batch's decide dispatch
     (promote_rows / PendingCheck.promote)."""
     shadow = getattr(engine, "shadow", None)
-    if shadow is None:
+    if shadow is None or getattr(engine, "_evictees", False):
+        # LocalEngine decides a shadowed key in its own miss path
+        # (`_decide_faulting`), on the engine thread, where the shadow and
+        # the table are one state; a probe ahead of the dispatch is not
         return None
     pf, rows = shadow.take(fps, now_ms)
     if pf.shape[0] == 0:
@@ -312,6 +325,13 @@ def promote_rows(engine, promote, now_ms: int):
     if not putback:
         return total, np.empty(0, dtype=np.int64)
     return total, np.concatenate(putback)
+
+
+def _fit(mask: np.ndarray, like) -> np.ndarray:
+    """`mask` over a batch's first rows, False-padded to its padded width."""
+    out = np.zeros(np.asarray(like).shape[0], dtype=bool)
+    out[: mask.shape[0]] = mask
+    return out
 
 
 def _batch_fps(batch, n: int) -> np.ndarray:
@@ -623,6 +643,24 @@ class _LazyWireBatch:
 
     def __iter__(self):
         return iter(self._materialize())
+
+    def select(self, rows: np.ndarray) -> HostBatch:
+        """The HostBatch of `rows` of this pass alone (a retry's, the tiered
+        table's deferred rows): packed from those rows' columns, not cut
+        from the whole chunk's HostBatch, unless that exists already or the
+        pass is an aggregate (whose rows are sums over the chunk)."""
+        if self._hb is not None or self._groups is not None:
+            return HostBatch(*[f[rows] for f in self._materialize()])
+        cols = concat_columns(self._parts)
+        idx = rows if self._pick is None else self._pick[rows]
+        hb, _ = pack_columns(
+            RequestColumns(*[f[idx] for f in cols]), self._now,
+            tolerance_ms=self._tol,
+        )
+        if self._pick is None:  # as staged: see `_materialize`
+            live = self.active[rows]
+            hb = hb._replace(fp=np.where(live, hb.fp, 0), active=live)
+        return hb
 
     def fp_view(self) -> np.ndarray:
         """Fingerprint column without materializing the HostBatch (the
@@ -1070,13 +1108,19 @@ def finish_check_columns(
             # like the sync path's retry loop
             retried_any = True
             rows = np.nonzero(dropped)[0]
+            # the rows' batch is made here, on the fetch thread: with a
+            # shadow tier every key the table does not hold comes this
+            # way, and the engine thread has the table to itself
+            if isinstance(batch, HostBatch):
+                sub = HostBatch(*[f[rows] for f in batch])
+            else:
+                sub = batch.select(rows)
 
-            def retry(rows=rows, batch=batch, uncounted=uncounted):
+            def retry(rows=rows, sub=sub, uncounted=uncounted):
                 # padding conventions are the engine's own (LocalEngine pads
                 # to _pad_size; ShardedEngine needs no row padding). Rows the
                 # phase-1 pass never processed (a2a capacity drops) have
                 # their outcome counted by the retry.
-                sub = HostBatch(*[f[rows] for f in batch])
                 unc = uncounted[rows] if uncounted is not None else None
                 return engine._redispatch_rows(sub, len(rows), uncounted=unc)
 
@@ -1212,6 +1256,11 @@ class LocalEngine:
         # arms the fault-back probe in the serving paths. None = zero
         # cost, bit-identical dispatch graphs.
         self.shadow = None
+        self._tier_metrics = None
+        self._tier = dict.fromkeys(
+            ("probed", "promoted", "returned", "merge_launches",
+             "rehydrate_dispatches"), 0,
+        )
         self.stats = EngineStats()
         # device passes launched, by padded batch size (_issue_from_dev, the
         # ring's fused drains): the shapes a resize compiles again, and what
@@ -1232,26 +1281,46 @@ class LocalEngine:
             self.ckpt.mark(np.asarray(fps))
 
     # --------------------------------------------------------------- tiering
+    #
+    # With a shadow attached the table is upstream's bounded LRU and the
+    # shadow the Store behind it, and every answer is what an unbounded
+    # table would give. What makes that exact is where a key's state may
+    # change place: only inside one engine-thread job, fetched before the
+    # job ends (`_decide_faulting`). The pipelined launches
+    # (`issue_staged`) run the hits-only program (evictees="defer"): a key
+    # the table does not hold is neither created nor answered there, no row
+    # is evicted, and its row comes back dropped for the finish half's
+    # fixup, which decides it here. So between two jobs every key's state
+    # is in the table or in the shadow, never on its way, and a decide
+    # never meets a key whose state it cannot see.
 
     @property
     def _evictees(self) -> bool:
-        """Whether dispatches compile the evictee sidecar (a shadow tier
-        is attached; the v1 oracle's unpacked outputs carry no sidecar)."""
+        """Whether dispatches compile the tiered programs (a shadow tier
+        is attached; the v1 oracle's unpacked outputs carry no sidecar).
+        The serving halves then leave the fault-back to the dispatch
+        itself (`shadow_probe` and what follows it stay the mesh engine's,
+        whose decide carries no sidecar)."""
         return self.shadow is not None and self._decide_fn is None
 
-    def attach_shadow(self, shadow) -> None:
+    def attach_shadow(self, shadow, metrics=None) -> None:
         """Arm hot-set tiering: evict capture + fault-back from `shadow`
         (tier.ShadowTable). Call before serving — flipping it mid-flight
-        only costs recompiles, the sidecar decode keys off the flag at
-        each dispatch's own issue."""
+        only costs recompiles. `metrics` (the daemon's) takes the tier's
+        stage samples (tier_probe, tier_promote, tier_harvest)."""
         self.shadow = shadow
+        self._tier_metrics = metrics
+        self._tier = dict.fromkeys(self._tier, 0)
+
+    def tier_counts(self) -> dict:
+        """The miss path's counts since start (engine thread writes)."""
+        return dict(self._tier)
 
     def _harvest_evictees(self, host_arr: np.ndarray) -> None:
         """Demote-on-evict: decode the dispatch's evictee sidecar and
-        append the victim rows to the shadow. `host_arr` must come from a
-        dispatch issued with evictees=True. Runs wherever the output was
-        fetched (engine thread on the serial path, a fetch worker on the
-        pipelined one) — ShadowTable is lock-guarded. Expiry filtering is
+        append the victim rows to the shadow. `host_arr`
+        must come from a dispatch issued with evictees=True, fetched on
+        the engine thread in the job that launched it. Expiry filtering is
         left to promote time (`take` drops dead rows against the request
         timeline; wall clock here could disagree with a test's synthetic
         clock)."""
@@ -1263,9 +1332,105 @@ class LocalEngine:
             return
         from gubernator_tpu.ops.kernel2 import unpack_evictees
 
-        fps, rows = unpack_evictees(host_arr)
-        if fps.shape[0]:
-            self.shadow.offer(fps, rows, now_ms=0, reason="evict")
+        with tracing.stage("tier_harvest", self._tier_metrics) as st:
+            fps, rows = unpack_evictees(host_arr)
+            st.note(rows=int(fps.shape[0]))
+            if fps.shape[0]:
+                self.shadow.offer(fps, rows, now_ms=0, reason="evict")
+        self.stats.demoted_live += int(fps.shape[0])
+
+    def _fault_in(self, fps: np.ndarray, now: int) -> np.ndarray:
+        """Bring the shadowed ones of `fps` back into the table before
+        their decide (engine thread): taken out of the shadow and installed
+        by ONE `merge2` launch whose own victims go to the shadow before
+        this returns. Returns the fingerprints whose state is in the shadow
+        as this returns, whose decide therefore has to wait for the next
+        round: those whose install found no lane (more than K keys of one
+        bucket in the batch; their rows are back in the shadow) and the
+        merge's own victims, one of which may be a key of this batch."""
+        from gubernator_tpu.ops.layout import FULL
+
+        t = self._tier
+        with tracing.stage("tier_probe", self._tier_metrics) as st:
+            pf, rows = self.shadow.take(fps, now)
+            st.note(rows=int(fps.shape[0]), hits=int(pf.shape[0]))
+        t["probed"] += int(fps.shape[0])
+        if pf.shape[0] == 0:
+            return pf
+        with tracing.stage(
+            "tier_promote", self._tier_metrics, rows=int(pf.shape[0]), launches=1
+        ):
+            _n, mask, ev_fps, ev_rows = self.merge_rows(
+                pf, rows, now_ms=now, layout=FULL, collect=True
+            )
+            t["merge_launches"] += 1
+            t["promoted"] += int(mask.sum())
+            held = pf[~mask]
+            if held.shape[0]:
+                self.shadow.offer(held, rows[~mask], now_ms=0, reason="return")
+                t["returned"] += int(held.shape[0])
+        if ev_fps.shape[0]:
+            # the merge's victims: its sidecar's way into the shadow, as
+            # the decide's (`_harvest_evictees`)
+            with tracing.stage(
+                "tier_harvest", self._tier_metrics, rows=int(ev_fps.shape[0])
+            ):
+                self.shadow.offer(ev_fps, ev_rows, now_ms=0, reason="evict")
+        return np.concatenate([held, ev_fps])
+
+    def _decide_faulting(self, batch, n: int, cascade: bool = False):
+        """One unique-fp pass over a tiered table, exactly (engine thread):
+        what `_dispatch_with_retry` is to a table without a shadow. Each
+        round takes the rows' shadowed state back into the table
+        (`_fault_in`), decides the rows whose state is now in it with the
+        claiming program (evictees=True), and puts that program's victims
+        into the shadow before anything else is launched; a row whose
+        claim was contended, or whose promote found no lane, is the next
+        round's. Counts every row it decides (the hits-only program counts
+        none of the rows it deferred)."""
+        status = np.zeros(n, dtype=np.int32)
+        limit = np.zeros(n, dtype=np.int64)
+        remaining = np.zeros(n, dtype=np.int64)
+        reset = np.zeros(n, dtype=np.int64)
+        hit = np.zeros(n, dtype=bool)
+        rows, sub = np.arange(n), batch
+        dropped = np.zeros(n, dtype=bool)
+        for attempt in range(self.max_claim_retries + 1):
+            m = len(rows)
+            live = np.asarray(sub.active[:m]) & (np.asarray(sub.fp[:m]) != 0)
+            wait = np.zeros(m, dtype=bool)
+            if live.any():
+                fps = np.asarray(sub.fp[:m])
+                now = int(np.asarray(sub.created_at[:m])[live].max())
+                held = self._fault_in(fps[live], now)
+                if held.shape[0]:
+                    wait = live & np.isin(fps, held)
+                    out = _fit(wait, sub.fp)
+                    sub = sub._replace(
+                        fp=np.where(out, 0, sub.fp),
+                        active=np.asarray(sub.active) & ~out,
+                    )
+            (s2, l2, r2, t2, d2, h2), st = unpack_outputs(
+                self._decide_packed(sub, cascade and attempt == 0), m
+            )
+            self._tier["rehydrate_dispatches"] += 1
+            self.stats.cache_hits += st[0]
+            self.stats.cache_misses += st[1]
+            self.stats.over_limit += st[2]
+            self.stats.evicted_unexpired += st[3]
+            self.stats.dispatches += 1
+            status[rows], limit[rows], remaining[rows], reset[rows] = s2, l2, r2, t2
+            hit[rows] = h2
+            again = d2 | wait
+            dropped[:] = False
+            dropped[rows[again]] = True
+            if not again.any():
+                break
+            rows = rows[again]
+            sub = pad_batch(
+                HostBatch(*[f[:n][rows] for f in batch]), _pad_size(len(rows))
+            )
+        return status, limit, remaining, reset, dropped, hit
 
     def extract_idle(self, now_ms: int, idle_ms: int,
                      max_rows: int = 1 << 16):
@@ -1360,12 +1525,14 @@ class LocalEngine:
 
     def _issue_from_dev(
         self, dev_arr, batch_rows: int, math: str, wired: bool = False,
-        cascade: bool = False,
+        cascade: bool = False, defer: bool = False,
     ) -> "jax.Array":
         """Launch one pass from a staged ingress array WITHOUT fetching (the
-        output is fetched later, off this thread); counted by its pad."""
+        output is fetched later, off this thread); counted by its pad. On a
+        tiered table `defer` selects the hits-only program (a pipelined
+        launch), else the claiming one whose victims ride home."""
         self._pad_passes[batch_rows] = self._pad_passes.get(batch_rows, 0) + 1
-        ev = self._evictees
+        ev = self._evictees and ("defer" if defer else True)
         if wired:
             from gubernator_tpu.ops.wire import decide2_wire_cols
 
@@ -1435,19 +1602,18 @@ class LocalEngine:
         if needs_full:
             # engine thread — the only thread allowed to swap the table
             self.migrate_layout_full()
-        return self._issue_from_dev(dev, batch_rows, math, wired, cascade)
+        return self._issue_from_dev(
+            dev, batch_rows, math, wired, cascade, defer=True
+        )
 
     def finish_staged(self, pending, n: int):
         """Materialize one pass's packed output → ((s, l, r, t, dropped,
         hit), (hits, misses, over, evicted), uncounted). The single-device
         kernel probes every row, so `uncounted` is always None here (cf.
-        ShardedEngine's a2a capacity drops). With a shadow attached the
-        fetched array carries the evictee sidecar — harvested here, on
-        the fetch thread, before the response decode."""
-        arr = np.asarray(pending)
-        if self._evictees:
-            self._harvest_evictees(arr)
-        outs, st = unpack_outputs(arr, n)
+        ShardedEngine's a2a capacity drops). With a shadow attached this
+        is the hits-only program's output: the rows it deferred come back
+        dropped and go the retry's way, `_redispatch_rows`."""
+        outs, st = unpack_outputs(np.asarray(pending), n)
         return outs, st, None
 
     def _redispatch_rows(self, batch, n: int, uncounted=None):
@@ -1455,8 +1621,13 @@ class LocalEngine:
         accounts dispatches/evictions/final drops only — hits/misses/over
         were already counted by the dropped phase-1 pass, exactly like the
         sync path's retry loop. `uncounted` is a mesh-engine concern
-        (ShardedEngine): ignored here."""
+        (ShardedEngine): ignored here. On a tiered table this is where a
+        key the table did not hold is decided (`_decide_faulting`)."""
         batch = pad_batch(batch, _pad_size(n))
+        if self._evictees:
+            outs = self._decide_faulting(batch, n)
+            self.stats.dropped += int(outs[4].sum())
+            return outs
         (status, limit, remaining, reset, dropped, hit), st = unpack_outputs(
             self._decide_packed(batch), n
         )
@@ -1545,6 +1716,10 @@ class LocalEngine:
         bucket within a single dispatch) are re-dispatched — the decision is
         only authoritative once persisted. Rows still unpersisted after
         `max_claim_retries` surface a per-item error (`ERR_NOT_PERSISTED`)."""
+        if self._evictees:
+            outs = self._decide_faulting(batch, n, cascade)
+            self.stats.dropped += int(outs[4].sum())
+            return outs
         (status, limit, remaining, reset, dropped, hit), st = unpack_outputs(
             self._decide_packed(batch, cascade), n
         )

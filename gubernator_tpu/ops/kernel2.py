@@ -86,6 +86,7 @@ from gubernator_tpu.ops.table2 import (
     ROW,
     STAMP_HI,
     STAMP_LO,
+    TOUCH,
     Table2,
 )
 from gubernator_tpu.types import Status
@@ -264,13 +265,22 @@ class Claim2(NamedTuple):
 
 
 def _probe_claim2(
-    rows_tbl: jnp.ndarray, fp, now, active, blk: int, u: int, layout=None
+    rows_tbl: jnp.ndarray, fp, now, active, blk: int, u: int, layout=None,
+    victim: str = "expiry", claim: bool = True,
 ) -> Claim2:
     """Probe + claim. `layout` (ops/layout.py) is the table's slot layout:
     the row gather fetches layout.row lanes per bucket — HALF the HBM
     bytes for the 32 B packed layouts — and the packed fields unpack to
     the canonical 16-field slots in registers, so every consumer below
-    (claim ordering, decision math, merge rules) stays layout-blind."""
+    (claim ordering, decision math, merge rules) stays layout-blind.
+
+    The two static switches are the tiered programs' (a shadow attached,
+    gubernator_tpu/tier/). `victim="lru"` orders a full bucket's live
+    lanes by their TOUCH lane, least recently used first (upstream's
+    lrucache.go), expiry breaking ties, where the default takes the
+    soonest-expiring. `claim=False` gives no row a lane it does not own:
+    a key the table does not hold comes back dropped and unwritten, for
+    the engine's miss path to fault in (ops/engine.LocalEngine)."""
     if layout is None:
         from gubernator_tpu.ops.layout import FULL as layout
     NB = rows_tbl.shape[0]
@@ -331,12 +341,17 @@ def _probe_claim2(
     lane_iota = jnp.broadcast_to(jnp.arange(K, dtype=i32), (B, K))
     exp_hi_k = slots[:, :, EXP_HI]
     exp_lo_k = _biased(slots[:, :, EXP_LO])
-    _, _, _, cand = jax.lax.sort(
-        (live.astype(i32), exp_hi_k, exp_lo_k, lane_iota), num_keys=3, dimension=1
-    )
+    keys = (live.astype(i32), exp_hi_k, exp_lo_k)
+    if victim == "lru":
+        keys = (keys[0], slots[:, :, TOUCH], *keys[1:])
+    cand = jax.lax.sort(
+        (*keys, lane_iota), num_keys=len(keys), dimension=1
+    )[-1]
     rank_c = jnp.clip(rank, 0, K - 1)
     ins_lane = jnp.take_along_axis(cand, rank_c[:, None], axis=1)[:, 0]
     claim_ok = need & (rank < K)
+    if not claim:
+        claim_ok = jnp.zeros_like(need)
 
     chosen = jnp.where(owns, own_j, ins_lane)
     got = active & (owns | claim_ok)
@@ -628,6 +643,14 @@ def _write_xla(rows_tbl, new16, c: Claim2, layout=None):
 # -------------------------------------------------------------------- decide
 
 
+def touch_tick(now):
+    """What the tiered programs write into a row's TOUCH lane: the row's
+    clock in units of 1,024 ms, which an int32 holds until 2039. Lanes
+    touched within one unit tie, and the victim rule then falls back on
+    their expiry."""
+    return (now >> 10).astype(i32)
+
+
 def decide_payload(lane16, req: ReqBatch, owns, *, math: str):
     """The per-row DECIDE stage: the chosen lane's canonical (B, 16) stored
     fields + the request rows → (exists, Decision, canonical (B, 16)
@@ -760,7 +783,14 @@ def decide2_impl(
     elsewhere) — the state today's eviction silently discards, captured so
     the engine can demote it to the host-RAM shadow instead. The return
     grows a 4th element; `evictees=False` keeps the historic 3-tuple and
-    a bit-identical trace.
+    a bit-identical trace. `evictees="defer"` is the tiered table's
+    pipelined program: it decides the rows whose key the table holds and
+    gives no other row a lane (`claim=False`), so it creates nothing,
+    evicts nothing and carries no sidecar; a deferred row comes back
+    dropped, in no count of the stats rows, and the engine's miss path
+    decides it (with `evictees=True`) once its state is where it belongs.
+    Both tiered programs stamp the TOUCH lane of every row they write and
+    take a full bucket's least recently touched lane as the victim.
     """
     layout = table.layout
     if not layout.supports_math(math):
@@ -779,13 +809,18 @@ def decide2_impl(
     now = req.created_at
     active = req.active
 
-    c = _probe_claim2(table.rows, req.fp, now, active, blk, u, layout)
+    c = _probe_claim2(
+        table.rows, req.fp, now, active, blk, u, layout,
+        victim="lru" if evictees else "expiry", claim=evictees != "defer",
+    )
 
     # ---- apply: chosen lane's stored state (shared decide stage)
     lane16 = jnp.take_along_axis(c.slots, c.chosen[:, None, None], axis=1)[
         :, 0, :
     ]  # (B, F)
     exists, d, new16 = decide_payload(lane16, req, c.owns, math=math)
+    if evictees:
+        new16 = new16.at[:, TOUCH].set(touch_tick(now))
 
     if write == "sweep":
         rows_out = _write_sweep(table.rows, new16, c, blk, u, layout)
@@ -794,6 +829,15 @@ def decide2_impl(
     else:
         rows_out = _write_xla(table.rows, new16, c, layout)
 
+    if evictees == "defer":
+        # a row with no lane of its own was not decided here: dropped for
+        # the engine to see, and counted by the program that decides it
+        resp, stats = assemble_resp(
+            req._replace(active=active & c.owns), d, exists, c.written,
+            c.evict_live,
+        )
+        resp = resp._replace(dropped=active & ~c.written)
+        return Table2(rows=rows_out, layout=layout), resp, stats
     resp, stats = assemble_resp(req, d, exists, c.written, c.evict_live)
     if evictees:
         ev16 = jnp.where(c.evict_live[:, None], lane16, 0).astype(i32)
@@ -966,12 +1010,15 @@ def decide2_packed_impl(
 ):
     """(table', packed (B+2, 4) i64[, evictee sidecar (B, 16) i32]) — the
     sidecar element exists only under evictees=True (see decide2_impl)."""
-    if evictees:
+    if evictees is True:
         table, resp, stats, ev16 = decide2_impl(
             table, req, write=write, math=math, evictees=True
         )
         return table, pack_outputs(resp, stats, req.behavior), ev16
-    table, resp, stats = decide2_impl(table, req, write=write, math=math)
+    # False, or "defer": the tiered table's hits-only program, no sidecar
+    table, resp, stats = decide2_impl(
+        table, req, write=write, math=math, evictees=evictees
+    )
     return table, pack_outputs(resp, stats, req.behavior)
 
 
@@ -1007,7 +1054,7 @@ def decide2_packed_cols_impl(
     carries level bits — see fold_cascade_packed). `evictees=True`
     rides the evictee sidecar home in the same fetched array
     (attach_evictees; decoded host-side by unpack_evictees)."""
-    if evictees:
+    if evictees is True:
         table, packed, ev16 = decide2_packed_impl(
             table, req_from_arr(arr), write=write, math=math, evictees=True
         )
@@ -1015,7 +1062,7 @@ def decide2_packed_cols_impl(
             packed = fold_cascade_packed(packed, arr)
         return table, attach_evictees(packed, ev16)
     table, packed = decide2_packed_impl(
-        table, req_from_arr(arr), write=write, math=math
+        table, req_from_arr(arr), write=write, math=math, evictees=evictees
     )
     if cascade:
         packed = fold_cascade_packed(packed, arr)
@@ -1485,7 +1532,9 @@ def merge2_impl(
     returns the (B, 16) i32 canonical rows of LIVE entries this merge's
     installs displaced, so a shadow fault-back that lands in a full
     bucket demotes the victim instead of silently destroying it — the
-    invariant that makes HBM + shadow a closed state set."""
+    invariant that makes HBM + shadow a closed state set. It is a tiered
+    program like the decide's: the victim is the least recently touched
+    lane and every row it writes is touched at `now`."""
     g_i = lambda f: slots[:, f]
     i_exp = _join64(g_i(EXP_LO), g_i(EXP_HI))
     active = active & (i_exp >= now)
@@ -1499,11 +1548,17 @@ def merge2_impl(
     else:
         blk, u = sweep_geometry(NB, B)
 
-    c = _probe_claim2(table.rows, fp, now, active, blk, u, layout)
+    c = _probe_claim2(
+        table.rows, fp, now, active, blk, u, layout,
+        victim="lru" if evictees else "expiry",
+    )
     lane16 = jnp.take_along_axis(c.slots, c.chosen[:, None, None], axis=1)[
         :, 0, :
     ]
     exists, new16 = merge_payload16(fp, slots, lane16, c.owns, now)
+    if evictees:
+        # a promote is a use: the row comes back as the bucket's newest
+        new16 = new16.at[:, TOUCH].set(touch_tick(now))
     if write == "sweep":
         rows_out = _write_sweep(table.rows, new16, c, blk, u, layout)
     elif write == "sparse":

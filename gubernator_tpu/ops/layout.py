@@ -72,6 +72,7 @@ K = 8  # slots per bucket — shared with table2 by construction
 # canonical full-layout field indices (ops/table2.py)
 _FP_LO, _FP_HI, _LIMIT, _BURST, _REM_I, _FLAGS = 0, 1, 2, 3, 4, 5
 _DUR_LO, _DUR_HI, _STAMP_LO, _STAMP_HI, _EXP_LO, _EXP_HI = 6, 7, 8, 9, 10, 11
+_TOUCH = 14  # ops/table2.TOUCH
 _REMF_HI, _REMF_LO = 12, 13
 
 _ALGO_TOKEN = 0
@@ -221,6 +222,11 @@ class SlotLayout:
                 p(_STAMP_LO).astype(i64) & 0xFFFFFFFF
             )
             ref = xp.where(stamp != 0, stamp, ref)
+            # a tiered table's programs write the time of a row's last use
+            # into the TOUCH lane (ops/table2.TOUCH, units of 1,024 ms; 0
+            # on every row an untiered program wrote): a token bucket's
+            # stamp is its window's creation, and a key in use is not idle
+            ref = xp.maximum(ref, p(_TOUCH).astype(i64) << 10)
         return ref
 
     # ---------------------------------------------------------- predicates

@@ -46,11 +46,12 @@ def default_ring_issue() -> str:
     return "fused" if jax.default_backend() == "tpu" else "host"
 
 
-def egress_rows(width: int, evictees: bool) -> int:
+def egress_rows(width: int, evictees) -> int:
     """Rows of one slot's compact egress bank: the (W+2, 4) encode_wire_out
     image, or (5W+2, 4) with the raw evictee sidecar rows interleaved
-    (kernel2.attach_evictees_wire — static per engine config)."""
-    return 5 * width + 2 if evictees else width + 2
+    (kernel2.attach_evictees_wire, `evictees=True`). A tiered engine's
+    drain runs the hits-only program ("defer"), which carries none."""
+    return 5 * width + 2 if evictees is True else width + 2
 
 
 def _drain_impl(
@@ -165,7 +166,10 @@ class DeviceRing:
             engine.table, self.grids, self.seq_in, self.seq_out,
             np.int64(start), np.int64(k),
             k_max=self.drain_k, write=engine.write_mode, math=math,
-            cascade=cascade, evictees=bool(engine._evictees),
+            # a drain is a pipelined launch: on a tiered table the
+            # hits-only program, whose deferred rows the finish half hands
+            # to the engine's miss path (ops/engine.LocalEngine)
+            cascade=cascade, evictees="defer" if engine._evictees else False,
         )
         engine.table = table
         return bank, n

@@ -19,7 +19,8 @@ vector lane row (128 lanes), so:
 
 Per-slot field order (16 int32 lanes): fp_lo, fp_hi, limit, burst, rem_i,
 flags(algo | status<<8), dur_lo, dur_hi, stamp_lo, stamp_hi, exp_lo, exp_hi,
-remf_hi(f32 bits), remf_lo(f32 bits), reserved, reserved. Semantics mirror
+remf_hi(f32 bits), remf_lo(f32 bits), touch (tiered tables; else 0),
+reserved. Semantics mirror
 TokenBucketItem/LeakyBucketItem (reference store.go:29-43) + CacheItem.ExpireAt
 (reference cache.go:29-41); the leaky float64 remainder is double-single
 (two f32, ~48-bit mantissa). fp == 0 marks an empty slot. Eviction is
@@ -46,6 +47,11 @@ ROW = K * F  # 128 int32 lanes per full-layout bucket row
 FP_LO, FP_HI, LIMIT, BURST, REM_I, FLAGS = 0, 1, 2, 3, 4, 5
 DUR_LO, DUR_HI, STAMP_LO, STAMP_HI, EXP_LO, EXP_HI = 6, 7, 8, 9, 10, 11
 REMF_HI, REMF_LO = 12, 13
+# the first reserved lane: with a shadow tier attached the decide and merge
+# programs write the time of the row's last use here (`touch_tick`), which
+# is what their victim rule reads (ops/kernel2._probe_claim2, victim="lru").
+# Untiered programs write 0 and never read it; the 32 B layouts drop it.
+TOUCH = 14
 
 
 class Table2:
@@ -276,35 +282,79 @@ def _extract_idle_sorted(rows, now_ms, idle_ms, *, layout):
     )
 
 
+_IDLE_CHUNK = 16_384  # buckets the idle sweep looks at in one step
+
+
+def _idle_first_impl(rows, now_ms, idle_ms, *, layout, max_rows):
+    """The first `max_rows` idle live slots of a rows array, in table order:
+    (slots (max_rows, F_layout), fp (max_rows,), idle count). Which slots
+    are idle is decided `_IDLE_CHUNK` buckets at a time (seeing rows as
+    (.., K, F) makes a TPU copy them into another tiling: done whole, the
+    sorted extract below wants 9.7 GiB of scratch beside a 1 GiB table, as
+    the telemetry scan did before ops/telemetry._scan_chunked), and only the
+    rows of the slots taken are gathered. Entries past the count repeat
+    slot 0 and are the caller's to drop."""
+    rows = rows.reshape(-1, layout.row)
+    nb = rows.shape[0]
+    chunk = math.gcd(nb, _IDLE_CHUNK)
+
+    def mask_of(part):
+        slots = part.reshape(-1, layout.F)
+        fp = (slots[:, FP_HI].astype(jnp.int64) << 32) | (
+            slots[:, FP_LO].astype(jnp.int64) & 0xFFFFFFFF
+        )
+        exp = (slots[:, layout.exp_lo_i].astype(jnp.int64) & 0xFFFFFFFF) | (
+            slots[:, layout.exp_hi_i].astype(jnp.int64) << 32
+        )
+        live = (fp != 0) & (exp >= now_ms)
+        return live & ((now_ms - layout.idle_ref(slots)) >= idle_ms)
+
+    def step(i, mask):
+        part = jax.lax.dynamic_slice_in_dim(rows, i * chunk, chunk)
+        return jax.lax.dynamic_update_slice_in_dim(
+            mask, mask_of(part), i * chunk * K, 0
+        )
+
+    mask = jax.lax.fori_loop(
+        0, nb // chunk, step, jnp.zeros(nb * K, dtype=bool)
+    )
+    take = min(max_rows, nb * K)
+    (idx,) = jnp.nonzero(mask, size=take, fill_value=0)
+    lanes = (idx % K)[:, None] * layout.F + jnp.arange(layout.F)[None, :]
+    slots = jnp.take_along_axis(rows[idx // K], lanes, axis=1)
+    fp = (slots[:, FP_HI].astype(jnp.int64) << 32) | (
+        slots[:, FP_LO].astype(jnp.int64) & 0xFFFFFFFF
+    )
+    return slots, fp, mask.sum()
+
+
+_extract_idle_first = functools.partial(
+    jax.jit, static_argnames=("layout", "max_rows")
+)(_idle_first_impl)
+
+
 def extract_idle_rows(rows, now_ms: int, idle_ms: int, layout=None,
                       max_rows: int = 1 << 16):
     """Idle-past-the-horizon live slots of a device-resident rows array:
     (fps (N,) i64, slots (N, F_layout) i32) host copies, N ≤ max_rows (the
     per-sweep demote cap — bounds the engine-thread job; the remainder
-    stays for the next sweep). The filter + pack runs on-device; the host
-    fetches only the idle prefix (the extract_live_rows fetch rule)."""
+    stays for the next sweep). The filter and the gather run on-device
+    (`_extract_idle_first`); the host fetches `max_rows` rows at most."""
     if layout is None:
         from gubernator_tpu.ops.layout import layout_for_row
 
         layout = layout_for_row(int(rows.shape[-1]))
-    slots_s, fp_s, cnt = _extract_idle_sorted(
+    slots, fp, cnt = _extract_idle_first(
         rows, jnp.asarray(np.int64(now_ms)), jnp.asarray(np.int64(idle_ms)),
-        layout=layout,
+        layout=layout, max_rows=int(max_rows),
     )
-    n = min(int(cnt), int(max_rows))
+    n = min(int(cnt), int(fp.shape[0]))
     if n == 0:
         return (
             np.empty(0, dtype=np.int64),
             np.empty((0, layout.F), dtype=np.int32),
         )
-    pad = 256
-    while pad < n:
-        pad *= 2
-    pad = min(pad, int(fp_s.shape[0]))
-    return (
-        np.asarray(fp_s[:pad])[:n].copy(),
-        np.asarray(slots_s[:pad])[:n].copy(),
-    )
+    return np.asarray(fp)[:n].copy(), np.asarray(slots)[:n].copy()
 
 
 def gather_slots_impl(rows: jnp.ndarray, fp: jnp.ndarray,

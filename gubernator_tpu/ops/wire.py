@@ -468,7 +468,7 @@ def decide2_wire_cols_impl(
     narrowing (slot fields are bit patterns, never clamped —
     kernel2.attach_evictees_wire)."""
     arr12, base = decode_wire_block(carr)
-    if evictees:
+    if evictees is True:
         from gubernator_tpu.ops.kernel2 import (
             attach_evictees_wire,
             decide2_packed_impl,
@@ -482,8 +482,10 @@ def decide2_wire_cols_impl(
         if cascade:
             packed = fold_cascade_packed(packed, arr12)
         return table, attach_evictees_wire(encode_wire_out(packed, base), ev16)
+    # False, or "defer": the tiered table's hits-only program, no sidecar
     table, packed = decide2_packed_cols_impl(
-        table, arr12, write=write, math=math, cascade=cascade
+        table, arr12, write=write, math=math, cascade=cascade,
+        evictees=evictees,
     )
     return table, encode_wire_out(packed, base)
 

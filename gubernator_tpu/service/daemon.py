@@ -575,6 +575,16 @@ class Daemon:
         family's graph — an off-family warm batch would migrate the table
         to full before the first real request arrives."""
         lay = getattr(self.engine.table, "layout", None)
+        tiered = self.tier.enabled and hasattr(self.engine, "tier_counts")
+        if tiered:
+            # with the tiering plane armed the engine runs the tiered
+            # programs (kernel2: evictees=True and "defer", merge2 with its
+            # sidecar): warm those, against a scratch shadow that takes the
+            # warm rows' evictions and goes with them (tier.attach arms the
+            # real one afterwards)
+            from gubernator_tpu.tier.shadow import ShadowTable
+
+            self.engine.attach_shadow(ShadowTable(max_bytes=1 << 24))
         variants = (
             [0],  # math="token" graph
             [2],  # math="gcra" graph (all-GCRA specialization)
@@ -670,6 +680,11 @@ class Daemon:
                     )
                     await self.runner.check_columns(warm)
                     shapes += 1
+                    if tiered:
+                        # the line above compiled the claiming program of
+                        # this pad (the miss path's); the pipelined launch
+                        # runs the hits-only one, and a promote the merge
+                        shapes += await self._warm_tier_pad(warm)
                 size *= 2
             # herd geometries: a same-key batch plans j sequential passes
             # (j ≤ max_exact) whose same-shape outputs fuse into one
@@ -706,6 +721,9 @@ class Daemon:
                 from gubernator_tpu.parallel.global_sync import GlobalStats
 
                 self.engine.global_stats = GlobalStats()
+        if tiered:
+            shapes += await self._warm_tier_sweep()
+            self.engine.attach_shadow(None)
         # the /metrics scrape and the grow tick count live keys on the device:
         # compile that program now, not under the first scrape
         await self.runner.live_count()
@@ -723,6 +741,42 @@ class Daemon:
         if hasattr(self.engine, "forget_passes"):
             self.engine.forget_passes()
         return shapes
+
+    async def _warm_tier_pad(self, warm) -> int:
+        """The tiered programs of one warm pad beside the claiming decide:
+        the hits-only decide (a pipelined dispatch, whose rows all come
+        back deferred and are decided by the miss path) and the promote's
+        merge with its sidecar (all-zero rows are expired at every clock:
+        the program compiles, the table keeps its bytes)."""
+        from gubernator_tpu.ops.layout import FULL
+        from gubernator_tpu.ops.table2 import F as F_FULL
+
+        await self.runner.check(warm)
+        n = int(warm.fp.shape[0])
+        await asyncio.get_running_loop().run_in_executor(
+            self.runner._exec,
+            lambda: self.engine.merge_rows(
+                warm.fp, np.zeros((n, F_FULL), dtype=np.int32), now_ms=1,
+                layout=FULL, collect=True,
+            ),
+        )
+        return 2
+
+    async def _warm_tier_sweep(self) -> int:
+        """The idle sweep's programs at the shapes a full sweep has: the
+        extract (nothing is idle yet) and the tombstone at the sweep's cap
+        (fingerprints no key has: every row a no-op)."""
+        from gubernator_tpu.tier.manager import SWEEP_MAX_ROWS
+
+        eng = self.engine
+
+        def run():
+            eng.extract_idle(self.now_ms(), 1 << 40, SWEEP_MAX_ROWS)
+            cap = min(SWEEP_MAX_ROWS, int(eng.table.capacity))
+            eng.tombstone_fps(-np.arange(1, cap + 1, dtype=np.int64))
+
+        await asyncio.get_running_loop().run_in_executor(self.runner._exec, run)
+        return 2
 
     async def _start_discovery(self) -> None:
         kind = self.conf.peer_discovery_type
@@ -1768,8 +1822,9 @@ class Daemon:
         if snap is None:
             snap = await self.collect_telemetry()
         out = snap.to_dict()
-        out["evicted_live_total"] = self.engine.stats.evicted_unexpired
+        out["evicted_live_total"] = self.tier.lost()
         if self.tier.enabled:
+            out["demoted_live_total"] = self.tier.demoted()
             out["tiering"] = {
                 "shadow_rows": self.tier.shadow.ram_rows,
                 "tracked_rows": self.tier.shadow.tracked_rows,
@@ -1860,7 +1915,15 @@ class Daemon:
                 # before the first), beside the live keys evicted since
                 # warm-up (/v1/debug/table has the same count)
                 "table_load": None if snap is None else snap.load_factor,
-                "evicted_live_total": eng.stats.evicted_unexpired,
+                "evicted_live_total": self.tier.lost(),
+                # with the tiering plane armed (GUBER_TIER_ENABLED) the
+                # count above is state LOST (none, while the shadow sheds
+                # nothing), and what left the table for the shadow is
+                # counted apart; neither key is there with the plane off
+                **({
+                    "tiering": "shadow",
+                    "demoted_live_total": self.tier.demoted(),
+                } if self.tier.enabled else {}),
                 # constants: bench/configs/*.json `expect_engine` and
                 # chip_smoke.py still compare these two keys (ROADMAP C8)
                 "probe_kernel": "xla",
@@ -1913,6 +1976,10 @@ class Daemon:
             # the incremental checkpoint plane's counts and cadence
             # (service/checkpoint.CheckpointManager.pipeline); None when off
             "checkpoint": self.checkpointer.pipeline(),
+            # the tiering plane's counts (tier/manager.TierManager.pipeline:
+            # the miss path's probes, promotes, merge launches and
+            # re-dispatches, the shadow's demotes and size); None when off
+            "tier": self.tier.pipeline(),
             # per-algorithm decision counts (live view of
             # gubernator_tpu_decisions_total) — scenario breadth at a glance
             "decisions_by_algorithm": dict(self.runner.algo_counts),

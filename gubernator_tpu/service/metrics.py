@@ -194,15 +194,24 @@ class DaemonMetrics:
             "Live (unexpired) items evicted for new keys",
             registry=r,
         )
-        # the same kernel stat under the TPU-native name the tiering plane
-        # documents (renders gubernator_tpu_evicted_live_total): each
-        # increment is LIVE state displaced by the claim — silent loss
-        # with tiering off, a demotion with it on (docs/tiering.md)
+        # the state-loss signal (renders gubernator_tpu_evicted_live_total):
+        # live rows the claim displaced whose count is GONE. With tiering
+        # off that is the kernel stat above; with it on, a displaced row
+        # the shadow took is a demotion (the counter below), and this one
+        # grows only by rows no shadow took and by rows the shadow shed at
+        # its RAM bound with no spill file (docs/tiering.md)
         self.evicted_live = Counter(
             "gubernator_tpu_evicted_live",
-            "Live (unexpired) rows the decision kernel's claim displaced "
-            "(kernel2 evicted_unexpired stat) — state loss when tiering "
-            "is off, demote-on-evict events when it is on",
+            "Live (unexpired) rows whose state was lost: displaced by the "
+            "decision kernel's claim and taken by no shadow tier, or shed "
+            "by the shadow at its RAM bound with no spill file",
+            registry=r,
+        )
+        self.demoted_live = Counter(
+            # renders gubernator_tpu_demoted_live_total
+            "gubernator_tpu_demoted_live",
+            "Live rows the decision kernel's claim displaced whose state "
+            "the shadow tier took (demote-on-evict): no state lost",
             registry=r,
         )
         # --- hot-set tiering (gubernator_tpu/tier/; docs/tiering.md)
@@ -778,9 +787,13 @@ class DaemonMetrics:
             self.cache_access.labels(type="miss").inc(d_miss)
         if d_over > 0:
             self.over_limit_counter.inc(d_over)
+        d_demo = getattr(stats, "demoted_live", 0) - last.get("demo", 0)
         if d_evic > 0:
             self.unexpired_evictions.inc(d_evic)
-            self.evicted_live.inc(d_evic)
+        if d_evic - d_demo > 0:
+            self.evicted_live.inc(d_evic - d_demo)
+        if d_demo > 0:
+            self.demoted_live.inc(d_demo)
         if d_drop > 0:
             self.dropped_rows.inc(d_drop)
         if d_disp > 0:
@@ -796,6 +809,7 @@ class DaemonMetrics:
             misses=stats.cache_misses,
             over=stats.over_limit,
             evic=stats.evicted_unexpired,
+            demo=getattr(stats, "demoted_live", 0),
             dropped=stats.dropped,
             disp=stats.dispatches,
             clamped=stats.created_at_clamped,

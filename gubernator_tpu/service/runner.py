@@ -547,18 +547,18 @@ class EngineRunner:
     # ------------------------------------------------------------- tiering
 
     async def tier_demote_idle(
-        self, idle_ms: int, max_rows: int = 1 << 16, now_ms=None
+        self, idle_ms: int, max_rows: int = 1 << 16, now_ms=None, sink=None
     ):
         """One demote-on-idle sweep (gubernator_tpu/tier/): extract rows
-        idle past the horizon AND tombstone them out of HBM in ONE
-        engine-thread job — no decide can interleave between the read and
-        the removal, so the demoted copy is exactly the state that left
-        the table. Returns (now_ms, fps, canonical full rows); the caller
-        (TierManager) appends them to the shadow. Crash ordering: a death
-        after the tombstone but before the shadow append loses nothing
-        the delta log doesn't still hold — restart replays the row back
-        (no tombstone frame was written yet), which is the conservative
-        direction."""
+        idle past the horizon, tombstone them out of HBM AND hand them to
+        `sink(fps, rows, now)` (the shadow's `offer`) in ONE engine-thread
+        job, stage `tier_sweep` — no decide can interleave between the
+        read, the removal and the shadow's append, so the demoted copy is
+        exactly the state that left the table and a miss path that runs
+        next finds it. Returns (now_ms, fps, canonical full rows). Crash
+        ordering: a death after the tombstone loses nothing the delta log
+        doesn't still hold — restart replays the row back (no tombstone
+        frame was written yet), which is the conservative direction."""
         loop = asyncio.get_running_loop()
 
         def run():
@@ -566,13 +566,17 @@ class EngineRunner:
 
             eng = self.engine
             now = now_ms if now_ms is not None else ms_now()
-            fps, slots = eng.extract_idle(now, idle_ms, max_rows)
-            if fps.shape[0] == 0:
-                return now, fps, np.empty((0, 16), dtype=np.int32)
-            eng.tombstone_fps(fps)
-            # canonical rows at the shadow boundary (the one cross-layout
-            # conversion point, ops/layout.py)
-            full = np.asarray(eng.table.layout.unpack(slots))
+            with tracing.stage("tier_sweep", self.metrics) as st:
+                fps, slots = eng.extract_idle(now, idle_ms, max_rows)
+                st.note(rows=int(fps.shape[0]))
+                if fps.shape[0] == 0:
+                    return now, fps, np.empty((0, 16), dtype=np.int32)
+                eng.tombstone_fps(fps)
+                # canonical rows at the shadow boundary (the one
+                # cross-layout conversion point, ops/layout.py)
+                full = np.asarray(eng.table.layout.unpack(slots))
+                if sink is not None:
+                    sink(fps, full, now)
             return now, fps, full
 
         return await loop.run_in_executor(self._exec, run)

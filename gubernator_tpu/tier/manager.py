@@ -9,11 +9,11 @@ metric families + /v1/debug/tier.
 
 Sweep ordering (the crash-safety argument, docs/tiering.md):
 
-  1. ONE engine-thread job extracts idle rows AND tombstones them out of
-     HBM (EngineRunner.tier_demote_idle — no decide interleaves, so the
-     demoted copy is exactly the state that left the table);
-  2. the rows enter the shadow (RAM) and, when a spill file is
-     configured, flush to it durably;
+  1. ONE engine-thread job extracts idle rows, tombstones them out of
+     HBM AND appends them to the shadow (EngineRunner.tier_demote_idle —
+     no decide interleaves, so the demoted copy is exactly the state that
+     left the table, and the next miss path finds it);
+  2. when a spill file is configured, the rows flush to it durably;
   3. only THEN the tombstone frame is appended to the delta log.
 
 A death between (1) and (3) leaves the row's last state frame replayable
@@ -71,8 +71,8 @@ class TierManager:
             log.info("tier shadow spill indexed %d rows", loaded)
         eng = self.daemon.engine
         if hasattr(eng, "attach_shadow"):
-            eng.attach_shadow(self.shadow)
-        else:
+            eng.attach_shadow(self.shadow, metrics=self.daemon.metrics)
+        else:  # a mesh engine: the serving halves' generic hooks
             eng.shadow = self.shadow
         log.info(
             "hot-set tiering armed: idle_ms=%d shadow_bytes=%d spill=%s",
@@ -95,13 +95,13 @@ class TierManager:
         """One demote-on-idle round; returns a summary for tests/debug."""
         daemon = self.daemon
         now, fps, rows = await daemon.runner.tier_demote_idle(
-            int(self.idle_ms), SWEEP_MAX_ROWS
+            int(self.idle_ms), SWEEP_MAX_ROWS,
+            sink=lambda f, r, t: self.shadow.offer(f, r, t, reason="idle"),
         )
         self.sweeps += 1
         self.last_sweep_demoted = int(fps.shape[0])
         out = {"demoted": self.last_sweep_demoted}
         if fps.shape[0]:
-            self.shadow.offer(fps, rows, now, reason="idle")
             self.shadow.flush(now)
             # removal record for warm restart — AFTER the shadow holds
             # the rows (module docstring ordering)
@@ -132,10 +132,45 @@ class TierManager:
             d = st[key] - last.get(key, 0)
             if d > 0:
                 (counter.labels(**labels) if labels else counter).inc(d)
+                if key == "shed":  # shed with no spill file: state lost
+                    m.evicted_live.inc(d)
         self._last = {
             k: st[k]
             for k in ("demoted_evict", "demoted_idle", "promoted", "shed",
                       "promote_returned")
+        }
+
+    def lost(self) -> int:
+        """Live keys whose count is gone: rows the table displaced that no
+        shadow took (a mesh engine's evictions, GLOBAL installs) plus rows
+        the shadow shed at its RAM bound with no spill file. This is
+        `evicted_live_total` with the plane armed."""
+        lost = self.daemon.engine.stats.lost_live
+        return lost + (self.shadow.shed if self.enabled else 0)
+
+    def demoted(self) -> int:
+        """Rows that left the table for the shadow, by eviction or idle."""
+        if not self.enabled:
+            return 0
+        return self.shadow.demoted_evict + self.shadow.demoted_idle
+
+    def pipeline(self) -> "dict | None":
+        """The `tier` block of /v1/debug/pipeline; None when off."""
+        if not self.enabled:
+            return None
+        eng, sh = self.daemon.engine, self.shadow
+        t = eng.tier_counts() if hasattr(eng, "tier_counts") else {}
+        return {
+            "probed": t.get("probed", sh.probes),
+            "promoted": t.get("promoted", sh.promoted),
+            "demoted_evict": sh.demoted_evict,
+            "demoted_idle": sh.demoted_idle,
+            "returned": t.get("returned", sh.promote_returned),
+            "rehydrate_dispatches": t.get("rehydrate_dispatches", 0),
+            "merge_launches": t.get("merge_launches", 0),
+            "lost": self.lost(),
+            "shadow_rows": sh.ram_rows,
+            "shadow_bytes": sh.resident_bytes,
         }
 
     def debug(self) -> dict:
@@ -149,7 +184,8 @@ class TierManager:
         }
         if self.enabled:
             out["shadow"] = self.shadow.stats()
-            out["evicted_live_total"] = self.daemon.engine.stats.evicted_unexpired
+            out["evicted_live_total"] = self.lost()
+            out["demoted_live_total"] = self.demoted()
         return out
 
     def close(self, now_ms: int) -> None:

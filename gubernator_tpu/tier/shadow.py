@@ -1,7 +1,9 @@
 """Host-RAM shadow table: fp-keyed canonical 64 B rows + optional spill.
 
 The shadow is the demotion target for rows leaving HBM (evictee sidecar,
-idle sweep) and the fault-back source during host staging. Design points:
+idle sweep) and the fault-back source of the engine's miss path. The RAM
+set is columnar (`ShadowTable`: one row array, one sequence column, one
+open-addressing index; every call handles a batch). Design points:
 
 * **Canonical rows.** Entries are always the 16-field full-width slot row
   (ops/layout.py conversion contract): demotes unpack the table's own
@@ -9,8 +11,8 @@ idle sweep) and the fault-back source during host staging. Design points:
   packs back — so a row that lived in a packed table round-trips
   bit-exactly and cross-layout restarts stay sound.
 * **Byte bound.** `max_bytes` bounds the RAM set at the nominal
-  ROW_BYTES (64) per row — the state bytes themselves. Over-budget entries shed oldest-demoted-first
-  (LRU over demote/refresh time): to the spill file when one is
+  ROW_BYTES (64) per row — the state bytes themselves. Over-budget entries
+  shed oldest-demoted-first (by demote/refresh time): to the spill file when one is
   configured (lossless), else dropped and counted — exactly today's
   eviction loss, never worse.
 * **Conservative conflicts.** A demote for a fingerprint already
@@ -34,11 +36,11 @@ import struct
 import tempfile
 import threading
 import zlib
-from collections import OrderedDict
 from typing import Optional, Tuple
 
 import numpy as np
 
+from gubernator_tpu import native
 from gubernator_tpu.ops.table2 import (
     BURST,
     DUR_HI,
@@ -271,94 +273,237 @@ class _SpillFile:
             return 0
 
 
+class _FpIndex:
+    """fingerprint → row id: one open-addressing table in two flat arrays,
+    probed and filled a batch at a time (no Python object per key).
+
+    `keys` holds the fingerprint (0 = never used, −1 = removed: a
+    fingerprint is a positive 63-bit number), `vals` the row id. Linear
+    probing from a multiplicative hash; the table doubles, and drops its
+    removed marks, once used + removed slots pass 0.7 of it, so a chain
+    stays a handful of slots. A probe or a fill of n fingerprints is one
+    call into the native module with the GIL released (`fp_index_find`,
+    `fp_index_place`: the engine thread does this inside the miss path, and
+    on a loaded host each of the NumPy twin's few hundred small array calls
+    queues for the GIL again); where the module is not loaded the twin is a
+    few array passes over a shrinking remainder. 12 B a slot: 17–34 B a
+    row."""
+
+    _EMPTY, _GONE = 0, -1
+    _MULT = np.uint64(0x9E3779B97F4A7C15)
+
+    def __init__(self, bits: int = 12):
+        self._alloc(bits)
+
+    def _alloc(self, bits: int) -> None:
+        self.bits = bits
+        self.keys = np.zeros(1 << bits, dtype=np.int64)
+        self.vals = np.zeros(1 << bits, dtype=np.int32)
+        self.used = 0  # slots holding a fingerprint
+        self.gone = 0  # slots holding a removed mark
+
+    @property
+    def nbytes(self) -> int:
+        return self.keys.nbytes + self.vals.nbytes
+
+    def _home(self, fps: np.ndarray) -> np.ndarray:
+        with np.errstate(over="ignore"):
+            h = fps.view(np.uint64) * self._MULT
+        return (h >> np.uint64(64 - self.bits)).astype(np.int64)
+
+    def find(self, fps: np.ndarray) -> np.ndarray:
+        """Slot of each fingerprint, −1 where the table does not hold it."""
+        fps = np.ascontiguousarray(fps, dtype=np.int64)
+        out = np.full(fps.shape[0], -1, dtype=np.int64)
+        mod = native.load()
+        if mod is not None:
+            # one GIL-free call (native/guberhost.cpp); below, its twin
+            mod.fp_index_find(self.keys, self.bits, fps, out)
+            return out
+        return self._find_numpy(fps, out)
+
+    def _find_numpy(self, fps: np.ndarray, out: np.ndarray) -> np.ndarray:
+        mask = (1 << self.bits) - 1
+        rest, cur, want = np.arange(fps.shape[0]), self._home(fps), fps
+        while rest.size:
+            k = self.keys[cur]
+            hit = k == want
+            out[rest[hit]] = cur[hit]
+            go = ~hit & (k != self._EMPTY)
+            rest, cur, want = rest[go], (cur[go] + 1) & mask, want[go]
+        return out
+
+    def insert(self, fps: np.ndarray, ids: np.ndarray) -> None:
+        """Add fingerprints the table does not hold, each once."""
+        n = int(fps.shape[0])
+        if n == 0:
+            return
+        if (self.used + self.gone + n) * 10 > (7 << self.bits):
+            self._grow(n)
+        self._place(np.ascontiguousarray(fps, dtype=np.int64),
+                    np.asarray(ids, dtype=np.int32))
+
+    def _place(self, fps: np.ndarray, ids: np.ndarray) -> None:
+        mod = native.load()
+        if mod is not None:
+            ids = np.ascontiguousarray(ids, dtype=np.int32)
+            self.gone -= mod.fp_index_place(self.keys, self.vals, self.bits, fps, ids)
+            self.used += int(fps.shape[0])
+            return
+        self._place_numpy(fps, ids)
+
+    def _place_numpy(self, fps: np.ndarray, ids: np.ndarray) -> None:
+        mask = (1 << self.bits) - 1
+        cur = self._home(fps)
+        while fps.size:
+            k = self.keys[cur]
+            free = (k == self._EMPTY) | (k == self._GONE)
+            # two of the batch may want one free slot: all write, the slot
+            # keeps one of them, and who reads its own fingerprint back won
+            self.keys[cur[free]] = fps[free]
+            won = free & (self.keys[cur] == fps)
+            self.vals[cur[won]] = ids[won]
+            self.used += int(won.sum())
+            self.gone -= int((k[won] == self._GONE).sum())
+            go = ~won
+            fps, ids, cur = fps[go], ids[go], (cur[go] + 1) & mask
+
+    def remove(self, slots: np.ndarray) -> None:
+        self.keys[slots] = self._GONE
+        self.used -= int(slots.shape[0])
+        self.gone += int(slots.shape[0])
+
+    def _grow(self, more: int) -> None:
+        live = self.keys > 0
+        fps, ids = self.keys[live], self.vals[live]
+        bits = self.bits
+        while (fps.shape[0] + more) * 100 > (35 << bits):
+            bits += 1  # refilled to at most 0.35 of the new table
+        self._alloc(bits)
+        self._place(fps, ids)
+
+
 class ShadowTable:
     """The host-side tier: fp → canonical 64 B row, byte-bounded RAM set
-    with LRU shed-to-spill (or shed-and-count), batched durable spill,
-    and exact-match fault-back probes. Thread-safe (one lock): offers
-    arrive from fetch threads (evict capture) and the sweep task, probes
-    from prep threads, flushes from the tier manager."""
+    with oldest-first shed-to-spill (or shed-and-count), batched durable
+    spill, and exact-match fault-back probes.
+
+    The RAM set is columnar: the rows lie in one (capacity, 16) int32
+    array, grown in place, beside an 8 B demote sequence a row (what
+    "oldest" means) and `_FpIndex`, the fingerprint's way to its row.
+    `offer`, `take` and `contains` each handle a batch with array
+    operations, so that 23M shadowed rows cost 64 B + 8 B + the index
+    (17–34 B) each and no Python object. Thread-safe (one lock): the
+    engine thread offers and takes, the sweep flushes."""
 
     def __init__(self, max_bytes: int, spill_path: Optional[str] = None):
         if max_bytes <= 0:
             raise ValueError("shadow max_bytes must be positive")
         self.max_bytes = int(max_bytes)
-        self._rows: "OrderedDict[int, np.ndarray]" = OrderedDict()
-        self._unspilled: set = set()  # fps RAM-newer than the spill file
+        self._index = _FpIndex()
+        self._rows = np.zeros((1 << 10, F), dtype=np.int32)
+        self._seq = np.zeros(1 << 10, dtype=np.int64)
+        self._top = 0  # rows [0, _top) have been handed out at least once
+        self._free = np.empty(1 << 10, dtype=np.int32)  # stack of freed ids
+        self._n_free = 0
+        self._clock = 0  # demote sequence: larger = demoted later
+        # oldest-first candidates (row id, its sequence then) for the byte
+        # bound, refilled by one partition of the sequence column when it
+        # runs dry: a shed costs O(1) amortised, not a scan each
+        self._old_ids = np.empty(0, dtype=np.int64)
+        self._old_seq = np.empty(0, dtype=np.int64)
+        self._old_at = 0
+        self._unspilled = None  # (capacity,) bool: RAM-newer than the file
         self.spill = _SpillFile(spill_path) if spill_path else None
+        if self.spill is not None:
+            self._unspilled = np.zeros(1 << 10, dtype=bool)
         self._lock = threading.Lock()
-        # Bloom pre-filter over everything ever shadowed: the fault-back
-        # probe runs per BATCH on the serving path, and for hot-set
-        # traffic every fingerprint misses — the vectorized two-probe
-        # reject makes a full-batch miss cost microseconds instead of a
-        # per-fp dict walk. Removals never clear bits (promotes leave
-        # false positives, which the dict then rejects exactly), so the
-        # filter only ever errs toward the slow-but-correct path. Sized
-        # ~16 bits per row the byte budget can hold, clamped to
-        # [2^16, 2^30] bits.
-        bits = 16 * max(1, self.max_bytes // ROW_BYTES)
-        p = 1 << 16
-        while p < bits and p < (1 << 30):
-            p *= 2
-        self._bloom_mask = np.uint64(p - 1)
-        self._bloom = np.zeros(p >> 6, dtype=np.uint64)
         # counters (cumulative; the metrics layer diffs them)
         self.demoted_evict = 0
         self.demoted_idle = 0
         self.promoted = 0
-        # promote rows handed BACK (claim dropped after retries — > K
-        # same-bucket promotes in one batch): their decide that batch may
-        # have fresh-granted; the bound docs/tiering.md documents
+        # promote rows handed BACK (claim dropped: > K same-bucket
+        # promotes in one batch); their decide waits for the next round
         self.promote_returned = 0
-        self.shed = 0  # rows dropped with no spill — today's eviction loss
+        self.shed = 0  # rows dropped with no spill — state lost
         self.probes = 0
         self.probe_hits = 0
         self.expired_dropped = 0
         self.conflicts_merged = 0
 
-    # --------------------------------------------------------- bloom filter
-
-    def _bloom_hashes(self, fps: np.ndarray):
-        x = np.asarray(fps, dtype=np.int64).view(np.uint64)
-        with np.errstate(over="ignore"):
-            h1 = (x * np.uint64(0x9E3779B97F4A7C15)) >> np.uint64(17)
-            h2 = (x * np.uint64(0xC2B2AE3D27D4EB4F)) >> np.uint64(17)
-        return h1 & self._bloom_mask, h2 & self._bloom_mask
-
-    def _bloom_add(self, fps: np.ndarray) -> None:
-        for h in self._bloom_hashes(fps):
-            np.bitwise_or.at(
-                self._bloom, (h >> np.uint64(6)).astype(np.int64),
-                np.uint64(1) << (h & np.uint64(63)),
-            )
-
-    def _bloom_maybe(self, fps: np.ndarray) -> np.ndarray:
-        h1, h2 = self._bloom_hashes(fps)
-        one = np.uint64(1)
-        g = lambda h: (
-            self._bloom[(h >> np.uint64(6)).astype(np.int64)]
-            >> (h & np.uint64(63))
-        ) & one
-        return (g(h1) & g(h2)).astype(bool)
-
     # ------------------------------------------------------------- geometry
     @property
     def ram_rows(self) -> int:
-        return len(self._rows)
+        return self._index.used
 
     @property
     def nominal_bytes(self) -> int:
         """RAM set cost at ROW_BYTES per row — the bounded figure."""
-        return len(self._rows) * ROW_BYTES
+        return self._index.used * ROW_BYTES
+
+    @property
+    def resident_bytes(self) -> int:
+        """What the RAM set's arrays hold: rows handed out so far, their
+        sequence numbers, the free stack and the index."""
+        n = self._top
+        return (
+            n * ROW_BYTES + n * 8 + self._free.nbytes + self._index.nbytes
+            + (n if self._unspilled is not None else 0)
+        )
 
     @property
     def tracked_rows(self) -> int:
         """Rows reachable for fault-back: RAM ∪ spill-only."""
-        n = len(self._rows)
-        if self.spill is not None:
-            n += sum(
-                1 for fp in self.spill.index if fp not in self._rows
+        with self._lock:
+            n = self._index.used
+            if self.spill is not None and self.spill.index:
+                fps = np.fromiter(self.spill.index.keys(), dtype=np.int64,
+                                  count=len(self.spill.index))
+                n += int((self._index.find(fps) < 0).sum())
+            return n
+
+    # ------------------------------------------------------------ row store
+    def _row_fps(self, ids: np.ndarray) -> np.ndarray:
+        r = self._rows
+        return (r[ids, 1].astype(np.int64) << 32) | (
+            r[ids, 0].astype(np.int64) & 0xFFFFFFFF
+        )
+
+    def _new_ids(self, n: int) -> np.ndarray:
+        take = min(n, self._n_free)
+        ids = np.empty(n, dtype=np.int32)
+        if take:
+            ids[:take] = self._free[self._n_free - take:self._n_free]
+            self._n_free -= take
+        fresh = n - take
+        if fresh:
+            cap = self._rows.shape[0]
+            if self._top + fresh > cap:
+                while cap < self._top + fresh:
+                    cap += max(cap >> 2, 1 << 10)
+                # grown where they lie (realloc): no second copy of 1.5 GB
+                self._rows.resize((cap, F), refcheck=False)
+                self._seq.resize(cap, refcheck=False)
+                if self._unspilled is not None:
+                    self._unspilled.resize(cap, refcheck=False)
+            ids[take:] = np.arange(self._top, self._top + fresh, dtype=np.int32)
+            self._top += fresh
+        return ids
+
+    def _release(self, slots: np.ndarray, ids: np.ndarray) -> None:
+        """Rows leave the RAM set (lock held): index, fp lanes, free stack."""
+        self._index.remove(slots)
+        self._rows[ids, 0] = 0
+        self._rows[ids, 1] = 0
+        if self._unspilled is not None:
+            self._unspilled[ids] = False
+        n = int(ids.shape[0])
+        if self._n_free + n > self._free.shape[0]:
+            self._free.resize(
+                max(2 * self._free.shape[0], self._n_free + n), refcheck=False
             )
-        return n
+        self._free[self._n_free:self._n_free + n] = ids
+        self._n_free += n
 
     # --------------------------------------------------------------- demote
     def offer(self, fps: np.ndarray, rows: np.ndarray, now_ms: int,
@@ -370,27 +515,13 @@ class ShadowTable:
         n = int(fps.shape[0])
         if n == 0:
             return 0
+        fps = np.ascontiguousarray(fps, dtype=np.int64)
         rows = np.ascontiguousarray(rows, dtype=np.int32)
-        exp = _join(rows, EXP_LO, EXP_HI)
-        live = exp >= now_ms
-        accepted = 0
+        live = _join(rows, EXP_LO, EXP_HI) >= now_ms
+        keep = live & (fps != 0)
         with self._lock:
             self.expired_dropped += int((~live).sum())
-            for i in np.nonzero(live)[0]:
-                fp = int(fps[i])
-                if fp == 0:
-                    continue
-                row = rows[i]
-                cur = self._rows.get(fp)
-                if cur is not None:
-                    row = merge_canonical_rows(row[None], cur[None])[0]
-                    self.conflicts_merged += 1
-                self._rows[fp] = row
-                self._rows.move_to_end(fp)
-                self._unspilled.add(fp)
-                accepted += 1
-            if accepted:
-                self._bloom_add(fps[live])
+            accepted = self._offer_locked(fps[keep], rows[keep])
             if reason == "idle":
                 self.demoted_idle += accepted
             elif reason == "return":
@@ -400,25 +531,85 @@ class ShadowTable:
             self._enforce_bound(now_ms)
         return accepted
 
+    def _offer_locked(self, fps: np.ndarray, rows: np.ndarray) -> int:
+        n = int(fps.shape[0])
+        if n == 0:
+            return 0
+        _, first = np.unique(fps, return_index=True)
+        if first.shape[0] != n:
+            # one fingerprint twice in a batch (no caller does): the first
+            # copies now, the rest after them, so that they merge in order
+            rest = np.ones(n, dtype=bool)
+            rest[first] = False
+            first.sort()
+            return self._offer_locked(fps[first], rows[first]) + (
+                self._offer_locked(fps[rest], rows[rest])
+            )
+        slots = self._index.find(fps)
+        had = slots >= 0
+        ids = np.empty(n, dtype=np.int32)
+        if had.any():
+            ids[had] = cur = self._index.vals[slots[had]]
+            rows = rows.copy()
+            rows[had] = merge_canonical_rows(rows[had], self._rows[cur])
+            self.conflicts_merged += int(had.sum())
+        new = ~had
+        if new.any():
+            ids[new] = fresh = self._new_ids(int(new.sum()))
+            self._index.insert(fps[new], fresh)
+        self._rows[ids] = rows
+        self._seq[ids] = np.arange(self._clock, self._clock + n)
+        self._clock += n
+        if self._unspilled is not None:
+            self._unspilled[ids] = True
+        return n
+
+    def _oldest(self, want: int) -> np.ndarray:
+        """Row ids of the `want` longest-shadowed rows (lock held), oldest
+        first. Candidates come from one partition of the sequence column,
+        a sixteenth of the rows at a time; one that was taken or offered
+        again since (its sequence moved, or its row was freed) is skipped."""
+        out = np.empty(want, dtype=np.int64)
+        got = 0
+        while got < want:
+            if self._old_at >= self._old_ids.shape[0]:
+                top = self._top
+                held = np.flatnonzero(self._rows[:top, 0] | self._rows[:top, 1])
+                k = min(held.shape[0], max(want - got, held.shape[0] >> 4, 1024))
+                seq = self._seq[held]
+                if k < held.shape[0]:
+                    part = np.argpartition(seq, k - 1)[:k]
+                    held, seq = held[part], seq[part]
+                order = np.argsort(seq, kind="stable")
+                self._old_ids, self._old_seq = held[order], seq[order]
+                self._old_at = 0
+            i, at = self._old_ids, self._old_at
+            end = min(i.shape[0], at + want - got)
+            ids = i[at:end]
+            ok = (self._seq[ids] == self._old_seq[at:end]) & (
+                (self._rows[ids, 0] | self._rows[ids, 1]) != 0
+            )
+            ids = ids[ok]
+            out[got:got + ids.shape[0]] = ids
+            got += ids.shape[0]
+            self._old_at = end
+        return out[:got]
+
     def _enforce_bound(self, now_ms: int) -> None:
-        """Pop oldest RAM entries past the byte budget (lock held). With a
-        spill the popped rows are appended there first (lossless); without
+        """Drop the oldest RAM rows past the byte budget (lock held). With a
+        spill the dropped rows are appended there first (lossless); without
         one they are shed — counted state loss, identical to the
         pre-tiering eviction behavior."""
-        over = len(self._rows) - self.max_bytes // ROW_BYTES
+        over = self._index.used - self.max_bytes // ROW_BYTES
         if over <= 0:
             return
-        popped_fps = np.empty(over, dtype=np.int64)
-        popped_rows = np.empty((over, F), dtype=np.int32)
-        for j in range(over):
-            fp, row = self._rows.popitem(last=False)
-            popped_fps[j] = fp
-            popped_rows[j] = row
-            self._unspilled.discard(fp)
+        ids = self._oldest(over)
+        fps = self._row_fps(ids)
         if self.spill is not None:
-            self.spill.append(popped_fps, popped_rows, now_ms)
+            self.spill.append(fps, self._rows[ids], now_ms)
         else:
             self.shed += over
+        self._release(self._index.find(fps), ids.astype(np.int32))
 
     def flush(self, now_ms: int) -> int:
         """Write RAM entries newer than the spill file out to it (sweep
@@ -426,16 +617,13 @@ class ShadowTable:
         if self.spill is None:
             return 0
         with self._lock:
-            fps = [fp for fp in self._unspilled if fp in self._rows]
-            if not fps:
-                self._unspilled.clear()
+            ids = np.flatnonzero(self._unspilled[:self._top])
+            if ids.shape[0] == 0:
                 return 0
-            arr_fps = np.asarray(fps, dtype=np.int64)
-            arr_rows = np.stack([self._rows[fp] for fp in fps])
-            self.spill.append(arr_fps, arr_rows, now_ms)
-            self._unspilled.clear()
+            self.spill.append(self._row_fps(ids), self._rows[ids], now_ms)
+            self._unspilled[ids] = False
             self.spill.maybe_compact(now_ms)
-            return len(fps)
+            return int(ids.shape[0])
 
     def load(self) -> int:
         """Boot: index an existing spill file (rows stay on disk; they
@@ -443,86 +631,79 @@ class ShadowTable:
         if self.spill is None:
             return 0
         with self._lock:
-            n = self.spill.load()
-            if n:
-                self._bloom_add(
-                    np.fromiter(self.spill.index.keys(), dtype=np.int64,
-                                count=len(self.spill.index))
-                )
-            return n
+            return self.spill.load()
 
     # ------------------------------------------------------------ fault-back
     def take(self, fps: np.ndarray, now_ms: int):
         """Exact-match probe-and-REMOVE for a batch of fingerprints:
-        (found_fps (m,) i64, rows (m, 16) i32). Misses cost one dict
-        lookup each (two with a spill) — the off-hot-path contract.
-        Expired entries are dropped, not promoted."""
+        (found_fps (m,) i64, rows (m, 16) i32), each found fingerprint
+        once. A miss costs its probe of the index (and one dictionary
+        lookup where a spill file is configured). Expired entries are
+        dropped, not promoted."""
         n = int(fps.shape[0])
+        none = np.empty(0, dtype=np.int64), np.empty((0, F), np.int32)
         if n == 0:
-            return np.empty(0, dtype=np.int64), np.empty((0, F), np.int32)
-        # vectorized Bloom reject: a batch with no shadowed key pays a
-        # few numpy ops, never a per-fp dict walk (the hot-set contract)
-        maybe = self._bloom_maybe(fps)
-        if not maybe.any():
-            with self._lock:
-                self.probes += n
-            return np.empty(0, dtype=np.int64), np.empty((0, F), np.int32)
-        out_fps = []
-        out_rows = []
-        fp_list = np.asarray(fps, dtype=np.int64)[maybe].tolist()
+            return none
+        fps = np.unique(np.ascontiguousarray(fps, dtype=np.int64))
+        fps = fps[fps != 0]
         with self._lock:
             self.probes += n
-            seen = set()
-            for fp in fp_list:
-                if fp == 0 or fp in seen:
-                    continue
-                seen.add(fp)
-                row = self._rows.pop(fp, None)
-                if row is None and self.spill is not None:
+            slots = self._index.find(fps)
+            had = slots >= 0
+            out_fps, hit = fps[had], slots[had]
+            ids = self._index.vals[hit]
+            out_rows = self._rows[ids]  # a copy: fancy indexing
+            if hit.shape[0]:
+                self._release(hit, ids)
+            if self.spill is not None and self.spill.index:
+                if out_fps.shape[0]:
+                    for fp in out_fps.tolist():
+                        self.spill.discard(fp)
+                more_f, more_r = [], []
+                for fp in fps[~had].tolist():
                     row = self.spill.read(fp)
-                if row is None:
-                    continue
-                self._unspilled.discard(fp)
-                if self.spill is not None:
-                    self.spill.discard(fp)
-                exp = (int(row[EXP_HI]) << 32) | (int(row[EXP_LO]) & 0xFFFFFFFF)
-                if exp < now_ms:
-                    self.expired_dropped += 1
-                    continue
-                out_fps.append(fp)
-                out_rows.append(row)
-            self.probe_hits += len(out_fps)
+                    if row is not None:
+                        self.spill.discard(fp)
+                        more_f.append(fp)
+                        more_r.append(row)
+                if more_f:
+                    out_fps = np.concatenate(
+                        [out_fps, np.asarray(more_f, dtype=np.int64)]
+                    )
+                    out_rows = np.concatenate(
+                        [out_rows, np.stack(more_r).astype(np.int32)]
+                    )
+            if out_fps.shape[0]:
+                live = _join(out_rows, EXP_LO, EXP_HI) >= now_ms
+                if not live.all():
+                    self.expired_dropped += int((~live).sum())
+                    out_fps, out_rows = out_fps[live], out_rows[live]
+            self.probe_hits += int(out_fps.shape[0])
             # taken rows ARE promoted by contract: the caller installs
-            # them through the conservative merge before its dispatch
-            self.promoted += len(out_fps)
-        if not out_fps:
-            return np.empty(0, dtype=np.int64), np.empty((0, F), np.int32)
-        return (
-            np.asarray(out_fps, dtype=np.int64),
-            np.stack(out_rows).astype(np.int32),
-        )
+            # them before the decide that needs them
+            self.promoted += int(out_fps.shape[0])
+        if out_fps.shape[0] == 0:
+            return none
+        return out_fps, out_rows
 
     def contains(self, fps: np.ndarray) -> np.ndarray:
-        """Non-destructive membership mask (RAM ∪ spill index) — the
-        miss re-check's cheap gate (ops/engine._shadow_rehydrate)."""
-        n = int(fps.shape[0])
-        out = np.zeros(n, dtype=bool)
-        fp_list = np.asarray(fps, dtype=np.int64).tolist()
+        """Non-destructive membership mask (RAM ∪ spill index)."""
+        fps = np.ascontiguousarray(fps, dtype=np.int64)
         with self._lock:
-            rows = self._rows
-            idx = self.spill.index if self.spill is not None else None
-            for i, fp in enumerate(fp_list):
-                if fp == 0:
-                    continue
-                out[i] = fp in rows or (idx is not None and fp in idx)
+            out = (self._index.find(fps) >= 0) & (fps != 0)
+            if self.spill is not None and self.spill.index:
+                idx = self.spill.index
+                for i in np.flatnonzero(~out & (fps != 0)).tolist():
+                    out[i] = int(fps[i]) in idx
         return out
 
     # ---------------------------------------------------------------- status
     def stats(self) -> dict:
         with self._lock:
             out = {
-                "ram_rows": len(self._rows),
-                "nominal_bytes": len(self._rows) * ROW_BYTES,
+                "ram_rows": self._index.used,
+                "nominal_bytes": self._index.used * ROW_BYTES,
+                "resident_bytes": self.resident_bytes,
                 "max_bytes": self.max_bytes,
                 "demoted_evict": self.demoted_evict,
                 "demoted_idle": self.demoted_idle,

@@ -355,6 +355,84 @@ def test_the_chips_compiler_takes_the_program_beside_an_8_gib_table(
         assert mem.alias_size_in_bytes >= table_bytes, "the table is not updated in place"
 
 
+# ------------------------------ the tiered deployment's programs (PR 42)
+
+
+def tiered_programs(sharding=None, pads=(16, 8192)):
+    """name -> a thunk that lowers one program of a TIERED table
+    (`token40m-tiered`: a shadow attached, docs/tiering.md) at that
+    deployment's 1 GiB table: the pipelined hits-only decide, the miss
+    path's claiming decide with its evictee sidecar, the promote's merge
+    with its own, and the idle sweep's extract."""
+    from gubernator_tpu.ops.table2 import _extract_idle_first
+
+    spec = lambda *shape, dtype=jnp.int32: jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+    rows = spec(GIB1_BUCKETS, ROW)
+    table = Table2(rows=rows, layout=FULL)
+    out = {}
+    for pad in pads:
+        for name, ev in (("hits-only decide", "defer"), ("claiming decide", True)):
+            out[f"tiered {name} pad={pad}"] = functools.partial(
+                wire_mod.decide2_wire_cols.lower, table, spec(5, pad + 1),
+                write="sparse", math="token", cascade=False, evictees=ev)
+        out[f"tiered merge2 pad={pad}"] = functools.partial(
+            kernel2.merge2.lower, table, spec(pad, dtype=jnp.int64), spec(pad, 16),
+            spec(pad, dtype=jnp.int64), spec(pad, dtype=jnp.bool_),
+            write="sparse", evictees=True)
+    out["tiered idle extract"] = functools.partial(
+        _extract_idle_first.lower, rows, spec(dtype=jnp.int64), spec(dtype=jnp.int64),
+        layout=FULL, max_rows=1 << 16)
+    return out
+
+
+@pytest.mark.parametrize("name", sorted(tiered_programs()))
+def test_a_tiered_program_lowers_at_the_deployments_shape(name):
+    lowered = tiered_programs()[name]()
+    shapes = [tuple(s.shape) for s in jax.tree_util.tree_leaves(lowered.out_info)]
+    pad = int(name.rsplit("=", 1)[1]) if "=" in name else None
+    if "extract" in name:
+        assert (GIB1_BUCKETS, ROW) not in shapes and (1 << 16, 16) in shapes
+        return
+    assert shapes[0] == (GIB1_BUCKETS, ROW)  # the table, donated through
+    if "hits-only" in name:
+        assert shapes[1] == (pad + 2, 4)  # no sidecar: it evicts nothing
+    elif "claiming" in name:
+        assert shapes[1] == (5 * pad + 2, 4)  # answers, 64 B a row of sidecar, stats
+    else:
+        assert shapes[1:] == [(pad,), (pad, 16)]  # landed mask, the merge's victims
+
+
+@pytest.mark.parametrize("name", [
+    "tiered hits-only decide pad=8192", "tiered claiming decide pad=8192",
+    "tiered merge2 pad=8192", "tiered idle extract",
+])
+def test_the_chips_compiler_takes_a_tiered_program(name, one_v5e, monkeypatch):
+    """Compiled for the chip beside the deployment's 1 GiB table: the table
+    is updated in place by the three programs that write it, and the idle
+    extract, which once sorted the whole table into another tiling (9.7 GiB
+    of scratch), stays under 1 GiB."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    jax.clear_caches()
+    try:
+        compiled = tiered_programs(one_v5e, pads=[8192])[name]().compile()
+    finally:
+        jax.clear_caches()
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    mem = compiled.memory_analysis()
+    table_bytes = GIB1_BUCKETS * ROW * 4
+    assert mem.argument_size_in_bytes >= table_bytes
+    assert mem.temp_size_in_bytes < 1 << 30, "scratch beside the table"
+    if "extract" not in name:
+        assert mem.alias_size_in_bytes >= table_bytes, "the table is not updated in place"
+        assert mem.temp_size_in_bytes < 256 << 20
+
+
 # ----------------------------------------- what the size added to be seen
 
 

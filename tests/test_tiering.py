@@ -543,9 +543,11 @@ def test_tier_config_validation():
 
 
 def test_debug_tier_and_metrics(tmp_path):
-    """Daemon wiring: /v1/debug/tier schema, the evicted_live_total field
-    on /v1/debug/table, and the gubernator_tpu_evicted_live_total +
-    gubernator_tier_* families on /metrics."""
+    """Daemon wiring: /v1/debug/tier schema, the evicted_live_total and
+    demoted_live_total fields on /v1/debug/table (a demotion is no state
+    lost: ported in PR 42, when `evicted_live_total` became the state-loss
+    count its docstring says it is), and the gubernator_tpu_demoted_live_total
+    + gubernator_tier_* families on /metrics."""
     from gubernator_tpu.proto import gubernator_pb2 as pb
     from gubernator_tpu.service.metrics import parse_metrics
     from tests.cluster import Cluster
@@ -571,11 +573,14 @@ def test_debug_tier_and_metrics(tmp_path):
             dbg = d.debug_tier()
             assert dbg["enabled"] and dbg["shadow"]["demoted_evict"] > 0
             tbl = await d.debug_table()
-            assert tbl["evicted_live_total"] > 0
+            assert tbl["evicted_live_total"] == 0
+            assert tbl["demoted_live_total"] > 0
             assert "tiering" in tbl
             d.tier.observe()
+            d.metrics.observe_engine(d.engine.stats)
             fams = parse_metrics(d.metrics.render().decode())
-            assert fams["gubernator_tpu_evicted_live_total"][()] > 0
+            assert fams["gubernator_tpu_evicted_live_total"][()] == 0
+            assert fams["gubernator_tpu_demoted_live_total"][()] > 0
             demo = fams["gubernator_tier_demoted_rows_total"]
             assert demo[(("reason", "evict"),)] > 0
             assert "gubernator_tier_shadow_rows" in fams
@@ -583,3 +588,42 @@ def test_debug_tier_and_metrics(tmp_path):
             await c.stop()
 
     asyncio.run(run())
+
+
+# ------------------------------------------------ the shadow's index
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+def test_fp_index_native_call_is_its_numpy_twin(seed, monkeypatch):
+    """`_FpIndex` probes and fills through one GIL-free native call
+    (`fp_index_find`, `fp_index_place`) where the module is loaded, and
+    through array passes where it is not: the same table, the same answers,
+    through fills, removals, refills over removed marks and a growth."""
+    from gubernator_tpu import native
+    from gubernator_tpu.tier import shadow as shadow_mod
+
+    if native.load() is None:
+        pytest.skip("native toolchain unavailable")
+    rng = np.random.default_rng(seed)
+    fps = np.unique(rng.integers(1, 1 << 62, size=60_000, dtype=np.int64))
+    ids = np.arange(fps.shape[0], dtype=np.int32)
+    tables = []
+    for loaded in (True, False):
+        if not loaded:
+            monkeypatch.setattr(shadow_mod.native, "load", lambda: None)
+        ix = shadow_mod._FpIndex(bits=4)
+        ix.insert(fps[:20_000], ids[:20_000])
+        slots = ix.find(fps[:30_000])
+        assert (slots[:20_000] >= 0).all() and (slots[20_000:] < 0).all()
+        assert np.array_equal(ix.vals[slots[:20_000]], ids[:20_000])
+        ix.remove(slots[:5_000])
+        assert (ix.find(fps[:5_000]) < 0).all()
+        ix.insert(fps[20_000:], ids[20_000:])  # reuses marks, then grows
+        assert np.array_equal(ix.vals[ix.find(fps[5_000:])], ids[5_000:])
+        tables.append((ix.bits, ix.used, ix.gone, ix.keys.copy(), ix.vals.copy()))
+    (b0, u0, g0, k0, v0), (b1, u1, g1, k1, v1) = tables
+    assert (b0, u0, g0) == (b1, u1, g1) == (b0, 55_000 + fps.shape[0] - 60_000, g0)
+    # the twin places a batch in another order, so slots may differ: what a
+    # key maps to may not
+    held = k0 > 0
+    assert np.array_equal(np.sort(k0[held]), np.sort(k1[k1 > 0]))

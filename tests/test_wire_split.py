@@ -271,10 +271,12 @@ async def test_a_clamped_stamp_is_counted_once_on_a_split_chunk():
 @async_test
 async def test_a_split_chunk_through_the_feedback_paths(shadow, monkeypatch):
     """One bucket of eight slots and 24 keys: pass 0's claims drop and are
-    retried on the engine thread; with a shadow attached the promote hands
-    rows back and the miss re-check re-dispatches them. Both select rows of
-    pass 0 by its mask, which leaves out the later copies of a key: a copy
-    taken for a miss would be applied twice."""
+    retried on the engine thread; with a shadow attached (ported in PR 42:
+    the pipelined launch now decides only the keys the table holds) every
+    pass hands the rows of the keys it does not hold to the same retry,
+    which faults them in (`LocalEngine._decide_faulting`). Both select rows
+    of a pass by its mask, which leaves out the later copies of a key: a
+    copy taken for a miss would be applied twice."""
     calls = []
     redispatch = LocalEngine._redispatch_rows
     monkeypatch.setattr(
@@ -299,12 +301,40 @@ async def test_a_split_chunk_through_the_feedback_paths(shadow, monkeypatch):
             r_wire, r_cols, parts, now + 5
         )
         assert n_fused == 4 and not got.err.any()
-        want_calls = 2 if shadow else 1  # the retry, and the miss re-check
+        # the retry; with a shadow, once for each of the four passes (key 3
+        # and key 20 come four and three times, and one bucket cannot hold
+        # the 24 keys of pass 0, so every pass meets a key that has left)
+        want_calls = 4 if shadow else 1
         assert calls.count(r_wire.engine) == want_calls
         assert calls.count(r_cols.engine) == want_calls
     finally:
         r_wire.close()
         r_cols.close()
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_a_retrys_rows_are_packed_as_the_whole_chunk_would_be(seed):
+    """The batch a retry (or a tiered table's miss path) re-dispatches is
+    packed from the selected rows' columns alone (`_LazyWireBatch.select`,
+    on the fetch thread): for every pass of a split chunk it is, row for
+    row, what cutting the whole chunk's HostBatch gives — the later copies
+    the grid left out, clamped stamps and the aggregate included."""
+    rng = np.random.default_rng(seed)
+    now = engine_mod.ms_now()
+    eng = LocalEngine(capacity=4096, wire="compact")
+    eng.created_at_tolerance_ms = 150
+    rows = lambda n: [
+        (int(k), int(rng.integers(-200, 200)), 0, int(rng.integers(0, 4)))
+        for k in rng.integers(0, 40, size=n)
+    ]
+    pending = prepare_check_wire(eng, [rpc(rows(100), now), rpc(rows(80), now)], now_ms=now)
+    assert pending is not None and len(pending.passes) == 8
+    for _p, n, batch, _staged in pending.passes:
+        pick = np.sort(rng.choice(n, size=min(n, 9), replace=False))
+        cut = engine_mod.HostBatch(*[f[pick] for f in batch._materialize()])
+        batch._hb = None  # `select` would cut the same HostBatch otherwise
+        for name, a, b in zip(cut._fields, batch.select(pick), cut):
+            assert np.array_equal(np.asarray(a), np.asarray(b)), name
 
 
 def test_what_needs_a_single_pass_still_refuses_a_repeated_key():
