@@ -829,7 +829,7 @@ struct LaterPass {
 struct ChunkStage {
   // in
   int64_t n = 0, now = 0, tol = 0, pad = 0, max_exact = 0, pad_floor = 0;
-  bool one_grid = false;
+  bool one_grid = false, keep_copies = false;
   // out
   std::vector<int32_t> grid;
   std::vector<int8_t> err;
@@ -899,22 +899,25 @@ static bool stage_chunk(const ChunkPart* parts, size_t k, ChunkStage& s) {
   // ops/plan.occurrence_rank without its sort: a row's rank is the number of
   // rows before it that carry its fingerprint (what its place in a stable
   // sort's run of that fingerprint is), counted in an open-addressed table;
-  // an error row is counted and is no copy
-  size_t cap = 16;
-  while (cap < 2 * (size_t)n) cap *= 2;
-  std::vector<int64_t> seen_fp(cap);
-  std::vector<int32_t> seen(cap, 0);
+  // an error row is counted and is no copy. Where the program folds a key's
+  // copies itself (`keep_copies`) every copy is a row of the grid: rank 0.
   std::vector<int64_t> rank(n, 0);
   int64_t top = 0;
-  for (int64_t i = 0; i < n; i++) {
-    size_t at = (size_t)(((uint64_t)fp[i] * 0x9E3779B97F4A7C15ULL) >> 20) & (cap - 1);
-    while (seen[at] && seen_fp[at] != fp[i]) at = (at + 1) & (cap - 1);
-    seen_fp[at] = fp[i];
-    const int64_t run = seen[at]++;
-    if (run && active[i]) {
-      rank[i] = run;
-      s.later++;
-      top = std::max(top, run);
+  if (!s.keep_copies) {
+    size_t cap = 16;
+    while (cap < 2 * (size_t)n) cap *= 2;
+    std::vector<int64_t> seen_fp(cap);
+    std::vector<int32_t> seen(cap, 0);
+    for (int64_t i = 0; i < n; i++) {
+      size_t at = (size_t)(((uint64_t)fp[i] * 0x9E3779B97F4A7C15ULL) >> 20) & (cap - 1);
+      while (seen[at] && seen_fp[at] != fp[i]) at = (at + 1) & (cap - 1);
+      seen_fp[at] = fp[i];
+      const int64_t run = seen[at]++;
+      if (run && active[i]) {
+        rank[i] = run;
+        s.later++;
+        top = std::max(top, run);
+      }
     }
   }
   if (s.later && (s.one_grid || s.max_exact < 2)) return false;
@@ -1042,7 +1045,7 @@ static PyObject* bytes_of(const std::vector<T>& v) {
 
 // stage_wire_chunk(parts: sequence[(lanes, fp, err, created_at)], now: int,
 //                  tolerance: int, pad: int, one_grid: bool, max_exact: int,
-//                  pad_floor: int)
+//                  pad_floor: int, keep_copies: bool = False)
 //   -> None | (grid, err, act_fp, first, clamped, math, cascade, later,
 //              passes)
 // The host staging of one fused chunk (ops/engine._stage_chunk_numpy and
@@ -1066,11 +1069,12 @@ static PyObject* stage_wire_chunk(PyObject*, PyObject* args) {
   PyObject* parts_o;
   ChunkStage s;
   long long now, tol, pad, max_exact, pad_floor;
-  int one_grid;
-  if (!PyArg_ParseTuple(args, "OLLLpLL", &parts_o, &now, &tol, &pad,
-                        &one_grid, &max_exact, &pad_floor))
+  int one_grid, keep_copies = 0;
+  if (!PyArg_ParseTuple(args, "OLLLpLL|p", &parts_o, &now, &tol, &pad,
+                        &one_grid, &max_exact, &pad_floor, &keep_copies))
     return nullptr;
   s.now = now; s.tol = tol; s.pad = pad; s.one_grid = one_grid != 0;
+  s.keep_copies = keep_copies != 0;
   s.max_exact = max_exact; s.pad_floor = pad_floor;
   PyObject* seq = PySequence_Fast(parts_o, "parts: a sequence expected");
   if (!seq) return nullptr;

@@ -707,6 +707,16 @@ def _assemble_wire_parts(engine, parts, now_ms=None, pad_to=None):
     grid, so with `pad_to` a repeated key still means None; so does one next
     to cascade level bits (the in-trace fold needs a single pass).
 
+    An engine whose program folds the copies itself (`folds_copies`: the
+    mesh's in-trace dedup, the property that makes its `plan` one pass)
+    gets the same staging with every copy left in the grid and nothing
+    behind it. Such an engine says how wide its grid is (`wire_pad`: D
+    device blocks of c rows), and declines on the parts themselves what the
+    lanes cannot tell it: a behavior bit the wire drops as inert that it
+    acts on (`wire_columns_behavior`: GLOBAL rows fork in `prepare_columns`),
+    level bits where it folds cascades on the host, and a ring slot, which
+    is one device's block.
+
     The staging is one call into the native module, GIL-free from the first
     row to the last (ops/wire.stage_wire_chunk): on a loaded host every
     array call queues for the GIL again, and the NumPy staging is 45 to 155
@@ -721,6 +731,12 @@ def _assemble_wire_parts(engine, parts, now_ms=None, pad_to=None):
         return None
     if not all(p.all_encodable for p in parts):
         return None
+    acts_on = getattr(engine, "wire_columns_behavior", 0)
+    if acts_on and any(_behavior_or(p) & acts_on for p in parts):
+        return None
+    wire_pad = getattr(engine, "wire_pad", None)
+    if wire_pad is not None and pad_to is not None:
+        return None
     cols_list = [p.cols for p in parts]
     n = sum(c.fp.shape[0] for c in cols_list)
     if n == 0 or (pad_to is not None and n > pad_to):
@@ -733,25 +749,42 @@ def _assemble_wire_parts(engine, parts, now_ms=None, pad_to=None):
     tol = engine.created_at_tolerance_ms
     if tol is None:
         tol = created_at_tolerance_ms()
-    pad = pad_to if pad_to is not None else _pad_size(n)
+    pad = pad_to if pad_to is not None else (wire_pad or _pad_size)(n)
     args = (parts, now, tol, pad, pad_to is not None, engine.max_exact_passes)
+    keep_copies = bool(getattr(engine, "folds_copies", False))
     mod = native.load()
-    if mod is None:
-        chunk = _stage_chunk_numpy(*args)
-    else:
-        chunk = wire_mod.stage_wire_chunk(mod, *args, pad_floor=_pad_size(0))
+    with tracing.stage.within("wire_pack"):
+        if mod is None:
+            chunk = _stage_chunk_numpy(*args, keep_copies)
+        else:
+            chunk = wire_mod.stage_wire_chunk(
+                mod, *args, _pad_size(0), keep_copies
+            )
     if chunk is None:
+        return None
+    if chunk.casc and not getattr(engine, "supports_cascade_intrace", False):
         return None
     return _WireAssembly(chunk, cols_list, now, n, tol, pad, mod is not None)
 
 
-def _stage_chunk_numpy(parts, now, tol, pad, one_grid, max_exact):
+def _behavior_or(part) -> int:
+    """OR of a parsed piece's behavior words: the parser's own reduction
+    where the piece still carries it, else a scan of the column."""
+    if part.summary is not None:
+        return part.summary.behavior_or
+    return int(np.bitwise_or.reduce(part.cols.behavior, initial=0))
+
+
+def _stage_chunk_numpy(
+    parts, now, tol, pad, one_grid, max_exact, keep_copies=False
+):
     """ops/wire.stage_wire_chunk in NumPy, of the same arguments: what a
     host with no toolchain runs, and what the tests hold the native staging
     to byte for byte. None: all-error chunk (the columns path produces it),
     a first copy's stamp outside the delta budget, a repeated key where one
     grid is all there is (`one_grid`, or no exact pass for the grid to be),
-    or beside cascade level bits."""
+    or beside cascade level bits. With `keep_copies` no key is looked for
+    twice: every active row keeps its lane."""
     from gubernator_tpu.ops import wire as wire_mod
 
     cols_list = [p.cols for p in parts]
@@ -770,7 +803,7 @@ def _stage_chunk_numpy(parts, now, tol, pad, one_grid, max_exact):
     # unique-fingerprint kernel contract: the grid takes the first of a
     # key's copies, the rest follow it as the planner's later passes
     first, later = active, None
-    order, rank = occurrence_rank(fp)
+    order, rank = (None, None) if keep_copies else occurrence_rank(fp)
     if rank is not None:
         rank[~active] = 0  # error rows (fp 0) are no copies of one another
         later = np.nonzero(rank)[0]
@@ -912,7 +945,9 @@ def prepare_check_wire(engine, parts, now_ms=None) -> "PendingCheck | None":
     more of every block behind it, and the objects around them. A key sent
     more than once in the chunk keeps the grid for its first copy; the later
     ones are gathers of the same lanes in the passes behind it
-    (`_later_passes`). Returns a PendingCheck for the standard issue/finish
+    (`_later_passes`); a mesh engine that folds copies in-trace keeps them
+    all in the grid, which its `stage_wire` lays out a block a device.
+    Returns a PendingCheck for the standard issue/finish
     halves, or None when the batch needs the general columns path — the
     fallback is semantically identical, it just pays the full pack."""
     a = _assemble_wire_parts(engine, parts, now_ms=now_ms)
@@ -1575,9 +1610,11 @@ class LocalEngine:
     def supports_wire_ingress(self) -> bool:
         """Whether the fused front-door path (prepare_check_wire: native
         parser lanes staged straight into a compact grid) may target this
-        engine. Compact-wire single-device engines only — full-width mode
-        stays the byte-for-byte parity oracle, and mesh engines stage routed
-        per-shard grids the front door cannot pre-assemble."""
+        engine. Compact-wire engines only — full-width mode stays the
+        byte-for-byte parity oracle. A mesh engine answers for itself
+        (parallel/sharded.ShardedEngine.supports_wire_ingress: the
+        arrival-order grid of the device route is the lanes in D blocks; a
+        host-routed per-shard grid is not)."""
         return self.wire == "compact" and self._decide_fn is None
 
     def stage_wire(self, grid: np.ndarray, math: str, cascade: bool = False):

@@ -315,6 +315,11 @@ def stamp_base(block: np.ndarray, base: int) -> None:
     block[1, -1] = np.int64(base >> 32).astype(np.int32)
 
 
+def block_base(block: np.ndarray) -> int:
+    """The base a wire block's trailing column carries (`stamp_base`)."""
+    return (int(block[0, -1]) & 0xFFFFFFFF) | (int(block[1, -1]) << 32)
+
+
 # ------------------------------------------------------------ trace decode
 
 
@@ -579,17 +584,19 @@ class StagedChunk(NamedTuple):
 
 def stage_wire_chunk(
     mod, parts, now: int, tol: int, pad: int, one_grid: bool, max_exact: int,
-    pad_floor: int,
+    pad_floor: int, keep_copies: bool = False,
 ) -> "StagedChunk | None":
     """`StagedChunk` of the WireBatch pieces `parts` from ONE call into the
     native module `mod` (native/guberhost.cpp stage_wire_chunk), which runs
     without the GIL from the first row to the last; what comes back is
     wrapped, not computed. None: the chunk cannot fuse. The NumPy staging
     of ops/engine.py is the same function of the same arguments, and what
-    the tests hold this one to byte for byte."""
+    the tests hold this one to byte for byte. `keep_copies`: the program
+    folds the copies of a key itself (the mesh's in-trace dedup), so each
+    keeps its lane in the grid and no pass follows it."""
     out = mod.stage_wire_chunk(
         [(p.lanes, p.cols.fp, p.cols.err, p.cols.created_at) for p in parts],
-        now, tol, pad, one_grid, max_exact, pad_floor,
+        now, tol, pad, one_grid, max_exact, pad_floor, keep_copies,
     )
     if out is None:
         return None

@@ -39,6 +39,13 @@ the kernel's unique-fingerprint contract is discharged:
   (ops/plan.single_pass). Same semantics as plan_passes(max_exact=1), i.e.
   the reference's GLOBAL hot-key aggregation applied from occurrence 0.
 
+With both on the device and the compact wire (the TPU's resolution of every
+`auto`), the engine is wire-capable (`supports_wire_ingress`): a chunk the
+native parser already holds as compact lanes is staged from them by one
+GIL-free native call with every copy of a key kept (`stage_wire`,
+ops/engine.prepare_check_wire) — the same grid, byte for byte, that
+`_stage_a2a` builds from the same rows as columns, so the same programs.
+
 Ingress/egress staging is persistent: packed grids build in a ring of
 reusable host buffers (_StagingPool), ship once, and are DONATED into the
 mesh step; the packed output allocation aliases a recycled egress buffer
@@ -97,7 +104,7 @@ from gubernator_tpu.parallel.mesh import (
     shard_spec,
 )
 from gubernator_tpu import tracing
-from gubernator_tpu.types import RateLimitRequest, RateLimitResponse
+from gubernator_tpu.types import Behavior, RateLimitRequest, RateLimitResponse
 
 
 def _staging_donate() -> tuple:
@@ -579,11 +586,19 @@ class ShardedEngine:
 
         return serve_columns(self, cols, now_ms, dispatch)
 
+    @property
+    def folds_copies(self) -> bool:
+        """The decide program folds the copies of a key itself
+        (kernel2.decide2_packed_dedup_impl): a batch is one pass whatever it
+        repeats, as columns (`plan`) and as the parser's lanes
+        (ops/engine._assemble_wire_parts) alike."""
+        return self.dedup == "device"
+
     def plan(self, hb: HostBatch):
         """Pass plan for one packed batch (serve_columns/prepare hook):
         O(1) when duplicates aggregate in-trace, the host group-by planner
         otherwise (exact sequential same-key semantics — fallback/oracle)."""
-        if self.dedup == "device":
+        if self.folds_copies:
             return single_pass(hb)
         return plan_passes(hb, max_exact=self.max_exact_passes)
 
@@ -597,7 +612,9 @@ class ShardedEngine:
     # ------------------------------------------------ boundary accounting
     # (the host stages themselves are tracing.stage parts of the runner's
     # put/fetch: shard_route, shard_pack | wire_pack, shard_put in _stage*,
-    # shard_unroute with wire_decode inside it in _unroute)
+    # shard_unroute with wire_decode inside it in _unroute; on the fused
+    # path wire_pack is the native staging call, ops/engine.py, and
+    # shard_put follows it in stage_wire)
 
     def _wire_count(self, direction: str, nbytes: int) -> None:
         with self._stage_lock:
@@ -1058,6 +1075,70 @@ class ShardedEngine:
         staged = self._stage(pass_batch, None)
         return pass_batch, staged
 
+    # The fused front door (ops/engine.prepare_check_wire): a chunk the
+    # native parser already holds as compact lanes is staged from them by
+    # the one native staging call the local engine uses, laid out here as
+    # `_stage_a2a` lays out the same rows from columns, byte for byte.
+
+    # behavior bits the compact lanes drop as inert (ops/wire._INERT) that a
+    # mesh engine acts on: GLOBAL rows fork to the replica plane in
+    # `prepare_columns`, which reads them off the columns
+    wire_columns_behavior = int(Behavior.GLOBAL | Behavior.MULTI_REGION)
+
+    @property
+    def supports_wire_ingress(self) -> bool:
+        """Whether a chunk's lanes may be staged for this engine as they
+        are: the arrival-order compact grid with the copies of a key folded
+        in-trace is the only layout that needs nothing of the host but the
+        rows in order. A host-routed grid sorts rows by owner, host dedup
+        plans passes over a HostBatch, a Store orders write-throughs on
+        the serial path: those engines take the parser's columns."""
+        return (
+            self.wire == "compact" and self.route == "device"
+            and self.folds_copies and self.store is None
+        )
+
+    def _a2a_rows(self, n: int) -> int:
+        """Rows per device block of an arrival-order grid of `n` rows."""
+        return _pad_size(max(1, -(-n // self.n_shards)), floor=8)
+
+    def wire_pad(self, n: int) -> int:
+        """Data columns of the fused grid of `n` rows: D blocks of c."""
+        return self.n_shards * self._a2a_rows(n)
+
+    def stage_wire(self, grid: np.ndarray, math: str, cascade: bool = False):
+        """Stage a fused front-door grid: the chunk's (5, D*c + 1) lanes in
+        arrival order (ops/wire.stage_wire_chunk with every copy kept, its
+        base in the trailing column) become the (D, 5, c+1) ingress grid of
+        `_stage_a2a` — row i on device i // c, the base column after every
+        block — and the `_StagedA2A` that `issue_staged` takes. `cascade`
+        as in `stage_pass`; a chunk with level bits does not come here."""
+        from gubernator_tpu.ops.wire import block_base
+
+        c = (grid.shape[1] - 1) // self.n_shards
+        blocks = self._wire_blocks(grid[:, :-1], c)
+        blocks[:, :, c] = grid[:, -1]  # the base, and zeros under it
+        with tracing.stage.within("shard_put"):
+            dev = self._put_grid(blocks)
+        self._wire_count("put", blocks.nbytes)
+        return _StagedA2A(
+            c=c, dev=dev, math=math, wire=True, base=block_base(grid),
+            needs_full=batch_needs_full_layout(self.table.layout, math),
+            lanes=grid,
+        )
+
+    def _wire_blocks(self, flat: np.ndarray, c: int) -> np.ndarray:
+        """The pooled (D, 5, c+1) compact ingress grid with the (5, D*c)
+        arrival-order lanes `flat` in its data columns: one strided copy."""
+        D, L = self.n_shards, flat.shape[0]
+        shape = (D, L, c + 1)
+        if self._pool is not None:
+            grid = self._pool.get(shape, dtype=np.int32)
+        else:
+            grid = np.empty(shape, dtype=np.int32)
+        np.copyto(grid[:, :, :c], flat.reshape(L, D, c).transpose(1, 0, 2))
+        return grid
+
     def migrate_layout_full(self, reason: str = "off-family traffic") -> bool:
         """Migrate the authoritative shards to the canonical full layout in
         place (engine thread only; cf. LocalEngine.migrate_layout_full).
@@ -1144,6 +1225,11 @@ class ShardedEngine:
         # (pass rows are all active; a2a capacity drops count at their
         # retry; dedup member rows are represented by their carrier)
         counted = ~unproc & ~member
+        lanes = getattr(staged, "lanes", None)
+        if lanes is not None:
+            # a fused grid holds the chunk's error rows in place, as zeroed
+            # lanes the kernel never saw: fp == 0 is the decode's rule
+            counted &= (lanes[0, :n] != 0) | (lanes[1, :n] != 0)
         st = (
             int(hit[counted].sum()),
             int((~hit[counted]).sum()),
@@ -1258,7 +1344,7 @@ class ShardedEngine:
         (20 B/row on the put vs the full layout's 96)."""
         D = self.n_shards
         n = batch.fp.shape[0]
-        c = _pad_size(max(1, -(-n // D)), floor=8)
+        c = self._a2a_rows(n)
         wired, base = self._wire_plan(batch)
         with tracing.stage.within("wire_pack" if wired else "shard_pack"):
             if wired:
@@ -1268,14 +1354,10 @@ class ShardedEngine:
                 if self._pool is not None:
                     flat = self._pool.get((L, D * c), dtype=np.int32)
                     flat[:, n:] = 0  # stale tail from the buffer's last use
-                    grid = self._pool.get((D, L, c + 1), dtype=np.int32)
                 else:
                     flat = np.zeros((L, D * c), dtype=np.int32)
-                    grid = np.empty((D, L, c + 1), dtype=np.int32)
                 wire_mod.pack_wire_rows(batch, base, out=flat[:, :n])
-                np.copyto(
-                    grid[:, :, :c], flat.reshape(L, D, c).transpose(1, 0, 2)
-                )
+                grid = self._wire_blocks(flat, c)
                 grid[:, :, c] = 0
                 for d in range(D):
                     wire_mod.stamp_base(grid[d], base)
@@ -1449,6 +1531,9 @@ class _StagedA2A(NamedTuple):
     wire: bool = False  # compact 5-lane int32 wire grids (ops/wire.py)
     base: int = 0  # created_at base of the compact encoding
     needs_full: bool = False  # batch unservable by a packed table layout
+    # a fused dispatch (stage_wire): the chunk's (5, D*c + 1) lanes it was
+    # laid out from, error rows zeroed in place; None for a pass of columns
+    lanes: Optional[np.ndarray] = None
 
 
 def _route_plan(routed: np.ndarray, D: int):

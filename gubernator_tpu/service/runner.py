@@ -227,9 +227,13 @@ class EngineRunner:
         cannot ride the fused path (a non-encodable row, `created_at` skew
         beyond the wire's budget) is staged again as columns by the same
         prep job that found it out, which is semantically identical; an
-        engine that is not wire-capable, or has a Store, takes `check` from
-        here. `done` as in `check`: its `fused` is the number of passes the
-        fused staging issued, 0 when the columns staging served the chunk."""
+        engine that is not wire-capable (a mesh engine with a host route or
+        a host plan), or has a Store, takes `check` from here. A wire-capable
+        mesh engine keeps every copy of a key in its one grid and declines,
+        on the same terms, what its lanes cannot tell it (a GLOBAL or
+        MULTI_REGION row, cascade level bits). `done` as in `check`: its
+        `fused` is the number of passes the fused staging issued, 0 when
+        the columns staging served the chunk."""
         engine = self.engine
         cols = [p.cols for p in parts]
         if (
@@ -257,6 +261,8 @@ class EngineRunner:
                 prepared = self._stage_columns(cols, now_ms, disp)
             else:
                 fused = len(prepared.passes)
+                if self.metrics is not None:
+                    self._observe_shard_stages()
             return prepared
 
         return await self._run_chain(
