@@ -1044,6 +1044,12 @@ def issue_check_columns(engine, pending: PendingCheck) -> PendingCheck:
             engine, pending.promote, pending.now
         )
         pending.promote = None
+    elif getattr(engine, "_evictees", False):
+        # a tiered table faults the grid's shadowed keys back ahead of the
+        # launches, in this job, and fetches nothing (`fault_ahead`)
+        _p, n0, batch, _staged = pending.passes[0]
+        live = np.asarray(batch.active[:n0])
+        engine.fault_ahead(_batch_fps(batch, n0)[live], pending.now)
     if pending.mark is not None and getattr(engine, "ckpt", None) is not None:
         # dirty-block marking for incremental checkpoints: same engine-
         # thread job as the launches below (ops/checkpoint.py contract)
@@ -1293,9 +1299,13 @@ class LocalEngine:
         self.shadow = None
         self._tier_metrics = None
         self._tier = dict.fromkeys(
-            ("probed", "promoted", "returned", "merge_launches",
-             "rehydrate_dispatches"), 0,
+            ("probed", "promoted", "promoted_ahead", "returned",
+             "merge_launches", "rehydrate_dispatches"), 0,
         )
+        # merges an issue job launched and left unfetched (`fault_ahead`),
+        # oldest first: (merged mask, evictee block, the fps and rows the
+        # launch was given); `drain_sidecars` empties it
+        self._sidecars: list = []
         self.stats = EngineStats()
         # device passes launched, by padded batch size (_issue_from_dev, the
         # ring's fused drains): the shapes a resize compiles again, and what
@@ -1320,14 +1330,21 @@ class LocalEngine:
     # With a shadow attached the table is upstream's bounded LRU and the
     # shadow the Store behind it, and every answer is what an unbounded
     # table would give. What makes that exact is where a key's state may
-    # change place: only inside one engine-thread job, fetched before the
-    # job ends (`_decide_faulting`). The pipelined launches
-    # (`issue_staged`) run the hits-only program (evictees="defer"): a key
-    # the table does not hold is neither created nor answered there, no row
-    # is evicted, and its row comes back dropped for the finish half's
-    # fixup, which decides it here. So between two jobs every key's state
-    # is in the table or in the shadow, never on its way, and a decide
-    # never meets a key whose state it cannot see.
+    # be: in the table, in the shadow, or in exactly one unfetched sidecar
+    # (`_sidecars`: the outputs of a `merge2` an issue job launched ahead
+    # of its passes, `fault_ahead`) whose launch precedes, on the device,
+    # every program launched after it. The shadow's only host reader is
+    # `take`, always behind a drain of those sidecars (`drain_sidecars`),
+    # and the only programs that create a key are the claiming decide
+    # (launched by `_decide_faulting` alone, behind a drain and a take) and
+    # `merge2`, which installs only rows a take returned. The pipelined
+    # launches (`issue_staged`) run the hits-only program
+    # (evictees="defer"): a key the table does not hold, one whose state
+    # is in a sidecar among them, is neither created nor answered there,
+    # no row is evicted, and its row comes back dropped for the finish
+    # half's fixup, which drains before it looks. So no key is granted
+    # afresh while its count is on its way, and a key is never in two
+    # places (docs/tiering.md).
 
     @property
     def _evictees(self) -> bool:
@@ -1342,13 +1359,20 @@ class LocalEngine:
         """Arm hot-set tiering: evict capture + fault-back from `shadow`
         (tier.ShadowTable). Call before serving — flipping it mid-flight
         only costs recompiles. `metrics` (the daemon's) takes the tier's
-        stage samples (tier_probe, tier_promote, tier_harvest)."""
+        stage samples (tier_probe, tier_promote, tier_harvest). What the
+        shadow attached until now is still owed goes to it first (engine
+        thread): the warm-up's scratch shadow leaks no sidecar into the
+        real one."""
+        self.drain_sidecars()
         self.shadow = shadow
         self._tier_metrics = metrics
         self._tier = dict.fromkeys(self._tier, 0)
 
     def tier_counts(self) -> dict:
-        """The miss path's counts since start (engine thread writes)."""
+        """The fault-back's counts since start (engine thread writes):
+        `promoted` and `merge_launches` count every promote and every
+        `merge2` launch, `promoted_ahead` the promotes an issue job's merge
+        installed (`fault_ahead`; counted when its sidecar is drained)."""
         return dict(self._tier)
 
     def _harvest_evictees(self, host_arr: np.ndarray) -> None:
@@ -1374,22 +1398,86 @@ class LocalEngine:
                 self.shadow.offer(fps, rows, now_ms=0, reason="evict")
         self.stats.demoted_live += int(fps.shape[0])
 
-    def _fault_in(self, fps: np.ndarray, now: int) -> np.ndarray:
-        """Bring the shadowed ones of `fps` back into the table before
-        their decide (engine thread): taken out of the shadow and installed
-        by ONE `merge2` launch whose own victims go to the shadow before
-        this returns. Returns the fingerprints whose state is in the shadow
-        as this returns, whose decide therefore has to wait for the next
-        round: those whose install found no lane (more than K keys of one
-        bucket in the batch; their rows are back in the shadow) and the
-        merge's own victims, one of which may be a key of this batch."""
-        from gubernator_tpu.ops.layout import FULL
-
+    def drain_sidecars(self) -> None:
+        """Fetch every sidecar an earlier engine-thread job left unfetched
+        (engine thread; the head of every job that reads or writes the
+        shadow): each a finished `merge2`'s merged mask and evictee block.
+        The victims go to the shadow, the promotes whose install found no
+        lane (more than K keys of one bucket in the launch) back to it.
+        Stage `tier_harvest`, one sample a sidecar."""
         t = self._tier
+        while self._sidecars:
+            merged, ev, pf, rows = self._sidecars[0]
+            with tracing.stage("tier_harvest", self._tier_metrics) as st:
+                mask, ev_fps, ev_rows = self._merge_collect(
+                    merged, ev, pf.shape[0]
+                )
+                # fetched: the record goes (a fetch that raises keeps it)
+                del self._sidecars[0]
+                st.note(rows=int(ev_fps.shape[0]), returned=int((~mask).sum()))
+                landed = int(mask.sum())
+                t["promoted"] += landed
+                t["promoted_ahead"] += landed
+                if landed < pf.shape[0]:
+                    t["returned"] += int(pf.shape[0]) - landed
+                    self.shadow.offer(
+                        pf[~mask], rows[~mask], now_ms=0, reason="return"
+                    )
+                if ev_fps.shape[0]:
+                    self.shadow.offer(ev_fps, ev_rows, now_ms=0, reason="evict")
+
+    def _take_shadowed(self, fps: np.ndarray, now: int):
+        """`shadow.take` of `fps`, timed and counted (stage `tier_probe`)."""
         with tracing.stage("tier_probe", self._tier_metrics) as st:
             pf, rows = self.shadow.take(fps, now)
             st.note(rows=int(fps.shape[0]), hits=int(pf.shape[0]))
-        t["probed"] += int(fps.shape[0])
+        self._tier["probed"] += int(fps.shape[0])
+        return pf, rows
+
+    def fault_ahead(self, fps: np.ndarray, now: int) -> None:
+        """The fault-back of a pipelined dispatch, at the head of its issue
+        job (engine thread): the shadowed ones of `fps` (the keys the
+        dispatch's grid holds live) are taken out of the shadow and
+        installed by ONE `merge2` launched ahead of the dispatch's passes
+        and NOT fetched: the device runs programs in launch order, so the
+        hits-only passes behind it see the installs, and the launch's
+        outputs wait in `_sidecars` for the next job that touches the
+        shadow. If the launch raises, the taken rows go back first."""
+        self.drain_sidecars()
+        t = self._tier
+        pf, rows = self._take_shadowed(fps, now)
+        if pf.shape[0] == 0:
+            return
+        with tracing.stage(
+            "tier_promote", self._tier_metrics, rows=int(pf.shape[0]), launches=1
+        ):
+            try:
+                merged, ev = self._merge_launch(pf, rows, now, True)
+            except BaseException:
+                self.shadow.offer(pf, rows, now_ms=0, reason="return")
+                raise
+        t["merge_launches"] += 1
+        # the outputs' way to the host starts here, behind the merge and
+        # ahead of the dispatch's passes, and is waited for by no one: the
+        # drain a job later finds them on the host
+        merged.copy_to_host_async()
+        ev.copy_to_host_async()
+        self._sidecars.append((merged, ev, pf, rows))
+
+    def _fault_in(self, fps: np.ndarray, now: int) -> np.ndarray:
+        """Bring the shadowed ones of `fps` back into the table before
+        their decide (the miss path, engine thread): taken out of the
+        shadow and installed by ONE `merge2` launch, fetched here, whose
+        own victims go to the shadow before this returns. Returns the
+        fingerprints whose state is in the shadow as this returns, whose
+        decide therefore has to wait for the next round: those whose
+        install found no lane (more than K keys of one bucket in the
+        batch; their rows are back in the shadow) and the merge's own
+        victims, one of which may be a key of this batch."""
+        from gubernator_tpu.ops.layout import FULL
+
+        t = self._tier
+        pf, rows = self._take_shadowed(fps, now)
         if pf.shape[0] == 0:
             return pf
         with tracing.stage(
@@ -1422,7 +1510,9 @@ class LocalEngine:
         into the shadow before anything else is launched; a row whose
         claim was contended, or whose promote found no lane, is the next
         round's. Counts every row it decides (the hits-only program counts
-        none of the rows it deferred)."""
+        none of the rows it deferred). What an issue job's merge left
+        unfetched is in the shadow before the first take."""
+        self.drain_sidecars()
         status = np.zeros(n, dtype=np.int32)
         limit = np.zeros(n, dtype=np.int64)
         remaining = np.zeros(n, dtype=np.int64)
@@ -1746,6 +1836,7 @@ class LocalEngine:
             batch = pad_batch(pass_batch, _pad_size(n_rows))
             return self._dispatch_with_retry(batch, n_rows, cascade)
 
+        self.drain_sidecars()
         return serve_columns(self, cols, now_ms, dispatch)
 
     def _dispatch_with_retry(self, batch, n: int, cascade: bool = False):
@@ -1902,12 +1993,10 @@ class LocalEngine:
         canonical rows): the mask says which incoming rows actually
         landed (a claim-dropped promote must return to the shadow, not
         vanish) and the evictees are LIVE rows the installs displaced
-        (demoted onward instead of destroyed)."""
-        import jax.numpy as jnp
-
-        from gubernator_tpu.ops.kernel2 import merge2
-        from gubernator_tpu.ops.table2 import FLAGS
-
+        (demoted onward instead of destroyed). It is the launch half and
+        the collect half in a row (`_merge_launch`, `_merge_collect`); the
+        issue job's fault-back launches and leaves the collecting to a
+        later job (`fault_ahead`, `drain_sidecars`)."""
         n = fps.shape[0]
         if n == 0:
             if collect:
@@ -1926,6 +2015,22 @@ class LocalEngine:
                 self.merge_rows(fps[rank == r], slots[rank == r], now_ms)
                 for r in range(int(rank.max()) + 1)
             )
+        merged, ev = self._merge_launch(fps, slots, now_ms, collect)
+        if collect:
+            mask, ev_fps, ev_rows = self._merge_collect(merged, ev, n)
+            return int(mask.sum()), mask, ev_fps, ev_rows
+        return int(np.asarray(merged).sum())
+
+    def _merge_launch(self, fps, slots, now_ms, evictees: bool):
+        """Launch half of `merge_rows`: ONE `merge2` over unique
+        fingerprints and their canonical full rows, padded to the pow2
+        shapes the warm-up compiles; the table is the new one as this
+        returns and nothing is fetched. Returns the un-fetched (merged
+        mask, evictee block or None)."""
+        from gubernator_tpu.ops.kernel2 import merge2
+        from gubernator_tpu.ops.table2 import FLAGS
+
+        n = fps.shape[0]
         if not self.table.layout.supports_algos(slots[:, FLAGS] & 0xFF):
             self.migrate_layout_full("merge of off-family rows")
         now = now_ms if now_ms is not None else ms_now()
@@ -1937,29 +2042,33 @@ class LocalEngine:
         slots_p[:n] = slots
         active = np.zeros(size, dtype=bool)
         active[:n] = True
-        args = (
-            self.table,
-            jnp.asarray(fp_p),
-            jnp.asarray(slots_p),
-            jnp.asarray(np.full(size, now, dtype=np.int64)),
-            jnp.asarray(active),
+        # one transfer call for the four: on a loaded host every call that
+        # gives the GIL away queues for it again
+        args = jax.device_put(
+            [fp_p, slots_p, np.full(size, now, dtype=np.int64), active]
         )
-        if collect:
+        ev = None
+        if evictees:
             self.table, merged, ev = merge2(
-                *args, write=self.write_mode, evictees=True
+                self.table, *args, write=self.write_mode, evictees=True
             )
-            self.stats.dispatches += 1
-            mask = np.asarray(merged)[:n].copy()
-            ev_h = np.asarray(ev)
-            ev_lo = ev_h[:, 0].astype(np.int64) & 0xFFFFFFFF
-            ev_fp = (ev_h[:, 1].astype(np.int64) << 32) | ev_lo
-            keep = ev_fp != 0
-            return (
-                int(mask.sum()), mask, ev_fp[keep], ev_h[keep].copy()
+        else:
+            self.table, merged = merge2(
+                self.table, *args, write=self.write_mode
             )
-        self.table, merged = merge2(*args, write=self.write_mode)
         self.stats.dispatches += 1
-        return int(np.asarray(merged).sum())
+        return merged, ev
+
+    @staticmethod
+    def _merge_collect(merged, ev, n: int):
+        """Collect half of `merge_rows(collect=True)`: fetch a launch's
+        outputs → (merged mask (n,), evictee fps, evictee canonical rows)."""
+        mask = np.asarray(merged)[:n].copy()
+        ev_h = np.asarray(ev)
+        ev_lo = ev_h[:, 0].astype(np.int64) & 0xFFFFFFFF
+        ev_fp = (ev_h[:, 1].astype(np.int64) << 32) | ev_lo
+        keep = ev_fp != 0
+        return mask, ev_fp[keep], ev_h[keep].copy()
 
     def read_state(self, fps: np.ndarray, raw: bool = False):
         """Read the full-width stored slots for `fps` without mutating

@@ -723,7 +723,11 @@ class Daemon:
                 self.engine.global_stats = GlobalStats()
         if tiered:
             shapes += await self._warm_tier_sweep()
-            self.engine.attach_shadow(None)
+            # on the engine thread, behind the warm dispatches' `apply`
+            # jobs: the scratch shadow takes what their merges still owe it
+            await asyncio.get_running_loop().run_in_executor(
+                self.runner._exec, self.engine.attach_shadow, None
+            )
         # the /metrics scrape and the grow tick count live keys on the device:
         # compile that program now, not under the first scrape
         await self.runner.live_count()

@@ -39,6 +39,14 @@ def _label_counts(algo_col) -> list:
     return np.bincount(lab, minlength=len(_ALGO_LABELS)).tolist()
 
 
+def _drain_sidecars(engine) -> None:
+    """`LocalEngine.drain_sidecars` where the engine has one (engine
+    thread): the head of every job here that touches the shadow."""
+    drain = getattr(engine, "drain_sidecars", None)
+    if drain is not None:
+        drain()
+
+
 class EngineRunner:
     """Serializes engine table access onto one thread; async façade.
 
@@ -330,6 +338,11 @@ class EngineRunner:
         ):
             for delta in deltas:
                 self.engine.stats.merge(delta)
+            # a tiered dispatch's merge (ops/engine.fault_ahead) finished
+            # before the passes this dispatch has just fetched: its sidecar
+            # goes to the shadow here unless a later job took it first, so
+            # an engine gone quiet holds none
+            _drain_sidecars(self.engine)
             if self.metrics is not None:
                 self.metrics.observe_engine(self.engine.stats)
                 # GLOBAL batches ride the pipeline too: without this the
@@ -572,6 +585,7 @@ class EngineRunner:
 
             eng = self.engine
             now = now_ms if now_ms is not None else ms_now()
+            _drain_sidecars(eng)
             with tracing.stage("tier_sweep", self.metrics) as st:
                 fps, slots = eng.extract_idle(now, idle_ms, max_rows)
                 st.note(rows=int(fps.shape[0]))
@@ -586,6 +600,12 @@ class EngineRunner:
             return now, fps, full
 
         return await loop.run_in_executor(self._exec, run)
+
+    def tier_drain_sync(self) -> None:
+        """Run the tiered engine's `drain_sidecars` as an engine-thread job
+        and wait for it (any thread but the engine's and the loop's): what
+        closes the shadow flushes behind it (`TierManager.close`)."""
+        self._exec.submit(_drain_sidecars, self.engine).result()
 
     # ------------------------------------------------- incremental checkpoint
     # (service/checkpoint.py) — split like telemetry: take+launch atomically

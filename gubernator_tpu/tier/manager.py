@@ -163,6 +163,7 @@ class TierManager:
         return {
             "probed": t.get("probed", sh.probes),
             "promoted": t.get("promoted", sh.promoted),
+            "promoted_ahead": t.get("promoted_ahead", 0),
             "demoted_evict": sh.demoted_evict,
             "demoted_idle": sh.demoted_idle,
             "returned": t.get("returned", sh.promote_returned),
@@ -191,6 +192,9 @@ class TierManager:
     def close(self, now_ms: int) -> None:
         """Shutdown flush (sync — runs in an executor off the loop):
         persist unspilled shadow rows so a graceful restart faults them
-        back from disk."""
+        back from disk. What the engine's last merges displaced is in
+        their sidecars until an engine-thread job drains them: that job
+        runs first."""
         if self.enabled and self.shadow is not None:
+            self.daemon.runner.tier_drain_sync()
             self.shadow.flush(now_ms)

@@ -28,10 +28,12 @@ one key are decided one after another, copy 7 and up as ONE check of their
 summed hits whose answer every one of them gets (the local engine's
 aggregate; ops/plan.py). Checks of different keys are concurrent, so any
 order is an answer; which keys end up resident depends on it, and the order
-taken here is the device's, pass by pass: the keys the table holds first;
-then the others in rounds — the stored ones are promoted, each bucket giving
-its K cheapest lanes to the first K of them, in arrival order (a ninth waits
-for the next round, back in the store); then the round's keys are decided,
+taken here is the device's: first the dispatch's stored keys are promoted,
+each bucket giving its K cheapest lanes to the first K of them, in the order
+of their keys (a ninth stays in the store); then, pass by pass, the keys the
+table now holds; then the others in rounds — the stored ones (a ninth, a key
+of this dispatch that a promote pushed out) are promoted by the same rule (a
+ninth waits for the next round); then the round's keys are decided,
 the promoted ones where they now lie, a new key on the cheapest lane of its
 bucket as it was when the round's decisions began, unless that lane belongs
 to a key decided in the same round (then the newcomer waits a round: a
@@ -69,6 +71,7 @@ class StoredTable:
         self.store: Set[int] = set()  # fps whose token bucket is in the store
         self.demoted = 0  # live keys that lost their lane to the store
         self.promoted = 0  # keys that came back from it
+        self.promoted_ahead = 0  # of those, ahead of their dispatch's passes
         self.returned = 0  # promotes that found no lane and waited a round
         self.lost = 0  # live keys whose count is gone: never
 
@@ -102,6 +105,34 @@ class StoredTable:
         lanes[j] = fp
         self.touch[fp] = touch_tick(now)
 
+    def _promote(self, back: List[int], now: int) -> Set[int]:
+        """One launch's promotes: the stored keys `back` come back in the
+        order of their keys, K a bucket at most. Returns those that found
+        no lane and wait, back in the store."""
+        waits: Set[int] = set()
+        ranks: Dict[int, int] = {}
+        order: Dict[int, List[int]] = {}
+        for fp in back:
+            b = fp % self.n_buckets
+            if b not in order:
+                order[b] = self._cheapest(self._lanes(fp), now)
+            r = ranks[b] = ranks.get(b, -1) + 1
+            if r >= K:
+                waits.add(fp)
+                self.returned += 1
+        # (two steps: every lane ranked first, then the moves, as one
+        # launch does)
+        ranks = {}
+        for fp in back:
+            if fp in waits:
+                continue
+            b = fp % self.n_buckets
+            r = ranks[b] = ranks.get(b, -1) + 1
+            self.store.discard(fp)
+            self._take_lane(self._lanes(fp), order[b][r], fp, now)
+            self.promoted += 1
+        return waits
+
     # -------------------------------------------------------------- one pass
     def _decide(self, fp: int, now: int, hits: int, limit: int, duration: int) -> Answer:
         self.touch[fp] = touch_tick(now)
@@ -123,31 +154,9 @@ class StoredTable:
                  duration: int, out: Dict[int, Answer]) -> None:
         """The keys a pass did not find in the table, in rounds."""
         while todo:
-            # the stored ones come back in the order of their keys, K a
-            # bucket at most
-            waits: Set[int] = set()
-            ranks: Dict[int, int] = {}
-            order: Dict[int, List[int]] = {}
-            back = sorted(fp for _row, fp, _hits in todo if fp in self.store)
-            for fp in back:
-                b = fp % self.n_buckets
-                if b not in order:
-                    order[b] = self._cheapest(self._lanes(fp), now)
-                r = ranks[b] = ranks.get(b, -1) + 1
-                if r >= K:
-                    waits.add(fp)
-                    self.returned += 1
-            # (two steps: every lane ranked first, then the moves, as one
-            # launch does)
-            ranks = {}
-            for fp in back:
-                if fp in waits:
-                    continue
-                b = fp % self.n_buckets
-                r = ranks[b] = ranks.get(b, -1) + 1
-                self.store.discard(fp)
-                self._take_lane(self._lanes(fp), order[b][r], fp, now)
-                self.promoted += 1
+            waits = self._promote(
+                sorted(fp for _row, fp, _hits in todo if fp in self.store), now
+            )
             # a key of this round that a promote has just pushed out waits
             # for the next round, where it is promoted in its turn
             waits |= {fp for _row, fp, _hits in todo if fp in self.store}
@@ -196,9 +205,13 @@ class StoredTable:
             passes.append([
                 (rows[-1], fp, hits * len(rows)) for fp, rows in sorted(tail.items())
             ])
-        # every pass first meets the table as the dispatch found it (the
-        # pipelined launches create nothing and push nothing out), and only
-        # then are the keys it did not hold faulted in, pass after pass
+        # the dispatch's stored keys come back first, ahead of its passes;
+        # every pass then meets the table as that left it (the pipelined
+        # launches create nothing and push nothing out), and only then are
+        # the keys it did not hold faulted in, pass after pass
+        before = self.promoted
+        self._promote(sorted({fp for fp in fps if fp in self.store}), now)
+        self.promoted_ahead += self.promoted - before
         got: Dict[int, Answer] = {}
         left = [self._held(items, now, limit, duration, got) for items in passes]
         for todo in left:

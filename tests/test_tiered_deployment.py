@@ -253,7 +253,7 @@ async def _overflowed(**conf):
         await c.stop()
 
 
-TIER_COUNTS = ("probed", "promoted", "demoted_evict", "demoted_idle", "returned",
+TIER_COUNTS = ("probed", "promoted", "promoted_ahead", "demoted_evict", "demoted_idle", "returned",
                "rehydrate_dispatches", "merge_launches", "lost", "shadow_rows",
                "shadow_bytes")
 
@@ -269,6 +269,8 @@ def test_a_tiered_overflow_demotes_and_loses_nothing():
     tier = pipe["tier"]
     assert set(tier) == set(TIER_COUNTS)
     assert tier["lost"] == 0 and tier["promoted"] > 0 and tier["merge_launches"] > 0
+    # the last wave's keys came back ahead of their dispatch's launch
+    assert 0 < tier["promoted_ahead"] <= tier["promoted"]
     assert tier["demoted_evict"] == table["demoted_live_total"]
     assert tier["rehydrate_dispatches"] > 0 and tier["shadow_rows"] > 0
     assert remaining == [8] * 32  # every count kept

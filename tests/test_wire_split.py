@@ -272,11 +272,13 @@ async def test_a_clamped_stamp_is_counted_once_on_a_split_chunk():
 async def test_a_split_chunk_through_the_feedback_paths(shadow, monkeypatch):
     """One bucket of eight slots and 24 keys: pass 0's claims drop and are
     retried on the engine thread; with a shadow attached (ported in PR 42:
-    the pipelined launch now decides only the keys the table holds) every
-    pass hands the rows of the keys it does not hold to the same retry,
-    which faults them in (`LocalEngine._decide_faulting`). Both select rows
-    of a pass by its mask, which leaves out the later copies of a key: a
-    copy taken for a miss would be applied twice."""
+    the pipelined launch decides only the keys the table holds; in PR 44:
+    the issue job first brings the chunk's shadowed keys back, as many as
+    the bucket has lanes) a pass hands the rows of the keys it still does
+    not hold to the same retry, which faults them in
+    (`LocalEngine._decide_faulting`). Both select rows of a pass by its
+    mask, which leaves out the later copies of a key: a copy taken for a
+    miss would be applied twice."""
     calls = []
     redispatch = LocalEngine._redispatch_rows
     monkeypatch.setattr(
@@ -301,10 +303,15 @@ async def test_a_split_chunk_through_the_feedback_paths(shadow, monkeypatch):
             r_wire, r_cols, parts, now + 5
         )
         assert n_fused == 4 and not got.err.any()
-        # the retry; with a shadow, once for each of the four passes (key 3
-        # and key 20 come four and three times, and one bucket cannot hold
-        # the 24 keys of pass 0, so every pass meets a key that has left)
-        want_calls = 4 if shadow else 1
+        # the retry; with a shadow, the residue of the fault-back ahead of
+        # the launch: keys 0-15 lie in the shadow and the issue job's merge
+        # brings eight of them back (key 3 among them: the bucket's eight
+        # lanes), pushing keys 16-23 out. Pass 0 defers the sixteen keys
+        # that are not resident; passes 1 and 2 (keys 3, 20, 1 and 3, 20)
+        # defer key 20, which was pushed out before they ran; pass 3 is key
+        # 3 alone and hits. Three calls, where every pass made one (four)
+        # while nothing came back before the launch.
+        want_calls = 3 if shadow else 1
         assert calls.count(r_wire.engine) == want_calls
         assert calls.count(r_cols.engine) == want_calls
     finally:
