@@ -33,7 +33,7 @@ import gubernator_tpu  # noqa: F401,E402  (x64 on, compile cache placed)
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
-from gubernator_tpu.ops.batch import RequestColumns, pack_columns, pad_batch  # noqa: E402
+from gubernator_tpu.ops.batch import RequestColumns  # noqa: E402
 from gubernator_tpu.ops.engine import LocalEngine  # noqa: E402
 
 CAPACITY = 16_777_216  # 1 GiB full layout, 512 MiB packed
@@ -97,37 +97,6 @@ def engine_case(kw: dict, mode: str, batches) -> dict:
     return out
 
 
-def fused_drain_case() -> dict:
-    """ops/ring_drain.DeviceRing.drain (one while_loop launch over K slots)
-    against K direct compact-wire dispatches."""
-    from gubernator_tpu.ops import wire
-    from gubernator_tpu.ops.ring_drain import DeviceRing
-
-    S, W, K = 16, 4096, 8
-    rng = np.random.default_rng(11)
-    eng = LocalEngine(capacity=CAPACITY, wire="compact")
-    ref = LocalEngine(capacity=CAPACITY, wire="compact")
-    ring = DeviceRing(S, W, K)
-    grids = []
-    for t in range(K):
-        hb, _ = pack_columns(traffic(rng, W - 96, "token", np.zeros(0, np.int64)), NOW)
-        grid = wire.pack_wire_full(pad_batch(hb, W), NOW)
-        ring.stage(t % S, grid, t)
-        grids.append(grid)
-    bank, n = ring.drain(eng, 0, K, "token", False)
-    bank = np.asarray(bank)
-    if int(n) != K:
-        return {"outcome": "MISMATCH", "drained": int(n), "published": K}
-    for t, grid in enumerate(grids):
-        ref.table, out = wire.decide2_wire_cols(
-            ref.table, jax.device_put(grid), write=ref.write_mode,
-            math="token", cascade=False, evictees=False,
-        )
-        if not np.array_equal(bank[t], np.asarray(out)):
-            return {"outcome": "MISMATCH", "slot": t}
-    return {"outcome": "matches", "rows": K * W, "slots": K}
-
-
 CASES = {
     # ---- on the default served path
     "write_sparse/full/blk64 (default ≤4K rows)": lambda: engine_case(
@@ -143,8 +112,6 @@ CASES = {
         {"write_mode": "sweep", "layout": "token32"}, "token", [16384, 16384]),
     "write_sparse/gcra32": lambda: engine_case(
         {"write_mode": "sparse", "layout": "gcra32"}, "gcra", [4096, 4096]),
-    # ---- off by default
-    "fused ring drain (GUBER_RING_ISSUE=fused)": fused_drain_case,
 }
 
 

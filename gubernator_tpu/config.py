@@ -39,6 +39,15 @@ class ConfigError(ValueError):
     returns actionable errors from SetupDaemonConfig, config.go:359-363)."""
 
 
+# settings of the request ring (documented in docs/latency.md and
+# example.conf until PR 45): a daemon given one refuses to start, so that
+# nobody is left believing the ring is on
+_RETIRED_SETTINGS = (
+    "GUBER_RING_ENABLE", "GUBER_RING_SLOTS", "GUBER_RING_ISSUE",
+    "GUBER_RING_DRAIN_K", "GUBER_RING_SLOT_WIDTH",
+)
+
+
 def load_config_file(path: str, env: Optional[Dict[str, str]] = None) -> None:
     """Parse a `key=value` file and set the pairs into the environment
     (reference config.go:703-726: `fromEnvFile`). Lines starting with # and
@@ -171,29 +180,6 @@ class BehaviorConfig:
     overload_tenant_buckets: int = 64
     # reset_time hint stamped on shed responses (client retry backoff)
     overload_retry_ms: int = 25
-    # device-resident request ring (service/ring.py; docs/latency.md
-    # "Dispatch budget"): all-wire flushes are staged into a fixed ring of
-    # compact wire-grid slots and consumed in ticket order by a persistent
-    # serving loop — on TPU this kills the per-flush dispatch round-trip;
-    # the CPU build runs a functional emulation of the same protocol. Off
-    # (default) = the direct per-flush dispatch every PR before this one
-    # shipped.
-    ring_enable: bool = False
-    # ring depth in slots: submits past this many published-but-unconsumed
-    # batches wait (bounded backpressure, no drops, FIFO order)
-    ring_slots: int = 64
-    # consume tier (docs/latency.md "Launch budget"): "auto" resolves per
-    # backend (fused on TPU, host on CPU); "host" = one XLA launch per
-    # published slot; "fused" = ONE jitted while_loop launch drains up to
-    # ring_drain_k published slots (ops/ring_drain.py)
-    ring_issue: str = "auto"
-    # max published slots one fused drain launch retires (the launch-
-    # amortization factor; clamped to ring_slots)
-    ring_drain_k: int = 8
-    # fixed device slot width in rows for the fused tiers; chunks wider
-    # than this ride the per-slot host path. 0 = auto-size to the first
-    # fused chunk's padded dispatch size
-    ring_slot_width: int = 0
     # warm-up breadth: "" compiles only the 1-row shapes (fast spawn);
     # "pow2" additionally compiles every pow2 coalesce shape up to
     # coalesce_limit (token graph), "pow2-mixed" both math graphs — without
@@ -580,26 +566,6 @@ class DaemonConfig:
             )
         if self.behaviors.overload_retry_ms <= 0:
             raise ConfigError("GUBER_OVERLOAD_RETRY_MS must be positive")
-        if self.behaviors.ring_slots < 2:
-            raise ConfigError(
-                "GUBER_RING_SLOTS must be >= 2 (a 1-slot ring serializes "
-                "staging against consumption — no overlap to buy)"
-            )
-        if self.behaviors.ring_issue not in ("auto", "host", "fused"):
-            raise ConfigError(
-                "GUBER_RING_ISSUE must be auto, host or fused, "
-                f"got {self.behaviors.ring_issue!r}"
-            )
-        if self.behaviors.ring_drain_k < 1:
-            raise ConfigError(
-                "GUBER_RING_DRAIN_K must be >= 1 (published slots one "
-                "fused drain launch may retire)"
-            )
-        if self.behaviors.ring_slot_width < 0:
-            raise ConfigError(
-                "GUBER_RING_SLOT_WIDTH must be >= 0 (0 = auto-size to the "
-                "first fused chunk)"
-            )
         if self.behaviors.peer_breaker_errors <= 0:
             raise ConfigError("GUBER_PEER_BREAKER_ERRORS must be >= 1")
         if self.behaviors.peer_breaker_probes <= 0:
@@ -713,6 +679,13 @@ def setup_daemon_config(
     env = dict(os.environ) if env is None else env
     if config_file:
         load_config_file(config_file, env)
+    for name in _RETIRED_SETTINGS:
+        if env.get(name):
+            raise ConfigError(
+                f"{name} is set, and the request ring it configured is gone "
+                "(PR 45): there is one dispatch path and nothing to turn on. "
+                "Remove the setting"
+            )
 
     host = socket.gethostname() or "localhost"
     conf = DaemonConfig(
@@ -764,11 +737,6 @@ def setup_daemon_config(
                 env, "GUBER_OVERLOAD_TENANT_BUCKETS", 64
             ),
             overload_retry_ms=_get_int(env, "GUBER_OVERLOAD_RETRY_MS", 25),
-            ring_enable=_get_bool(env, "GUBER_RING_ENABLE", False),
-            ring_slots=_get_int(env, "GUBER_RING_SLOTS", 64),
-            ring_issue=_get(env, "GUBER_RING_ISSUE", "auto"),
-            ring_drain_k=_get_int(env, "GUBER_RING_DRAIN_K", 8),
-            ring_slot_width=_get_int(env, "GUBER_RING_SLOT_WIDTH", 0),
             warm_shapes=_get(env, "GUBER_WARM_SHAPES", ""),
             global_timeout_ms=_get_float_ms(env, "GUBER_GLOBAL_TIMEOUT", 500.0),
             global_sync_wait_ms=_get_float_ms(env, "GUBER_GLOBAL_SYNC_WAIT", 100.0),

@@ -829,7 +829,7 @@ struct LaterPass {
 struct ChunkStage {
   // in
   int64_t n = 0, now = 0, tol = 0, pad = 0, max_exact = 0, pad_floor = 0;
-  bool one_grid = false, keep_copies = false;
+  bool keep_copies = false;
   // out
   std::vector<int32_t> grid;
   std::vector<int8_t> err;
@@ -920,7 +920,7 @@ static bool stage_chunk(const ChunkPart* parts, size_t k, ChunkStage& s) {
       }
     }
   }
-  if (s.later && (s.one_grid || s.max_exact < 2)) return false;
+  if (s.later && s.max_exact < 2) return false;
 
   // the first active row's stamp is the base; lane 4 takes each active
   // row's delta from it, which a row of the grid has to fit
@@ -1044,8 +1044,8 @@ static PyObject* bytes_of(const std::vector<T>& v) {
 }
 
 // stage_wire_chunk(parts: sequence[(lanes, fp, err, created_at)], now: int,
-//                  tolerance: int, pad: int, one_grid: bool, max_exact: int,
-//                  pad_floor: int, keep_copies: bool = False)
+//                  tolerance: int, pad: int, max_exact: int, pad_floor: int,
+//                  keep_copies: bool = False)
 //   -> None | (grid, err, act_fp, first, clamped, math, cascade, later,
 //              passes)
 // The host staging of one fused chunk (ops/engine._stage_chunk_numpy and
@@ -1062,18 +1062,18 @@ static PyObject* bytes_of(const std::vector<T>& v) {
 // block ready to put — None where the lanes cannot carry the pass (a stamp
 // beyond the delta budget, summed hits past the lane) and the caller packs
 // that pass alone as columns — and the aggregate's fan-out. None: the chunk
-// cannot fuse. `one_grid` refuses a repeated key (a ring slot holds one
-// grid); later passes pad to pad_floor doubled until the rows fit. Holds no
-// state, and runs with the GIL released from the first row to the last.
+// cannot fuse. Later passes pad to pad_floor doubled until the rows fit.
+// Holds no state, and runs with the GIL released from the first row to the
+// last.
 static PyObject* stage_wire_chunk(PyObject*, PyObject* args) {
   PyObject* parts_o;
   ChunkStage s;
   long long now, tol, pad, max_exact, pad_floor;
-  int one_grid, keep_copies = 0;
-  if (!PyArg_ParseTuple(args, "OLLLpLL|p", &parts_o, &now, &tol, &pad,
-                        &one_grid, &max_exact, &pad_floor, &keep_copies))
+  int keep_copies = 0;
+  if (!PyArg_ParseTuple(args, "OLLLLL|p", &parts_o, &now, &tol, &pad,
+                        &max_exact, &pad_floor, &keep_copies))
     return nullptr;
-  s.now = now; s.tol = tol; s.pad = pad; s.one_grid = one_grid != 0;
+  s.now = now; s.tol = tol; s.pad = pad;
   s.keep_copies = keep_copies != 0;
   s.max_exact = max_exact; s.pad_floor = pad_floor;
   PyObject* seq = PySequence_Fast(parts_o, "parts: a sequence expected");

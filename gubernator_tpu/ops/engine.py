@@ -691,21 +691,19 @@ class _WireAssembly(NamedTuple):
     native: bool  # staged by the native call, not by NumPy
 
 
-def _assemble_wire_parts(engine, parts, now_ms=None, pad_to=None):
-    """Shared gating + host staging of the fused wire paths (direct front
-    door and ring slots): the parts' pre-packed native lanes become ONE
-    padded compact ingress grid. Returns None when the batch needs the
-    general columns path (engine not wire-capable, non-encodable rows,
-    created_at skew beyond the ±511 ms delta budget, Store attached, or
-    rows exceeding `pad_to`), else a `_WireAssembly`.
+def _assemble_wire_parts(engine, parts, now_ms=None):
+    """Gating + host staging of the fused wire path: the parts' pre-packed
+    native lanes become ONE padded compact ingress grid. Returns None when
+    the batch needs the general columns path (engine not wire-capable,
+    non-encodable rows, created_at skew beyond the ±511 ms delta budget,
+    Store attached), else a `_WireAssembly`.
 
     Copies of one key need the planner's sequential passes, and the grid is
     its pass 0: occurrence 0 of every key rides its parser lane, and every
     later copy has its lane zeroed (fp == 0, inactive on decode, as an error
     row) and is a row of one of the passes behind the grid, each a gather of
-    the chunk's own lanes (`StagedChunk.passes`). A ring slot holds one
-    grid, so with `pad_to` a repeated key still means None; so does one next
-    to cascade level bits (the in-trace fold needs a single pass).
+    the chunk's own lanes (`StagedChunk.passes`). A repeated key next to
+    cascade level bits means None (the in-trace fold needs a single pass).
 
     An engine whose program folds the copies itself (`folds_copies`: the
     mesh's in-trace dedup, the property that makes its `plan` one pass)
@@ -713,18 +711,14 @@ def _assemble_wire_parts(engine, parts, now_ms=None, pad_to=None):
     behind it. Such an engine says how wide its grid is (`wire_pad`: D
     device blocks of c rows), and declines on the parts themselves what the
     lanes cannot tell it: a behavior bit the wire drops as inert that it
-    acts on (`wire_columns_behavior`: GLOBAL rows fork in `prepare_columns`),
-    level bits where it folds cascades on the host, and a ring slot, which
-    is one device's block.
+    acts on (`wire_columns_behavior`: GLOBAL rows fork in `prepare_columns`)
+    and level bits where it folds cascades on the host.
 
     The staging is one call into the native module, GIL-free from the first
     row to the last (ops/wire.stage_wire_chunk): on a loaded host every
     array call queues for the GIL again, and the NumPy staging is 45 to 155
     of them. Where the module is not loaded (`native.load()` is None: no
-    toolchain) `_stage_chunk_numpy` is that staging, byte for byte.
-
-    `pad_to` fixes the padded width (the ring's static slot shape); the
-    default pads to the bucketed dispatch size."""
+    toolchain) `_stage_chunk_numpy` is that staging, byte for byte."""
     if not getattr(engine, "supports_wire_ingress", False):
         return None
     if engine.store is not None or not engine.supports_pipeline:
@@ -735,11 +729,9 @@ def _assemble_wire_parts(engine, parts, now_ms=None, pad_to=None):
     if acts_on and any(_behavior_or(p) & acts_on for p in parts):
         return None
     wire_pad = getattr(engine, "wire_pad", None)
-    if wire_pad is not None and pad_to is not None:
-        return None
     cols_list = [p.cols for p in parts]
     n = sum(c.fp.shape[0] for c in cols_list)
-    if n == 0 or (pad_to is not None and n > pad_to):
+    if n == 0:
         return None
     from gubernator_tpu import native
     from gubernator_tpu.ops import wire as wire_mod
@@ -749,8 +741,8 @@ def _assemble_wire_parts(engine, parts, now_ms=None, pad_to=None):
     tol = engine.created_at_tolerance_ms
     if tol is None:
         tol = created_at_tolerance_ms()
-    pad = pad_to if pad_to is not None else (wire_pad or _pad_size)(n)
-    args = (parts, now, tol, pad, pad_to is not None, engine.max_exact_passes)
+    pad = (wire_pad or _pad_size)(n)
+    args = (parts, now, tol, pad, engine.max_exact_passes)
     keep_copies = bool(getattr(engine, "folds_copies", False))
     mod = native.load()
     with tracing.stage.within("wire_pack"):
@@ -775,16 +767,14 @@ def _behavior_or(part) -> int:
     return int(np.bitwise_or.reduce(part.cols.behavior, initial=0))
 
 
-def _stage_chunk_numpy(
-    parts, now, tol, pad, one_grid, max_exact, keep_copies=False
-):
+def _stage_chunk_numpy(parts, now, tol, pad, max_exact, keep_copies=False):
     """ops/wire.stage_wire_chunk in NumPy, of the same arguments: what a
     host with no toolchain runs, and what the tests hold the native staging
     to byte for byte. None: all-error chunk (the columns path produces it),
-    a first copy's stamp outside the delta budget, a repeated key where one
-    grid is all there is (`one_grid`, or no exact pass for the grid to be),
-    or beside cascade level bits. With `keep_copies` no key is looked for
-    twice: every active row keeps its lane."""
+    a first copy's stamp outside the delta budget, a repeated key where
+    there is no exact pass for the grid to be, or beside cascade level bits.
+    With `keep_copies` no key is looked for twice: every active row keeps
+    its lane."""
     from gubernator_tpu.ops import wire as wire_mod
 
     cols_list = [p.cols for p in parts]
@@ -809,7 +799,7 @@ def _stage_chunk_numpy(
         later = np.nonzero(rank)[0]
     if later is None or later.size == 0:
         later = None
-    elif one_grid or max_exact < 2:
+    elif max_exact < 2:
         # with max_exact 1 the planner aggregates from occurrence 0
         return None
     else:
@@ -916,9 +906,8 @@ def _later_passes(engine, a: _WireAssembly) -> "tuple[list, int]":
 
 
 def _wire_pending(engine, a: _WireAssembly, staged):
-    """PendingCheck over one assembled wire grid (direct or ring slot) —
-    the object both finish halves consume unchanged. Later copies of a key
-    (`chunk.later`, direct path only) are its passes after the grid's."""
+    """PendingCheck over one assembled wire grid. Later copies of a key
+    (`chunk.later`) are its passes after the grid's."""
     c = a.chunk
     lazy = _LazyWireBatch(a.cols_list, a.now, a.tol, a.pad, c.first)
     p = Pass(rows=np.arange(a.n), batch=lazy)
@@ -955,39 +944,6 @@ def prepare_check_wire(engine, parts, now_ms=None) -> "PendingCheck | None":
         return None
     staged = engine.stage_wire(a.chunk.grid, a.chunk.math, cascade=a.chunk.casc)
     return _wire_pending(engine, a, staged)
-
-
-class RingSlotPrep:
-    """One ring slot's prepared dispatch (prep pool, no engine state): the
-    assembled HOST-side wire grid padded to the ring's FIXED slot width —
-    the device slot buffer's static shape — plus the PendingCheck the
-    standard finish half consumes once the fused drain's egress bank is
-    fetched. The grid is staged into the device ring by the engine thread
-    (ops/ring_drain.DeviceRing.stage, serialized with the drain launches),
-    never device_put here; `math`/`cascade` are the static dispatch modes
-    the ring groups consecutive slots by."""
-
-    __slots__ = ("grid", "math", "cascade", "pending")
-
-    def __init__(self, grid, math, cascade, pending):
-        self.grid = grid
-        self.math = math
-        self.cascade = cascade
-        self.pending = pending
-
-
-def prepare_ring_slot(
-    engine, parts, width: int, now_ms=None
-) -> "RingSlotPrep | None":
-    """Ring-slot variant of prepare_check_wire: same gating, same grid
-    assembly, but padded to the ring's fixed `width`. None routes the
-    chunk to the host per-slot path (which pays a launch but is
-    byte-identical) — including chunks wider than the slot."""
-    a = _assemble_wire_parts(engine, parts, now_ms=now_ms, pad_to=width)
-    if a is None:
-        return None
-    pending = _wire_pending(engine, a, None)
-    return RingSlotPrep(a.chunk.grid, a.chunk.math, a.chunk.casc, pending)
 
 
 def prepare_check_columns(engine, cols, now_ms=None) -> PendingCheck:
@@ -1307,9 +1263,9 @@ class LocalEngine:
         # launch was given); `drain_sidecars` empties it
         self._sidecars: list = []
         self.stats = EngineStats()
-        # device passes launched, by padded batch size (_issue_from_dev, the
-        # ring's fused drains): the shapes a resize compiles again, and what
-        # `passes_by_write` counts
+        # device passes launched, by padded batch size (_issue_from_dev):
+        # the shapes a resize compiles again, and what `passes_by_write`
+        # counts
         self._pad_passes: dict = {}
         # reason string when a failed donated launch left device state
         # suspect (see GlobalShardedEngine._requeue_popped); surfaces as

@@ -583,7 +583,7 @@ class StagedChunk(NamedTuple):
 
 
 def stage_wire_chunk(
-    mod, parts, now: int, tol: int, pad: int, one_grid: bool, max_exact: int,
+    mod, parts, now: int, tol: int, pad: int, max_exact: int,
     pad_floor: int, keep_copies: bool = False,
 ) -> "StagedChunk | None":
     """`StagedChunk` of the WireBatch pieces `parts` from ONE call into the
@@ -596,7 +596,7 @@ def stage_wire_chunk(
     keeps its lane in the grid and no pass follows it."""
     out = mod.stage_wire_chunk(
         [(p.lanes, p.cols.fp, p.cols.err, p.cols.created_at) for p in parts],
-        now, tol, pad, one_grid, max_exact, pad_floor, keep_copies,
+        now, tol, pad, max_exact, pad_floor, keep_copies,
     )
     if out is None:
         return None

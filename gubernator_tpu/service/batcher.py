@@ -182,7 +182,6 @@ class Batcher:
         close_rows: int = 0,
         close_bytes: int = 1 << 20,
         max_queue_rows: int = 0,
-        ring=None,
         overload_deadline_ms: float = 0.0,
         overload_deadline_auto: bool = False,
         tenant_share: float = 0.5,
@@ -190,11 +189,6 @@ class Batcher:
         shed_retry_ms: int = 25,
     ):
         self.runner = runner
-        # device-resident request ring (service/ring.py): when armed,
-        # all-wire chunks are staged into ring slots and consumed by the
-        # persistent serving loop instead of paying a fresh dispatch
-        # round-trip per flush; None = the direct path
-        self.ring = ring
         self.batch_wait_s = batch_wait_ms / 1e3
         self.coalesce_limit = coalesce_limit
         self.metrics = metrics
@@ -262,7 +256,6 @@ class Batcher:
         # fused dispatches that carried at least one follow-on pass: the
         # later copies of a key sent more than once in the chunk
         self.split_dispatches = 0
-        self.ring_dispatches = 0  # all-wire chunk staged into the ring
         # entries answered with the bytes their dispatch's encode link wrote
         self.encoded_requests = 0
         # entries made from the parser's summary alone, no column read
@@ -753,7 +746,7 @@ class Batcher:
         t_answered = t0
         payloads = [e.payload for e in batch]
         wire = all(isinstance(p, WireBatch) for p in payloads)
-        answered = ringed = False
+        answered = False
         n_encoded = sum(e.encoded for e in batch)
         bodies = None  # per entry, what `encode` wrote for it
 
@@ -783,14 +776,6 @@ class Batcher:
                 return
             answered = True
             disp.tail = None  # it holds `disp`: no cycle is left to the collector
-            on_worker = bodies is not None
-            if exc is None and n_encoded and not on_worker:
-                # the chunk came back as columns with no link run behind
-                # them (the ring's fused drain): encoded here, on the loop
-                try:
-                    encode(rc)
-                except Exception as e:
-                    exc = e
             self._inflight -= 1
             self._note_drained(sum(e.cost for e in batch))
             if self._full is not None:
@@ -803,9 +788,7 @@ class Batcher:
                         e.fut.set_exception(exc)
                 t_answered = self._observe_dispatch(t0, disp)
                 return
-            if ringed:
-                self.ring_dispatches += 1
-            elif fused:
+            if fused:
                 self.fused_dispatches += 1
                 self.split_dispatches += fused > 1
             else:
@@ -845,7 +828,7 @@ class Batcher:
                     pass
                 elif e.encoded:
                     e.fut.set_result(bodies[i])
-                    self.encoded_requests += on_worker
+                    self.encoded_requests += 1
                 else:
                     sl = slice(off, off + e.rows)
                     e.fut.set_result(
@@ -867,21 +850,6 @@ class Batcher:
             # batch, the distribution deadlines cut into
             for e in batch:
                 tracing.observe("queue_wait", self.metrics, t0 - e.t_enq)
-            if wire and self.ring is not None:
-                # ring path: stage the chunk into a request-ring slot; the
-                # persistent serving loop consumes it in ticket order
-                # through the SAME runner surface (byte-identical
-                # responses). A ring racing drain falls through to the
-                # direct path below — zero loss.
-                from gubernator_tpu.service.ring import RingClosed
-
-                try:
-                    rc = await self.ring.submit(payloads, disp=disp)
-                    ringed = True
-                    answer(rc, None, 1)
-                    return t_answered
-                except RingClosed:
-                    pass
             if wire:
                 # fused path: pre-packed parser lanes scatter straight into
                 # one staged compact grid (ops/engine.prepare_check_wire) —
@@ -924,8 +892,6 @@ class Batcher:
             "column_dispatches": self.column_dispatches,
             "wire_fallbacks": self.wire_fallbacks,
             "split_dispatches": self.split_dispatches,
-            "ring_dispatches": self.ring_dispatches,
-            "ring": self.ring.debug() if self.ring is not None else None,
             "adaptive_closes": self.adaptive_closes,
             "window_expires": self.window_expires,
             "close_reasons": dict(self.close_reasons),

@@ -153,29 +153,6 @@ class Daemon:
             metrics=self.metrics,
             fetch_workers=conf.behaviors.pipeline_inflight,
         )
-        # device-resident request ring (service/ring.py; docs/latency.md
-        # "Dispatch budget"): when armed, all-wire flushes stage into ring
-        # slots and the persistent serving loop consumes them in ticket
-        # order — the CPU build runs the functional emulation of the
-        # device ring protocol over the same runner surface
-        self.ring = None
-        if conf.behaviors.ring_enable:
-            from gubernator_tpu.ops.ring_drain import default_ring_issue
-            from gubernator_tpu.service.ring import RequestRing
-
-            ring_issue = conf.behaviors.ring_issue
-            if ring_issue == "auto":
-                # fused drain on real TPU, host issue loop on CPU builds
-                # (docs/latency.md "Launch budget")
-                ring_issue = default_ring_issue()
-            self.ring = RequestRing(
-                self.runner,
-                slots=conf.behaviors.ring_slots,
-                metrics=self.metrics,
-                issue_mode=ring_issue,
-                drain_k=conf.behaviors.ring_drain_k,
-                slot_width=conf.behaviors.ring_slot_width,
-            )
         self.batcher = Batcher(
             self.runner,
             batch_wait_ms=conf.behaviors.batch_wait_ms,
@@ -187,7 +164,6 @@ class Daemon:
             close_rows=conf.behaviors.batch_close_rows,
             close_bytes=conf.behaviors.batch_close_bytes,
             max_queue_rows=conf.behaviors.batch_queue_rows,
-            ring=self.ring,
             overload_deadline_ms=conf.behaviors.overload_deadline_ms,
             overload_deadline_auto=conf.behaviors.overload_deadline_auto,
             tenant_share=conf.behaviors.overload_tenant_share,
@@ -1886,8 +1862,7 @@ class Daemon:
             # thread with no per-row work (all rows valid, local and free
             # of GLOBAL/MULTI_REGION: _serve_plain), how many of those
             # were answered with bytes that their dispatch's encode link
-            # wrote on a worker thread (all but the shed, and the chunks
-            # of the request ring's fused drain), and how many were
+            # wrote on a worker thread (all but the shed), and how many were
             # enqueued from the parser's summary alone, no column read and
             # no array call on the loop thread (Batcher.check)
             "daemon": {
@@ -1967,8 +1942,7 @@ class Daemon:
                 "later_lane_rows": eng.stats.later_lane_rows,
                 # fused dispatches whose host staging was the one native
                 # call (ops/wire.stage_wire_chunk): all of
-                # batcher.fused_dispatches where the module is loaded (and
-                # the request ring's fused slots, where that is on)
+                # batcher.fused_dispatches where the module is loaded
                 "native_staged": eng.stats.native_staged,
                 # buckets a dirty block of the incremental checkpoint's
                 # tracker holds: there when the plane is armed and the
@@ -2303,11 +2277,6 @@ class Daemon:
         await self.global_manager.close()  # flushes pending GLOBAL queues
         await self.region_manager.close()
         await self.batcher.drain()
-        if self.ring is not None:
-            # after the batcher: its drain flushes pending chunks THROUGH
-            # the ring; only then can the ring retire every published
-            # ticket and park the serving loop (zero-loss ordering)
-            await self.ring.drain()
         if drain and self.conf.behaviors.handoff_enabled:
             # hand owned live rows to ring successors under the deadline;
             # whatever stays unacked is snapshotted by maybe_checkpoint below

@@ -270,29 +270,10 @@ class DaemonMetrics:
         self.dispatch_launches = Counter(
             # renders as gubernator_tpu_dispatch_launches_total
             "gubernator_tpu_dispatch_launches",
-            "Decision-kernel launches by feed path: ring = per-slot "
-            "dispatches from the request ring's host issue loop "
-            "(service/ring.py), fused = multi-slot drain launches that "
-            "retire up to GUBER_RING_DRAIN_K published slots each "
-            "(ops/ring_drain.py), xla = the direct per-flush dispatch "
-            "round-trip (docs/latency.md 'Launch budget')",
-            ["path"],  # ring | fused | xla
+            "Decision-kernel launches by feed path: xla = the per-flush "
+            "dispatch, the one path there is",
+            ["path"],  # xla
             registry=r,
-        )
-        self.ring_occupancy = Gauge(
-            "gubernator_tpu_ring_occupancy",
-            "Request-ring slots published but not yet consumed — bounded "
-            "by GUBER_RING_SLOTS; sustained saturation means submitters "
-            "are in backpressure and the serving loop is the bottleneck",
-            registry=r,
-        )
-        self.ring_drain_slots = Histogram(
-            "gubernator_tpu_ring_drain_slots",
-            "Published ring slots retired per fused drain launch — "
-            "_sum/_count is the scrapeable launch-amortization factor "
-            "(slots/launch; docs/latency.md 'Launch budget')",
-            registry=r,
-            buckets=(1, 2, 4, 8, 16, 32, 64),
         )
         self.stage_duration = Histogram(
             "gubernator_tpu_stage_duration",
@@ -307,10 +288,6 @@ class DaemonMetrics:
             # egress; docs/latency.md "wire budget"), and later_stage
             # inside put on the local engine's fused path (a chunk's later
             # copies of a key staged as column passes, ops/engine.py).
-            # The request-ring plane adds ring_put (submit-side slot claim
-            # + payload staging + ingress-fence publish) and ring_poll
-            # (the egress-fence wait for the coalesced response) —
-            # service/ring.py, docs/latency.md "Dispatch budget".
             # A HISTOGRAM (was a Summary) so per-stage TAILS are scrapeable:
             # _sum/_count keep the same series names the e2e bench means
             # used, and the buckets let later records report per-stage p99 —

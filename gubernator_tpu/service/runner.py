@@ -182,7 +182,7 @@ class EngineRunner:
 
     async def check(
         self, cols, now_ms: Optional[int] = None, disp=None,
-        launch_path: str = "xla", done=None, counted=None,
+        done=None, counted=None,
     ) -> ResponseColumns:
         """Pipelined check when the engine supports the prepare/issue/finish
         split, else the serial path. `cols` is one RequestColumns or a list
@@ -214,18 +214,17 @@ class EngineRunner:
             or getattr(self.engine, "store", None) is not None
         ):
             return await self.check_columns(
-                concat_columns(parts), now_ms=now_ms, launch_path=launch_path,
+                concat_columns(parts), now_ms=now_ms,
                 done=done, disp=disp, counted=counted,
             )
         return await self._run_chain(
             ((self._prep, lambda _: self._stage_columns(parts, now_ms, disp)),
-             *self._issue_and_finish(disp, launch_path)),
+             *self._issue_and_finish(disp)),
             counted or parts, done,
         )
 
     async def check_wire(
-        self, parts, now_ms=None, disp=None, launch_path: str = "xla",
-        done=None,
+        self, parts, now_ms=None, disp=None, done=None,
     ) -> ResponseColumns:
         """Fused front-door check: pre-parsed WireBatch pieces
         (service/wire.py — native-parser lanes) staged straight into ONE
@@ -249,8 +248,7 @@ class EngineRunner:
             or getattr(engine, "store", None) is not None
         ):
             return await self.check(
-                cols, now_ms=now_ms, disp=disp, launch_path=launch_path,
-                done=done, counted=parts,
+                cols, now_ms=now_ms, disp=disp, done=done, counted=parts,
             )
         from gubernator_tpu.ops.engine import prepare_check_wire
 
@@ -275,7 +273,7 @@ class EngineRunner:
 
         return await self._run_chain(
             ((self._prep, prepare),
-             *self._issue_and_finish(disp, launch_path)),
+             *self._issue_and_finish(disp)),
             parts, done, lambda: fused,
         )
 
@@ -284,7 +282,7 @@ class EngineRunner:
             dt if self.issue_ewma == 0.0 else 0.9 * self.issue_ewma + 0.1 * dt
         )
 
-    def _issue_and_finish(self, disp=None, launch_path: str = "xla"):
+    def _issue_and_finish(self, disp=None):
         """The issue and finish links of a pipelined dispatch (`_run_chain`
         runs them after the prepare link): ISSUE on the engine thread
         (enqueue kernel launches, no fetch), FINISH on a fetch worker
@@ -300,10 +298,7 @@ class EngineRunner:
                 pending = issue_check_columns(self.engine, prepared)
             self._note_issue(st.dt)
             if self.metrics is not None:
-                # feed-path accounting (docs/latency.md "Dispatch budget"):
-                # ring = launched from the device-resident request ring's
-                # serving loop, xla = the direct per-flush round-trip
-                self.metrics.dispatch_launches.labels(path=launch_path).inc()
+                self.metrics.dispatch_launches.labels(path="xla").inc()
             return pending
 
         def fixup(fn):
@@ -320,14 +315,6 @@ class EngineRunner:
             return rc if disp is None or disp.tail is None else disp.tail(rc)
 
         return (self._exec, issue), (self._fetch, finish)
-
-    # ------------------------------------------------- fused ring drain
-    # (ops/ring_drain.py) — the multi-slot twin of _issue_and_finish: one
-    # ENGINE-THREAD launch decides a whole group of published ring slots,
-    # one FETCH-THREAD materialization decodes every slot's egress bank.
-    # Split into two awaitables (not one) so the ring's consume loop can
-    # serialize LAUNCH order across groups while group j's finish overlaps
-    # group j+1's issue — the same pipelining shape the host issue loop has.
 
     def _apply(self, deltas, disp=None) -> None:
         """Engine-thread tail of a dispatch: fold its stats deltas into the
@@ -351,95 +338,6 @@ class EngineRunner:
                 gs = getattr(self.engine, "global_stats", None)
                 if gs is not None:
                     self.metrics.observe_global(gs)
-
-    async def drain_ring_issue(self, dring, group, start: int, disp=None):
-        """ENGINE-THREAD half of one fused drain: per-slot issue-time work
-        (shadow promote for the group head, checkpoint marks) in ticket
-        order, stage each slot's grid + ingress fence into the device ring,
-        then ONE `drain_ring` launch over the whole group. Returns the
-        un-fetched (bank, drained) device handles."""
-        loop = asyncio.get_running_loop()
-
-        def issue():
-            with tracing.stage("issue", self.metrics, disp=disp) as st:
-                bank, n = launch()
-            self._note_issue(st.dt)
-            if self.metrics is not None:
-                self.metrics.dispatch_launches.labels(path="fused").inc()
-                self.metrics.ring_drain_slots.observe(len(group))
-            return bank, n
-
-        def launch():
-            from gubernator_tpu.ops.engine import promote_rows
-
-            engine = self.engine
-            for prep in group:
-                pending = prep.pending
-                if pending.promote is not None:
-                    # shadow fault-back through the conservative merge
-                    # BEFORE the drain launch — grouping guarantees only
-                    # the HEAD slot carries a promote, so merge→decide
-                    # order matches the per-slot path exactly
-                    _, pending.promote_putback = promote_rows(
-                        engine, pending.promote, pending.now
-                    )
-                    pending.promote = None
-                if (
-                    pending.mark is not None
-                    and getattr(engine, "ckpt", None) is not None
-                ):
-                    engine.ckpt.mark(pending.mark)
-            head = group[0]
-            if engine._batch_needs_full(head.math):
-                engine.migrate_layout_full()
-            # a fused drain decides one slot-wide pass a slot
-            engine._pad_passes[dring.width] = (
-                engine._pad_passes.get(dring.width, 0) + len(group)
-            )
-            for i, prep in enumerate(group):
-                dring.stage((start + i) % dring.slots, prep.grid, start + i)
-            return dring.drain(
-                engine, start, len(group), head.math, head.cascade
-            )
-
-        return await loop.run_in_executor(self._exec, issue)
-
-    async def drain_ring_finish(self, group, bank, n, disp=None):
-        """FETCH-THREAD half of one fused drain: ONE bank fetch covers the
-        whole group; each slot's PendingCheck then runs the standard
-        finish (dropped-claim retries and shadow rehydrates via the engine
-        thread, evictee harvest, cascade folds) over its egress slice.
-        Returns the per-slot responses in ticket order."""
-        loop = asyncio.get_running_loop()
-
-        def fixup(fn):
-            return self._exec.submit(fn).result()
-
-        def finish():
-            with tracing.stage("fetch", self.metrics, disp=disp):
-                done = fetch()
-            # fire-and-forget, engine thread
-            self._exec.submit(self._apply, [delta for _rc, delta in done], disp)
-            return [rc for rc, _delta in done]
-
-        def fetch():
-            from gubernator_tpu.ops.engine import finish_check_columns
-
-            fetched = np.asarray(bank)
-            drained = int(n)
-            if drained != len(group):
-                raise RuntimeError(
-                    f"ring drain fence violation: group of {len(group)} "
-                    f"published slots, device retired {drained}"
-                )
-            done = []
-            for i, prep in enumerate(group):
-                pending = prep.pending
-                pending.passes[0][3] = fetched[i]
-                done.append(finish_check_columns(self.engine, pending, fixup))
-            return done
-
-        return await loop.run_in_executor(self._fetch, finish)
 
     def _observe_shard_stages(self) -> None:
         """Fold what the mesh engine counted since the last call into the
@@ -465,7 +363,7 @@ class EngineRunner:
 
     async def check_columns(
         self, cols: RequestColumns, now_ms: Optional[int] = None,
-        launch_path: str = "xla", done=None, disp=None, counted=None,
+        done=None, disp=None, counted=None,
     ) -> ResponseColumns:
         """The serial path: the whole check, and the dispatch's `tail`, is one
         engine-thread job, a chain of one link. `done` and `counted` as in
@@ -474,7 +372,7 @@ class EngineRunner:
         def run(_):
             rc = self.engine.check_columns(cols, now_ms=now_ms)
             if self.metrics is not None:
-                self.metrics.dispatch_launches.labels(path=launch_path).inc()
+                self.metrics.dispatch_launches.labels(path="xla").inc()
                 self._observe_shard_stages()
                 self.metrics.observe_engine(self.engine.stats)
                 gs = getattr(self.engine, "global_stats", None)

@@ -43,8 +43,10 @@ def async_test(fn):
 
 
 def req(tag: str, i: int, **kw) -> "pb.RateLimitReq":
+    # an hour: NOW is as old as this module's import, and a bucket must not
+    # have expired by the wall clock when the tier's sweep looks at it
     d = dict(name="de", unique_key=f"{tag}{i}", hits=2, limit=3,
-             duration=60_000, created_at=NOW)
+             duration=3_600_000, created_at=NOW)
     d.update(kw)
     return pb.RateLimitReq(**d)
 
@@ -54,11 +56,17 @@ def body(items) -> bytes:
 
 
 def _engine(kind: str):
-    if kind == "sharded1":  # the mesh engine over one device
+    if kind.startswith("sharded"):
         from gubernator_tpu.parallel import make_mesh
         from gubernator_tpu.parallel.global_sync import GlobalShardedEngine
 
-        return GlobalShardedEngine(make_mesh(1), capacity_per_shard=8192)
+        if kind == "sharded1":  # the mesh engine over one device
+            return GlobalShardedEngine(make_mesh(1), capacity_per_shard=8192)
+        # four devices as a TPU resolves them (cell 3): it takes the lanes
+        return GlobalShardedEngine(
+            make_mesh(4), capacity_per_shard=8192, route="device",
+            dedup="device", wire="compact",
+        )
     if kind == "store":  # a Store: the runner's serial path, one link
         from gubernator_tpu.store import RecordingStore
 
@@ -66,15 +74,30 @@ def _engine(kind: str):
     return LocalEngine(capacity=8192, wire="compact")
 
 
-async def _spawn(kind: str = "local", **behaviors) -> Daemon:
-    """A daemon whose batch window stays open for WINDOW_S whatever the
-    engine does, so that RPCs sent together are one chunk."""
+def _conf(kind: str = "local", tmp_path=None, **behaviors):
+    """The configuration of a daemon whose batch window stays open for
+    WINDOW_S whatever the engine does, so that RPCs sent together are one
+    chunk. `tiered` arms the tiering plane (a shadow behind the table: cell
+    7) and `durable` the checkpoint plane (every dispatch marks its blocks:
+    cell 5)."""
     conf = daemon_config(http_address="")
     conf.behaviors = BehaviorConfig(
         batch_wait_ms=WINDOW_S * 1e3, adaptive_batch=False,
         batch_timeout_ms=5000.0, **behaviors,
     )
-    return await Daemon.spawn(conf, engine=_engine(kind))
+    if kind == "tiered":
+        conf.tier_enabled, conf.tier_idle_ms = True, 1.0
+        conf.tier_shadow_bytes = 1 << 20
+    if kind == "durable":
+        conf.checkpoint_path = str(tmp_path / "base.npz")
+        conf.checkpoint_interval_ms = 25.0
+    return conf
+
+
+async def _spawn(kind: str = "local", tmp_path=None, **behaviors) -> Daemon:
+    return await Daemon.spawn(
+        _conf(kind, tmp_path, **behaviors), engine=_engine(kind)
+    )
 
 
 async def _over_limit_count(d) -> float:
@@ -100,19 +123,25 @@ def _round():
 
 
 @pytest.mark.parametrize(
-    "kind", ["local", "sharded1", "store", "ring_host", "ring_fused"]
+    "kind", ["local", "sharded1", "store", "sharded4", "tiered", "durable"]
 )
 @async_test
-async def test_mixed_chunk_answers_as_the_pb_path(kind, monkeypatch):
+async def test_mixed_chunk_answers_as_the_pb_path(kind, monkeypatch, tmp_path):
+    """Whatever engine a cell runs behind the one dispatch protocol. The
+    reference daemon has neither plane armed: both are exact."""
     monkeypatch.setattr(batcher_mod, "ms_now", lambda: NOW + 9)
-    ring = (
-        dict(ring_enable=True, ring_issue=kind.split("_")[1])
-        if kind.startswith("ring") else {}
-    )
-    d = await _spawn(kind, **ring)
+    d = await _spawn(kind, tmp_path)
     d_pb = await Daemon.spawn(daemon_config(http_address=""), engine=_engine(kind))
     try:
+        assert d.engine.supports_wire_ingress == (kind != "sharded1")
         for rnd in range(2):
+            if rnd and kind == "tiered":
+                # round 1's rows, idle for a millisecond, go to the shadow
+                # (by this sweep, or by the daemon's own if it came first):
+                # round 2 brings every key back ahead of its launch
+                await asyncio.sleep(0.005)
+                await d.tier.sweep_once()
+                assert d.tier.pipeline()["demoted_idle"] == 10
             n0 = d.batcher.dispatches
             got = await asyncio.gather(
                 *(d.get_rate_limits_raw(body(items)) for items in _round())
@@ -124,11 +153,14 @@ async def test_mixed_chunk_answers_as_the_pb_path(kind, monkeypatch):
                 assert [_fields(r) for r in answers] == [_fields(r) for r in want]
                 if rnd and len(items) != 3:  # a plain RPC, refused
                     assert {r.status for r in answers} == {pb.OVER_LIMIT}
-                    assert answers[0].metadata["retry_after_ms"] == str(60_000 - 9)
+                    assert answers[0].metadata["retry_after_ms"] == str(3_600_000 - 9)
         assert (d.raw_rpcs, d.plain_rpcs) == (6, 4)
-        # the fused drain hands columns back with no link behind them
-        encoded = 0 if kind == "ring_fused" else 4
-        assert d.debug_pipeline()["daemon"]["dispatch_encoded_rpcs"] == encoded
+        pipe = d.debug_pipeline()
+        assert pipe["daemon"]["dispatch_encoded_rpcs"] == 4
+        if kind == "tiered":
+            assert pipe["tier"]["promoted"] == 10 and pipe["tier"]["lost"] == 0
+        if kind == "durable":
+            assert pipe["engine"]["ckpt_blk"] and pipe["checkpoint"] is not None
         over = await _over_limit_count(d)
         assert over == await _over_limit_count(d_pb) and over > 0
     finally:

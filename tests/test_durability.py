@@ -494,9 +494,16 @@ async def test_daemon_checkpoint_loop_and_debug(tmp_path):
                     duration=3_600_000,
                 )
             ])
+        # what the assertions below state: every one of the four rows is in
+        # a frame on disk. An epoch is taken under the loop's lock, so the
+        # second one to become durable from here was taken after the fourth
+        # answer (the first may have been in flight when it came)
+        cp = d.checkpointer
+        answered_at = cp.last_epoch
         await wait_for(
-            lambda: asyncio.sleep(0, d.checkpointer.last_epoch > 0
-                                  and d.checkpointer._log.size_bytes() > 8)
+            lambda: asyncio.sleep(0, cp.last_epoch >= answered_at + 2
+                                  and cp.rows >= 4
+                                  and cp._log.size_bytes() > 8)
         )
         scraped = await scrape(d)
         assert metric_value(

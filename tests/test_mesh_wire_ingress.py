@@ -136,7 +136,7 @@ def test_the_numpy_twin_is_the_native_call_with_every_copy_kept():
     for rows in ([[1, 2, 1, 7, 1]], [[7] * 9, [EMPTY_KEY, 7, 8]],
                  [[(1, 200, 0), (1, -200, 0), 2], [(3, 9_000, 0)]]):
         parts = [rpc(r, STAGE_NOW) for r in rows]
-        args = (parts, STAGE_NOW, 300, 64, False, 8)
+        args = (parts, STAGE_NOW, 300, 64, 8)
         got = wire_mod.stage_wire_chunk(native.load(), *args, 16, True)
         want = engine_mod._stage_chunk_numpy(*args, True)
         _same_staging(got, want)
@@ -339,15 +339,6 @@ async def test_an_engine_that_is_not_wire_capable_takes_columns(mesh, how):
         assert "put_miss" not in _stage_sums(metrics)
     finally:
         close(*runners)
-
-
-def test_a_ring_slot_is_not_a_mesh_grid(mesh):
-    """A ring slot is one device's block of a fixed width: the mesh's D
-    blocks do not fit one, so the ring's prep hands the chunk to the host
-    path as it did before the mesh was wire-capable."""
-    now = ms_now()
-    eng = new_engine(mesh)
-    assert engine_mod.prepare_ring_slot(eng, [rpc([1, 2], now)], 64, now_ms=now) is None
 
 
 # ------------------------------------------------------------- (d) overflow

@@ -373,9 +373,9 @@ STAGINGS = {
     "every_row_an_error": ([[EMPTY_KEY, EMPTY_NAME], [EMPTY_KEY]], {}),
     "cascade_bits_and_no_repeat": ([[1, 2, 3]], {"edit": _level_bit}),
     "cascade_bits_beside_a_repeat": ([[1, 2], [3, 1]], {"edit": _level_bit}),
-    "a_ring_slot_and_no_repeat": ([[1, 2], [3]], {"pad_to": 64}),
-    "a_ring_slot_and_a_repeat": ([[1, 2], [3, 1]], {"pad_to": 64}),
-    "a_ring_slot_filled_to_the_last_row": ([[1, 2], [3, 4]], {"pad_to": 4}),
+    "a_chunk_that_fills_its_pad_to_the_last_row": (
+        [list(range(1, 9)), list(range(9, 17))], {},
+    ),
     "no_exact_pass_for_the_grid": ([[1, 1]], {"max_exact": 1}),
     "every_later_copy_in_the_aggregate": ([[7, 8, 7, 7], [8, 9]], {"max_exact": 2}),
     "one_exact_pass_then_the_aggregate": ([[7] * 5, [8, 7, 8]], {"max_exact": 3}),
@@ -423,14 +423,14 @@ def _same_staging(got, want):
             _same_bytes(getattr(g, field), getattr(w, field), (i, field))
 
 
-def _stage_both(parts, tol=5_000, pad_to=None, max_exact=8, edit=None):
+def _stage_both(parts, tol=5_000, max_exact=8, edit=None):
     from gubernator_tpu.ops import engine, wire
 
     parts = [rpc(p, STAGE_NOW) for p in parts]
     if edit is not None:
         parts = edit(parts)
-    pad = pad_to or engine._pad_size(sum(p.rows for p in parts))
-    args = (parts, STAGE_NOW, tol, pad, pad_to is not None, max_exact)
+    pad = engine._pad_size(sum(p.rows for p in parts))
+    args = (parts, STAGE_NOW, tol, pad, max_exact)
     return (
         wire.stage_wire_chunk(m, *args, engine._pad_size(0)),
         engine._stage_chunk_numpy(*args),
@@ -445,14 +445,15 @@ def test_stage_wire_chunk_is_the_numpy_staging_byte_for_byte(case):
     # what each case is there for
     refused = case in (
         "a_first_copy_outside_the_budget", "every_row_an_error",
-        "cascade_bits_beside_a_repeat", "a_ring_slot_and_a_repeat",
-        "no_exact_pass_for_the_grid",
+        "cascade_bits_beside_a_repeat", "no_exact_pass_for_the_grid",
     )
     assert (want is None) == refused
     if refused:
         return
     n = sum(len(p) for p in parts) if "edit" not in how else want.first.size
-    assert want.grid.shape == (5, (how.get("pad_to") or max(16, 1 << (n - 1).bit_length())) + 1)
+    assert want.grid.shape == (5, max(16, 1 << (n - 1).bit_length()) + 1)
+    if case == "a_chunk_that_fills_its_pad_to_the_last_row":
+        assert n == 16 and want.grid.shape == (5, 17) and want.first.all()
     assert want.later == sum(p.rows.size if p.members is None else p.members.size for p in want.passes)
     assert not want.grid[:, :n][:, ~want.first].any()
     off_lanes = {
@@ -497,10 +498,10 @@ def test_stage_wire_chunk_refuses_what_it_cannot_read():
         (good[:3], TypeError),
     ):
         with pytest.raises(exc):
-            m.stage_wire_chunk([bad], STAGE_NOW, 5_000, 16, False, 8, 16)
+            m.stage_wire_chunk([bad], STAGE_NOW, 5_000, 16, 8, 16)
     with pytest.raises(ValueError):  # a pad below the rows
-        m.stage_wire_chunk([good], STAGE_NOW, 5_000, 2, False, 8, 16)
-    assert wire.stage_wire_chunk(m, [wb], STAGE_NOW, 5_000, 16, False, 8, 16) is not None
+        m.stage_wire_chunk([good], STAGE_NOW, 5_000, 2, 8, 16)
+    assert wire.stage_wire_chunk(m, [wb], STAGE_NOW, 5_000, 16, 8, 16) is not None
 
 
 def test_stage_wire_chunk_holds_no_state_between_threads():
@@ -514,7 +515,7 @@ def test_stage_wire_chunk_holds_no_state_between_threads():
     chunks = []
     for seed in range(240):
         rows = _zipf_chunk(seed, rpcs=2, rows=150, keys=40, late=0.01)
-        args = ([rpc(p, STAGE_NOW) for p in rows], STAGE_NOW, 5_000, 512, False, 8)
+        args = ([rpc(p, STAGE_NOW) for p in rows], STAGE_NOW, 5_000, 512, 8)
         chunks.append((args, engine._stage_chunk_numpy(*args)))
     assert sum(len(want.passes) == 7 for _a, want in chunks) > 100
 

@@ -104,6 +104,10 @@ async def _spawn(window_ms: float = 0.0):
     """`window_ms`: a fixed batch window in place of the adaptive one, so
     that RPCs sent together are one chunk whatever the host's load."""
     conf = daemon_config(http_address="")
+    # the owner's async GLOBAL update decides its rows again and counts an
+    # OVER_LIMIT one a second time (force_global): not while a test reads
+    # the counter, 50 ms after the hits were queued
+    conf.behaviors.global_sync_wait_ms = 60_000.0
     if window_ms:
         conf.behaviors.adaptive_batch = False
         conf.behaviors.batch_wait_ms = window_ms

@@ -71,8 +71,26 @@ def wire_batch(keys, now, tag="rc", gregorian=()):
     return wb
 
 
-def new_runner(metrics=None):
-    return EngineRunner(LocalEngine(capacity=4096, wire="compact"), metrics)
+def new_runner(metrics=None, kind="local"):
+    """A runner over the engine a cell runs: `mesh` is four devices as a TPU
+    resolves them (cell 3: it takes the parser's lanes and folds the copies
+    of a key in its program), `tiered` a table with a shadow behind it
+    (cell 7)."""
+    if kind == "mesh":
+        from gubernator_tpu.parallel import ShardedEngine, make_mesh
+
+        eng = ShardedEngine(
+            make_mesh(4), capacity_per_shard=4096, route="device",
+            dedup="device", wire="compact",
+        )
+        assert eng.supports_wire_ingress and eng.folds_copies
+        return EngineRunner(eng, metrics)
+    eng = LocalEngine(capacity=4096, wire="compact")
+    if kind == "tiered":
+        from gubernator_tpu.tier import ShadowTable
+
+        eng.attach_shadow(ShadowTable(max_bytes=1 << 22))
+    return EngineRunner(eng, metrics)
 
 
 async def dispatch(runner, path, wb, now, **kw):
@@ -88,19 +106,19 @@ def assert_same(a: ResponseColumns, b: ResponseColumns):
 
 
 @pytest.mark.parametrize(
-    "path", ["wire", "wire_split", "columns", "wire_miss", "serial"]
+    "path", ["wire", "wire_split", "columns", "wire_miss", "serial", "mesh_wire"]
 )
 @async_test
 async def test_a_dispatch_is_one_loop_trip(path):
     """Whatever staging serves it, a dispatch's completion is the one
     callback the loop runs for it, and `done` hears which staging it was
     (the passes the fused one issued, 0 for columns) before the awaiting
-    coroutine goes on."""
+    coroutine goes on. A mesh keeps a repeated key in its one grid."""
     now = ms_now()
-    runner = new_runner()
+    runner = new_runner(kind="mesh" if path == "mesh_wire" else "local")
     order = []
     try:
-        keys = [1, 2, 2, 3] if path == "wire_split" else list(range(8))
+        keys = [1, 2, 2, 3] if path in ("wire_split", "mesh_wire") else list(range(8))
         wb = wire_batch(keys, now, gregorian=[3] if path == "wire_miss" else ())
 
         def done(rc, exc, fused):
@@ -116,7 +134,7 @@ async def test_a_dispatch_is_one_loop_trip(path):
             )
         order.append(("resumed", None, None))
         assert runner.loop_trips - before == 1
-        fused = {"wire": 1, "wire_split": 2}.get(path, 0)
+        fused = {"wire": 1, "wire_split": 2, "mesh_wire": 1}.get(path, 0)
         assert order == [("done", None, fused), ("resumed", None, None)]
         assert rc.status.shape == (len(keys),)
         assert sum(runner.algo_counts.values()) == len(keys)
@@ -161,7 +179,7 @@ async def test_each_stage_runs_on_its_own_pool(path, monkeypatch):
     assert seen["done"] == {threading.current_thread().name}, seen
 
 
-@pytest.mark.parametrize("path", ["wire", "columns"])
+@pytest.mark.parametrize("path", ["wire", "columns", "mesh_wire"])
 @pytest.mark.parametrize("stage", STAGES)
 @async_test
 async def test_an_exception_in_any_link_reaches_the_caller(stage, path, monkeypatch):
@@ -169,9 +187,9 @@ async def test_an_exception_in_any_link_reaches_the_caller(stage, path, monkeypa
     the dispatch's crossing back; the batcher's slot is freed and the next
     dispatch is served."""
     now = ms_now()
-    runner = new_runner()
+    runner = new_runner(kind="mesh" if path == "mesh_wire" else "local")
     b = Batcher(runner, batch_wait_ms=0.5, workers=2)
-    payload = (lambda wb: wb) if path == "wire" else (lambda wb: wb.cols)
+    payload = (lambda wb: wb.cols) if path == "columns" else (lambda wb: wb)
     try:
         with monkeypatch.context() as m:
             for name in STAGE_FNS[stage]:
@@ -272,25 +290,40 @@ async def test_a_wire_miss_is_restaged_where_it_was_found(chunk):
         r_cols.close()
 
 
-@pytest.mark.parametrize("path", ["fused", "split", "miss", "columns"])
+@pytest.mark.parametrize(
+    "path", ["fused", "split", "miss", "columns", "mesh", "tiered"]
+)
 @async_test
 async def test_dispatch_is_its_stages_plus_its_self_time(path):
     """`dispatch` = put + put_miss + issue + fetch + `dispatch_wait`, to the
     float: every stage of the chain is timed under the dispatch, on the
-    thread that runs it, and the batcher states the rest."""
+    thread that runs it, and the batcher states the rest. So on a mesh (its
+    shard stages are parts of `put` and `fetch`), and with a shadow behind
+    the table when every key of the chunk comes back from it ahead of the
+    launch (the probe is part of `put`, the merge of `issue`)."""
     now = ms_now()
     metrics = DaemonMetrics()
-    runner = new_runner(metrics)
+    runner = new_runner(metrics, path if path in ("mesh", "tiered") else "local")
     b = Batcher(runner, batch_wait_ms=0.5, workers=1, metrics=metrics)
+    shadow = getattr(runner.engine, "shadow", None)
     try:
         for i in range(5):
+            keys = range(8) if path == "tiered" else range(8 * i, 8 * i + 8)
             wb = wire_batch(
-                [7, 7, 8] if path == "split" else range(8 * i, 8 * i + 8), now,
+                [7, 7, 8] if path == "split" else keys, now,
                 gregorian=[8 * i] if path == "miss" else (),
             )
+            if path == "tiered" and i:  # the rows of the dispatch before
+                _now, fps, _rows = await runner.tier_demote_idle(
+                    1, now_ms=now + 10,
+                    sink=lambda f, r, t: shadow.offer(f, r, t, reason="idle"),
+                )
+                assert fps.size == 8 and shadow.contains(wb.cols.fp).all()
             s0 = _stage_sums(metrics)
-            await b.check(wb.cols if path == "columns" else wb, now_ms=now)
+            rc = await b.check(wb.cols if path == "columns" else wb, now_ms=now)
             s1 = _stage_sums(metrics)
+            if path == "tiered":  # one count through five trips to the shadow
+                assert (rc.remaining == 9 - i).all()
 
             def delta(stage, k=0):
                 return s1.get(stage, (0, 0))[k] - s0.get(stage, (0, 0))[k]
@@ -366,7 +399,9 @@ def decision_samples(metrics) -> dict:
     }
 
 
-DECISION_KINDS = ["fused", "wire_miss", "columns", "mixed", "no_lanes", "serial"]
+DECISION_KINDS = [
+    "fused", "wire_miss", "columns", "mixed", "no_lanes", "serial", "mesh_fused",
+]
 
 
 @pytest.mark.parametrize("kind", DECISION_KINDS)
@@ -379,8 +414,8 @@ async def test_a_dispatch_counts_its_decisions_once(kind, monkeypatch):
     touched once an algorithm a dispatch, not once an RPC."""
     now = ms_now()
     metrics = DaemonMetrics()
-    runner = new_runner(metrics)
-    if kind == "no_lanes":  # as a mesh engine: it takes the parser's columns
+    runner = new_runner(metrics, "mesh" if kind == "mesh_fused" else "local")
+    if kind == "no_lanes":  # as a CPU mesh engine: it takes the parser's columns
         runner.close()
         runner = EngineRunner(LocalEngine(capacity=4096, wire="full"), metrics)
         assert not runner.engine.supports_wire_ingress
@@ -419,7 +454,7 @@ async def test_a_dispatch_counts_its_decisions_once(kind, monkeypatch):
             )
         else:
             await runner.check_wire(parts, now_ms=now, done=done)
-        assert bool(fused[0]) == (kind in ("fused", "mixed"))
+        assert bool(fused[0]) == (kind in ("fused", "mixed", "mesh_fused"))
         assert runner.algo_counts == want
         assert sorted(incs) == sorted(k for k, v in want.items() if v)
         assert decision_samples(metrics) == want

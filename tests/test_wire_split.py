@@ -7,8 +7,8 @@ off the grid's base, an aggregate's hits past 18 bits) is alone staged as
 columns. These tests hold the split to the columns path on the same rows:
 answers byte for byte, the table row for row, the `EngineStats` delta, the
 programs selected, through the dropped-claim retry and the shadow's miss
-re-check; and hold the ring slot and the cascade fold, which need a single
-pass, to their refusal.
+re-check; and hold the cascade fold, which needs a single pass, to its
+refusal.
 """
 
 import dataclasses
@@ -24,7 +24,6 @@ from gubernator_tpu.ops.engine import (
     ms_now,
     prepare_check_columns,
     prepare_check_wire,
-    prepare_ring_slot,
 )
 from gubernator_tpu.proto import gubernator_pb2 as pb
 from gubernator_tpu.service.runner import EngineRunner
@@ -345,14 +344,12 @@ def test_a_retrys_rows_are_packed_as_the_whole_chunk_would_be(seed):
 
 
 def test_what_needs_a_single_pass_still_refuses_a_repeated_key():
-    """A ring slot holds one grid and the in-trace cascade fold one pass:
-    with a repeated key in the chunk both stagings are refused whole, as
-    before; without one, both are served."""
+    """The in-trace cascade fold is one pass: with a repeated key in the
+    chunk the staging is refused whole, as before; without one, it is
+    served."""
     now = ms_now()
     eng = LocalEngine(capacity=4096, wire="compact")
     unique, repeated = [rpc([1, 2, 3], now)], [rpc([1, 2], now), rpc([3, 1], now)]
-    assert prepare_ring_slot(eng, unique, 64, now_ms=now) is not None
-    assert prepare_ring_slot(eng, repeated, 64, now_ms=now) is None
     split = prepare_check_wire(eng, repeated, now_ms=now)
     assert [n for _p, n, _b, _s in split.passes] == [4, 1]
     assert split.hb.active.tolist() == [True, True, True, False]
