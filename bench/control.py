@@ -16,6 +16,14 @@ whole check:
            read-back all take it from there) a key above cannot be told from
            one evicted live, so counters_evicted_in_sample must pass its
            limit, which is 0 where the server counted no live eviction
+A GLOBAL keyspace (`keyspace.behavior`) is broken the same way and has to
+fail by the same numbers: GLOBAL's contract lets a peer answer from stale
+state, not count a hit twice or lose one, and the read-back comes after a
+drain (bench/checker.py).
+A stretch has to hold its 1,000th RPC or nothing is broken (`rpcs_broken`
+0, three verdicts of true, exit 1): a cell that answers under 125 RPC/s needs
+more than the default 8 s (the scratch GLOBAL cell, 44 RPC/s on four chips:
+--seconds 30).
 The benchmark's own runs never come here. One JSON line per stretch; exit
 code 0 when the three verdicts are true, false, false.
 """
@@ -61,7 +69,7 @@ def phantom_rpcs(led: loadgen.Ledger) -> int:
     generator now counts hits the server never saw."""
     picks = [i for i in range(EVERY - 1, len(led.idx), EVERY) if led.resp[i] is not None]
     for i in picks:
-        for col in (led.idx, led.due, led.sent, led.done, led.resp):
+        for col in (led.idx, led.behavior, led.due, led.sent, led.done, led.resp):
             col.append(col[i])
     checker.settle(led)
     return len(picks)

@@ -154,10 +154,20 @@ class Session:
         self.spec, self.seed, self.platform = spec, seed, platform
         self.cfg = spec["config"]
         self.keyspec = self.cfg["keyspace"]
-        checker.refuse_keyspec(self.keyspec, RUN_BUDGET_S)
+        checker.refuse_keyspec(self.keyspec, RUN_BUDGET_S, spec.get("traffic"))
+        # a row of this run can carry GLOBAL (then the cluster is drained
+        # before anything eventually consistent is judged), and the keyspace
+        # itself is GLOBAL (then scenarios and read-back are judged by
+        # GLOBAL's contract: checker.py's docstring)
+        self.drains_global = checker.carries_global(self.keyspec, spec.get("traffic"))
+        self.global_keys = checker.is_global(self.keyspec)
+        self.drains: list = []  # every drain of the run, as checker.drain returned it
         self.door_cls = door_cls
         self.server = self.door = None
         self.ledgers: list = []
+
+    async def drain(self) -> None:
+        self.drains.append(await checker.drain(self.door, self.seed))
 
     async def open(self, while_starting=None) -> None:
         chips = int(self.spec["cell"]["chips"])
@@ -197,11 +207,14 @@ class Session:
                 raise BenchFailure(f"native_parser={pipe.get('native_parser')!r}")
         self.t_fill0_ms = checker.now_ms()
         self.fill_out = await checker.fill(door, self.seed, self.keyspec)
+        if self.drains_global:
+            await self.drain()
+        self.t_filled_ms = checker.now_ms()
         t = time.monotonic()
         self.scen = await checker.run_scenarios(door, checker.fresh_scenarios(
             self.seed, checker.FRESH_KEYS_PER_SCRIPT, checker.now_ms(),
             dup_aggregates=eng.get("dedup") == "device", keyspec=self.keyspec,
-        ))
+        ), drain=self.drain if self.global_keys else None)
         self.t_scen = time.monotonic() - t
 
     async def offer(self, traffic: loadgen.Traffic, trace: bool) -> dict:
@@ -213,6 +226,8 @@ class Session:
         if trace:
             ctx["stages_before"] = parse_stages(await door.get("/metrics", as_json=False))
         ctx["pipeline_before"] = await door.get("/v1/debug/pipeline")
+        if self.drains_global:
+            ctx["global_before"] = (await door.get("/v1/debug/global"))["mesh"]
         ctx["t_scrape"] = time.monotonic() - t
         watch = asyncio.ensure_future(
             _watch_window(self.server, ctx, traffic.warm_s, traffic.seconds, trace)
@@ -226,6 +241,8 @@ class Session:
         ctx["ledger"] = led
         ctx["generator"] = generator_report(led)
         ctx["pipeline_after"] = await door.get("/v1/debug/pipeline")
+        if self.drains_global:
+            ctx["global_after"] = (await door.get("/v1/debug/global"))["mesh"]
         if trace:
             ctx["stages_after"] = parse_stages(await door.get("/metrics", as_json=False))
         return ctx
@@ -240,10 +257,19 @@ class Session:
         inv = checker.window_invariants(
             self.ledgers, counts, keyspec, self.t_fill0_ms, checker.now_ms()
         )
+        if self.drains_global:
+            await self.drain()
         idx = checker.draw_sample(self.seed, n_keys, known, self.cfg["check"])
         t_peek = checker.now_ms()
-        ans = await checker.read_back(door, self.seed, idx, keyspec, t_peek)
-        judged = checker.judge_counters(idx, ans, counts, fill_out["created"], keyspec, t_peek)
+        if self.global_keys:
+            ans = await checker.read_back(
+                door, self.seed, idx, keyspec, t_peek, readings=int(self.cfg["peers"]))
+            judged = checker.judge_global_counters(
+                idx, ans, counts, fill_out["created"], keyspec, t_peek, self.t_filled_ms)
+        else:
+            ans = await checker.read_back(door, self.seed, idx, keyspec, t_peek)
+            judged = checker.judge_counters(
+                idx, ans, counts, fill_out["created"], keyspec, t_peek)
         table = await door.get("/v1/debug/table")
         eng_end = (await door.get("/v1/debug/pipeline"))["engine"]
         health = await door.get("/v1/HealthCheck")
@@ -263,9 +289,24 @@ class Session:
             C("server_unhealthy",
               int(health.get("status") != "healthy" or bool(eng_end.get("poisoned"))), 0),
         ]
+        extra = {}
+        if self.drains_global:
+            over = checker.over_admission(self.ledgers, n_keys, keyspec, int(self.cfg["peers"]))
+            compared += [
+                C("global_undrained", max(d["undrained"] for d in self.drains), 0,
+                  len(self.drains)),
+                C("global_over_admitted", over["keys"], 0, n_keys),
+            ]
+            extra = {"drain_ms": [round(d["ms"], 1) for d in self.drains],
+                     "global_excess_hits": over["excess_hits"]}
+        if self.global_keys:
+            compared.append(C("replica_disagreements", judged["replica_disagreements"], 0, n))
+            extra.update(readings_per_key=judged["readings"],
+                         evicted_at_every_peer=judged["evicted_at_every_peer"])
         return {
             "correct": all(c.ok for c in compared),
             "compared": [c.to_dict() for c in compared],
+            **extra,
             "examples": (fill_out["examples"] + scen["examples"] + inv["examples"]
                          + judged["examples"])[:8],
             "live_keys": table.get("live_keys"),
@@ -295,6 +336,8 @@ async def run_cell(
     """Drive one run. Returns {"result": the contract's last line, "context":
     the line before it}. `platform`, `spec` and `door_cls` are the tests' way
     in (bench/tests); the command line has no switch for them."""
+    import grpc  # here, not at the top: doors.py keeps it out of a start that needs none
+
     t_process0 = t_process0 if t_process0 is not None else time.monotonic()
     spec = spec or load_cell(workload)
     ses = Session(spec, seed, platform, door_cls)
@@ -304,6 +347,13 @@ async def run_cell(
         ctx = await ses.offer(traffic, trace)
         dev_end = await ses.server.command(cmd="device")
         verdict = await ses.check()
+    except grpc.aio.AioRpcError as exc:
+        # the traffic's own RPCs may fail and are counted; one of the fill,
+        # the scenarios, a drain or the read-back cannot, and ends the run
+        await ses.close(failed=True)
+        raise BenchFailure(
+            "an RPC of the fill, the scenarios, a drain or the read-back failed: "
+            f"{exc.code().name}: {exc.details()}") from exc
     except BaseException:
         await ses.close(failed=True)
         raise
@@ -351,6 +401,8 @@ async def run_cell(
         "jax": dev.get("jax"),
         "keys_loaded": ses.fill_out["keys"],
         "algorithm": ses.keyspec.get("algorithm", "token"),
+        "behavior": ses.keyspec.get("behavior", []),
+        "scripts_left_out": sorted(checker.scripts_left_out(ses.keyspec)),
         "fill_byte_identical_rpcs": ses.fill_out["byte_identical_rpcs"],
         **verdict,
         "close_reasons": {
@@ -368,6 +420,13 @@ async def run_cell(
                           "at_window_end": ctx.get("cache_at_window_end")},
         "device_bytes_in_use": dev_end["bytes_in_use"],
     }
+    if ses.drains_global:
+        g0, g1 = ctx["global_before"], ctx["global_after"]
+        context["global_in_traffic"] = {
+            k: g1[k] - g0[k]
+            for k in ("sync_rounds", "hits_queued", "broadcasts_applied", "updates_installed")}
+        context["rpcs_by_behavior"] = {
+            str(b): led.behavior.count(b) for b in sorted(set(led.behavior))}
     if trace:
         device["busy_s"], device["window_s"] = red["busy_s"], red["window_s"]
         result["breakdown"] = xplane.breakdown(red)

@@ -14,13 +14,24 @@ A traffic mix is a JSON file of parameters under bench/traffic/:
   keys            {"dist": "uniform"} or {"dist": "zipf", "theta": t} over
                   the configuration's live keys (rank r is key index r; the
                   key's id hashes the index with the seed)
+  behavior        absent: every RPC carries the configuration's `keyspace.
+                  behavior`. {"mix": [[share, [names]], ...]}: every RPC
+                  carries one entry's behavior in all its rows (upstream's
+                  names, gubernator.proto; [] is none), drawn with these
+                  shares. The check has a rule for "GLOBAL" alone and refuses
+                  any other name (bench/checker.py). The ledger keeps which
+                  behavior each RPC carried
   warm_seconds    the same traffic sent before the window opens (set-up)
   channels        gRPC channels of the client
   rpc_timeout_s   after which an RPC has failed
 
 An open loop's gaps, sizes and key ranks are drawn once, from SHAPE_SEED, and
 only re-ordered by --seed: every seed offers the same multiset of work, so
-that runs differ by the order of the work and not by its amount.
+that runs differ by the order of the work and not by its amount. A behavior
+mix is drawn the same way, after them (a file without one draws what it
+always drew): one entry per RPC of an open loop, and for a closed loop,
+whose number of RPCs is the server's to decide, a cycle of BEHAVIOR_CYCLE
+entries that its RPCs take in the order they start.
 
 The traffic runs without a break through warm-up and window; what falls in
 which is decided afterwards from the clock. Everything sent is kept in the
@@ -38,6 +49,7 @@ import numpy as np
 import wirefmt
 
 SHAPE_SEED = 20260927
+BEHAVIOR_CYCLE = 1000
 
 
 class Ledger:
@@ -48,6 +60,7 @@ class Ledger:
     def __init__(self, warm_s: float, seconds: float):
         self.warm_s, self.seconds = warm_s, seconds
         self.idx: list = []
+        self.behavior: list = []  # the wire number every row of the RPC carried
         self.due: list = []
         self.sent: list = []
         self.done: list = []
@@ -72,6 +85,16 @@ class Ledger:
             np.fromiter((r is not None for r in self.resp), dtype=bool, count=n),
             np.fromiter((len(i) for i in self.idx), dtype=np.int64, count=n),
         )
+
+
+def behavior_mix(spec: dict):
+    """A traffic file's `behavior` as (shares, wire numbers), one entry of
+    each per entry of its mix; None where the file has no such key."""
+    mix = spec.get("behavior")
+    if mix is None:
+        return None
+    return (np.asarray([m[0] for m in mix["mix"]], dtype=np.float64),
+            [wirefmt.behavior_bits(m[1]) for m in mix["mix"]])
 
 
 def size_sampler(spec: dict):
@@ -121,6 +144,9 @@ class Traffic:
         self.duration = int(keyspec["duration_ms"])
         self.hits = int(keyspec["hits"])
         self.algorithm = wirefmt.keyspec_algorithm(keyspec)
+        self.behavior = wirefmt.keyspec_behavior(keyspec)
+        self._mix = behavior_mix(spec)
+        self._cycle = None  # a closed loop's behaviors, where the file has a mix
         self.warm_s = float(spec.get("warm_seconds", 0))
         self.timeout_s = float(spec.get("rpc_timeout_s", 60))
         self._sizes = size_sampler(spec["items_per_rpc"])
@@ -129,15 +155,28 @@ class Traffic:
         if not self.closed and spec["loop"] != "open":
             raise ValueError(f"unknown loop kind {spec['loop']!r}")
 
-    def _body(self, idx: np.ndarray) -> bytes:
+    def _body(self, idx: np.ndarray, behavior: int | None = None) -> bytes:
         return wirefmt.request_bytes(
             wirefmt.key_ids(self.key_seed, idx), self.hits, self.limit, self.duration,
             algorithm=self.algorithm,
+            behavior=self.behavior if behavior is None else behavior,
         )
+
+    def _behaviors(self, shape, n: int) -> np.ndarray:
+        """The behavior of each of `n` RPCs: the mix drawn from `shape` (the
+        SHAPE_SEED generator, after everything else it gives) and re-ordered
+        by the seed, through a generator of its own so that the seed's other
+        draws are what they are without a mix."""
+        shares, bits = self._mix
+        drawn = np.asarray(bits)[shape.choice(len(bits), size=n, p=shares / shares.sum())]
+        return np.random.default_rng((self.seed, BEHAVIOR_CYCLE)).permutation(drawn)
 
     def prepare(self) -> None:
         self._keys = key_sampler(self.spec["keys"], self.n_keys)
         if self.closed:
+            if self._mix is not None:
+                self._cycle = self._behaviors(
+                    np.random.default_rng(SHAPE_SEED), BEHAVIOR_CYCLE).tolist()
             return
         total = self.warm_s + self.seconds
         n = int(round(float(self.spec["rate_rpc_per_s"]) * total))
@@ -150,14 +189,28 @@ class Traffic:
         due = np.cumsum(gaps)
         due *= total / due[-1]
         offsets = np.concatenate([[0], np.cumsum(sizes)])
+        behaviors = (np.full(n, self.behavior) if self._mix is None
+                     else self._behaviors(shape, n))
         # every body in one pass: items are fixed-width, so an RPC's bytes
-        # are a slice of one long buffer
-        step, chunks = 200_000, []
-        for lo in range(0, len(ranks), step):
-            chunks.append(self._body(ranks[lo : lo + step]))
-        blob = b"".join(chunks)
-        width = len(blob) // max(len(ranks), 1)
-        self._plan = (due, offsets, ranks, blob, width)
+        # are a slice of one long buffer (one buffer a behavior of the mix:
+        # the flag is two bytes of every row)
+        of_item, step = np.repeat(behaviors, sizes), 200_000
+        blobs, first = {}, np.zeros(n, dtype=np.int64)  # an RPC's first row in its blob
+        for b in np.unique(behaviors).tolist():
+            mine = ranks[of_item == b]
+            blob = b"".join(self._body(mine[lo : lo + step], b)
+                            for lo in range(0, len(mine), step))
+            blobs[b] = (blob, len(blob) // max(len(mine), 1))
+            rpcs = behaviors == b
+            first[rpcs] = np.cumsum(sizes[rpcs]) - sizes[rpcs]
+        self._plan = (due, offsets, ranks, blobs, behaviors.tolist(), first)
+
+    def _planned_body(self, i: int) -> bytes:
+        """The bytes of the open loop's i-th RPC."""
+        _due, offsets, _ranks, blobs, behaviors, first = self._plan
+        blob, width = blobs[behaviors[i]]
+        lo = first[i] * width
+        return blob[lo : lo + (offsets[i + 1] - offsets[i]) * width]
 
     async def run(self, door) -> Ledger:
         led = Ledger(self.warm_s, self.seconds)
@@ -190,6 +243,7 @@ class Traffic:
 
         rng = np.random.default_rng(self.seed)
         t_end = led.t1
+        cycle = self._cycle
 
         async def worker() -> None:
             while True:
@@ -197,9 +251,11 @@ class Traffic:
                 if now >= t_end:
                     return
                 idx = self._keys(rng, int(self._sizes(rng, 1)[0]))
-                body = self._body(idx)
                 i = len(led.idx)
+                behavior = cycle[i % len(cycle)] if cycle else self.behavior
+                body = self._body(idx, behavior)
                 led.idx.append(idx)
+                led.behavior.append(behavior)
                 led.due.append(now)
                 led.sent.append(time.perf_counter() - t0)
                 led.done.append(np.nan)
@@ -221,7 +277,7 @@ class Traffic:
     async def _run_open(self, door, led: Ledger, t0: float) -> None:
         import grpc
 
-        due, offsets, ranks, blob, width = self._plan
+        due, offsets, ranks, _blobs, behaviors, _first = self._plan
         n = len(due)
         cap = int(self.spec.get("max_outstanding", 1 << 30))
         sent = np.full(n, np.nan)
@@ -246,8 +302,7 @@ class Traffic:
             stop = min(n, i + 64)
             while i < stop and due[i] <= now:
                 if state["out"] < cap:
-                    body = blob[offsets[i] * width : offsets[i + 1] * width]
-                    call = door.start(body)
+                    call = door.start(self._planned_body(i))
                     call.add_done_callback(
                         lambda c, i=i: finished(i, c)
                     )
@@ -271,4 +326,5 @@ class Traffic:
                 except grpc.aio.AioRpcError as exc:
                     led.note(f"{exc.code()}: {exc.details()}")
             led.resp.append(data)
+        led.behavior = behaviors
         led.due, led.sent, led.done = due.tolist(), sent.tolist(), done.tolist()

@@ -24,6 +24,9 @@ CPU_MESH_ENV = {
 # on the chip to prove `keyspace.algorithm`: PERF.md, section 6).
 SCRATCH = {
     "leaky1m-scratch.bulk1000-closed64": ("token10m.bulk1000-closed64", "leaky1m-scratch.json"),
+    # PR 46 ran this one at size on four chips to prove `keyspace.behavior`
+    "mesh4-global-scratch.bulk1000-closed64": (
+        "mesh4-sharded.bulk1000-closed64", "mesh4-global-scratch.json"),
 }
 
 
@@ -66,5 +69,21 @@ def small_spec(workload: str, keys: int = 2000, rate: float = 100.0) -> dict:
             # as at full size, a table that holds every key: a leaky key
             # evicted live cannot be told from a lost hit (bench/checker.py)
             cfg["server_env"]["GUBER_CACHE_SIZE"] = str(65536)
+    if "GLOBAL" in cfg["keyspace"].get("behavior", ()):
+        # Sixteen slots a key on every device, where the full-size file has
+        # 1.7 (every device also holds, in its replica table, a copy of every
+        # key it does not own). What has to match the full size is not the
+        # table's load but a dispatch's: `kernel2._probe_claim2` drops the
+        # ninth new key of one dispatch in one 8-lane bucket, the engine
+        # retries the row, and a row of a replica table retried after a sync
+        # tick counts its hit twice (ROADMAP C2). The fill's dispatches hold
+        # up to `keys` new rows here, 0.003 a bucket at full size; at 2 and
+        # at 0.5 slots a key they put nine in a bucket somewhere, and 2 of 4
+        # and 2 of 2 small runs then read fill_mismatches and
+        # counters_below_expected above 0 (PERF.md section 6, PR 46).
+        per_device = 4096
+        while per_device < 16 * keys:
+            per_device *= 2
+        cfg["server_env"]["GUBER_CACHE_SIZE"] = str(per_device * chips)
     spec["extra_env"] = dict(CPU_MESH_ENV) if chips > 1 else {"GUBER_WIRE_COMPACT": "1"}
     return spec

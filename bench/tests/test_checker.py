@@ -275,3 +275,114 @@ def test_scenarios_add_the_leaky_script_at_the_keyspaces_own_limit(keyspec, item
             (0, 99), (0, 49), (0, 49), (0, 0), (1, 0), (0, 0)]
         per_step = [step[0][2][2] - T0 for step in steps]
         assert per_step[0] == PER_TOKEN and per_step[4] == 200_000 + 100 * PER_TOKEN
+
+
+# --------------------------- what a keyspace without a behavior is sent (PR 46)
+
+# sha256 over every item's bytes, limit, expectation and label of
+# `fresh_scenarios(3_000_000_019, 3, T0, dup_aggregates, keyspec)` at e445c33
+_SCENARIOS_BEFORE = {
+    ("token", False): "9f45499ed98286fc6e8f0faeb19c76930219af8b04e2c8d9b9acc82b389cb79f",
+    ("token", True): "ae2364a6660ec7252e71f62afa25aaed2e8a72a79aac00150cb738cff39725cb",
+    ("leaky", False): "930912e7d9531d7d79b16588bbb4a84ec07906eda9c49030b8c6d403a90ac8c7",
+    ("leaky", True): "67748ed4c8c60b649b27fe3649f1f94ffeb4254737383347e6986acc390ce5d0",
+}
+
+
+def _scenario_digest(groups) -> str:
+    import hashlib
+
+    return hashlib.sha256(repr(
+        [[[(b.hex(), lim, e, lab) for b, lim, e, lab in step] for step in steps]
+         for steps in groups]).encode()).hexdigest()
+
+
+@pytest.mark.parametrize("algorithm,dup", list(_SCENARIOS_BEFORE))
+def test_the_scripts_of_a_keyspace_without_a_behavior_are_what_they_were(algorithm, dup):
+    keyspec = {"keys": 10, "hits": 1, "limit": 100, "duration_ms": 3_600_000}
+    if algorithm == "leaky":
+        keyspec.update(algorithm="leaky", duration_ms=134_000_000)
+    for extra in ({}, {"script_algorithms": ["token", "leaky"]}, {"behavior": []}):
+        groups = checker.fresh_scenarios(3_000_000_019, 3, T0, dup, {**keyspec, **extra})
+        assert _scenario_digest(groups) == _SCENARIOS_BEFORE[algorithm, dup]
+        assert checker.scripts_left_out({**keyspec, **extra}) == {}
+
+
+def test_a_keyspace_may_keep_the_leaky_script_from_its_table():
+    """`keyspace.script_algorithms` ["token"]: what lets a packed token
+    layout be timed as itself; one leaky row would migrate it to `full`."""
+    both = checker.fresh_scenarios(7, 200, T0, False, KEYSPEC)
+    token = checker.fresh_scenarios(7, 200, T0, False, dict(KEYSPEC, script_algorithms=["token"]))
+    labels = [steps[0][0][3].split("/")[0] for steps in token]
+    assert labels == ["drain", "reset", "drainover", "peek", "wide", "dup"]
+    assert [s for s in both if not s[0][0][3].startswith("leak/")] == token
+    assert sum(len(step) for steps in token for step in steps) == 5600 - 6 * 200
+    assert not any(b"\x30\x01" in item[0] for steps in token for step in steps for item in step)
+    assert any(b"\x30\x01" in item[0] for steps in both for step in steps for item in step)
+    assert list(checker.scripts_left_out(dict(KEYSPEC, script_algorithms=["token"]))) == ["leak"]
+
+
+class _RecordingDoor(_OracleDoor):
+    """Every RPC's bytes, in the order they were started, and how many were
+    in flight at once."""
+
+    def __init__(self, algorithm, remaining=None):
+        super().__init__(algorithm)
+        self.bodies, self.inflight, self.most = [], 0, 0
+
+    async def check_raw(self, body: bytes) -> bytes:
+        import asyncio
+
+        self.bodies.append(body)
+        self.inflight += 1
+        self.most = max(self.most, self.inflight)
+        await asyncio.sleep(0)
+        self.inflight -= 1
+        return await super().check_raw(body)
+
+
+@pytest.mark.parametrize("keyspec", [KEYSPEC, LEAKY], ids=["token", "leaky"])
+def test_fill_and_read_back_send_a_keyspace_without_a_behavior_what_they_sent(keyspec):
+    """The bytes are `request_bytes` without a behavior (pinned to the
+    parent's in test_wirefmt.py), one RPC a thousand keys, 64 in flight."""
+    import asyncio
+
+    algorithm = wirefmt.keyspec_algorithm(keyspec)
+    limit, dur = keyspec["limit"], keyspec["duration_ms"]
+    door = _RecordingDoor(algorithm)
+    out = asyncio.run(checker.fill(door, 7, keyspec))
+    assert out["mismatches"] == 0 and len(door.bodies) == 4 and door.most == 4
+    for r, body in enumerate(sorted(door.bodies)):
+        assert b"\x38" + wirefmt.varint(wirefmt.GLOBAL) not in body[:40]
+    want = {wirefmt.request_bytes(wirefmt.key_ids(7, np.arange(r * 1000, r * 1000 + 1000)), 1,
+                                  limit, dur, created_at=int(out["created"][r]),
+                                  algorithm=algorithm) for r in range(4)}
+    assert set(door.bodies) == want
+
+    door = _RecordingDoor(algorithm)
+    idx = np.arange(0, 4000, 3)
+    ans = asyncio.run(checker.read_back(door, 7, idx, keyspec, T_PEEK))
+    assert isinstance(ans, wirefmt.Answers) and len(ans.status) == len(idx)
+    assert door.bodies == [
+        wirefmt.request_bytes(wirefmt.key_ids(7, idx[lo : lo + 1000]), 0, limit, dur,
+                              created_at=T_PEEK, algorithm=algorithm)
+        for lo in range(0, len(idx), 1000)]
+    assert door.most == 2
+
+
+def test_a_global_keyspace_is_read_once_a_peer_one_rpc_at_a_time():
+    import asyncio
+
+    keyspec = dict(KEYSPEC, behavior=["GLOBAL"])
+    door = _RecordingDoor(wirefmt.TOKEN)
+    idx = np.arange(0, 4000, 3)
+    readings = asyncio.run(checker.read_back(door, 7, idx, keyspec, T_PEEK, readings=4))
+    assert len(readings) == 4 and all(len(a.status) == len(idx) for a in readings)
+    parts = [wirefmt.request_bytes(wirefmt.key_ids(7, idx[lo : lo + 1000]), 0, 100, 3_600_000,
+                                   created_at=T_PEEK, behavior=wirefmt.GLOBAL)
+             for lo in range(0, len(idx), 1000)]
+    # a part's four readings straight after one another, nothing beside them
+    assert door.bodies == [p for p in parts for _ in range(4)] and door.most == 1
+    door = _RecordingDoor(wirefmt.TOKEN)
+    asyncio.run(checker.fill(door, 7, keyspec))
+    assert all(body.count(b"\x38\x02") == 1000 for body in door.bodies)

@@ -31,6 +31,50 @@ def is_leaky(workload) -> bool:
     return small.full_spec(workload)["config"]["keyspace"].get("algorithm") == "leaky"
 
 
+def is_global(workload) -> bool:
+    return "GLOBAL" in small.full_spec(workload)["config"]["keyspace"].get("behavior", ())
+
+
+class CountingDoor(Door):
+    """Counts at the door what a run asks of the server: every GET by its
+    path, in order, and every check RPC by what its first row is (a fill
+    row pins `created_at` and carries the hit, a read-back row carries none,
+    a script's row has a name of its own, the drain's sentinel is `drain`)."""
+
+    seen = None  # the newest door, for the test to read once the run is over
+
+    def __init__(self, *args, **kw):
+        super().__init__(*args, **kw)
+        self.gets, self.rpcs = [], {}
+        type(self).seen = self
+
+    async def get(self, path, as_json=True):
+        self.gets.append(path)
+        return await super().get(path, as_json)
+
+    def start(self, body: bytes):
+        n = body[3]
+        name, tail = body[4 : 4 + n], body[4 + n :]
+        tail = tail[2 + tail[1] :]  # past the key
+        if name != b"bulk":
+            kind = "sentinel" if name == b"drain" and len(body) < 64 else "script"
+        elif tail[0] != 0x18:
+            kind = "read_back"
+        else:
+            kind = "fill" if b"\x50" in tail[: body[1] - 24] else "traffic"
+        self.rpcs[kind] = self.rpcs.get(kind, 0) + 1
+        return super().start(body)
+
+
+# what a run asked of the server over HTTP at e445c33, in order
+GETS_BEFORE = {
+    False: ["/v1/debug/pipeline"] * 3 + ["/v1/debug/table", "/v1/debug/pipeline",
+                                          "/v1/HealthCheck"],
+    True: ["/v1/debug/pipeline", "/metrics", "/v1/debug/pipeline", "/v1/debug/pipeline",
+           "/metrics", "/v1/debug/table", "/v1/debug/pipeline", "/v1/HealthCheck"],
+}
+
+
 def run(workload, seed, trace, seconds=3.0, **kw):
     return asyncio.run(harness.run_cell(
         workload, seed, seconds, trace, platform="cpu",
@@ -54,9 +98,35 @@ def warmed():
 @pytest.mark.parametrize("trace", [False, True], ids=["untraced", "traced"])
 @pytest.mark.parametrize("workload", ALL_CELLS)
 def test_cell_runs_and_is_correct(workload, trace, warmed):
-    out = run(workload, 3_000_000_011, trace)
+    out = run(workload, 3_000_000_011, trace, door_cls=CountingDoor)
     res, spec = out["result"], small.full_spec(workload)
     assert out["context"]["algorithm"] == spec["config"]["keyspace"].get("algorithm", "token")
+    door, compared = CountingDoor.seen, out["context"]["compared"]
+    names = [c["name"] for c in compared]
+    read_back = -(-next(c["of"] for c in compared if c["name"] == "counters_below_expected") // 1000)
+    if is_global(workload):
+        # drained after the fill, after each of the 16 scripted steps and
+        # before the read-back, one sentinel a drain; four readings a key
+        drains = len(out["context"]["drain_ms"])
+        assert drains == 18 and door.rpcs["sentinel"] == drains
+        assert door.gets.count("/v1/debug/global") >= 2 * drains + 2
+        assert [g for g in door.gets if g != "/v1/debug/global"] == GETS_BEFORE[trace]
+        assert door.rpcs["script"] == 16 and door.rpcs["read_back"] == 4 * read_back
+        assert names[10:] == ["global_undrained", "global_over_admitted", "replica_disagreements"]
+        assert out["context"]["scripts_left_out"] == ["dup", "leak", "reset"]
+    else:
+        # no request, no wait and no compared name that the parent's harness
+        # did not have
+        assert door.gets == GETS_BEFORE[trace]
+        assert door.rpcs == {"fill": 2, "script": 32 if is_leaky(workload) else 26,
+                             "traffic": door.rpcs["traffic"], "read_back": read_back}
+        assert len(names) == 10 and out["context"]["scripts_left_out"] == []
+    assert names[:10] == [
+        "fill_mismatches", "scenario_mismatches", "window_answer_violations",
+        "counters_below_expected", "counters_above_expected_not_evicted",
+        "counters_status_wrong", "counters_fields_wrong", "counters_evicted_in_sample",
+        "server_decisions_dropped", "server_unhealthy"]
+    assert all(c["limit"] == 0 for c in compared if c["name"] != "counters_evicted_in_sample")
     cache = out["context"]["cache_entries"]
     # one run, no retry: a compile inside the window is a failure to count
     assert cache["at_window_end"] == cache["before"], "a program compiled inside the window"
@@ -73,6 +143,33 @@ def test_cell_runs_and_is_correct(workload, trace, warmed):
     else:
         assert set(res["metrics"]) == {m["name"] for m in spec["end_to_end"]}
         assert all(v["value"] > 0 for v in res["metrics"].values())
+
+
+@pytest.mark.parametrize("seed", [3_000_000_012, 3_000_000_013])
+def test_the_global_scratch_cell_is_correct_on_two_more_seeds(seed, warmed):
+    out = run("mesh4-global-scratch.bulk1000-closed64", seed, False)
+    assert out["result"]["correct"], (out["context"]["compared"], out["context"]["examples"])
+    assert max(out["context"]["drain_ms"]) < 5000.0
+
+
+class FailingDoor(Door):
+    """The first read-back RPC fails as gRPC reports it."""
+
+    async def check_raw(self, body: bytes) -> bytes:
+        import grpc
+
+        if body[2:8] == b"\x0a\x04bulk" and body[26] != 0x18:
+            raise grpc.aio.AioRpcError(
+                grpc.StatusCode.UNAVAILABLE, grpc.aio.Metadata(), grpc.aio.Metadata(),
+                details="the door closed")
+        return await super().check_raw(body)
+
+
+def test_an_rpc_error_outside_the_traffic_ends_the_run_as_a_bench_failure():
+    from doors import BenchFailure
+
+    with pytest.raises(BenchFailure, match="read-back failed: UNAVAILABLE: the door closed"):
+        run(CELLS[0], 3_000_000_041, False, seconds=1.0, door_cls=FailingDoor)
 
 
 class AlteringDoor(Door):

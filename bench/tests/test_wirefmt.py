@@ -65,6 +65,62 @@ def test_token_request_bytes_are_the_bytes_they_were(hits, created):
                                  algorithm=wirefmt.TOKEN) == want
 
 
+# what `request_bytes` gave a leaky keyspace at e445c33, before it knew of
+# field 7: the same two keys, limit 100 per 134,000,000 ms
+_LEAKY_ROWS_BEFORE = {
+    (1, None): "0a230a0462756c6b121064376361666238356135643331646132180120642880dbf23f3001"
+               "0a230a0462756c6b121037363032373533663235316439396237180120642880dbf23f3001",
+    (0, 1_790_000_000_123):
+        "0a280a0462756c6b12106437636166623835613564333164613220642880dbf23f300150fbd8c1a28c34"
+        "0a280a0462756c6b12103736303237353366323531643939623720642880dbf23f300150fbd8c1a28c34",
+}
+
+
+@pytest.mark.parametrize("hits,created", list(_LEAKY_ROWS_BEFORE))
+def test_request_bytes_without_a_behavior_are_the_bytes_they_were(hits, created):
+    ids = wirefmt.key_ids(3_000_000_019, np.arange(5, 7))
+    for algorithm, dur, rows in ((wirefmt.LEAKY, 134_000_000, _LEAKY_ROWS_BEFORE),
+                                 (wirefmt.TOKEN, 3_600_000, _TOKEN_ROWS_BEFORE)):
+        want = bytes.fromhex(rows[hits, created])
+        for kw in ({}, {"behavior": 0}):
+            assert wirefmt.request_bytes(ids, hits, 100, dur, created_at=created,
+                                         algorithm=algorithm, **kw) == want
+
+
+@pytest.mark.parametrize("behavior", [0, wirefmt.GLOBAL, wirefmt.GLOBAL | wirefmt.DRAIN_OVER_LIMIT])
+@pytest.mark.parametrize("algorithm", [wirefmt.TOKEN, wirefmt.LEAKY])
+def test_request_bytes_are_encode_item_row_by_row(algorithm, behavior):
+    """With and without a behavior: field 7 sits where `encode_item` has
+    always put it, and is left out when 0."""
+    ids = wirefmt.key_ids(3_000_000_019, np.arange(5, 12))
+    for hits, created in ((1, None), (0, 1_790_000_000_123), (3, 1_790_000_000_123)):
+        ours = wirefmt.request_bytes(ids, hits, 100, 3_600_000, created_at=created,
+                                     algorithm=algorithm, behavior=behavior)
+        assert ours == b"".join(
+            wirefmt.encode_item("bulk", f"{int(i):016x}", hits, 100, 3_600_000, algorithm,
+                                behavior, created) for i in ids)
+        assert (b"\x38" + wirefmt.varint(behavior) in ours) == bool(behavior)
+
+
+def test_a_global_row_is_what_protobuf_serializes(pb):
+    ids = wirefmt.key_ids(3_000_000_019, np.arange(5, 12))
+    ours = wirefmt.request_bytes(ids, 1, 100, 3_600_000, created_at=1_790_000_000_123,
+                                 behavior=wirefmt.keyspec_behavior({"behavior": ["GLOBAL"]}))
+    req = pb.GetRateLimitsReq()
+    for i in ids:
+        req.requests.add(name="bulk", unique_key=f"{int(i):016x}", hits=1, limit=100,
+                         duration=3_600_000, behavior=pb.GLOBAL, created_at=1_790_000_000_123)
+    assert ours == req.SerializeToString()
+
+
+def test_behavior_names_are_upstreams(pb):
+    assert wirefmt.BEHAVIORS == {
+        name: number for name, number in pb.Behavior.items() if number}
+    assert wirefmt.keyspec_behavior({}) == 0
+    with pytest.raises(ValueError, match="behavior 'GLOBL'"):
+        wirefmt.behavior_bits(["GLOBL"])
+
+
 def test_a_window_rpc_of_a_token_cell_is_the_bytes_it_was():
     import hashlib
 
@@ -78,6 +134,9 @@ def test_a_window_rpc_of_a_token_cell_is_the_bytes_it_was():
         "93682c192d7134ab36e71453a790822f976d5b436fd53cfdc4b690f767f01d72")
     leaky = loadgen.Traffic(tr.spec, dict(keyspec, algorithm="leaky"), 2654435761, 1.0)
     assert len(leaky._body(np.arange(0, 1000))) == len(body) + 2 * 1000
+    glob = loadgen.Traffic(tr.spec, dict(keyspec, behavior=["GLOBAL"]), 2654435761, 1.0)
+    assert len(glob._body(np.arange(0, 1000))) == len(body) + 2 * 1000
+    assert glob._body(np.arange(0, 1000), 0) == body
 
 
 def test_an_unknown_algorithm_is_refused():

@@ -21,8 +21,15 @@ import numpy as np
 
 METHOD = "/pb.gubernator.V1/GetRateLimits"
 TOKEN, LEAKY = 0, 1
-RESET_REMAINING, DRAIN_OVER_LIMIT = 8, 32
+GLOBAL, RESET_REMAINING, DRAIN_OVER_LIMIT = 2, 8, 32
 ALGORITHMS = {"token": TOKEN, "leaky": LEAKY}  # a keyspace's `algorithm`
+# upstream's names (gubernator.proto, enum Behavior), as a keyspace's or a
+# traffic mix's `behavior` lists them; BATCHING is 0, the default
+BEHAVIORS = {
+    "NO_BATCHING": 1, "GLOBAL": GLOBAL, "DURATION_IS_GREGORIAN": 4,
+    "RESET_REMAINING": RESET_REMAINING, "MULTI_REGION": 16,
+    "DRAIN_OVER_LIMIT": DRAIN_OVER_LIMIT,
+}
 UNDER, OVER = 0, 1
 _U64 = (1 << 64) - 1
 
@@ -34,6 +41,22 @@ def keyspec_algorithm(keyspec: dict) -> int:
     if name not in ALGORITHMS:
         raise ValueError(f"keyspace.algorithm {name!r}: one of {sorted(ALGORITHMS)}")
     return ALGORITHMS[name]
+
+
+def behavior_bits(names) -> int:
+    """The wire number of a list of upstream's behavior names (a
+    configuration's `keyspace.behavior`, an entry of a traffic mix); no name,
+    or no list, is 0."""
+    bits = 0
+    for name in names or ():
+        if name not in BEHAVIORS:
+            raise ValueError(f"behavior {name!r}: one of {sorted(BEHAVIORS)}")
+        bits |= BEHAVIORS[name]
+    return bits
+
+
+def keyspec_behavior(keyspec: dict) -> int:
+    return behavior_bits(keyspec.get("behavior"))
 
 
 def varint(v: int) -> bytes:
@@ -65,11 +88,13 @@ _SHIFTS = np.arange(60, -4, -4, dtype=np.uint64)
 def request_bytes(
     ids: np.ndarray, hits: int, limit: int, duration: int,
     created_at: int | None = None, name: bytes = b"bulk", algorithm: int = TOKEN,
+    behavior: int = 0,
 ) -> bytes:
     """Serialized GetRateLimitsReq of checks on the keys `ids` (unique_key =
     16 hex digits of the id), built as one fixed-width byte matrix instead of
-    n messages. `algorithm` is field 6 of every row: proto3 leaves out 0
-    (TOKEN), so a token row's bytes do not know the field exists."""
+    n messages. `algorithm` is field 6 of every row and `behavior` field 7:
+    proto3 leaves out 0 (TOKEN, BATCHING), so a plain token row's bytes do
+    not know either field exists."""
     head = b"\x0a" + varint(len(name)) + name + b"\x12\x10"
     tail = b""
     if hits:
@@ -77,6 +102,8 @@ def request_bytes(
     tail += b"\x20" + varint(limit) + b"\x28" + varint(duration)
     if algorithm:
         tail += b"\x30" + varint(algorithm)
+    if behavior:
+        tail += b"\x38" + varint(behavior)
     if created_at is not None:
         tail += b"\x50" + varint(created_at)
     frame = b"\x0a" + varint(len(head) + 16 + len(tail))
