@@ -88,6 +88,7 @@ def main(argv=None) -> int:
         "healthy_s": ctx["walls_s"]["server_healthy"],
         "dispatches": dispatches, "fused": ctx["dispatches"],
         "native_staged": p1["engine"].get("native_staged"),
+        "native_finished": p1["engine"].get("native_finished"),
         "stage_ms": stage_ms, "cpu_ms_per_dispatch": cpu,
         # the growth, over warm-up and window, of the counters of the tier
         # block (a tiered deployment's; absent otherwise), of the engine's

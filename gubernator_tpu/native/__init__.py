@@ -12,7 +12,10 @@ columns + compact-wire lanes; service/wire.py), `encode_responses` and
 `encode_responses_many` (response columns → wire bytes, one RPC or every
 plain RPC of a dispatch), `stage_wire_chunk` (a fused chunk's lanes → its
 grid and every pass behind it, staged; ops/wire.stage_wire_chunk, twin
-ops/engine._stage_chunk_numpy), `fp_index_find` and `fp_index_place` (the
+ops/engine._stage_chunk_numpy), `finish_wire_chunk` (the way out of the
+same dispatch: its passes' fetched egress blocks → its response columns,
+written in place, and the rows to retry; ops/wire.finish_wire_chunk, twin
+ops/engine._finish_numpy), `fp_index_find` and `fp_index_place` (the
 shadow tier's fingerprint index probed and filled a batch at a time over
 the caller's two arrays; tier/shadow._FpIndex holds the NumPy twins), the
 hashes `fingerprint64` and `fnv1a32`, and `set_error_strings` (the

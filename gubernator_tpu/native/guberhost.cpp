@@ -799,6 +799,12 @@ struct LaneBlock {
     return true;
   }
   Py_ssize_t cols() const { return view.shape[1]; }
+  inline int32_t at(int lane, Py_ssize_t j) const {
+    int32_t v;
+    memcpy(&v, (const char*)view.buf + lane * view.strides[0] +
+                   j * view.strides[1], 4);
+    return v;
+  }
   void copy_lane(int lane, int32_t* dst) const {
     const char* p = (const char*)view.buf + lane * view.strides[0];
     if (view.strides[1] == 4) {
@@ -1239,6 +1245,248 @@ static PyObject* fp_index_place(PyObject*, PyObject* args) {
   return PyLong_FromLongLong(reused);
 }
 
+// ------------------------------------------------------ fused chunk finish
+
+// Flag bits of an egress row's 4th cell (ops/kernel2.FLAG_*) and the reset
+// cell's "reset_time == 0" (ops/wire.RESET_SENTINEL)
+static const int32_t FLAG_STATUS = 1, FLAG_HIT = 2, FLAG_DROPPED = 4,
+                     FLAG_UNPROCESSED = 8, FLAG_MEMBER = 16;
+static const int32_t RESET_SENTINEL = INT32_MIN;
+
+// One pass's fetched compact egress behind the buffer protocol, int32, any
+// strides: an (R, 4) block whose first rows answer the pass, with the
+// kernel's stats row at R-2 and the base row at R-1 (a tiered block keeps
+// its evictee sidecar between the rows and those two), or a mesh's
+// (D, c+2, 4) grid of D such blocks, pass row i at [i / c][i % c]. Opened
+// and released with the GIL held; read without it.
+struct EgressBlock {
+  Py_buffer view;
+  bool held = false;
+  ~EgressBlock() { if (held) PyBuffer_Release(&view); }
+
+  // 1: opened; 0: a block this call does not read (not int32: a full-width
+  // pass), no error set; -1: not a block at all, error set
+  int open(PyObject* obj) {
+    if (PyObject_GetBuffer(obj, &view, PyBUF_STRIDES | PyBUF_FORMAT) < 0)
+      return -1;
+    held = true;
+    const char* f = view.format ? view.format : "B";
+    if (*f == '@' || *f == '=' || *f == '<') f++;
+    if ((view.ndim != 2 && view.ndim != 3) || view.shape[view.ndim - 1] != 4 ||
+        view.shape[view.ndim - 2] < 2) {
+      PyErr_SetString(PyExc_TypeError,
+                      "block: (rows + 2, 4) or (devices, rows + 2, 4) expected");
+      return -1;
+    }
+    return view.itemsize == 4 && *f && strchr("il", *f) && !f[1];
+  }
+  bool grid() const { return view.ndim == 3; }
+  Py_ssize_t devices() const { return grid() ? view.shape[0] : 1; }
+  Py_ssize_t height() const { return view.shape[view.ndim - 2]; }
+  inline int32_t at(Py_ssize_t d, Py_ssize_t r, int k) const {
+    const char* p = (const char*)view.buf + r * view.strides[view.ndim - 2] +
+                    k * view.strides[view.ndim - 1];
+    if (grid()) p += d * view.strides[0];
+    int32_t v;
+    memcpy(&v, p, 4);
+    return v;
+  }
+};
+
+// One pass of the dispatch: its block, its `n` real rows and their place in
+// request order: `rows` (row i answers request row rows[i]) or, for the
+// aggregate, `members` group after group, `counts` to a group (row g
+// answers every member of group g). A grid also names its `base` (a block
+// carries its own) and may name the chunk's `lanes`, whose zeroed rows the
+// kernel never saw.
+struct FinishPass {
+  EgressBlock block;
+  IntCol rows, members, counts;
+  LaneBlock lanes;
+  bool aggregate = false, has_lanes = false;
+  int64_t n = 0, base = 0;
+};
+
+struct ChunkFinish {
+  // the dispatch's response columns, `width` request rows each
+  int32_t* status = nullptr;
+  int64_t *limit = nullptr, *remaining = nullptr, *reset = nullptr;
+  int64_t width = 0;
+  // cache_hits, cache_misses, over_limit, evicted_unexpired, summed
+  int64_t stats[4] = {0, 0, 0, 0};
+  int64_t later = 0, aggregate = 0, overflow = 0;
+  std::vector<int64_t> dropped;  // (pass, row of the pass, its flags)
+  const char* bad = nullptr;
+};
+
+// ops/wire.unpack_wire_out and the scatter of ops/engine's finish loop for
+// one pass (a grid: parallel/sharded._unroute and finish_staged's
+// accounting); touches no Python object.
+static void finish_pass(const FinishPass& p, int64_t pi, ChunkFinish& f) {
+  const EgressBlock& b = p.block;
+  // c: a grid's rows a device; either way the stats row, the base row after
+  const int64_t n = p.n, c = b.height() - 2;
+  int64_t base = p.base;
+  if (b.grid()) {
+    // per-row accounting below; the one stat no row carries is summed over
+    // the devices' stats rows
+    for (Py_ssize_t d = 0; d < b.devices(); d++) f.stats[3] += b.at(d, c, 3);
+  } else {
+    for (int k = 0; k < 4; k++) f.stats[k] += b.at(0, c, k);
+    base = (int64_t)((uint64_t)(uint32_t)b.at(0, c + 1, 1) |
+                     ((uint64_t)(int64_t)b.at(0, c + 1, 2) << 32));
+  }
+  const int64_t answered = p.aggregate ? p.members.size() : n;
+  if (pi) {
+    f.later += answered;
+    if (p.aggregate) f.aggregate += answered;
+  }
+  int64_t start = 0;  // the aggregate: where group i's members begin
+  for (int64_t i = 0; i < n; i++) {
+    const Py_ssize_t d = b.grid() ? i / c : 0, r = b.grid() ? i % c : i;
+    const int64_t limit = b.at(d, r, 0), remaining = b.at(d, r, 1);
+    const int32_t delta = b.at(d, r, 2), flags = b.at(d, r, 3);
+    const int64_t reset =
+        delta == RESET_SENTINEL ? 0 : (int64_t)((uint64_t)base + (uint64_t)(int64_t)delta);
+    const int32_t status = flags & FLAG_STATUS;
+    if (flags & FLAG_DROPPED) {
+      f.dropped.push_back(pi);
+      f.dropped.push_back(i);
+      f.dropped.push_back(flags);
+    }
+    if (b.grid()) {
+      const bool unproc = flags & FLAG_UNPROCESSED, member = flags & FLAG_MEMBER;
+      f.overflow += unproc && !member;
+      if (!unproc && !member &&
+          (!p.has_lanes || p.lanes.at(0, i) || p.lanes.at(1, i))) {
+        f.stats[(flags & FLAG_HIT) ? 0 : 1]++;
+        f.stats[2] += status == 1;
+      }
+    }
+    const int64_t fan = p.aggregate ? p.counts.at(i) : 1;
+    if (fan < 0 || start + fan > answered) {
+      f.bad = "an aggregate's counts pass its members";
+      return;
+    }
+    for (int64_t q = start; q < start + fan; q++) {
+      const int64_t at = p.aggregate ? p.members.at(q) : p.rows.at(i);
+      if (at < 0 || at >= f.width) {
+        f.bad = "a pass names a row outside the columns";
+        return;
+      }
+      f.status[at] = status;
+      f.limit[at] = limit;
+      f.remaining[at] = remaining;
+      f.reset[at] = reset;
+    }
+    if (p.aggregate) start += fan;
+  }
+  if (p.aggregate && start != answered)
+    f.bad = "an aggregate's counts fall short of its members";
+}
+
+// finish_wire_chunk(passes: sequence[(block, n, rows | None, members | None,
+//                                     counts | None, base | None,
+//                                     lanes | None)],
+//                   status, limit, remaining, reset_time)
+//   -> None | (cache_hits, cache_misses, over_limit, evicted_unexpired,
+//              later_rows, aggregate_rows, overflow_rows, dropped)
+// The finish half of one fused dispatch (ops/engine._finish_numpy stays as
+// the NumPy twin the tests hold this to, byte for byte), the way out of
+// what stage_wire_chunk staged: every pass's fetched compact egress block
+// decoded (limit and remaining widened, the base-relative reset made
+// absolute, RESET_SENTINEL -> 0, the status bit split from the flags) and
+// written in place into the dispatch's response columns (status int32; limit,
+// remaining, reset_time int64; contiguous, writable) at the rows the pass
+// answers, each member of an aggregate from its group's row. Returns the
+// passes' stats rows summed, the rows answered behind the first pass and of
+// those the aggregate's members, and `dropped`: int64 triples (pass, row of
+// the pass, the row's flags) of the rows whose FLAG_DROPPED is set, in pass
+// and row order, for the caller's retry (their columns hold the dropped
+// answer until it patches them). A (D, c+2, 4) grid is a mesh's pass: its
+// rows are counted one by one (hit, miss, over) unless unprocessed, a
+// member of an in-trace aggregate or a zeroed lane of `lanes`,
+// evicted_unexpired is summed over its D stats rows, `base` is named, and
+// overflow_rows counts its unprocessed rows that are no members. None: a
+// block is not int32 (a full-width pass) and the caller's Python finish
+// runs. Holds no state, and runs with the GIL released from the first row
+// to the last.
+static PyObject* finish_wire_chunk(PyObject*, PyObject* args) {
+  PyObject *passes_o, *so, *lo, *ro, *to;
+  if (!PyArg_ParseTuple(args, "OOOOO", &passes_o, &so, &lo, &ro, &to))
+    return nullptr;
+  FlatBuf st, li, re, rt;
+  if (!st.open(so, 4, true, "status") || !li.open(lo, 8, true, "limit") ||
+      !re.open(ro, 8, true, "remaining") || !rt.open(to, 8, true, "reset_time"))
+    return nullptr;
+  ChunkFinish f;
+  f.width = st.size();
+  if (li.size() != f.width || re.size() != f.width || rt.size() != f.width) {
+    PyErr_SetString(PyExc_ValueError, "response columns differ in length");
+    return nullptr;
+  }
+  f.status = (int32_t*)st.view.buf;
+  f.limit = (int64_t*)li.view.buf;
+  f.remaining = (int64_t*)re.view.buf;
+  f.reset = (int64_t*)rt.view.buf;
+  PyObject* seq = PySequence_Fast(passes_o, "passes: a sequence expected");
+  if (!seq) return nullptr;
+  const size_t k = (size_t)PySequence_Fast_GET_SIZE(seq);
+  std::unique_ptr<FinishPass[]> passes(new FinishPass[k]);
+  bool ok = true, readable = true;
+  for (size_t i = 0; i < k; i++) {
+    FinishPass& p = passes[i];
+    PyObject *block, *rows, *members, *counts, *base, *lanes;
+    long long n;
+    ok = PyArg_ParseTuple(PySequence_Fast_GET_ITEM(seq, (Py_ssize_t)i),
+                          "OLOOOOO", &block, &n, &rows, &members, &counts,
+                          &base, &lanes);
+    if (!ok) break;
+    const int opened = p.block.open(block);
+    if (opened <= 0) {
+      ok = opened == 0;
+      readable = false;
+      break;
+    }
+    p.n = n;
+    p.aggregate = members != Py_None;
+    p.has_lanes = lanes != Py_None;
+    ok = p.aggregate ? p.members.open(members, "members") &&
+                           p.counts.open(counts, "member_counts")
+                     : p.rows.open(rows, "rows");
+    if (ok && p.has_lanes) ok = p.lanes.open(lanes);
+    if (ok && base != Py_None) {
+      p.base = PyLong_AsLongLong(base);
+      ok = !(p.base == -1 && PyErr_Occurred());
+    }
+    if (!ok) break;
+    const EgressBlock& b = p.block;
+    if (n < 0 || n > (b.grid() ? b.devices() * (b.height() - 2) : b.height() - 2) ||
+        (p.aggregate ? p.counts.size() : p.rows.size()) != n ||
+        (p.has_lanes && p.lanes.cols() < n) || (b.grid() && base == Py_None)) {
+      PyErr_SetString(PyExc_ValueError,
+                      "a pass's rows, block, lanes and base disagree");
+      ok = false;
+      break;
+    }
+  }
+  Py_DECREF(seq);
+  if (!ok) return nullptr;
+  if (!readable) Py_RETURN_NONE;
+
+  Py_BEGIN_ALLOW_THREADS;
+  for (size_t i = 0; i < k && !f.bad; i++) finish_pass(passes[i], (int64_t)i, f);
+  Py_END_ALLOW_THREADS;
+  if (f.bad) {
+    PyErr_SetString(PyExc_ValueError, f.bad);
+    return nullptr;
+  }
+  return Py_BuildValue("(LLLLLLLN)", (long long)f.stats[0], (long long)f.stats[1],
+                       (long long)f.stats[2], (long long)f.stats[3],
+                       (long long)f.later, (long long)f.aggregate,
+                       (long long)f.overflow, bytes_of(f.dropped));
+}
+
 // fingerprint64(data: bytes) -> int — parity check hook for tests
 static PyObject* fingerprint64(PyObject*, PyObject* args) {
   Py_buffer buf;
@@ -1267,6 +1515,9 @@ static PyMethodDef methods[] = {
     {"stage_wire_chunk", stage_wire_chunk, METH_VARARGS,
      "a fused chunk's lane blocks and columns -> its grid and every pass "
      "behind it, staged"},
+    {"finish_wire_chunk", finish_wire_chunk, METH_VARARGS,
+     "a fused dispatch's fetched egress blocks -> its response columns, "
+     "written in place, and the rows to retry"},
     {"set_error_strings", set_error_strings, METH_O,
      "the error code -> wire string table encode_responses_many uses"},
     {"fp_index_find", fp_index_find, METH_VARARGS,

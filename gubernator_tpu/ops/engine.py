@@ -41,6 +41,7 @@ from gubernator_tpu.ops.batch import (
     to_device,
 )
 from gubernator_tpu.ops.kernel2 import (
+    FLAG_UNPROCESSED,
     decide2_packed_cols,
     install2,
     pack_outputs,
@@ -216,6 +217,9 @@ class EngineStats:
     # fused dispatches whose host staging was the one native call
     # (ops/wire.stage_wire_chunk), not the NumPy staging
     native_staged: int = 0
+    # fused dispatches whose response columns the one native call wrote
+    # (ops/wire.finish_wire_chunk), not the NumPy finish
+    native_finished: int = 0
     # live rows a decide displaced whose state went to the host-RAM shadow
     # (gubernator_tpu/tier/), each also in `evicted_unexpired`, the
     # kernel's count of displaced live rows: the difference is state LOST
@@ -251,6 +255,7 @@ class EngineStats:
         self.aggregate_rows += d.aggregate_rows
         self.later_lane_rows += d.later_lane_rows
         self.native_staged += d.native_staged
+        self.native_finished += d.native_finished
         self.demoted_live += d.demoted_live
 
 
@@ -557,7 +562,7 @@ class PendingCheck:
     transfer of dispatch N+1 overlap device execution and fetch of N."""
 
     __slots__ = (
-        "hb", "err", "now", "passes", "clamped", "stacked", "rows", "mark",
+        "hb", "err", "now", "passes", "clamped", "rows", "mark",
         "casc", "casc_intrace", "promote", "promote_putback", "native",
     )
 
@@ -565,7 +570,6 @@ class PendingCheck:
         self, hb, err, now, passes, clamped, rows=None, mark=None,
         casc=False, casc_intrace=False, promote=None, native=False,
     ):
-        self.stacked = None  # same-shape pass outputs fused for ONE fetch
         self.hb = hb
         self.err = err
         self.now = now
@@ -1013,9 +1017,6 @@ def issue_check_columns(engine, pending: PendingCheck) -> PendingCheck:
     for entry in pending.passes:
         _p, _n, batch, staged = entry
         entry[3] = engine.issue_staged(staged, _padded_rows(batch))
-    pending.stacked = _stack_pass_outputs(
-        [_pending_out(entry[3]) for entry in pending.passes]
-    )
     return pending
 
 
@@ -1030,65 +1031,141 @@ def _pending_with_out(pend, out):
     return (pend[0], out) if isinstance(pend, tuple) else out
 
 
-# one extra launch that turns N per-pass output fetches into ONE — every
-# device->host fetch is a host sync, and a multi-pass batch (hot-key herds
-# plan up to max_exact sequential passes) otherwise pays N of them per
-# request (the saving is not measured on a co-located host)
-@jax.jit
-def _stack_outs(xs):
-    return jnp.stack(xs)
-
-
-def _stack_pass_outputs(outs):
-    """Fuse same-shape pass outputs into one stacked device array (None when
-    there is nothing to fuse or shapes differ — hot-key herds produce
-    uniformly tiny passes, the case that matters; mixed-shape pass lists
-    would compile a new stack per combination, so they stay per-pass)."""
-    if len(outs) < 2:
-        return None
-    shape = getattr(outs[0], "shape", None)
-    if shape is None or any(getattr(o, "shape", None) != shape for o in outs[1:]):
-        return None
-    # dtype must match too: a batch can mix compact-wire (int32) and
-    # full-width (int64) passes when one pass isn't wire-encodable, and
-    # stacking would silently promote the int32 outputs to int64 —
-    # destroying the dtype tag the host decoder dispatches on
-    dtype = outs[0].dtype
-    if any(o.dtype != dtype for o in outs[1:]):
-        return None
-    return _stack_outs(tuple(outs))
+def fetch_passes(engine, passes) -> None:
+    """ONE fetch of every pass's pending output (`jax.device_get` of the
+    list: every copy to the host is started before the first is waited
+    for), each entry's device handle replaced by its host array, which is
+    what `finish_staged` and `finish_wire` take. A mesh engine banks the
+    fetched device arrays as its next egress buffers (`_recycle_egress`)."""
+    outs = [_pending_out(entry[3]) for entry in passes]
+    fetched = jax.device_get(outs)
+    recycle = getattr(engine, "_recycle_egress", None)
+    for entry, dev, host in zip(passes, outs, fetched):
+        if recycle is not None:
+            recycle(dev)
+        entry[3] = _pending_with_out(entry[3], host)
 
 
 def finish_check_columns(
     engine, pending: PendingCheck, fixup
 ) -> "tuple[ResponseColumns, EngineStats]":
-    """Fetch-thread half: materialize each pass's packed output and assemble
-    the response. The rare feedback path — claim drops needing a re-dispatch
-    — runs through `fixup(fn)`, which executes fn ON THE ENGINE THREAD and
-    returns its result (table mutations stay single-writer). Returns the
-    response plus a stats delta for the caller to apply on the engine
-    thread. Store-configured engines never take this path (EngineRunner
-    routes them to the serial one): the Store contract needs rehydrates and
-    write-throughs ordered against every same-key dispatch, which a
-    pipeline with interleaved chunks cannot guarantee."""
+    """Fetch-thread half: materialize every pass's packed output in one
+    fetch and assemble the response. The rare feedback path — claim drops
+    needing a re-dispatch — runs through `fixup(fn)`, which executes fn ON
+    THE ENGINE THREAD and returns its result (table mutations stay
+    single-writer). Returns the response plus a stats delta for the caller
+    to apply on the engine thread. Store-configured engines never take
+    this path (EngineRunner routes them to the serial one): the Store
+    contract needs rehydrates and write-throughs ordered against every
+    same-key dispatch, which a pipeline with interleaved chunks cannot
+    guarantee.
+
+    A fused dispatch's passes are decoded and scattered to request order
+    by one native call, GIL-free from the first row to the last
+    (`_finish_native`): on a loaded host every array call queues for the
+    GIL again, and the NumPy finish is about thirty of them a pass. Where
+    the module is not loaded, or the dispatch is not one the call reads,
+    `_finish_numpy` is that finish, byte for byte."""
     if not isinstance(pending, PendingCheck):  # engine-specific pending
         return engine.finish_pending(pending, fixup)
-    if pending.stacked is not None:
-        # ONE fetch materializes every pass's output; hand each pass its
-        # already-fetched slice (finish_staged's np.asarray is then a no-op)
-        fetched = np.asarray(pending.stacked)
-        for i, entry in enumerate(pending.passes):
-            entry[3] = _pending_with_out(entry[3], fetched[i])
-    err, now = pending.err, pending.now
+    fetch_passes(engine, pending.passes)
     n = pending.rows
-    status = np.zeros(n, dtype=np.int32)
-    limit_o = np.zeros(n, dtype=np.int64)
-    remaining = np.zeros(n, dtype=np.int64)
-    reset = np.zeros(n, dtype=np.int64)
+    cols = (
+        np.zeros(n, dtype=np.int32), np.zeros(n, dtype=np.int64),
+        np.zeros(n, dtype=np.int64), np.zeros(n, dtype=np.int64),
+    )
     delta = EngineStats(
         created_at_clamped=pending.clamped, checks=n,
         native_staged=int(pending.native),
     )
+    retried = _finish_native(engine, pending, cols, delta, fixup)
+    if retried is None:
+        retried = _finish_numpy(engine, pending, cols, delta, fixup)
+    status, limit_o, remaining, reset = cols
+    if pending.casc and (retried or not pending.casc_intrace):
+        # the in-trace fold (when it ran) predates any dropped-row retry;
+        # the idempotent host fold makes the carriers authoritative again.
+        # Fused wire batches materialize their HostBatch only on this rare
+        # path (cascade batch AND a claim drop).
+        hbm = pending.hb
+        if not isinstance(hbm, HostBatch):
+            hbm = hbm._materialize()
+        _fold_cascades_host(hbm.behavior, status, remaining, reset, pending.err)
+    rc = ResponseColumns(
+        status=status, limit=limit_o, remaining=remaining,
+        reset_time=reset, err=pending.err,
+    )
+    return rc, delta
+
+
+def _finish_native(engine, pending, cols, delta, fixup) -> "bool | None":
+    """The finish of a fused dispatch in one native call: every fetched
+    block decoded into `cols` at its rows' places (the engine names its
+    blocks: `finish_wire`), the summed stats into `delta`. Returns whether
+    a row was retried, or None where the NumPy finish has to run: no
+    native module, a pass that was not staged from the lanes (packed as
+    columns, its output maybe full-width), or a promote that handed rows
+    back to the shadow, whose re-check reads pass 0 decoded
+    (`_shadow_rehydrate`). The rows that came back dropped are retried as
+    the NumPy finish retries them, pass after pass, and patched into the
+    columns the call wrote: a dropped row is a live one (kernel2: `active &
+    ~written`), and a live row of the grid is answered by no pass behind
+    it, so one pass's patches never meet another's rows."""
+    from gubernator_tpu import native
+
+    mod = native.load()
+    finish = getattr(engine, "finish_wire", None)
+    putback = pending.promote_putback
+    if (
+        mod is None or finish is None
+        or (putback is not None and putback.shape[0])
+        or not all(isinstance(e[2], _LazyWireBatch) for e in pending.passes)
+    ):
+        return None
+    done = finish(mod, pending.passes, cols)
+    if done is None:
+        return None
+    delta.native_finished = 1
+    delta.cache_hits, delta.cache_misses, delta.over_limit, \
+        delta.evicted_unexpired = done.stats
+    delta.dispatches = len(pending.passes)
+    delta.later_rows = delta.later_lane_rows = done.later_rows
+    delta.aggregate_rows = done.aggregate_rows
+    if not len(done.dropped):
+        return False
+    status, limit_o, remaining, reset = cols
+    for pi in np.unique(done.dropped[:, 0]):
+        p, _n, batch, _pend = pending.passes[pi]
+        mine = done.dropped[done.dropped[:, 0] == pi]
+        rows = mine[:, 1]
+        sub = batch.select(rows)
+        # rows the pass never processed (a2a capacity drops) have their
+        # outcome counted by the retry
+        unc = (mine[:, 2] & FLAG_UNPROCESSED) != 0
+        s2, l2, r2, t2, d2, _h2 = fixup(
+            lambda: engine._redispatch_rows(sub, len(rows), uncounted=unc)
+        )
+        if p.members is None:
+            at = p.rows[rows]
+        else:  # a retried row of the aggregate answers its group's members
+            ends = np.cumsum(p.member_counts)
+            at = np.concatenate(
+                [p.members[ends[g] - p.member_counts[g]:ends[g]] for g in rows]
+            )
+            fan = np.repeat(np.arange(len(rows)), p.member_counts[rows])
+            s2, l2, r2, t2, d2 = s2[fan], l2[fan], r2[fan], t2[fan], d2[fan]
+        status[at], limit_o[at], remaining[at], reset[at] = s2, l2, r2, t2
+        pending.err[at[d2]] = ERR_DROPPED
+    return True
+
+
+def _finish_numpy(engine, pending, cols, delta, fixup) -> bool:
+    """The finish in NumPy, pass after pass: what a host with no toolchain
+    runs, what every dispatch staged as columns runs, and what the tests
+    hold `_finish_native` to byte for byte. Returns whether a row was
+    retried."""
+    status, limit_o, remaining, reset = cols
+    err = pending.err
     retried_any = False
     for pi, (p, np_, batch, pend) in enumerate(pending.passes):
         (s, l, r, t, dropped, hit), st, uncounted = engine.finish_staged(
@@ -1154,20 +1231,7 @@ def finish_check_columns(
             delta.later_rows += len(rows)
             if not isinstance(batch, HostBatch):
                 delta.later_lane_rows += len(rows)
-    if pending.casc and (retried_any or not pending.casc_intrace):
-        # the in-trace fold (when it ran) predates any dropped-row retry;
-        # the idempotent host fold makes the carriers authoritative again.
-        # Fused wire batches materialize their HostBatch only on this rare
-        # path (cascade batch AND a claim drop).
-        hbm = pending.hb
-        if not isinstance(hbm, HostBatch):
-            hbm = hbm._materialize()
-        _fold_cascades_host(hbm.behavior, status, remaining, reset, err)
-    rc = ResponseColumns(
-        status=status, limit=limit_o, remaining=remaining,
-        reset_time=reset, err=err,
-    )
-    return rc, delta
+    return retried_any
 
 
 class LocalEngine:
@@ -1690,14 +1754,30 @@ class LocalEngine:
         )
 
     def finish_staged(self, pending, n: int):
-        """Materialize one pass's packed output → ((s, l, r, t, dropped,
+        """Decode one pass's fetched packed output → ((s, l, r, t, dropped,
         hit), (hits, misses, over, evicted), uncounted). The single-device
         kernel probes every row, so `uncounted` is always None here (cf.
         ShardedEngine's a2a capacity drops). With a shadow attached this
         is the hits-only program's output: the rows it deferred come back
         dropped and go the retry's way, `_redispatch_rows`."""
-        outs, st = unpack_outputs(np.asarray(pending), n)
+        outs, st = unpack_outputs(pending, n)
         return outs, st, None
+
+    def finish_wire(self, mod, passes, cols):
+        """The native finish of a fused dispatch's `passes` (fetched) into
+        `cols` (ops/wire.finish_wire_chunk; fetch thread): each pass's
+        compact egress array is its block, base and stats rows in it.
+        None where a pass came back full-width."""
+        from gubernator_tpu.ops.wire import finish_wire_chunk
+
+        return finish_wire_chunk(
+            mod,
+            [
+                (out, n, p.rows, p.members, p.member_counts, None, None)
+                for p, n, _batch, out in passes
+            ],
+            cols,
+        )
 
     def _redispatch_rows(self, batch, n: int, uncounted=None):
         """Re-dispatch rows whose phase-1 claim dropped (pipelined retry):

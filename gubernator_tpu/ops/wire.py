@@ -625,3 +625,42 @@ def _as_block(buf, pad: int) -> "np.ndarray | None":
 
 def _as_rows(buf) -> "np.ndarray | None":
     return None if buf is None else np.frombuffer(buf, np.int64)
+
+
+# ---------------------------------------------- a fused dispatch, finished
+
+
+class WireFinish(NamedTuple):
+    """What `finish_wire_chunk` found while it wrote a dispatch's response
+    columns: the passes' `stats` summed (cache_hits, cache_misses,
+    over_limit, evicted_unexpired), the rows answered behind the first pass
+    and of those the aggregate's members, a mesh's unprocessed rows
+    (`overflow`), and `dropped`: one (pass, row of the pass, the row's
+    flags) for every row whose FLAG_DROPPED is set, in pass and row order."""
+
+    stats: "tuple[int, int, int, int]"
+    later_rows: int
+    aggregate_rows: int
+    overflow: int
+    dropped: np.ndarray  # (k, 3) int64
+
+
+def finish_wire_chunk(mod, passes, cols) -> "WireFinish | None":
+    """The finish half of one fused dispatch from ONE call into the native
+    module `mod` (native/guberhost.cpp finish_wire_chunk), which runs
+    without the GIL from the first row to the last: every pass's fetched
+    compact egress block decoded and scattered to request order, in place,
+    into `cols` (status int32; limit, remaining, reset_time int64).
+    `passes`: a (block, n, rows, members, member_counts, base, lanes) each —
+    `rows`, or `members` with `member_counts` for the aggregate, as
+    ops/plan.Pass holds them; `base` and `lanes` a mesh's grid names, None
+    for a block that carries its own. None: a block is not int32 and the
+    NumPy finish (ops/engine._finish_numpy, the same function of the same
+    arrays, and what the tests hold this one to byte for byte) is the path."""
+    out = mod.finish_wire_chunk(passes, *cols)
+    if out is None:
+        return None
+    return WireFinish(
+        out[:4], out[4], out[5], out[6],
+        np.frombuffer(out[7], np.int64).reshape(-1, 3),
+    )

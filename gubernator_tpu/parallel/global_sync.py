@@ -194,7 +194,6 @@ class GlobalPending:
     # [Pass, n_rows, batch, staged→(staged, out), table_attr, home_pin, rowmap]
     passes: list
     clamped: int
-    stacked: object = None  # same-shape pass outputs fused for ONE fetch
 
 
 @dataclass
@@ -704,8 +703,6 @@ class GlobalShardedEngine(ShardedEngine):
     def issue_pending(self, pending: "GlobalPending") -> "GlobalPending":
         """Issue hook (engine thread): fold the queued hits into the sync
         accumulator, then launch every staged dispatch without fetching."""
-        from gubernator_tpu.ops.engine import _stack_pass_outputs
-
         self._ensure_global_plane()
         # checkpoint marking for the pipelined GLOBAL fork: replica-pinned
         # rows are a harmless superset (dirty blocks only cost extract
@@ -718,22 +715,16 @@ class GlobalShardedEngine(ShardedEngine):
             table, out = self._decide(getattr(self, table_attr), staged)
             setattr(self, table_attr, table)
             entry[3] = (staged, out)
-        pending.stacked = _stack_pass_outputs(
-            [entry[3][1] for entry in pending.passes]
-        )
         return pending
 
     def finish_pending(self, pending: "GlobalPending", fixup):
         """Finish hook (fetch thread): materialize every pass's output and
         assemble the full response; claim-drop retries run on the engine
         thread via `fixup` against the same table (replica pins preserved)."""
-        from gubernator_tpu.ops.engine import EngineStats
+        from gubernator_tpu.ops.engine import EngineStats, fetch_passes
 
-        if pending.stacked is not None:
-            # ONE fetch for every pass's output (cf. finish_check_columns)
-            fetched = np.asarray(pending.stacked)
-            for i, entry in enumerate(pending.passes):
-                entry[3] = (entry[3][0], fetched[i])
+        # ONE fetch for every pass's output (cf. finish_check_columns)
+        fetch_passes(self, pending.passes)
         hb, err = pending.hb, pending.err
         n = hb.fp.shape[0]
         status = np.zeros(n, dtype=np.int32)

@@ -659,11 +659,9 @@ class ShardedEngine:
         )
 
     def _recycle_egress(self, out) -> None:
-        """Bank a fetched output array for reuse as a donated egress buffer.
-        Fused multi-pass fetches hand finish_staged a numpy slice instead of
-        the device array (engine._stack_pass_outputs) — nothing to bank."""
-        if isinstance(out, np.ndarray):
-            return
+        """Bank a fetched output array for reuse as a donated egress buffer
+        (the serial dispatch after its fetch; the pipelined finish after
+        its one fetch of every pass, engine.fetch_passes)."""
         with self._egress_lock:
             bank = self._egress.setdefault((out.shape, out.dtype.str), [])
             if len(bank) < 8:
@@ -1215,9 +1213,7 @@ class ShardedEngine:
         return staged, out
 
     def finish_staged(self, pending, n: int):
-        staged, out = pending
-        outh = np.asarray(out)
-        self._recycle_egress(out)
+        staged, outh = pending  # fetched (engine.fetch_passes)
         s, l, r, t, dropped, hit, unproc, member, evicted = self._unroute(
             staged, outh, n
         )
@@ -1237,6 +1233,35 @@ class ShardedEngine:
             evicted,
         )
         return (s, l, r, t, dropped, hit), st, unproc
+
+    def finish_wire(self, mod, passes, cols):
+        """The native finish of a fused dispatch's `passes` (fetched) into
+        `cols` (ops/wire.finish_wire_chunk; fetch thread): `_unroute` and
+        `finish_staged`'s accounting for an arrival-order compact grid, in
+        the one routine the local engine's finish is — the (D, c+2, 4)
+        egress grid is the block, the staged base its base, and the
+        chunk's lanes say which rows the kernel never saw. Timed as
+        `shard_unroute`, like the NumPy decode. None where a pass is not
+        such a grid (host-routed, full-width)."""
+        from gubernator_tpu.ops.wire import finish_wire_chunk
+
+        blocks = []
+        for p, n, _batch, (staged, outh) in passes:
+            if not (isinstance(staged, _StagedA2A) and staged.wire):
+                return None
+            blocks.append((
+                outh, n, p.rows, p.members, p.member_counts, staged.base,
+                staged.lanes,
+            ))
+        with tracing.stage.within("shard_unroute"):
+            done = finish_wire_chunk(mod, blocks, cols)
+        if done is None:
+            return None
+        self._wire_count("fetch", sum(b[0].nbytes for b in blocks))
+        if done.overflow:
+            with self._stage_lock:
+                self.a2a_overflow += done.overflow
+        return done
 
     def _redispatch_rows(self, batch: HostBatch, n: int, uncounted=None):
         """Pipelined-retry hook (engine thread): depth=1 counts evictions and

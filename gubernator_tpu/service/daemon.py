@@ -662,28 +662,6 @@ class Daemon:
                         # runs the hits-only one, and a promote the merge
                         shapes += await self._warm_tier_pad(warm)
                 size *= 2
-            # herd geometries: a same-key batch plans j sequential passes
-            # (j ≤ max_exact) whose same-shape outputs fuse into one
-            # stacked fetch (ops/engine._stack_pass_outputs) — trace the
-            # stack kernel for every pass count now, or the first
-            # production herd pays that compile on the request path
-            max_exact = getattr(self.engine, "max_exact_passes", 8)
-            for j in range(2, max_exact + 1):
-                warm = RequestColumns(
-                    fp=np.full(j, 7, dtype=np.int64),
-                    algo=np.zeros(j, dtype=np.int32),
-                    behavior=np.zeros(j, dtype=np.int32),
-                    hits=np.zeros(j, dtype=np.int64),
-                    limit=np.ones(j, dtype=np.int64),
-                    burst=np.zeros(j, dtype=np.int64),
-                    duration=np.ones(j, dtype=np.int64),
-                    created_at=np.zeros(j, dtype=np.int64),
-                    err=np.zeros(j, dtype=np.int8),
-                )
-                # through the PIPELINED door: the stack kernel only traces
-                # on the issue path (serial check_columns never stacks)
-                await self.runner.check(warm)
-                shapes += 1
             if (
                 getattr(self.engine, "mesh_global", False)
                 and self.engine.store is None
@@ -1944,6 +1922,11 @@ class Daemon:
                 # call (ops/wire.stage_wire_chunk): all of
                 # batcher.fused_dispatches where the module is loaded
                 "native_staged": eng.stats.native_staged,
+                # and those whose finish half was the one native call
+                # (ops/wire.finish_wire_chunk: every pass decoded and
+                # scattered to request order): all of them too, but a
+                # dispatch with a pass the lanes could not carry
+                "native_finished": eng.stats.native_finished,
                 # buckets a dirty block of the incremental checkpoint's
                 # tracker holds: there when the plane is armed and the
                 # tracker attached (every dispatch marks), else None

@@ -308,8 +308,12 @@ class EngineRunner:
             return self._exec.submit(fn).result()
 
         def finish(pending):
-            with tracing.stage("fetch", self.metrics, disp=disp):
+            with tracing.stage("fetch", self.metrics, disp=disp) as st:
                 rc, delta = finish_check_columns(self.engine, pending, fixup)
+                st.note(
+                    passes=delta.dispatches, rows=delta.checks,
+                    native=delta.native_finished,
+                )
             # fire-and-forget, engine thread
             self._exec.submit(self._apply, [delta], disp)
             return rc if disp is None or disp.tail is None else disp.tail(rc)

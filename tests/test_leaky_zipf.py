@@ -15,6 +15,7 @@ its place in `/metrics` and in a profiler trace.
 
 import asyncio
 import json
+import dataclasses
 import os
 
 import numpy as np
@@ -246,8 +247,10 @@ async def test_a_zipf_chunk_answers_the_same_staged_natively_or_by_numpy(rpcs, m
     """The deployment's chunk, twice (the second past the hot keys' limit),
     on two equal engines: one staged by the native call, one with
     `native.load()` patched to None. Every answer and every stored row are
-    the same bytes, both dispatches issue the same passes, and
-    `engine.native_staged` counts the dispatches of the first alone."""
+    the same bytes, both dispatches issue the same passes and count the
+    same stats, and `engine.native_staged` and `engine.native_finished`
+    count the dispatches of the first alone: the staging and the finish
+    (ops/wire.finish_wire_chunk against ops/engine._finish_numpy)."""
     rng = np.random.default_rng(39_001)
     now = ms_now()
     chunks = []
@@ -282,6 +285,10 @@ async def test_a_zipf_chunk_answers_the_same_staged_natively_or_by_numpy(rpcs, m
         assert found_a.all() and found_b.all() and (rows_a == rows_b).all()
         for field in ("later_rows", "aggregate_rows", "later_lane_rows", "dispatches"):
             assert getattr(r_native.engine.stats, field) == getattr(r_numpy.engine.stats, field) > 0
+        a, b = (dataclasses.asdict(r.engine.stats) for r in (r_native, r_numpy))
+        assert [a.pop(k) for k in ("native_staged", "native_finished")] == [2, 2]
+        assert [b.pop(k) for k in ("native_staged", "native_finished")] == [0, 0]
+        assert a == b
     finally:
         r_native.close()
         r_numpy.close()
@@ -326,6 +333,9 @@ async def test_chunks_staged_on_two_threads_at_once_answer_as_one_at_a_time(monk
         assert a.later_rows == b.later_rows > 240 * 40
         assert a.aggregate_rows == b.aggregate_rows > 0
         assert 0 < a.later_lane_rows == b.later_lane_rows < a.later_rows
+        # finished on four fetch threads side by side by the call that holds
+        # no state either, but the chunks with a pass off the lanes
+        assert 0 < a.native_finished < 240 and b.native_finished == 0
     finally:
         r_native.close()
         r_numpy.close()

@@ -19,7 +19,7 @@ import pytest
 
 import jax
 
-from gubernator_tpu import native
+from gubernator_tpu import native, tracing
 from gubernator_tpu.ops import engine as engine_mod
 from gubernator_tpu.ops.batch import pack_columns
 from gubernator_tpu.ops.engine import ms_now, prepare_check_wire
@@ -31,7 +31,7 @@ from gubernator_tpu.service.runner import EngineRunner
 from gubernator_tpu.service.wire import concat_columns
 from gubernator_tpu.store import RecordingStore
 
-from tests.test_native import _level_bit
+from tests.test_native import _blank_columns, _level_bit
 from tests.test_observability import _stage_sums
 from tests.test_runner_chain import assert_same, async_test, wire_batch
 from tests.test_wire_split import EMPTY_KEY, EMPTY_NAME, rpc
@@ -182,8 +182,11 @@ async def wire_against_columns(r_wire, r_cols, parts, now):
     want = await r_cols.check(cols, now_ms=now)
     assert_same(got, want)
     deltas = [{k: a[k] - b[k] for k in a} for a, b in zip(stats(), before)]
-    staged = deltas[0].pop("native_staged")
-    assert deltas[1].pop("native_staged") == 0 and deltas[0] == deltas[1]
+    staged, finished = (
+        deltas[0].pop(k) for k in ("native_staged", "native_finished")
+    )
+    assert not deltas[1].pop("native_staged") + deltas[1].pop("native_finished")
+    assert deltas[0] == deltas[1] and finished == staged
     fps = np.unique(cols.fp[cols.err == 0])
     (found_w, rows_w), (found_c, rows_c) = (
         r.engine.read_state(fps) for r in (r_wire, r_cols)
@@ -373,3 +376,98 @@ async def test_an_exchange_overflow_on_a_fused_chunk_is_retried(mesh, monkeypatc
         assert delta["cache_misses"] == 2048 and delta["dispatches"] > 1
     finally:
         close(r_wire, r_cols)
+
+
+# ---------------------------------------------------------- (e) the finish
+
+
+@pytest.mark.parametrize("n", [7, 1000, 2977])
+def test_the_native_finish_is_the_numpy_unroute_byte_for_byte(mesh, n):
+    """A fused mesh dispatch finished by the one native call
+    (`ShardedEngine.finish_wire` over the (D, c+2, 4) egress grid) and by
+    `_unroute` + `finish_staged` + the NumPy scatter, both handed one
+    made-up grid in which every flag turns up — a dropped row, an
+    unprocessed one, a member of an in-trace aggregate, the reset sentinel —
+    on live rows and on the error rows' zeroed lanes: the same columns, the
+    same `err`, the same stats delta and overflow count, the same rows
+    retried with the same `uncounted` mask."""
+    now = ms_now()
+    parts = chunk(n, now, repeats=True)
+    rng = np.random.default_rng(n)
+    grid, out = None, []
+    for finish in (engine_mod._finish_native, engine_mod._finish_numpy):
+        eng = new_engine(mesh)
+        pending = prepare_check_wire(eng, parts, now_ms=now)
+        (p, n_rows, lazy, staged), = pending.passes
+        if grid is None:
+            D, c = eng.n_shards, staged.c
+            grid = rng.integers(-(2**31), 2**31, (D, c + 2, 4)).astype(np.int32)
+            flags = rng.integers(0, 4, (D, c))  # status, hit
+            live = np.zeros(D * c, bool)
+            live[:n_rows] = lazy.active
+            lost = live.reshape(D, c) & (rng.random((D, c)) < 0.1)
+            unproc = lost & (rng.random((D, c)) < 0.5)
+            member = rng.random((D, c)) < 0.2
+            grid[:, :c, 3] = flags | lost << 2 | unproc << 3 | member << 4
+            grid[:, :c, 2][rng.random((D, c)) < 0.2] = -(2**31)
+            grid[:, c] = rng.integers(0, 1000, (D, 4))
+            assert lost.any() and unproc.any() and (member & ~live.reshape(D, c)).any()
+        pending.passes[0][3] = (staged, grid.copy())
+        cols = _blank_columns(n_rows)
+        delta, calls = engine_mod.EngineStats(), []
+
+        def redispatch(sub, m, uncounted=None):
+            fp = np.asarray(sub.fp[:m])
+            calls.append((fp.copy(), uncounted.copy()))
+            return (
+                (fp % 2).astype(np.int32), fp % 1009, fp % 997, fp % 991 + 1,
+                fp % 3 == 0, fp % 5 == 0,
+            )
+
+        eng._redispatch_rows = redispatch
+        assert finish(eng, pending, cols, delta, lambda fn: fn()) is True
+        out.append((
+            cols, bytes(pending.err), dataclasses.asdict(delta), calls,
+            eng.a2a_overflow, eng.wire_bytes["fetch"],
+        ))
+    got, want = out
+    for g, w in zip(got[0], want[0]):
+        assert g.dtype == w.dtype and g.tobytes() == w.tobytes()
+    assert got[1] == want[1] and got[4] == want[4] > 0 and got[5] == want[5] > 0
+    assert got[2].pop("native_finished") == 1 and not want[2].pop("native_finished")
+    assert got[2] == want[2] and got[2]["cache_hits"] > 0 < got[2]["over_limit"]
+    (fp_g, unc_g), = got[3]
+    (fp_w, unc_w), = want[3]
+    assert (fp_g == fp_w).all() and (unc_g == unc_w).all() and unc_g.any()
+
+
+@async_test
+async def test_a_mesh_chunk_answers_the_same_finished_natively_or_by_numpy(
+    mesh, monkeypatch
+):
+    """One chunk with repeats and error rows through `check_wire` on two
+    equal mesh engines, the second with `native.load()` patched to None:
+    same bytes, same stats, `native_finished` 1 and 0, and the decode is a
+    `shard_unroute` sample on both."""
+    now = ms_now()
+    parts = chunk(2977, now, repeats=True)
+    metrics = [DaemonMetrics(), DaemonMetrics()]
+    r_native, r_numpy = (
+        EngineRunner(new_engine(mesh), metrics[i]) for i in range(2)
+    )
+    try:
+        disp = [tracing.Dispatch(seq=i, rows=2977) for i in range(2)]
+        got = await r_native.check_wire(parts, now_ms=now, disp=disp[0])
+        monkeypatch.setattr(native, "load", lambda: None)
+        assert_same(await r_numpy.check_wire(parts, now_ms=now, disp=disp[1]), got)
+        for r in (r_native, r_numpy):
+            r._exec.submit(lambda: None).result()
+        a, b = (dataclasses.asdict(r.engine.stats) for r in (r_native, r_numpy))
+        assert [a.pop(k) for k in ("native_staged", "native_finished")] == [1, 1]
+        assert [b.pop(k) for k in ("native_staged", "native_finished")] == [0, 0]
+        assert a == b and a["checks"] == 2977
+        for m in metrics:
+            assert _stage_sums(m)["shard_unroute"][1] == 1
+        assert r_native.engine.wire_bytes == r_numpy.engine.wire_bytes
+    finally:
+        close(r_native, r_numpy)

@@ -535,6 +535,278 @@ def test_stage_wire_chunk_holds_no_state_between_threads():
     assert done == [True] * 240
 
 
+# ------------------------------------------------------ finish_wire_chunk
+# (the finish half of a fused dispatch, one GIL-free call) against the NumPy
+# finish of ops/engine.py, byte for byte: the same prepared dispatch twice,
+# both handed the same "fetched" egress blocks — made up here, so that every
+# flag, the reset sentinel and a dropped row turn up in every pass — and a
+# retry that answers from the rows it is asked for
+
+
+def _egress_block(rng, pad, n, base, dropped=0.0, sidecar=False, strided=False,
+                  live=None):
+    """A compact egress block as a pass of `n` rows padded to `pad` would
+    fetch it: rows, (a tiered program's 4*pad sidecar rows,) stats, base.
+    Only a `live` row comes back dropped (kernel2: active & ~written)."""
+    height = (5 * pad if sidecar else pad) + 2
+    big = rng.integers(-(2**31), 2**31, (2 * height, 8), dtype=np.int64).astype(np.int32)
+    block = big[::2, ::2] if strided else np.ascontiguousarray(big[:height, :4])
+    block[:pad, 3] = rng.integers(0, 4, pad)  # status and hit
+    lost = rng.random(pad) < dropped
+    if live is not None:
+        lost[:n] &= live
+    block[:pad, 3] |= lost.astype(np.int32) << 2
+    block[:pad, 2][rng.random(pad) < 0.2] = -(2**31)  # reset_time == 0
+    block[-2] = rng.integers(0, 1000, 4)
+    block[-1] = [0, np.int64(base & 0xFFFFFFFF).astype(np.int32), base >> 32, 0]
+    assert block.flags.c_contiguous != strided
+    return block
+
+
+def _blank_columns(n):
+    """A dispatch's four response columns before its finish."""
+    return (
+        np.zeros(n, np.int32), np.zeros(n, np.int64),
+        np.zeros(n, np.int64), np.zeros(n, np.int64),
+    )
+
+
+def _retry_from_the_rows(calls):
+    """`_redispatch_rows` as a function of the rows alone: what each answers
+    is read off its fingerprint, one in three stays dropped."""
+    def redispatch(sub, n, uncounted=None):
+        fp = np.asarray(sub.fp[:n])
+        assert uncounted is None or not uncounted.any()
+        calls.append(fp.copy())
+        return (
+            (fp % 2).astype(np.int32), fp % 1009, fp % 997, fp % 991 + 1,
+            fp % 3 == 0, fp % 5 == 0,
+        )
+    return redispatch
+
+
+def _finish_both(parts, blocks, tol=5_000, max_exact=8, edit=None):
+    """The chunk prepared twice on one engine, each pending handed its own
+    copy of `blocks(pads, base)` as fetched; finished by the native call
+    and by NumPy. Returns both (columns, err, stats delta, retried rows)."""
+    import dataclasses
+
+    from gubernator_tpu.ops import engine as eng_mod
+    from gubernator_tpu.ops.wire import block_base
+
+    parts = [rpc(p, STAGE_NOW) for p in parts]
+    if edit is not None:
+        parts = edit(parts)
+    engine = eng_mod.LocalEngine(
+        capacity=1024, wire="compact", max_exact_passes=max_exact,
+        created_at_tolerance_ms=tol,
+    )
+    out = []
+    for finish in (eng_mod._finish_native, eng_mod._finish_numpy):
+        pending = eng_mod.prepare_check_wire(engine, parts, now_ms=STAGE_NOW)
+        if pending is None:
+            return None
+        staged = [entry[3][0] for entry in pending.passes]
+        made = blocks(
+            [
+                (eng_mod._padded_rows(e[2]), e[1], e[2].active if i == 0 else None)
+                for i, e in enumerate(pending.passes)
+            ],
+            block_base(np.asarray(staged[0])),
+        )
+        for entry, block in zip(pending.passes, made):
+            entry[3] = block.copy() if block.flags.c_contiguous else block
+        cols = _blank_columns(pending.rows)
+        delta, calls = eng_mod.EngineStats(), []
+        engine._redispatch_rows = _retry_from_the_rows(calls)
+        retried = finish(engine, pending, cols, delta, lambda fn: fn())
+        if retried is None:
+            out.append(None)
+            continue
+        out.append((cols, pending.err, dataclasses.asdict(delta), calls, retried))
+    return out
+
+
+def _same_finish(got, want):
+    (cols_g, err_g, delta_g, calls_g, retried_g) = got
+    (cols_w, err_w, delta_w, calls_w, retried_w) = want
+    for g, w, name in zip(cols_g, cols_w, ("status", "limit", "remaining", "reset")):
+        _same_bytes(g, w, name)
+    assert bytes(err_g) == bytes(err_w)
+    assert delta_g.pop("native_finished") == 1 and not delta_w.pop("native_finished")
+    assert delta_g == delta_w and retried_g == retried_w
+    assert len(calls_g) == len(calls_w)
+    for g, w in zip(calls_g, calls_w):
+        _same_bytes(g, w, "the rows a retry was asked for")
+
+
+# a staging shape, then how its passes' blocks come back
+FINISHES = {
+    **{
+        name: (parts, how, {})
+        for name, (parts, how) in STAGINGS.items()
+        if name in (
+            "one_part_no_repeat", "many_parts_no_repeat", "pair_inside_one_rpc",
+            "one_key_3_times", "one_key_8_times", "one_key_9_times",
+            "one_key_20_times", "two_keys_past_the_aggregate",
+            "next_to_error_rows", "error_rows_between_copies_past_the_aggregate",
+            "every_later_copy_in_the_aggregate", "one_exact_pass_then_the_aggregate",
+            "lanes_that_are_not_contiguous", "an_error_row_under_a_keys_fingerprint",
+            "cascade_bits_and_no_repeat", "reset_remaining_beside_priority_bits",
+            "a_chunk_that_fills_its_pad_to_the_last_row", "zipf_3000_rows",
+            # a pass the lanes cannot carry is packed as columns: NumPy's
+            "an_aggregate_whose_hits_pass_the_lane", "zipf_3000_rows_some_stamps_late",
+        )
+    },
+    "dropped_rows_in_the_grid_and_in_later_passes": (
+        STAGINGS["zipf_3000_rows"][0], {}, {"dropped": 0.05},
+    ),
+    "dropped_members_of_the_aggregate": (
+        [[7] * 12 + [8] * 9 + [9, 7, 8]], {"max_exact": 3}, {"dropped": 1.0},
+    ),
+    "a_dropped_cascade_row_folds_again_on_the_host": (
+        [[1, 2, 3]], {"edit": _level_bit}, {"dropped": 0.7},
+    ),
+    "blocks_that_are_not_contiguous": (
+        STAGINGS["zipf_3000_rows"][0], {}, {"dropped": 0.01, "strided": True},
+    ),
+    "a_tiered_block_with_its_sidecar": (
+        [[1, 2, 1, 3, 1]], {}, {"dropped": 0.9, "sidecar": True},
+    ),
+}
+
+
+def test_the_finish_cases_name_stagings_that_exist():
+    assert len(FINISHES) == 25  # a name that SHAPES lost would drop its case
+
+
+@pytest.mark.parametrize("case", FINISHES)
+def test_finish_wire_chunk_is_the_numpy_finish_byte_for_byte(case):
+    parts, how, back = FINISHES[case]
+
+    def blocks(pads, base):  # the same for both finishes
+        rng = np.random.default_rng(49)
+        return [
+            _egress_block(rng, pad, n, base, live=live, **back)
+            for pad, n, live in pads
+        ]
+
+    got, want = _finish_both(parts, blocks, **how)
+    if case in ("an_aggregate_whose_hits_pass_the_lane", "zipf_3000_rows_some_stamps_late"):
+        assert got is None and want[2]["later_lane_rows"] < want[2]["later_rows"]
+        return
+    _same_finish(got, want)
+    cols, _err, delta, calls, retried = want
+    assert delta["dispatches"] >= 1 and retried == bool(calls)
+    assert retried == ("dropped" in back)
+    if case == "zipf_3000_rows":
+        assert delta["dispatches"] == 8 and delta["aggregate_rows"] > 100
+        assert delta["later_lane_rows"] == delta["later_rows"] > 1000
+        assert (cols[3] == 0).any() and (cols[0] == 1).any()
+    if case == "dropped_rows_in_the_grid_and_in_later_passes":
+        assert len(calls) == 8  # every pass had a row to retry
+    if case == "dropped_members_of_the_aggregate":
+        assert delta["dispatches"] == 3 and delta["aggregate_rows"] == 19
+        assert [c.size for c in calls] == [3, 2, 2]  # both groups retried
+
+
+def test_finish_wire_chunk_refuses_what_it_cannot_read():
+    """A full-width (int64) block among the passes is the Python finish's
+    (None, nothing written); what is no block, or names rows outside the
+    columns, raises."""
+    from gubernator_tpu.ops import wire
+
+    rng = np.random.default_rng(5)
+    block = _egress_block(rng, 16, 3, STAGE_NOW)
+    rows = np.arange(3)
+    cols = lambda: _blank_columns(3)
+    one = lambda b=block, n=3, r=rows, mem=None, cnt=None: [(b, n, r, mem, cnt, None, None)]
+    done = wire.finish_wire_chunk(m, one(), cols())
+    assert done.stats == tuple(block[-2]) and done.later_rows == 0
+    out = cols()
+    assert wire.finish_wire_chunk(m, one(block.astype(np.int64)), out) is None
+    assert wire.finish_wire_chunk(m, one() + one(block.astype(np.int64)), cols()) is None
+    assert not any(c.any() for c in out)
+    for bad, exc in (
+        (one(block[:, :3]), TypeError),  # three cells a row
+        (one(b"x" * 8), TypeError),  # no block at all
+        (one(n=17), ValueError),  # more rows than the block holds
+        (one(r=rows[:2]), ValueError),  # a row without a place
+        (one(r=np.array([0, 1, 3])), ValueError),  # a place outside the columns
+        (one(mem=np.arange(3), cnt=np.array([1, 1, 2])), ValueError),
+        (one(mem=np.arange(3), cnt=np.array([1, 1, 0])), ValueError),
+        ([(block.reshape(1, 18, 4), 3, rows, None, None, None, None)], ValueError),  # a grid names its base
+        ([(block, 3, rows)], TypeError),
+    ):
+        with pytest.raises(exc):
+            wire.finish_wire_chunk(m, bad, cols())
+    with pytest.raises((TypeError, ValueError, BufferError)):  # columns of the wrong width
+        m.finish_wire_chunk(one(), *[c.astype(np.int64) for c in cols()])
+    with pytest.raises(ValueError):
+        m.finish_wire_chunk(one(), *cols()[:3], np.zeros(2, np.int64))
+
+
+def test_finish_wire_chunk_holds_no_state_between_threads():
+    """240 dispatches' blocks finished from eight threads at a 10 µs switch
+    interval, each twice: every one is the finish NumPy makes of it alone."""
+    import sys
+    from concurrent.futures import ThreadPoolExecutor
+
+    from gubernator_tpu.ops import wire
+    from gubernator_tpu.ops.kernel2 import unpack_outputs
+
+    jobs = []
+    for seed in range(240):
+        rng = np.random.default_rng(seed)
+        n = int(rng.integers(40, 400))
+        cut = sorted(rng.choice(np.arange(1, n), 3, replace=False))
+        order = rng.permutation(n)
+        passes, want = [], [np.zeros(n, np.int64) for _ in range(4)]
+        stats = np.zeros(4, np.int64)
+        for rows in np.split(order, cut)[:3]:
+            block = _egress_block(rng, 512, rows.size, STAGE_NOW + seed, dropped=0.1)
+            passes.append((block, rows.size, rows, None, None, None, None))
+            (s, l, r, t, _d, _h), st = unpack_outputs(block, rows.size)
+            for col, val in zip(want, (s, l, r, t)):
+                col[rows] = val
+            stats += st
+        # the aggregate: the last rows in groups of one to four
+        tail = np.split(order, cut)[3]
+        counts = []
+        while sum(counts) < tail.size:
+            counts.append(min(int(rng.integers(1, 5)), tail.size - sum(counts)))
+        counts = np.asarray(counts)
+        block = _egress_block(rng, 512, counts.size, STAGE_NOW + seed)
+        passes.append((block, counts.size, None, tail, counts, None, None))
+        (s, l, r, t, _d, _h), st = unpack_outputs(block, counts.size)
+        src = np.repeat(np.arange(counts.size), counts)
+        for col, val in zip(want, (s, l, r, t)):
+            col[tail] = val[src]
+        jobs.append((passes, want, tuple(stats + st), n))
+
+    def finish(job):
+        passes, want, stats, n = job
+        for _ in range(2):
+            cols = _blank_columns(n)
+            done = wire.finish_wire_chunk(m, passes, cols)
+            assert done.stats == stats
+            assert done.later_rows == n - passes[0][1]
+            for got, w in zip(cols, want):
+                assert (got == w).all()
+            flagged = sum(int((p[0][: p[1], 3] & 4 != 0).sum()) for p in passes)
+            assert len(done.dropped) == flagged
+        return True
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        with ThreadPoolExecutor(8) as pool:
+            done = list(pool.map(finish, jobs, timeout=120))
+    finally:
+        sys.setswitchinterval(interval)
+    assert done == [True] * 240
+
+
 # ------------------------------------------- the parser's stamp and its sums
 STAMP_NOW = 1_790_000_000_123
 

@@ -260,9 +260,10 @@ def test_sharded_pipeline_matches_serial(mesh, frozen_now):
 
 def test_pipelined_multi_pass_single_fetch(mesh, frozen_now):
     """A hot-key batch plans max_exact same-shape passes; the pipelined path
-    must fuse their outputs into ONE stacked fetch (pending.stacked) and
-    still produce responses identical to the serial path — each fetch is
-    a host sync, so without the fuse a herd request pays max_exact of
+    fetches their outputs in ONE call (`fetch_passes`: every pass's handle
+    becomes its host array, the device arrays banked as egress buffers) and
+    still produces responses identical to the serial path — each fetch is
+    a host sync, so fetched one by one a herd request pays max_exact of
     them."""
     from gubernator_tpu.ops.batch import columns_from_requests
     from gubernator_tpu.ops.engine import (
@@ -282,8 +283,10 @@ def test_pipelined_multi_pass_single_fetch(mesh, frozen_now):
     pending = prepare_check_columns(piped, cols, now_ms=t)
     assert len(pending.passes) > 1  # herd → multiple sequential passes
     pending = issue_check_columns(piped, pending)
-    assert pending.stacked is not None  # same-shape passes fused
+    assert not any(isinstance(e[3][1], np.ndarray) for e in pending.passes)
     rc_piped, delta = finish_check_columns(piped, pending, lambda fn: fn())
+    assert all(isinstance(e[3][1], np.ndarray) for e in pending.passes)
+    assert sum(len(bank) for bank in piped._egress.values()) > 0
     piped.stats.merge(delta)
 
     np.testing.assert_array_equal(rc_piped.status, rc_serial.status)

@@ -166,16 +166,24 @@ def test_egress_saturation_and_sentinel():
     assert bool(hit[4]) and bool(dropped[2]) and not bool(hit[0])
 
 
-def test_stack_pass_outputs_dtype_guard():
-    """Mixed compact/full pass outputs must NOT fuse into one stacked
-    fetch: stacking would promote int32 to int64 and destroy the dtype
-    tag the host decoder dispatches on."""
-    from gubernator_tpu.ops.engine import _stack_pass_outputs
+def test_a_mixed_dispatch_is_fetched_once_and_keeps_each_dtype():
+    """One fetch of a dispatch whose passes mix compact (int32) and
+    full-width (int64) outputs hands each pass its own array, dtype and
+    shape as launched: the dtype is the tag the host decoder dispatches
+    on, so nothing may widen on the way."""
+    from gubernator_tpu.ops.engine import fetch_passes
 
-    a = jnp.zeros((4, 4), dtype=jnp.int64)
-    b = jnp.zeros((4, 4), dtype=jnp.int32)
-    assert _stack_pass_outputs([a, b]) is None
-    assert _stack_pass_outputs([b, b]) is not None
+    outs = [
+        jnp.arange(24, dtype=jnp.int64).reshape(6, 4),
+        jnp.arange(72, dtype=jnp.int32).reshape(18, 4),
+        jnp.ones((6, 4), dtype=jnp.int32),
+    ]
+    passes = [[None, 4, None, out] for out in outs]
+    fetch_passes(object(), passes)
+    for (*_, host), dev in zip(passes, outs):
+        assert isinstance(host, np.ndarray)
+        assert host.dtype == dev.dtype and host.shape == dev.shape
+        assert (host == np.asarray(dev)).all()
 
 
 # ----------------------------------------------------------- local engine

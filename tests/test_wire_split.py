@@ -97,11 +97,18 @@ async def wire_against_columns(r_wire, r_cols, parts, now):
     deltas = [
         {k: a[k] - b[k] for k in a} for a, b in zip(stats(), before)
     ]
-    # the counters only the wire can move: rows staged from its lanes, and
-    # the dispatch that the native call staged
-    wire_only = {k: deltas[0].pop(k) for k in ("later_lane_rows", "native_staged")}
+    # the counters only the wire can move: rows staged from its lanes, the
+    # dispatch that the native call staged, and finished unless a pass fell
+    # to columns
+    wire_only = {
+        k: deltas[0].pop(k)
+        for k in ("later_lane_rows", "native_staged", "native_finished")
+    }
     assert not any(deltas[1].pop(k) for k in wire_only)
     assert deltas[0] == deltas[1] and wire_only["native_staged"] == 1
+    assert wire_only["native_finished"] == (
+        wire_only["later_lane_rows"] == deltas[0]["later_rows"]
+    )
     deltas[0].update(wire_only)
     fps = np.unique(cols.fp[cols.err == 0])
     (found_w, rows_w), (found_c, rows_c) = (
